@@ -20,6 +20,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", ".."))
 
 from paddlenlp_tpu.trainer import PdArgumentParser
+from paddlenlp_tpu.utils.env import enable_compile_cache
 from paddlenlp_tpu.utils.log import logger
 from predictor import BlockPredictor, PredictorArgument, create_predictor
 
@@ -108,6 +109,7 @@ def serve_v1(predictor, port: int = 8011):
 
 
 def main():
+    enable_compile_cache()
     parser = PdArgumentParser((PredictorArgument,))
     (args, remaining) = parser.parse_args_into_dataclasses(return_remaining_strings=True)
     port, api = 8011, "legacy"

@@ -25,6 +25,7 @@ import numpy as np
 
 from paddlenlp_tpu.trainer import PdArgumentParser
 from paddlenlp_tpu.transformers import AutoConfig, AutoModelForCausalLM, AutoTokenizer
+from paddlenlp_tpu.utils.env import enable_compile_cache
 from paddlenlp_tpu.utils.log import logger
 
 
@@ -291,6 +292,7 @@ def benchmark(predictor: BasePredictor, texts: List[str], warmup: int = 1, iters
 
 
 def main():
+    enable_compile_cache()
     parser = PdArgumentParser((PredictorArgument,))
     (args,) = parser.parse_args_into_dataclasses()
     predictor = create_predictor(args)

@@ -21,6 +21,7 @@ from paddlenlp_tpu.data import DataCollatorForSeq2Seq
 from paddlenlp_tpu.datasets import ZeroPaddingMapDataset
 from paddlenlp_tpu.trainer import PdArgumentParser, Trainer, TrainingArguments
 from paddlenlp_tpu.transformers import AutoConfig, AutoModelForCausalLM, AutoTokenizer, LlmMetaConfig
+from paddlenlp_tpu.utils.env import enable_compile_cache
 from paddlenlp_tpu.utils.log import logger
 
 
@@ -88,6 +89,7 @@ class ListDataset:
 
 
 def main():
+    enable_compile_cache()
     parser = PdArgumentParser((ModelArguments, DataArguments, TrainingArguments))
     model_args, data_args, training_args = parser.parse_args_into_dataclasses()
 

@@ -17,6 +17,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from paddlenlp_tpu.data import build_train_valid_test_datasets
 from paddlenlp_tpu.trainer import PdArgumentParser, Trainer, TrainingArguments, get_last_checkpoint
 from paddlenlp_tpu.transformers import AutoConfig, AutoModelForCausalLM, AutoTokenizer, LlmMetaConfig
+from paddlenlp_tpu.utils.env import enable_compile_cache
 from paddlenlp_tpu.utils.log import logger
 
 
@@ -78,6 +79,7 @@ def _resolve_prefix(input_dir: str) -> str:
 
 
 def main():
+    enable_compile_cache()
     parser = PdArgumentParser((ModelArguments, DataArguments, PreTrainingArguments))
     model_args, data_args, training_args = parser.parse_args_into_dataclasses()
 

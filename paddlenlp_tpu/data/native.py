@@ -1,14 +1,16 @@
 """ctypes bridge to the native data helpers (csrc/sample_idx.cpp).
 
 The reference ships compiled dataset helpers for the index-building hot loop;
-here a single C++ TU is compiled lazily with g++ (cached beside the source) and
-loaded via ctypes — no pybind11 dependency. Every entry point has a NumPy
-fallback so the package works without a toolchain.
+here a single C++ TU is compiled lazily with g++ (cached beside the source,
+under a name made from the source's hash) and loaded via ctypes — no pybind11
+dependency. Every entry point has a NumPy fallback so the package works
+without a toolchain.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -34,15 +36,24 @@ def _load() -> Optional[ctypes.CDLL]:
             return _lib
         _tried = True
         src = os.path.join(_CSRC, "sample_idx.cpp")
-        so = os.path.join(_CSRC, "libpdnlp_data.so")
         try:
-            if not os.path.isfile(so) or os.path.getmtime(so) < os.path.getmtime(src):
+            # the binary is named by the source it was built from (git ignores
+            # csrc/*.so): a stale .so carried along by a tree copy can never
+            # be taken for a current one, whatever its mtime says
+            with open(src, "rb") as f:
+                digest = hashlib.sha256(f.read()).hexdigest()[:16]
+            so = os.path.join(_CSRC, f"libpdnlp_data.{digest}.so")
+            try:
+                lib = ctypes.CDLL(so)
+            except OSError:  # missing, or does not load here: build it
+                tmp = f"{so}.build"
                 subprocess.run(
-                    ["g++", "-O3", "-shared", "-fPIC", "-o", so, src],
+                    ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, src],
                     check=True,
                     capture_output=True,
                 )
-            lib = ctypes.CDLL(so)
+                os.replace(tmp, so)
+                lib = ctypes.CDLL(so)
             lib.build_sample_idx.restype = ctypes.c_int
             lib.build_sample_idx.argtypes = [
                 ctypes.POINTER(ctypes.c_int32),

@@ -102,15 +102,20 @@ class PagedInferenceModel:
         self.num_blocks = num_blocks
         self.max_blocks_per_seq = max_blocks_per_seq
         self.decode_steps = decode_steps
-        # Pallas paged decode kernel: default-on for TPU when the tile shapes
-        # are Mosaic-safe (compile errors would surface at the enclosing jit's
-        # compile, uncatchable here); the XLA gather path stays the fallback.
+        # Pallas ragged paged kernel: default-on for TPU when the tile shapes
+        # are Mosaic-safe; otherwise the XLA gather path. On TPU a default
+        # that comes out off is said once, so it is never a silent choice.
         if use_paged_kernel is None:
+            on_tpu = jax.default_backend() == "tpu"
             use_paged_kernel = (
-                jax.default_backend() == "tpu"
-                and self.config.head_dim % 64 == 0
-                and block_size % 8 == 0
-            )
+                on_tpu and self.config.head_dim % 64 == 0 and block_size % 8 == 0)
+            if on_tpu and not use_paged_kernel:
+                from ..utils.log import logger
+
+                logger.warning_once(
+                    f"paged attention kernel off for {type(model).__name__} "
+                    f"(head_dim={self.config.head_dim}, block_size={block_size}): needs "
+                    "head_dim % 64 == 0 and block_size % 8 == 0; using the XLA gather path")
         self.use_paged_kernel = use_paged_kernel
         # [-1] sentinel when no eos: never matches a sampled id
         self.eos_arr = jnp.asarray(sorted(eos_ids) or [-1], jnp.int32)
@@ -132,8 +137,9 @@ class PagedInferenceModel:
         the historical un-annotated jits."""
         self._prefill = jax.jit(self._prefill_impl, donate_argnums=(1,))
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
-        self._verify = jax.jit(self._verify_impl, donate_argnums=(1,),
-                               static_argnames=("need_logits",))
+        # need_logits is static BY POSITION: a jit with in_shardings (the
+        # sharded subclass) takes no keyword arguments
+        self._verify = jax.jit(self._verify_impl, donate_argnums=(1,), static_argnums=(7,))
         self._mixed = jax.jit(self._mixed_impl, donate_argnums=(1,))
         self._mixed_flat = jax.jit(self._mixed_flat_impl, donate_argnums=(1,))
 
@@ -200,6 +206,22 @@ class PagedInferenceModel:
         out = jnp.einsum("bnts,bsnh->btnh", probs, v.astype(jnp.float32))
         return out.astype(q.dtype)
 
+    def _paged_attention(self, q, pool_layer, scale_layer, block_tables, q_start, q_lens):
+        """Fused block-table walk + attend over one layer's pool: the Pallas
+        ragged kernel streams addressed KV blocks instead of materializing the
+        gathered cache (dequant rides in-kernel for int8/fp8 pools). One
+        launch covers the whole ragged batch — decode rows (q_lens=1), prefill
+        chunks (q_lens up to T), and inactive padding (q_lens=0) together.
+        The sharded subclass runs it under ``shard_map``."""
+        from ..ops.pallas.paged_attention import ragged_paged_attention
+
+        return ragged_paged_attention(
+            q, pool_layer[0], pool_layer[1], block_tables,
+            q_start=q_start, q_lens=q_lens,
+            k_scale=None if scale_layer is None else scale_layer[0],
+            v_scale=None if scale_layer is None else scale_layer[1],
+        )
+
     def _layer(self, carry, scanned, block_tables, q_positions, kv_len_mask, write_pos,
                q_lens, adapter_idx):
         """One decoder layer inside lax.scan: scanned = (layer_params, pool_layer,
@@ -232,19 +254,8 @@ class PagedInferenceModel:
             else:
                 pool_layer = written
         if self.use_paged_kernel:
-            # fused block-table walk + attend: the Pallas ragged kernel streams
-            # addressed KV blocks instead of materializing the gathered cache
-            # (dequant rides in-kernel for int8/fp8 pools). One launch covers
-            # the whole ragged batch — decode rows (q_lens=1), prefill chunks
-            # (q_lens up to T), and inactive padding (q_lens=0) together.
-            from ..ops.pallas.paged_attention import ragged_paged_attention
-
-            attn_out = ragged_paged_attention(
-                q, pool_layer[0], pool_layer[1], block_tables,
-                q_start=q_positions[:, 0], q_lens=q_lens,
-                k_scale=None if scale_layer is None else scale_layer[0],
-                v_scale=None if scale_layer is None else scale_layer[1],
-            )
+            attn_out = self._paged_attention(q, pool_layer, scale_layer, block_tables,
+                                             q_positions[:, 0], q_lens)
         else:
             k_all, v_all = gather_kv(pool_layer, block_tables, scale_layer)
             attn_out = self._attend(q, k_all, v_all, q_positions, kv_len_mask)
@@ -539,7 +550,7 @@ class PagedInferenceModel:
     def verify(self, params, pool: PagedKVPool, tokens, block_tables, start_pos,
                lora=None, adapter_idx=None, need_logits: bool = True):
         return self._verify(params, pool, tokens, block_tables, start_pos,
-                            lora, adapter_idx, need_logits=need_logits)
+                            lora, adapter_idx, need_logits)
 
     def prefill(self, params, pool: PagedKVPool, input_ids, block_tables, suffix_lens,
                 cached_lens, cached_counts, samp, lora=None, adapter_idx=None):

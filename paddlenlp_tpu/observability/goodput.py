@@ -43,9 +43,11 @@ On top of the token ledger:
   cardinality gauge — a retrace storm shows up as a compile-rate spike with
   the guilty program named;
 - **serving FLOPs estimation** — ``estimate_model_flops_per_token`` (2 *
-  params, from config arithmetic) and a per-device peak-FLOPs table keyed on
-  the jax device kind, so ``paddlenlp_serving_mfu`` reads real on TPU and NaN
-  off it (a CPU smoke run must not report a fake MFU).
+  params, from config arithmetic) over the per-device peak of
+  ``utils.env.PEAK_FLOPS_BY_DEVICE_KIND`` (the repo's one peak table), so
+  ``paddlenlp_serving_mfu`` reads real on a listed TPU, NaN on the CPU (a CPU
+  smoke run must not report a fake MFU), and an unlisted accelerator is an
+  error at engine construction.
 
 Stdlib-only at import time (the compile listener imports jax lazily): the
 ledger must be constructible from tools and tests without a backend.
@@ -58,6 +60,8 @@ import math
 import threading
 import time
 from typing import Dict, Optional, Tuple
+
+from ..utils.env import device_peak_flops  # the one peak table; re-exported here
 
 __all__ = [
     "GoodputLedger",
@@ -327,39 +331,6 @@ def estimate_model_flops_per_token(config) -> float:
     mlp = 3 * h * inter
     params = vocab * h * 2 + layers * (attn + mlp)
     return 2.0 * params
-
-
-#: per-device peak dense FLOPs (bf16) by jax device-kind substring, ordered
-#: most-specific first (matched case-insensitively). Off-table kinds (CPU,
-#: GPU, future TPUs) read NaN: an unknown denominator must not fake an MFU.
-_PEAK_FLOPS_BY_KIND = (
-    ("v6e", 918e12),
-    ("v5p", 459e12),
-    ("v5e", 197e12),
-    ("v5litepod", 197e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
-
-
-def device_peak_flops(device_kind: Optional[str] = None) -> float:
-    """Peak per-device FLOPs for the current (or named) jax device kind; NaN
-    when unknown/off-TPU. Lazy jax import so the module stays stdlib-only."""
-    if device_kind is None:
-        try:
-            import jax
-
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            return float("nan")
-    kind = str(device_kind).lower()
-    if "tpu" not in kind and not kind.startswith("v"):
-        return float("nan")
-    for sub, peak in _PEAK_FLOPS_BY_KIND:
-        if sub in kind:
-            return peak
-    return float("nan")
 
 
 # ---------------------------------------------------------------- doc helper

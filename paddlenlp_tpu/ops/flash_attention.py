@@ -22,8 +22,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..parallel.mesh import shard_map as _shard_map
-
 __all__ = ["dot_product_attention", "make_causal_mask", "make_segment_mask"]
 
 
@@ -99,6 +97,9 @@ def dot_product_attention(
     kernel runs under a ``shard_map`` over the batch/head mesh axes so it
     composes with GSPMD (pallas_call alone is opaque to the partitioner).
     Pass False to force the XLA path, True to force Pallas (interpret off-TPU).
+    A shape or sharding the kernel cannot take is turned away by
+    :func:`_pallas_dispatch`'s explicit gates (logged once each); a failure
+    of the kernel itself raises — it never gives way to the XLA path.
     """
     B, T, N, H = query.shape
     S = key.shape[1]
@@ -119,14 +120,9 @@ def dot_product_attention(
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"  # default ON for TPU
     if use_pallas and pallas_eligible:
-        try:
-            out = _pallas_dispatch(query, key, value, segment_ids, scale, window)
-            if out is not None:
-                return out
-        except Exception as e:  # pallas unavailable/lowering failure: fall through
-            from ..utils.log import logger
-
-            logger.warning_once(f"pallas flash attention failed ({type(e).__name__}: {e}); using XLA path")
+        out = _pallas_dispatch(query, key, value, segment_ids, scale, window)
+        if out is not None:
+            return out
 
     mask = None
     if causal and positions is not None:
@@ -161,59 +157,44 @@ def dot_product_attention(
             bias = ab if bias is None else bias + ab
 
     if dropout_rate == 0.0:
-        try:
-            return jax.nn.dot_product_attention(query, key, value, bias=bias, mask=mask, scale=scale)
-        except TypeError:  # API-signature drift across jax versions only
-            from ..utils.log import logger
-
-            logger.warning_once("jax.nn.dot_product_attention signature mismatch; using math attention")
+        return jax.nn.dot_product_attention(query, key, value, bias=bias, mask=mask, scale=scale)
     return _math_attention(query, key, value, mask, scale, dropout_rate, dropout_rng, bias=bias)
 
 
 def _pallas_dispatch(query, key, value, segment_ids, scale, window):
     """Run the Pallas kernel directly (off-mesh) or under a shard_map over the
     batch/head mesh axes (the GSPMD composition the reference gets from fleet's
-    per-rank kernel launches). Returns None when the active sharding cannot be
-    expressed (fall back to the XLA path)."""
-    import os
-
-    from jax.sharding import Mesh, PartitionSpec as PS
+    per-rank kernel launches). Returns None — logged once with the shape —
+    when the shape or the active sharding cannot be expressed; the caller
+    then takes the XLA path."""
+    from jax.sharding import PartitionSpec as PS
 
     from ..parallel.partition import _current_mesh
-    from .pallas.flash_attention import flash_attention as _pf
-
-    # hardware-sweepable tile sizes (tools/bench sweep; default 128x128).
-    # Invalid values fall back to the default rather than crashing at the
-    # ENCLOSING jit's compile (same contract as the shape gate below).
-    def _tile(env_name):
-        try:
-            b = int(os.environ.get(env_name, 128))
-        except ValueError:
-            return 128
-        return b if b >= 128 and b % 128 == 0 else 128
-
-    pallas_flash = functools.partial(
-        _pf, block_q=_tile("PDNLP_FLASH_BLOCK_Q"), block_kv=_tile("PDNLP_FLASH_BLOCK_KV")
-    )
+    from ..utils.log import logger
+    from .pallas.flash_attention import flash_attention as pallas_flash
 
     B, T, N, H = query.shape
     K = key.shape[2]
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu and not (T % 128 == 0 and H % 64 == 0):
-        # Mosaic tiling gate: compile errors surface at the ENCLOSING jit's
-        # compile, outside our try/except — so unsupported shapes must be
-        # rejected here, not discovered as a crash.
+
+    def turned_away(why: str):
+        logger.warning_once(
+            f"pallas flash attention not used for q{tuple(query.shape)} kv{tuple(key.shape)}: "
+            f"{why}; using the XLA attention path")
         return None
+
+    if jax.default_backend() == "tpu" and not (T % 128 == 0 and H % 64 == 0):
+        # Mosaic tiling gate: a shape the kernel cannot tile is turned away
+        # here, by rule, and not discovered as a compile error of the
+        # enclosing jit
+        return turned_away("needs T % 128 == 0 and head_dim % 64 == 0")
     mesh = _current_mesh()
     if mesh is None:
         return pallas_flash(query, key, value, segment_ids, scale, True, window)
     live = lambda axes: tuple(a for a in axes if mesh.shape.get(a, 1) > 1)
     if not live(("dp", "fsdp", "tp", "sep", "cp")):
         return pallas_flash(query, key, value, segment_ids, scale, True, window)
-    if not isinstance(mesh, Mesh):
-        return None  # AbstractMesh (AOT/topology): let the XLA path partition
     if live(("cp",)):  # seq would be sharded; ring/XLA paths own that case
-        return None
+        return turned_away("sequence sharded over cp")
     batch_ax = live(("dp", "fsdp"))
     head_ax = live(("tp", "sep"))
     nb, nh = 1, 1
@@ -222,11 +203,11 @@ def _pallas_dispatch(query, key, value, segment_ids, scale, window):
     for a in head_ax:
         nh *= mesh.shape[a]
     if B % nb or N % nh or K % nh or (N // nh) % max(K // nh, 1):
-        return None
+        return turned_away(f"batch/heads do not divide the mesh (batch x{nb}, heads x{nh})")
     qkv_spec = PS(batch_ax or None, None, head_ax or None, None)
     fn = functools.partial(pallas_flash, scale=scale, causal=True, window=window)
     if segment_ids is None:
-        return _shard_map(
+        return jax.shard_map(
             lambda q, k, v: fn(q, k, v, None),
             mesh=mesh,
             in_specs=(qkv_spec, qkv_spec, qkv_spec),
@@ -234,7 +215,7 @@ def _pallas_dispatch(query, key, value, segment_ids, scale, window):
             check_vma=False,
         )(query, key, value)
     seg_spec = PS(batch_ax or None, None)
-    return _shard_map(
+    return jax.shard_map(
         lambda q, k, v, s: fn(q, k, v, s),
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, seg_spec),

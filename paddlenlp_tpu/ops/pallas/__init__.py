@@ -1,12 +1,1 @@
-"""Pallas TPU kernels (flash attention, paged/ragged paged attention).
-
-jax renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams``; the shim
-below resolves whichever this jax provides so the kernels import (and their
-tests run) on both sides of the rename.
-"""
-
-from jax.experimental.pallas import tpu as _pltpu
-
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or _pltpu.TPUCompilerParams
-
-__all__ = ["CompilerParams"]
+"""Pallas TPU kernels (flash attention, paged/ragged paged attention)."""

@@ -36,8 +36,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import CompilerParams
-
 __all__ = ["flash_attention"]
 
 NEG_INF = -1e30
@@ -177,8 +175,10 @@ def _flash_fwd(q, k, v, segments, scale, causal, window, block_q, block_kv, inte
             pltpu.VMEM((block_q, 1), jnp.float32),  # l
             pltpu.VMEM((block_q, H), jnp.float32),  # acc
         ],
-        compiler_params=CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qf, kf, vf, seg_q3, seg_k3)
     return out.reshape(B, N, T, H).transpose(0, 2, 1, 3), lse[..., 0]
 
@@ -288,7 +288,7 @@ def _flash_bwd(q, k, v, segments, out, lse, g, scale, causal, window, block_q, b
 
     common = dict(scale=scale, block_q=block_q, block_kv=block_kv, causal=causal,
                   window=window, q_len=T, kv_len=S, use_segments=use_seg)
-    params = CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"))
+    params = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
@@ -308,6 +308,7 @@ def _flash_bwd(q, k, v, segments, out, lse, g, scale, causal, window, block_q, b
         scratch_shapes=[pltpu.VMEM((block_q, H), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(qf, kf, vf, dof, lse3, delta, seg_q3, seg_k3)
 
     # dk/dv: grid batch axis is the B*K kv heads; the sequential axis walks the
@@ -341,6 +342,7 @@ def _flash_bwd(q, k, v, segments, out, lse, g, scale, causal, window, block_q, b
         ],
         compiler_params=params,
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(qf, kf, vf, dof, lse3, delta, seg_q3, seg_k3)
 
     dq = dq.reshape(B, N, T, H).transpose(0, 2, 1, 3)
@@ -365,7 +367,7 @@ def flash_attention(
 ) -> jnp.ndarray:
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
+        interpret = jax.default_backend() != "tpu"
     out, _ = _flash_fwd(q, k, v, segment_ids, scale, causal, window, block_q, block_kv, interpret)
     return out
 
@@ -373,7 +375,7 @@ def flash_attention(
 def _fwd(q, k, v, segment_ids, scale, causal, window, block_q, block_kv, interpret):
     scale_v = scale if scale is not None else q.shape[-1] ** -0.5
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
+        interpret = jax.default_backend() != "tpu"
     out, lse = _flash_fwd(q, k, v, segment_ids, scale_v, causal, window, block_q, block_kv, interpret)
     return out, (q, k, v, segment_ids, out, lse)
 
@@ -382,7 +384,7 @@ def _bwd(scale, causal, window, block_q, block_kv, interpret, residuals, g):
     q, k, v, segment_ids, out, lse = residuals
     scale_v = scale if scale is not None else q.shape[-1] ** -0.5
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
+        interpret = jax.default_backend() != "tpu"
     dq, dk, dv = _flash_bwd(q, k, v, segment_ids, out, lse, g,
                             scale_v, causal, window, block_q, block_kv, interpret)
     dseg = None if segment_ids is None else np.zeros(segment_ids.shape, jax.dtypes.float0)

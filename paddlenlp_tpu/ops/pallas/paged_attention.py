@@ -12,8 +12,11 @@ online-softmax accumulator. No ``[B, max_blocks*bs, K, H]`` gathered copy of
 the cache ever materializes (the XLA fallback's cost).
 
 Design:
-- grid = (B, K, max_blocks); the block axis is innermost and sequential,
-  carrying (m, l, acc) VMEM scratch per (T*group, H) query tile;
+- grid = (B, K, q_tiles, max_blocks); the block axis is innermost and
+  sequential, carrying (m, l, acc) VMEM scratch per (tq*group, H) query tile.
+  The query rows are tiled (``_MAX_Q_ROWS``) because the chip's compiler gives
+  a kernel 16 MiB of scoped VMEM: a whole 2048-token prefill bucket at GQA
+  group 6 (12288 rows x 128) needed 18 MiB and was refused;
 - the block table plus per-sequence ``q_start``/``q_lens`` ride scalar
   prefetch (``pltpu.PrefetchScalarGridSpec``): the KV BlockSpec index map
   reads ``tables[b, j]`` to aim the DMA at the right pool block — the table
@@ -24,10 +27,12 @@ Design:
   — correct across chunk boundaries (a chunk's first token attends over the
   whole prefilled span, its last over prefilled+chunk-1);
 - rows past ``q_lens[b]`` (padding) and fully-masked rows produce exact zeros
-  (their softmax denominator stays 0); blocks past the highest live query
-  position are skipped entirely (@pl.when);
+  (their softmax denominator stays 0); blocks past the tile's highest live
+  query position are skipped entirely (@pl.when). A skipped block is exactly
+  what a fully-masked one computes (m, l, acc unchanged), so the tiling does
+  not change any row's result;
 - GQA: queries fold to [B, K, T*group, H]; each grid cell attends its kv
-  head's whole query group for every chunk token at once;
+  head's whole query group for every token of its query tile at once;
 - ``q_lens = 1`` everywhere reduces to the classic paged decode kernel —
   :func:`paged_decode_attention` is that wrapper, kept as the stable
   decode-only API (``_layer`` now always dispatches the ragged kernel; the
@@ -46,23 +51,35 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import CompilerParams
-
 __all__ = ["paged_decode_attention", "ragged_paged_attention"]
 
 NEG_INF = -1e30
 
+# most query rows (tokens x GQA group) one grid cell holds in VMEM. 3072 rows
+# x 128 (a 512-token chunk at group 6) is the largest tile the v5e compiler
+# accepted inside its 16 MiB scoped limit; see tests/ops/test_tpu_compile.py.
+_MAX_Q_ROWS = 3072
+
+
+def _q_tile_tokens(T: int, group: int) -> int:
+    """Largest halving of ``T`` whose rows fit ``_MAX_Q_ROWS`` (``T`` itself
+    when it already fits — the engine's buckets are powers of two)."""
+    tq = T
+    while tq * group > _MAX_Q_ROWS and tq % 2 == 0:
+        tq //= 2
+    return tq
+
 
 def _kernel(tables_ref, start_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-            bs, scale, use_kv_scale, group):
+            bs, scale, use_kv_scale, group, tq):
     if use_kv_scale:
         ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = rest
     else:
         o_ref, m_s, l_s, acc_s = rest
         ks_ref = vs_ref = None
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    j = pl.program_id(3)
+    nj = pl.num_programs(3)
 
     @pl.when(j == 0)
     def _init():
@@ -72,20 +89,22 @@ def _kernel(tables_ref, start_ref, len_ref, q_ref, k_ref, v_ref, *rest,
 
     start = start_ref[b]
     qlen = len_ref[b]
+    t0 = pl.program_id(2) * tq  # first query token of this tile
+    live = jnp.minimum(qlen - t0, tq)  # live tokens in this tile (<= 0: none)
     # highest live query position: blocks past it contribute nothing to any row
-    hi = start + qlen - 1
+    hi = start + t0 + live - 1
 
-    @pl.when((qlen > 0) & (j * bs <= hi))
+    @pl.when((live > 0) & (j * bs <= hi))
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # [T*group, H]
+        q = q_ref[0, 0].astype(jnp.float32)  # [tq*group, H]
         k = k_ref[0, 0].astype(jnp.float32)  # [bs, H]
         v = v_ref[0, 0].astype(jnp.float32)
         if use_kv_scale:  # int8/fp8 cache: dequant the streamed block in VMEM
             k = k * ks_ref[0, 0]
             v = v * vs_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # [T*group, bs]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # [tq*group, bs]
         kv_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group  # query token idx
+        t = t0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group  # query token idx
         valid = (kv_pos <= start + t) & (t < qlen)
         s = jnp.where(valid, s, NEG_INF)
         m_prev = m_s[...]
@@ -128,41 +147,42 @@ def ragged_paged_attention(
     max_blocks = block_tables.shape[1]
     scale = scale if scale is not None else H**-0.5
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
+        interpret = jax.default_backend() != "tpu"
     use_kv_scale = k_scale is not None
+    tq = _q_tile_tokens(T, group)
+    rows = tq * group
 
     # [B, T, N, H] -> [B, K, T*group, H]: head n = kh*group + g, so T and group
     # interleave as rows (t, g) -> row t*group + g of kv head kh
     qf = q.reshape(B, T, K, group, H).transpose(0, 2, 1, 3, 4).reshape(B, K, T * group, H)
-    kv_spec = pl.BlockSpec((1, 1, bs, H), lambda b, kh, j, t, s, l: (t[b, j], kh, 0, 0))
-    sc_spec = pl.BlockSpec((1, 1, bs, 1), lambda b, kh, j, t, s, l: (t[b, j], kh, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, T * group, H), lambda b, kh, j, t, s, l: (b, kh, 0, 0)),
-        kv_spec,
-        kv_spec,
-    ]
+    q_spec = pl.BlockSpec((1, 1, rows, H), lambda b, kh, qt, j, t, s, l: (b, kh, qt, 0))
+    kv_spec = pl.BlockSpec((1, 1, bs, H), lambda b, kh, qt, j, t, s, l: (t[b, j], kh, 0, 0))
+    sc_spec = pl.BlockSpec((1, 1, bs, 1), lambda b, kh, qt, j, t, s, l: (t[b, j], kh, 0, 0))
+    in_specs = [q_spec, kv_spec, kv_spec]
     operands = [qf, pool_k, pool_v]
     if use_kv_scale:
         in_specs += [sc_spec, sc_spec]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, K, max_blocks),
+        grid=(B, K, T // tq, max_blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, T * group, H), lambda b, kh, j, t, s, l: (b, kh, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((T * group, 1), jnp.float32),  # m
-            pltpu.VMEM((T * group, 1), jnp.float32),  # l
-            pltpu.VMEM((T * group, H), jnp.float32),  # acc
+            pltpu.VMEM((rows, 1), jnp.float32),  # m
+            pltpu.VMEM((rows, 1), jnp.float32),  # l
+            pltpu.VMEM((rows, H), jnp.float32),  # acc
         ],
     )
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, scale=scale, use_kv_scale=use_kv_scale,
-                          group=group),
+                          group=group, tq=tq),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, T * group, H), q.dtype),
-        compiler_params=CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(block_tables.astype(jnp.int32), q_start.astype(jnp.int32),
       q_lens.astype(jnp.int32), *operands)
     return out.reshape(B, K, T, group, H).transpose(0, 2, 1, 3, 4).reshape(B, T, N, H)

@@ -27,19 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..parallel.mesh import shard_map as _shard_map
-
-
-def _axis_size(axis_name: str) -> int:
-    """Static size of a manual mesh axis: ``jax.lax.axis_size`` where it
-    exists; older jax spells it ``jax.core.axis_frame`` (which returns the
-    bare int on those builds)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    frame = jax.core.axis_frame(axis_name)
-    return getattr(frame, "size", frame)
-
 __all__ = ["ring_attention_local", "ring_self_attention", "zigzag_split", "zigzag_unsplit"]
 
 
@@ -90,7 +77,7 @@ def ring_attention_local(
     ppermutes (k, v, kv_positions) one hop around the ring."""
     H = q.shape[-1]
     scale = scale if scale is not None else H**-0.5
-    cp = _axis_size(axis_name)
+    cp = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % cp) for i in range(cp)]
 
     @jax.checkpoint
@@ -133,7 +120,7 @@ def ring_self_attention(
         return ring_attention_local(q_c, k_c, v_c, pos_c, pos_c, axis_name, scale)
 
     qspec = P(None, axis_name, None, None)
-    return _shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(qspec, qspec, qspec, P(None, axis_name)),

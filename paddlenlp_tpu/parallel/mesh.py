@@ -91,11 +91,9 @@ def create_mesh(config: Optional[MeshConfig] = None, devices: Optional[Sequence]
     All axes are ``AxisType.Auto``: GSPMD propagates shardings from the hints the
     models emit (``shard_constraint``) — the moral equivalent of the reference's
     semi-auto parallel (``auto_trainer.py``), but applied to every strategy.
-    On jax builds predating ``jax.sharding.AxisType`` (<= 0.4.x) every axis is
-    implicitly Auto already, so the Mesh is built without axis_types.
     """
     import jax
-    from jax.sharding import Mesh
+    from jax.sharding import AxisType, Mesh
 
     if devices is None:
         devices = jax.devices()
@@ -107,49 +105,14 @@ def create_mesh(config: Optional[MeshConfig] = None, devices: Optional[Sequence]
         dev_array = mesh_utils.create_device_mesh(shape, devices=np.asarray(devices))
     except Exception:
         dev_array = np.asarray(devices).reshape(shape)
-    AxisType = getattr(jax.sharding, "AxisType", None)
-    if AxisType is None:
-        return Mesh(dev_array, MESH_AXES)
     return Mesh(dev_array, MESH_AXES, axis_types=(AxisType.Auto,) * len(MESH_AXES))
 
 
 def use_mesh(mesh):
-    """Context manager activating ``mesh`` for bare-PartitionSpec sharding hints.
-
-    ``jax.sharding.set_mesh`` where this jax has it; on older builds the Mesh
-    object itself is the context manager (the legacy ``with mesh:`` thread
-    resource, which `partition._current_mesh` also knows how to read)."""
+    """Context manager activating ``mesh`` for bare-PartitionSpec sharding hints."""
     import jax
 
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is None:
-        return mesh
-    return set_mesh(mesh)
-
-
-def shard_map(f, mesh, in_specs, out_specs, axis_names=None, check_vma: bool = False):
-    """Version-portable ``shard_map`` (mirrors the ``use_mesh`` shim above).
-
-    Newer jax exposes ``jax.shard_map(..., axis_names=..., check_vma=...)``;
-    older builds only have ``jax.experimental.shard_map.shard_map`` with the
-    ``check_rep``/``auto`` spelling — ``axis_names`` (axes mapped manually)
-    is the complement of ``auto`` (axes left to GSPMD)."""
-    import jax
-
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return native(f, **kw)
-    from jax.experimental.shard_map import shard_map as legacy
-
-    # ``axis_names`` (manual over a subset, GSPMD over the rest) maps to the
-    # legacy ``auto=`` complement — but partially-auto shard_map ABORTS XLA's
-    # CPU backend on these old builds, so run fully manual instead: the specs
-    # leave the other axes unmentioned (replicated), which is numerically the
-    # same program minus GSPMD's freedom to co-shard the untouched axes.
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma)
+    return jax.sharding.set_mesh(mesh)
 
 
 def mesh_axis_size(mesh, axis) -> int:

@@ -69,6 +69,7 @@ from typing import Dict, Optional
 from ..observability.exporter import handle_profile_request, route_observability
 from ..observability.postmortem import handle_postmortem_request
 from ..observability.tracer import TRACEPARENT_HEADER, TRACER, parse_traceparent, use_trace
+from ..utils.env import enable_compile_cache
 from ..utils.faults import FaultPoint
 from ..utils.log import logger
 from .chat import ChatTemplate
@@ -868,6 +869,7 @@ class ServingServer:
         return bound
 
     def run(self, host: str = "0.0.0.0", port: int = 8011):
+        enable_compile_cache()  # before the loop's first step compiles
         self.loop.start()
         self._httpd = self._make_httpd(host, port)
         logger.info(f"serving API on {host}:{port} (POST /v1/completions, GET /metrics)")

@@ -283,7 +283,13 @@ class SubprocessProvisioner(ReplicaProvisioner):
         python -m my_serving_entrypoint --host {host} --port {port}
 
     ``provision`` blocks until the replica's ``/health`` answers (bounded by
-    ``ready_timeout_s``); ``deprovision`` terminates the subprocess."""
+    ``ready_timeout_s``); ``deprovision`` terminates the subprocess.
+
+    A chip belongs to one process at a time: on one host every replica needs
+    a chip of its own. Pinning each subprocess to one (``TPU_VISIBLE_DEVICES``
+    or the platform's equivalent, in the command template or its
+    environment) is the launcher's job — a second replica started on a chip
+    another process holds fails or hangs at jax start-up."""
 
     def __init__(self, command: str, host: str = "127.0.0.1",
                  ready_timeout_s: float = 60.0):

@@ -217,17 +217,14 @@ class MetricsCallback(TrainerCallback):
             m["tokens_per_second"].set(tps)
             flops_per_token = self._flops_per_token(seq_len)
             if flops_per_token:
-                try:
-                    import jax
+                import jax
 
-                    from ..utils.env import device_peak_flops
+                from ..utils.env import device_peak_flops
 
-                    peak = device_peak_flops()
-                    if peak > 0:
-                        n_dev = max(jax.device_count(), 1)
-                        m["mfu"].set(flops_per_token * tps / n_dev / peak)
-                except Exception:
-                    pass
+                peak = device_peak_flops()  # NaN on the CPU: no MFU there
+                if peak > 0:
+                    n_dev = max(jax.device_count(), 1)
+                    m["mfu"].set(flops_per_token * tps / n_dev / peak)
 
     # ------------------------------------------------------------- per log
     def on_log(self, args, state, control, logs=None, **kwargs):

@@ -1,6 +1,8 @@
 """Data subsystem tests: mmap indexed dataset, GPT pretraining dataset (native +
 numpy index builders), blending, collators, zero-padding packing."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -55,6 +57,33 @@ class TestSampleIdx:
 
     def test_native_compiled(self):
         assert native_available(), "g++ helper should compile on this image"
+
+    def test_stale_binary_in_a_copied_tree_is_not_used(self, tmp_path, monkeypatch):
+        """git ignores csrc/*.so, a tree copy carries them along and resets
+        mtimes: the binary is named by its source's hash, so one built from
+        another source is never loaded, and one that does not load (here a
+        truncated copy) is rebuilt."""
+        import hashlib
+        import shutil
+
+        from paddlenlp_tpu.data import native
+
+        csrc = tmp_path / "csrc"
+        csrc.mkdir()
+        shutil.copy(os.path.join(native._CSRC, "sample_idx.cpp"), csrc)
+        with open(csrc / "sample_idx.cpp", "a") as f:
+            f.write("\n// edited after the stale binary was built\n")
+        stale = csrc / "libpdnlp_data.so"  # the old fixed name, newer than the source
+        stale.write_bytes(b"built from another source")
+        digest = hashlib.sha256((csrc / "sample_idx.cpp").read_bytes()).hexdigest()[:16]
+        current = csrc / f"libpdnlp_data.{digest}.so"
+        current.write_bytes(b"truncated by the copy")
+        monkeypatch.setattr(native, "_CSRC", str(csrc))
+        monkeypatch.setattr(native, "_tried", False)
+        monkeypatch.setattr(native, "_lib", None)
+        assert native.native_available()
+        assert native._lib._name == str(current) and current.stat().st_size > 1000
+        assert stale.read_bytes() == b"built from another source"
 
     def test_exhaustion_raises(self, corpus):
         ds = MMapIndexedDataset(corpus)

@@ -245,6 +245,50 @@ class TestRaggedKernel:
         ref = self._ref(q, pk, pv, tables, q_start, q_lens)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
+    def test_kernel_default_off_on_tpu_is_said(self, model, monkeypatch):
+        """On a TPU the kernel is on by default; where the shape gate turns
+        it off (head_dim 16 here) the model says so once at construction."""
+        from paddlenlp_tpu.experimental.inference_model import PagedInferenceModel
+        from paddlenlp_tpu.utils.log import logger
+
+        said = []
+        monkeypatch.setattr(logger, "warning_once", said.append)
+        infer = PagedInferenceModel(model, block_size=8, num_blocks=16, max_blocks_per_seq=4)
+        assert infer.use_paged_kernel is False and said == []  # off the TPU: nothing to say
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        infer = PagedInferenceModel(model, block_size=8, num_blocks=16, max_blocks_per_seq=4)
+        assert infer.use_paged_kernel is False
+        assert len(said) == 1 and "head_dim=16" in said[0] and "block_size=8" in said[0]
+
+    @pytest.mark.parametrize("max_rows", [16, 8])
+    def test_query_tiling_changes_nothing(self, monkeypatch, max_rows):
+        """The query-row grid axis (the chip refuses a whole long prompt as one
+        VMEM tile): 16 tokens x group 2 cut into 2 or 4 query tiles must give
+        every row bit for bit what the single tile gives — a block skipped
+        because it lies past a tile's last live row is exactly a fully masked
+        one. Rows cover a chunk ending inside a tile, a decode row, a dead row
+        and a chunk filling every tile."""
+        from paddlenlp_tpu.ops.pallas import paged_attention as pa
+
+        rng = np.random.default_rng(5)
+        B, T, N, K, H, nb, bs, mb = 4, 16, 4, 2, 64, 24, 8, 5
+        q = jnp.asarray(rng.standard_normal((B, T, N, H)), jnp.float32)
+        pk = jnp.asarray(rng.standard_normal((nb, K, bs, H)), jnp.float32)
+        pv = jnp.asarray(rng.standard_normal((nb, K, bs, H)), jnp.float32)
+        tables = jnp.asarray(rng.permutation(np.arange(1, nb))[: B * mb].reshape(B, mb),
+                             jnp.int32)
+        q_start = jnp.asarray([9, 22, 0, 3], jnp.int32)
+        q_lens = jnp.asarray([11, 1, 0, 16], jnp.int32)
+        assert pa._q_tile_tokens(T, N // K) == T  # default bound: one tile
+        whole = pa.ragged_paged_attention(q, pk, pv, tables, q_start, q_lens, interpret=True)
+        monkeypatch.setattr(pa, "_MAX_Q_ROWS", max_rows)
+        assert pa._q_tile_tokens(T, N // K) == max_rows // (N // K)
+        tiled = pa.ragged_paged_attention(q, pk, pv, tables, q_start, q_lens, interpret=True)
+        np.testing.assert_array_equal(np.asarray(tiled), np.asarray(whole))
+        np.testing.assert_allclose(
+            np.asarray(tiled), np.asarray(self._ref(q, pk, pv, tables, q_start, q_lens)),
+            atol=2e-5)
+
     def test_decode_wrapper_matches_ragged(self):
         from paddlenlp_tpu.ops.pallas.paged_attention import (
             paged_decode_attention, ragged_paged_attention)
@@ -280,7 +324,7 @@ class TestPreemption:
 class TestQuantizedKVCache:
     def test_engine_parity_int8_and_fp8(self, model):
         """Quantized-cache greedy decode must stay close to the fp path
-        (VERDICT r2 item 4: cosine > 0.99 on sampled logprob trajectories is
+        (cosine > 0.99 on sampled logprob trajectories is
         approximated here by token-level agreement on short continuations +
         quantize/dequant cosine on the pool content)."""
         prompts = [[5, 6, 7, 8, 9], [40, 41, 42]]
@@ -348,8 +392,8 @@ class TestQuantizedKVCache:
 
 
 class TestQuantizedServing:
-    """Scan-layout quantized weights through the paged engine (VERDICT r3 #3:
-    quantized serving must be reachable in the DEFAULT layout)."""
+    """Scan-layout quantized weights through the paged engine (quantized
+    serving must be reachable in the DEFAULT layout)."""
 
     def _engine_tokens(self, m, prompt, **kw):
         eng = InferenceEngine(m, max_batch_size=2, block_size=4, num_blocks=64,

@@ -131,6 +131,28 @@ class TestTokenIdentity:
         want = eng_ref.generate(prompts, SamplingParams(max_new_tokens=6))
         assert eng.generate(prompts, SamplingParams(max_new_tokens=6)) == want
 
+    @pytest.mark.parametrize("kv_heads,chunk,quant", [(8, None, None), (8, 8, "int8"), (2, None, None)])
+    def test_paged_kernel_under_shard_map(self, eight_devices, kv_heads, chunk, quant):
+        """The Pallas ragged kernel on a mesh (what a TPU runs by default):
+        GSPMD cannot partition a Mosaic kernel, so the sharded model runs it
+        under shard_map — per tp shard with a sharded pool (kv_heads=8), on
+        every shard with a replicated one (kv_heads=2 on tp=4). Tokens must
+        equal the single-device kernel's."""
+        cfg = LlamaConfig(vocab_size=96, hidden_size=64, intermediate_size=112,
+                          num_hidden_layers=2, num_attention_heads=8,
+                          num_key_value_heads=kv_heads, max_position_embeddings=256,
+                          eos_token_id=None, pad_token_id=0, use_scan_layers=True)
+        m = LlamaForCausalLM.from_config(cfg, seed=0)
+        kw = dict(KW, prefill_chunk_tokens=chunk, kv_cache_quant=quant)
+        ref = InferenceEngine(m, **kw)
+        eng = InferenceEngine(m, mesh_shape=(2, 4), **kw)
+        # the kernel choice is read at trace time: flip it before any step
+        ref.infer.use_paged_kernel = eng.infer.use_paged_kernel = True
+        assert eng.stats()["backend"]["kv_pool_sharded"] is (kv_heads == 8)
+        prompts = [[11, 12, 13, 14], list(range(44, 63))]
+        sp = SamplingParams(max_new_tokens=6)
+        assert eng.generate(prompts, sp) == ref.generate(prompts, sp)
+
     def test_weight_update_resync(self, model, eng_ref, eng_tp8):
         """Rebinding model.params re-places them on the mesh (id check), and
         the updated sharded engine still matches the updated single-device

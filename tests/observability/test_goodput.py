@@ -124,10 +124,12 @@ class TestFlopsModel:
         assert math.isnan(estimate_model_flops_per_token(object()))
 
     def test_peak_flops_table(self):
-        assert device_peak_flops("TPU v5e") == pytest.approx(197e12)
+        # keyed by device_kind exactly as jax reports it ("TPU v5 lite" = v5e)
+        assert device_peak_flops("TPU v5 lite") == pytest.approx(197e12)
         assert device_peak_flops("TPU v4") == pytest.approx(275e12)
         assert math.isnan(device_peak_flops("cpu"))
-        assert math.isnan(device_peak_flops("NVIDIA H100"))
+        with pytest.raises(ValueError):  # an unlisted accelerator is an error,
+            device_peak_flops("NVIDIA H100")  # never a default or a NaN
 
     def test_mfu_real_and_nan(self):
         led = GoodputLedger(flops_per_token=2.0, peak_flops=float("nan"))

@@ -140,6 +140,33 @@ class TestPallasFlash:
             q, k, v, causal=True, use_pallas=False).astype(jnp.float32).sum(), argnums=0)(q, k, v)
         np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), atol=5e-4, rtol=1e-3)
 
+    def test_kernel_failure_is_not_swallowed(self, monkeypatch):
+        """A failure of the Pallas kernel raises out of the dispatcher; it no
+        longer gives way to the XLA path behind a warning."""
+        from paddlenlp_tpu.ops.pallas import flash_attention as kernel_module
+
+        def broken(*args, **kwargs):
+            raise RuntimeError("mosaic said no")
+
+        monkeypatch.setattr(kernel_module, "flash_attention", broken)
+        q, k, v = qkv()
+        with pytest.raises(RuntimeError, match="mosaic said no"):
+            dot_product_attention(q, k, v, causal=True, use_pallas=True)
+
+    def test_untileable_shape_is_turned_away_in_the_open(self, monkeypatch):
+        """On a TPU a shape Mosaic cannot tile goes to the XLA path by the
+        explicit gate, and says so once, with the shape."""
+        from paddlenlp_tpu.utils.log import logger
+
+        said = []
+        monkeypatch.setattr(logger, "warning_once", said.append)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        q, k, v = qkv(T=72)  # not a multiple of 128
+        out = dot_product_attention(q, k, v, causal=True)  # default: pallas on a TPU
+        ref = dot_product_attention(q, k, v, causal=True, use_pallas=False)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+        assert len(said) == 1 and "(2, 72, 4, 64)" in said[0] and "T % 128" in said[0]
+
     def test_causal_cross_length_rejected(self):
         q, _, _ = qkv(T=64)
         _, k, v = qkv(T=128)
