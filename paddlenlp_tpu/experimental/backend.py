@@ -20,13 +20,23 @@ Contract (one backend == one way to run the forward + lay out KV):
 
 Every step entry point additionally stamps ``self.step_accounting`` —
 ``{"fed": <token positions the launch processed>, "shape": <launch-geometry
-key>}`` — immediately before dispatch. The engine reads it right after the
-call to feed the goodput ledger (observability/goodput.py): ``fed`` is the
-*padded* geometry (``n_rows * bucket_width``), which is what the device
-actually burnt cycles on, and ``shape`` keys the live shape-bucket
-cardinality gauge. Backends never decompose fed into useful/padding/rework —
-that split needs scheduler knowledge (prefix hits, preemption history,
-speculative acceptance) the backend deliberately does not have.
+key>, **LAUNCH_GEOMETRY}`` — immediately before dispatch. The engine reads it
+right after the call to feed the goodput ledger (observability/goodput.py):
+``fed`` is the *padded* geometry (``n_rows * bucket_width``), which is what
+the device actually burnt cycles on, ``shape`` keys the live shape-bucket
+cardinality gauge, and the ``LAUNCH_GEOMETRY`` counts (``rows_live``,
+``rows``, ``kv_positions``: what the launch was asked to do, see
+:func:`launch_geometry`) ride the launch span and the ledger's per-program
+totals. A decode launch only knows its attended positions once ``valid`` is
+back, so it restamps after its sync. Backends never decompose fed into
+useful/padding/rework — that split needs scheduler knowledge (prefix hits,
+preemption history, speculative acceptance) the backend deliberately does
+not have.
+
+Each entry point records two child spans of the engine's launch span:
+``dispatch`` (host arrays to the device and the jit call returning) and
+``wait`` (the ``np.asarray`` sync point), mirrored to the profiler like every
+live engine span.
 
 External weight updates (serving epochs, PPO rollouts) flow through the
 ``params`` property: callers rebind ``model.params`` and the backend picks it
@@ -69,11 +79,25 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability.tracer import TRACER
 from .inference_model import PagedInferenceModel
 from .kv_host_tier import HostPromoteTicket, gather_blocks, scatter_blocks
 from .paged_cache import PagedKVPool, copy_blocks, init_paged_pool
 
-__all__ = ["ModelBackend", "SingleDeviceBackend", "MixedRow", "samp_arrays"]
+__all__ = ["ModelBackend", "SingleDeviceBackend", "MixedRow", "samp_arrays",
+           "launch_geometry"]
+
+
+def launch_geometry(rows: int, q_lens, kv_lens) -> dict:
+    """The LAUNCH_GEOMETRY counts of one launch (observability/goodput.py).
+    ``q_lens`` holds the real query tokens of each live row (a dead or padding
+    row has none), ``kv_lens`` the KV positions each live row's attention had
+    to read; ``rows`` is the padded row count."""
+    live = np.asarray(q_lens, np.int64).reshape(-1) > 0  # sync-ok: host row counts
+    kv_lens = np.asarray(kv_lens, np.int64).reshape(-1)  # sync-ok: host row counts
+    return {"rows_live": int(live.sum()),  # sync-ok: host numpy
+            "rows": int(rows),
+            "kv_positions": int(kv_lens[live].sum())}  # sync-ok: host numpy
 
 
 def samp_arrays(sampling: Sequence, n: Optional[int] = None):
@@ -344,47 +368,69 @@ class SingleDeviceBackend(ModelBackend):
     def prefill(self, input_ids, block_tables, suffix_lens, cached_entries,
                 sampling, slot_idx, adapter_table=None) -> np.ndarray:
         n = input_ids.shape[0]
-        self.step_accounting = {"fed": n * input_ids.shape[1],
-                                "shape": ("prefill", n, input_ids.shape[1])}
         cached_lens = np.zeros(n, np.int32)
         for row, _ids, n_cached in cached_entries:
             cached_lens[row] = n_cached
-        counts_dev = self._cached_counts(cached_entries, n)
-        tokens, counts_rows, self.pool = self.infer.prefill(
-            self.params, self.pool, jnp.asarray(input_ids), jnp.asarray(block_tables),
-            jnp.asarray(suffix_lens), jnp.asarray(cached_lens), counts_dev,
-            samp_arrays(sampling, n),
-            lora=self._lora_tree(), adapter_idx=self._adapter_idx(adapter_table, n),
-        )
-        self.counts = self.counts.at[jnp.asarray(np.asarray(slot_idx))].set(  # sync-ok: slot_idx is a host int list
-            counts_rows[: len(slot_idx)])
-        return np.asarray(tokens)  # sync-ok: THE prefill sync point — sampled int32 ids only
+        self.step_accounting = dict(
+            {"fed": n * input_ids.shape[1], "shape": ("prefill", n, input_ids.shape[1])},
+            **launch_geometry(n, suffix_lens, cached_lens + np.asarray(suffix_lens)))  # sync-ok: suffix_lens is host numpy
+        with TRACER.span("dispatch", cat="engine", program="prefill"):
+            counts_dev = self._cached_counts(cached_entries, n)
+            tokens, counts_rows, self.pool = self.infer.prefill(
+                self.params, self.pool, jnp.asarray(input_ids), jnp.asarray(block_tables),
+                jnp.asarray(suffix_lens), jnp.asarray(cached_lens), counts_dev,
+                samp_arrays(sampling, n),
+                lora=self._lora_tree(), adapter_idx=self._adapter_idx(adapter_table, n),
+            )
+            self.counts = self.counts.at[jnp.asarray(np.asarray(slot_idx))].set(  # sync-ok: slot_idx is a host int list
+                counts_rows[: len(slot_idx)])
+        with TRACER.span("wait", cat="engine", program="prefill"):
+            return np.asarray(tokens)  # sync-ok: THE prefill sync point — sampled int32 ids only
 
     def decode(self, last_tokens, block_tables, context_lens, done0, remaining,
                sampling, adapter_table=None) -> Tuple[np.ndarray, np.ndarray]:
         B, steps = last_tokens.shape[0], self.infer.decode_steps
-        self.step_accounting = {"fed": B * steps, "shape": ("decode", B, steps)}
-        toks, valid, _, _, self.counts, self.pool = self.infer.decode(
-            self.params, self.pool, jnp.asarray(last_tokens), jnp.asarray(block_tables),
-            jnp.asarray(context_lens), jnp.asarray(done0), jnp.asarray(remaining),
-            self.counts, samp_arrays(sampling, len(sampling)),
-            lora=self._lora_tree(), adapter_idx=self._adapter_idx(adapter_table, B),
-        )
-        return np.asarray(toks), np.asarray(valid)  # sync-ok: THE decode sync point — int32 ids + validity flags only
+        live = ~np.asarray(done0, bool)  # sync-ok: done0 is host numpy
+        ctx = np.asarray(context_lens, np.int64)  # sync-ok: context_lens is host numpy
+        acct = dict({"fed": B * steps, "shape": ("decode", B, steps)},
+                    **launch_geometry(B, live, live * (ctx + 1)))
+        self.step_accounting = acct
+        with TRACER.span("dispatch", cat="engine", program="decode"):
+            toks, valid, _, _, self.counts, self.pool = self.infer.decode(
+                self.params, self.pool, jnp.asarray(last_tokens), jnp.asarray(block_tables),
+                jnp.asarray(context_lens), jnp.asarray(done0), jnp.asarray(remaining),
+                self.counts, samp_arrays(sampling, len(sampling)),
+                lora=self._lora_tree(), adapter_idx=self._adapter_idx(adapter_table, B),
+            )
+        with TRACER.span("wait", cat="engine", program="decode"):
+            toks, valid = np.asarray(toks), np.asarray(valid)  # sync-ok: THE decode sync point — int32 ids + validity flags only
+        # what the launch really read is known only now: a row still emitting
+        # in sub-step s read ctx + s + 1 positions there (restamped, not
+        # mutated: see ModelBackend.step_accounting)
+        sub = np.arange(1, valid.shape[0] + 1, dtype=np.int64)[:, None]
+        self.step_accounting = dict(
+            acct, kv_positions=int(((ctx[None, :] + sub) * valid).sum()))  # sync-ok: valid already host
+        return toks, valid
 
     def verify(self, tokens, block_tables, start_pos, need_logits: bool,
                adapter_table=None):
-        self.step_accounting = {
-            "fed": tokens.shape[0] * tokens.shape[1],
-            "shape": ("verify", tokens.shape[0], tokens.shape[1])}
-        argmax, logits, self.pool = self.infer.verify(
-            self.params, self.pool, jnp.asarray(tokens), jnp.asarray(block_tables),
-            jnp.asarray(start_pos),
-            lora=self._lora_tree(),
-            adapter_idx=self._adapter_idx(adapter_table, tokens.shape[0]),
-            need_logits=need_logits,
-        )
-        return np.asarray(argmax), (np.asarray(logits) if need_logits else None)  # sync-ok: THE verify sync point (logits only when rejection sampling asks)
+        B, T = tokens.shape
+        # a live row's table starts at a real block (block 0 is the sentinel);
+        # every live row feeds all K + 1 positions, drafted or zero-padded
+        live = np.asarray(block_tables)[:, 0] != 0  # sync-ok: block_tables is host numpy
+        self.step_accounting = dict(
+            {"fed": B * T, "shape": ("verify", B, T)},
+            **launch_geometry(B, live, live * (np.asarray(start_pos, np.int64) + T)))  # sync-ok: start_pos is host numpy
+        with TRACER.span("dispatch", cat="engine", program="verify"):
+            argmax, logits, self.pool = self.infer.verify(
+                self.params, self.pool, jnp.asarray(tokens), jnp.asarray(block_tables),
+                jnp.asarray(start_pos),
+                lora=self._lora_tree(),
+                adapter_idx=self._adapter_idx(adapter_table, B),
+                need_logits=need_logits,
+            )
+        with TRACER.span("wait", cat="engine", program="verify"):
+            return np.asarray(argmax), (np.asarray(logits) if need_logits else None)  # sync-ok: THE verify sync point (logits only when rejection sampling asks)
 
     def apply_cow(self, pairs):
         self.pool = copy_blocks(self.pool, pairs)
@@ -475,10 +521,12 @@ class SingleDeviceBackend(ModelBackend):
         if flat is None:
             flat = not self.infer.use_paged_kernel
         launch = self._mixed_flat_launch if flat else self._mixed_padded_launch
-        tokens_dev, mapper = launch(chunk_rows, decode_rows)
+        with TRACER.span("dispatch", cat="engine", program="mixed"):
+            tokens_dev, mapper = launch(chunk_rows, decode_rows)
 
         def collect() -> np.ndarray:
-            return mapper(np.asarray(tokens_dev))  # sync-ok: THE mixed-step sync point — sampled int32 ids only
+            with TRACER.span("wait", cat="engine", program="mixed"):
+                return mapper(np.asarray(tokens_dev))  # sync-ok: THE mixed-step sync point — sampled int32 ids only
 
         return collect
 
@@ -489,17 +537,21 @@ class SingleDeviceBackend(ModelBackend):
         mapper)."""
         B = self.max_batch_size
         T = _bucket(max([len(r.tokens) for r in chunk_rows], default=1), minimum=1)
-        self.step_accounting = {"fed": B * T, "shape": ("mixed_padded", B, T)}
+        rows = chunk_rows + decode_rows
         ids = np.zeros((B, T), np.int32)
         tables = np.zeros((B, chunk_rows[0].table.shape[0] if chunk_rows
                            else decode_rows[0].table.shape[0]), np.int32)
+        self.step_accounting = dict(
+            {"fed": B * T, "shape": ("mixed_padded", B, T)},
+            **launch_geometry(B, [len(r.tokens) for r in rows],
+                              [r.start + len(r.tokens) for r in rows]))
         q_lens = np.zeros(B, np.int32)
         q_start = np.zeros(B, np.int32)
         count_fed = np.zeros(B, bool)
         emit = np.zeros(B, bool)
         adapter = np.zeros(B, np.int32)
         sampling: List = [None] * B
-        for r in chunk_rows + decode_rows:
+        for r in rows:
             n = len(r.tokens)
             ids[r.slot, :n] = r.tokens
             tables[r.slot] = r.table
@@ -515,7 +567,6 @@ class SingleDeviceBackend(ModelBackend):
             jnp.asarray(count_fed), jnp.asarray(emit), samp_arrays(sampling, B),
             lora=self._lora_tree(), adapter_idx=self._adapter_idx(adapter, B),
         )
-        rows = chunk_rows + decode_rows
         return tokens, lambda host: np.asarray([host[r.slot] for r in rows])  # sync-ok: host reshuffle of already-synced ids
 
     def _mixed_flat_launch(self, chunk_rows, decode_rows):
@@ -528,8 +579,12 @@ class SingleDeviceBackend(ModelBackend):
         C = _bucket(len(chunk_rows), minimum=1)
         T = _bucket(max([len(r.tokens) for r in chunk_rows], default=1), minimum=1)
         D = _bucket(len(decode_rows), minimum=1)
-        self.step_accounting = {"fed": C * T + D, "shape": ("mixed_flat", C, T, D)}
         M = (chunk_rows[0].table.shape[0] if chunk_rows else decode_rows[0].table.shape[0])
+        rows = chunk_rows + decode_rows
+        self.step_accounting = dict(
+            {"fed": C * T + D, "shape": ("mixed_flat", C, T, D)},
+            **launch_geometry(C + D, [len(r.tokens) for r in rows],
+                              [r.start + len(r.tokens) for r in rows]))
         c_ids = np.zeros((C, T), np.int32)
         c_tables = np.zeros((C, M), np.int32)
         c_qlens = np.zeros(C, np.int32)

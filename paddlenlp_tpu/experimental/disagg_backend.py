@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability.goodput import LAUNCH_GEOMETRY
 from ..utils.log import logger
 from .backend import MixedRow, ModelBackend
 from .paged_cache import PagedKVPool
@@ -276,19 +277,18 @@ class DisaggBackend(ModelBackend):
         Returns tokens in ``[*chunk_rows, *decode_rows]`` order, the
         single-backend contract."""
         collectors = []
-        fed = 0
+        acct = {"fed": 0, **{g: 0 for g in LAUNCH_GEOMETRY}}
         shapes = []
-        if chunk_rows:
-            collectors.append(self.prefill_stage.mixed_step_begin(chunk_rows, []))
-            fed += self.prefill_stage.step_accounting["fed"]
-            shapes.append(("stage_prefill",) + self.prefill_stage.step_accounting["shape"])
-        if decode_rows:
-            collectors.append(self.decode_stage.mixed_step_begin([], decode_rows))
-            fed += self.decode_stage.step_accounting["fed"]
-            shapes.append(("stage_decode",) + self.decode_stage.step_accounting["shape"])
+        for tag, stage, chunks, decodes in (("stage_prefill", self.prefill_stage, chunk_rows, []),
+                                            ("stage_decode", self.decode_stage, [], decode_rows)):
+            if chunks or decodes:
+                collectors.append(stage.mixed_step_begin(chunks, decodes))
+                for k in acct:
+                    acct[k] += stage.step_accounting[k]
+                shapes.append((tag,) + stage.step_accounting["shape"])
         # one engine mixed step = the SUM of both stage launches: the goodput
         # ledger accounts device positions burnt fleet-of-stages-wide
-        self.step_accounting = {"fed": fed, "shape": tuple(shapes)}
+        self.step_accounting = dict(acct, shape=tuple(shapes))
         if not collectors:
             return np.zeros(0, np.int32)
         return np.concatenate([collect() for collect in collectors])

@@ -32,6 +32,7 @@ device programs:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import time
@@ -44,6 +45,7 @@ import numpy as np
 
 from ..observability.flight_recorder import RECORDER
 from ..observability.goodput import (
+    LAUNCH_GEOMETRY,
     GoodputLedger,
     compile_attribution,
     device_peak_flops,
@@ -61,6 +63,12 @@ from .kv_host_tier import HostKVTier, pool_block_bytes
 from .paged_cache import BlockManager
 
 __all__ = ["InferenceEngine", "Request", "SamplingParams"]
+
+# one call site per phase records on both clocks: every live engine / engine-loop
+# span also opens a profiler annotation of the same name and args (a no-op
+# TraceMe while no capture runs); see observability/tracer.py
+TRACER.mirror_spans(jax.profiler.TraceAnnotation, cats=("engine", "engine_loop"))
+
 
 _F_STEP = FaultPoint("engine.step")
 _F_CHUNK = FaultPoint("engine.prefill_chunk")
@@ -95,7 +103,13 @@ class Request:
     output_ids: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
     stream_cb: Optional[Callable[[int, bool], None]] = None
+    # the request clock: arrival_t is when the client's submission was taken
+    # (the serving loop passes its handle's submitted_t, stamped on the HTTP
+    # thread), enqueued_t when add_request put it on the waiting queue — on the
+    # loop thread, so after whatever step was running when it came in. A bare
+    # engine user gets both at once.
     arrival_t: float = 0.0
+    enqueued_t: Optional[float] = None
     sched_t: Optional[float] = None  # first admitted to a slot (prefill launch)
     first_token_t: Optional[float] = None
     finish_t: Optional[float] = None
@@ -181,7 +195,8 @@ class Request:
 
     @property
     def queue_wait(self) -> Optional[float]:
-        """Seconds spent waiting before first admission (TTFT = queue + prefill)."""
+        """Seconds from submission to first admission, the wait for the loop
+        thread to take the request in included (TTFT = queue_wait + prefill)."""
         if self.sched_t is None:
             return None
         return self.sched_t - self.arrival_t
@@ -393,10 +408,6 @@ class InferenceEngine:
         self._last_step_end: Optional[float] = None
         self._prev_step_busy = False
         self._step_device_s = 0.0
-        # serving hook: called after every step() with a stats dict (queue
-        # depth, running slots, free KV blocks) — the metrics plane subscribes
-        # here instead of monkey-patching the loop
-        self.step_cb: Optional[Callable[[Dict], None]] = None
 
     # device state lives in the backend; these stay as read paths for tests,
     # tools and the metrics plane that predate the backend split
@@ -412,13 +423,31 @@ class InferenceEngine:
     def counts(self):
         return self.backend.counts
 
+    @property
+    def cur_step(self) -> int:
+        """Number of the last ``step()`` started (-1 before the first): the
+        ``step=`` arg of its spans and the ``step_num`` of its annotation."""
+        return self._cur_step
+
+    @property
+    def last_step_device_s(self) -> float:
+        """Seconds the last ``step()`` spent inside backend launches, as its
+        launch spans measured them (0.0: it launched nothing)."""
+        return self._step_device_s
+
     # ------------------------------------------------------------------ api
     def add_request(self, prompt_ids, sampling: Optional[SamplingParams] = None,
                     stream_cb: Optional[Callable] = None, trace: Optional[str] = None,
                     priority: str = "interactive", rework_hwm: int = 0,
                     adapter_id: Optional[str] = None,
-                    tenant: str = DEFAULT_TENANT) -> int:
-        """``rework_hwm`` marks the first ``rework_hwm`` prompt positions as
+                    tenant: str = DEFAULT_TENANT,
+                    arrival_t: Optional[float] = None) -> int:
+        """``arrival_t`` (``time.time()`` clock) is when the submission was
+        taken, if that was earlier than this call: the serving loop passes its
+        handle's ``submitted_t``, so queue wait, TTFT and e2e cover what the
+        client waited, the time on the loop's inbox included.
+
+        ``rework_hwm`` marks the first ``rework_hwm`` prompt positions as
         already-fed-once (a supervisor requeue resubmitting a folded prompt
         after an engine rebuild): the goodput ledger then books their
         re-prefill as ``requeue_refill`` rework instead of useful work.
@@ -442,12 +471,13 @@ class InferenceEngine:
             prompt_ids=np.asarray(prompt_ids, dtype=np.int32).reshape(-1),
             sampling=sampling,
             stream_cb=stream_cb,
-            arrival_t=time.time(),
             trace=trace,
             priority=priority,
             tenant=tenant,
             adapter_id=adapter_id,
         )
+        req.enqueued_t = time.time()
+        req.arrival_t = req.enqueued_t if arrival_t is None else min(arrival_t, req.enqueued_t)
         req.base_prompt_len = len(req.prompt_ids)
         self._tenant_counts(tenant)["requests"] += 1
         if rework_hwm > 0:
@@ -755,7 +785,6 @@ class InferenceEngine:
         pairs = self.mgr.drain_pending_spills()
         if not pairs:
             return
-        t0 = time.perf_counter()
         try:
             _F_SPILL.fire(blocks=len(pairs))
             kv, scale = self.backend.kv_spill([b for _h, b in pairs])
@@ -767,10 +796,6 @@ class InferenceEngine:
             return
         RECORDER.record("spill.batch", blocks=len(pairs),
                         resident=self._host_tier.num_blocks)
-        TRACER.add_span("kv_spill", TRACER.epoch_time(t0),
-                        time.perf_counter() - t0, cat="engine",
-                        blocks=len(pairs), resident=self._host_tier.num_blocks,
-                        step=self._cur_step)
 
     def _advance_promotions(self, finished: List[Request]):
         """Poll in-flight host→device KV promotions (same marker-poll gate as
@@ -846,7 +871,8 @@ class InferenceEngine:
         logger.warning("inference engine reset: scheduler + KV allocator state dropped")
 
     def stats(self) -> Dict:
-        """Point-in-time scheduler/allocator stats (the step_cb payload)."""
+        """Point-in-time scheduler/allocator stats (what the serving loop
+        hands to ``ServingMetrics.on_step`` after every step)."""
         out = {
             "queue_depth": len(self.waiting),
             "running": sum(1 for r in self.slots if r is not None),
@@ -880,8 +906,8 @@ class InferenceEngine:
                 "chunk_tokens_total": self.chunk_stats["chunk_tokens"],
             },
             "backend": self.backend.describe(),
-            # the goodput ledger rides stats() so the step_cb metrics plane,
-            # /health and postmortem bundles all carry the waste accounting
+            # the goodput ledger rides stats() so the metrics plane, /health
+            # and postmortem bundles all carry the waste accounting
             "goodput": self.ledger.snapshot(),
         }
         if self.adapter_registry is not None or self.tenant_goodput:
@@ -984,8 +1010,9 @@ class InferenceEngine:
         _F_STEP.fire()
         self._cur_step = next(self._step_seq)
         # step anatomy: host gap since the previous BUSY step ended (loop
-        # overhead between steps) vs device time inside backend calls vs the
-        # step's own host scheduling time. Post-idle steps have no meaningful
+        # overhead between steps) vs device time inside backend calls (the
+        # launch spans' own durations: anatomy and span are one measurement)
+        # vs the step's own host scheduling time. Post-idle steps have no meaningful
         # gap (the loop slept on purpose) — marked unmeasured (-1)
         t_step0 = time.perf_counter()
         gap_s = (t_step0 - self._last_step_end
@@ -1019,25 +1046,44 @@ class InferenceEngine:
             else:
                 self._admit(finished)
                 self._decode_running(finished)
-        # usage metering: advance each admitted request's kv_block_seconds
-        # integral piecewise per step (block counts grow during decode, so a
-        # single count-at-free rectangle would misbill long requests)
-        t_occ = time.perf_counter()
-        for req in self.slots:
-            if req is not None and req.kv_occ_t is not None:
-                req.kv_block_seconds += (t_occ - req.kv_occ_t) \
-                    * len(self.mgr.tables.get(req.req_id, ()))
-                req.kv_occ_t = t_occ
-        t_end = time.perf_counter()
-        host_s = max(t_end - t_step0 - self._step_device_s, 0.0)
-        self.ledger.note_step(max(gap_s, 0.0), self._step_device_s, host_s)
-        self.recent_step_times.append(
-            (next(self._step_time_seq), gap_s, self._step_device_s, host_s))
-        self._last_step_end = t_end
-        self._prev_step_busy = self.has_work()
-        if self.step_cb is not None:
-            self.step_cb(self.stats())
+            with TRACER.span("step_tail", cat="engine", step=self._cur_step) as tail:
+                # usage metering: advance each admitted request's
+                # kv_block_seconds integral piecewise per step (block counts
+                # grow during decode, so a single count-at-free rectangle
+                # would misbill long requests)
+                t_occ = time.perf_counter()
+                for req in self.slots:
+                    if req is not None and req.kv_occ_t is not None:
+                        req.kv_block_seconds += (t_occ - req.kv_occ_t) \
+                            * len(self.mgr.tables.get(req.req_id, ()))
+                        req.kv_occ_t = t_occ
+                t_end = time.perf_counter()
+                host_s = max(t_end - t_step0 - self._step_device_s, 0.0)
+                self.ledger.note_step(max(gap_s, 0.0), self._step_device_s, host_s)
+                self.recent_step_times.append(
+                    (next(self._step_time_seq), gap_s, self._step_device_s, host_s))
+                self._last_step_end = t_end
+                self._prev_step_busy = self.has_work()
+                if not self._step_device_s:
+                    # a step that launched nothing (every slot waiting on a
+                    # copy) re-polls at once: keep the spin out of the ring
+                    tail.discard()
         return finished
+
+    @contextlib.contextmanager
+    def _launch(self, name: str, program: str, **args):
+        """One backend call: the launch span (mirrored to the profiler; what
+        the launch was asked to do joins its args once the backend has stamped
+        it) under compile attribution. The span's own duration is the step
+        anatomy's device time: anatomy and span are one measurement."""
+        span = TRACER.span(name, cat="engine", step=self._cur_step, **args)  # span-names: prefill decode mixed_step spec_verify
+        try:
+            with span, compile_attribution(self.ledger, program):
+                yield span
+                acct = self.backend.step_accounting
+                span.set(**{g: acct[g] for g in LAUNCH_GEOMETRY})
+        finally:
+            self._step_device_s += span.dur
 
     def _free_slot_indices(self) -> List[int]:
         return [i for i, r in enumerate(self.slots) if r is None]
@@ -1113,11 +1159,39 @@ class InferenceEngine:
         free = self._free_slot_indices()
         if not self.waiting or not free:
             return []
-        queue_depth = len(self.waiting)
         n_finished0 = len(finished)
-        admit_t0 = time.perf_counter()
         cache_on = self.enable_prefix_cache
         hits0, cached0 = self.mgr.cache_hits, self.mgr.cached_tokens_total
+        # admission closes BEFORE prefill (sibling phases, not nested) and is
+        # kept in the ring only when something happened — a blocked queue
+        # spinning admitted=0 every step must not flood it (the profiler
+        # annotation brackets the attempt either way)
+        with TRACER.span("admission", cat="engine", step=self._cur_step,
+                         queue_depth=len(self.waiting)) as span:
+            admitted = self._bind_waiting(finished, free, cache_on)
+            span.set(admitted=len(admitted),
+                     rejected_capacity=len(finished) - n_finished0)
+            if not admitted and len(finished) == n_finished0:
+                span.discard()
+        # spill drain BEFORE the COW copies: a pending spill's D2H gather must
+        # be enqueued before any device write can touch the recycled blocks
+        # (apply_cow may write into freshly popped LRU blocks)
+        self._drain_spills()
+        if cache_on and admitted:
+            # prefix_cache phase: match/COW bookkeeping + the owed block copies
+            with TRACER.span("prefix_cache", cat="engine", step=self._cur_step,
+                             hits=self.mgr.cache_hits - hits0,
+                             cached_tokens=self.mgr.cached_tokens_total - cached0) as span:
+                cow = self.mgr.drain_cow_pairs()
+                if cow:
+                    self.backend.apply_cow(cow)
+                span.set(cow_copies=len(cow))
+        return admitted
+
+    def _bind_waiting(self, finished: List[Request], free: List[int],
+                      cache_on: bool) -> List[tuple]:
+        """The body of the ``admission`` phase: walk the waiting queue while
+        slots are free. Returns ``[(slot, req, n_cached), ...]``."""
         admitted: List[tuple] = []  # (slot, req, n_cached)
         # stage-aware admission (staged backends): new prompts are prefill-
         # stage work, so their gate is PREFILL-stage KV pressure — blocks held
@@ -1329,30 +1403,6 @@ class InferenceEngine:
         # (they were popped from the head before anything behind them)
         for r in reversed(tenant_deferred):
             self.waiting.appendleft(r)
-        # admission span closes BEFORE prefill (sibling phases, not nested) and
-        # only when something happened — a blocked queue spinning admitted=0
-        # every step must not flood the span ring
-        if admitted or len(finished) > n_finished0:
-            TRACER.add_span("admission", TRACER.epoch_time(admit_t0),
-                            time.perf_counter() - admit_t0, cat="engine",
-                            step=self._cur_step,
-                            queue_depth=queue_depth, admitted=len(admitted),
-                            rejected_capacity=len(finished) - n_finished0)
-        # spill drain BEFORE the COW copies: a pending spill's D2H gather must
-        # be enqueued before any device write can touch the recycled blocks
-        # (apply_cow may write into freshly popped LRU blocks)
-        self._drain_spills()
-        if cache_on and admitted:
-            # prefix_cache phase: match/COW bookkeeping + the owed block copies
-            pc_t0 = time.perf_counter()
-            cow = self.mgr.drain_cow_pairs()
-            if cow:
-                self.backend.apply_cow(cow)
-            TRACER.add_span("prefix_cache", TRACER.epoch_time(pc_t0),
-                            time.perf_counter() - pc_t0, cat="engine",
-                            hits=self.mgr.cache_hits - hits0,
-                            cached_tokens=self.mgr.cached_tokens_total - cached0,
-                            cow_copies=len(cow))
         return admitted
 
     def _admit(self, finished: List[Request]):
@@ -1386,54 +1436,54 @@ class InferenceEngine:
             by_bucket.setdefault(_bucket(len(req.prompt_ids) - n_cached),
                                  []).append((slot, req, n_cached))
         for padded, group in by_bucket.items():
-            n = _bucket(len(group), minimum=1)
-            ids = np.zeros((n, padded), np.int32)
-            tables = np.zeros((n, self.mgr.max_blocks_per_seq), np.int32)
-            suffix_lens = np.zeros(n, np.int32)
-            cached_lens = np.zeros(n, np.int32)
-            sampling: List = [None] * n
-            for j, (slot, req, n_cached) in enumerate(group):
-                suffix = req.prompt_ids[n_cached:]
-                ids[j, : len(suffix)] = suffix
-                tables[j] = self.mgr.table_array(req.req_id)
-                suffix_lens[j] = len(suffix)
-                cached_lens[j] = n_cached
-                sampling[j] = req.sampling
-            entries = [(j, req.prompt_ids, c) for j, (_, req, c) in enumerate(group)]
-            cached_total = int(cached_lens.sum())  # sync-ok: cached_lens is host numpy
-            with TRACER.span("prefill", cat="engine", bucket=padded, batch=len(group),
-                             step=self._cur_step,
-                             req_ids=[r.req_id for _, r, _ in group],
-                             cached_tokens=cached_total), \
-                    compile_attribution(self.ledger, "prefill"):
-                t_dev = time.perf_counter()
+            with TRACER.span("launch_build", cat="engine", step=self._cur_step,
+                             program="prefill"):
+                n = _bucket(len(group), minimum=1)
+                ids = np.zeros((n, padded), np.int32)
+                tables = np.zeros((n, self.mgr.max_blocks_per_seq), np.int32)
+                suffix_lens = np.zeros(n, np.int32)
+                cached_lens = np.zeros(n, np.int32)
+                sampling: List = [None] * n
+                for j, (slot, req, n_cached) in enumerate(group):
+                    suffix = req.prompt_ids[n_cached:]
+                    ids[j, : len(suffix)] = suffix
+                    tables[j] = self.mgr.table_array(req.req_id)
+                    suffix_lens[j] = len(suffix)
+                    cached_lens[j] = n_cached
+                    sampling[j] = req.sampling
+                entries = [(j, req.prompt_ids, c) for j, (_, req, c) in enumerate(group)]
+                cached_total = int(cached_lens.sum())  # sync-ok: cached_lens is host numpy
                 # adapter_table only with a registry attached: prebuilt test
                 # backends predating the kwarg keep working registry-off
                 extra = ({"adapter_table": [r.adapter_slot for _, r, _ in group]}
                          if self.adapter_registry is not None else {})
+            # the per-request prefill spans join this launch on step=
+            with self._launch("prefill", "prefill", bucket=padded, batch=len(group),
+                              cached_tokens=cached_total):
                 tokens = self.backend.prefill(
                     ids, tables, suffix_lens, entries, sampling,
                     [slot for slot, _, _ in group], **extra)
-                self._step_device_s += time.perf_counter() - t_dev
-            # goodput: fed = the padded launch geometry; useful = the uncached
-            # suffixes minus any re-fed (post-preemption/requeue/COW) positions
             acct = self.backend.step_accounting
-            g_useful = g_rework = 0
-            g_by: Dict[str, int] = {}
-            for slot, req, n_cached in group:
-                n_fed = len(req.prompt_ids) - n_cached
-                rw, by = self._note_fed_span(req, n_cached, n_fed)
-                g_useful += n_fed - rw
-                g_rework += rw
-                self._merge_rework(g_by, by)
-            self.ledger.note_shape(acct["shape"])
-            self.ledger.record(
-                "prefill", acct["fed"], g_useful,
-                padding=acct["fed"] - g_useful - g_rework,
-                rework=g_rework, rework_by=g_by or None)
-            for j, (slot, req, _) in enumerate(group):
-                req.prefilled_len = len(req.prompt_ids)
-                self._settle_sampled(slot, req, int(tokens[j]), finished)  # sync-ok: tokens already host (backend.prefill synced)
+            with TRACER.span("emit", cat="engine", step=self._cur_step, program="prefill"):
+                # goodput: fed = the padded launch geometry; useful = the
+                # uncached suffixes minus any re-fed (post-preemption/requeue/
+                # COW) positions
+                g_useful = g_rework = 0
+                g_by: Dict[str, int] = {}
+                for slot, req, n_cached in group:
+                    n_fed = len(req.prompt_ids) - n_cached
+                    rw, by = self._note_fed_span(req, n_cached, n_fed)
+                    g_useful += n_fed - rw
+                    g_rework += rw
+                    self._merge_rework(g_by, by)
+                self.ledger.note_shape(acct["shape"])
+                self.ledger.record(
+                    "prefill", acct["fed"], g_useful,
+                    padding=acct["fed"] - g_useful - g_rework,
+                    rework=g_rework, rework_by=g_by or None, geometry=acct)
+                for j, (slot, req, _) in enumerate(group):
+                    req.prefilled_len = len(req.prompt_ids)
+                    self._settle_sampled(slot, req, int(tokens[j]), finished)  # sync-ok: tokens already host (backend.prefill synced)
 
     def _settle_sampled(self, slot: int, req: Request, tok: int, finished: List[Request]):
         """Post-sample bookkeeping shared by every sampling site (monolithic
@@ -1475,14 +1525,10 @@ class InferenceEngine:
         self.backend.seed_counts(
             slot_idx, [(i, req.prompt_ids, c) for i, (_, req, c) in enumerate(admitted)])
 
-    def _mixed_step(self, finished: List[Request]):
-        """One ragged mixed step: up to ``prefill_chunk_tokens`` prompt tokens
-        (split across mid-prefill slots, oldest request first) plus ONE decode token
-        for every running sequence, in a single forward. Decode keeps flowing
-        while a long prompt fills — the per-step stall is bounded by the chunk
-        budget, not the prompt length."""
-        _F_CHUNK.fire(
-            prefilling=sum(1 for r in self.slots if r is not None and r.needs_prefill))
+    def _mixed_rows(self):
+        """The ``launch_build`` phase of a mixed step: the capacity pass, the
+        chunk budget and the row payloads. Returns ``(chunk_rows, decode_rows,
+        chunk_payload, dec_payload)``, or None when no row can ride."""
         # capacity pass: every decoding slot needs a block covering this step's
         # KV write. Oldest slots secure theirs first; exhaustion preempts the
         # YOUNGEST active slot — which may be a mid-prefill request (its chunk
@@ -1535,8 +1581,7 @@ class InferenceEngine:
             RECORDER.record("chunk.grant", req_id=req.req_id, trace=req.trace,
                             tokens=n, budget_left=budget, step=self._cur_step)
         if not chunk_rows and not decode_rows:
-            return
-        t0 = time.perf_counter()
+            return None
         chunk_payload = []
         for slot, req, n in chunk_rows:
             p0 = req.prefilled_len
@@ -1553,19 +1598,38 @@ class InferenceEngine:
                      sampling=req.sampling, is_chunk=False,
                      adapter=req.adapter_slot)
             for slot, req in decode_rows]
-        with TRACER.span("mixed_step", cat="engine", step=self._cur_step,
-                         chunks=len(chunk_rows), decodes=len(decode_rows),
-                         chunk_tokens=int(sum(n for _, _, n in chunk_rows)),
-                         req_ids=[r.req_id for _, r, _ in chunk_rows]), \
-                compile_attribution(self.ledger, "mixed"):
-            t_dev = time.perf_counter()
+        return chunk_rows, decode_rows, chunk_payload, dec_payload
+
+    def _mixed_step(self, finished: List[Request]):
+        """One ragged mixed step: up to ``prefill_chunk_tokens`` prompt tokens
+        (split across mid-prefill slots, oldest request first) plus ONE decode token
+        for every running sequence, in a single forward. Decode keeps flowing
+        while a long prompt fills — the per-step stall is bounded by the chunk
+        budget, not the prompt length."""
+        _F_CHUNK.fire(
+            prefilling=sum(1 for r in self.slots if r is not None and r.needs_prefill))
+        with TRACER.span("launch_build", cat="engine", step=self._cur_step,
+                         program="mixed") as build:
+            rows = self._mixed_rows()
+            if rows is None:
+                build.discard()  # nothing to launch: every slot waits on a copy
+                return
+            chunk_rows, decode_rows, chunk_payload, dec_payload = rows
+        t0 = time.perf_counter()
+        with self._launch("mixed_step", "mixed", chunks=len(chunk_rows), decodes=len(decode_rows),
+                          chunk_tokens=int(sum(n for _, _, n in chunk_rows))):
             tokens = self.backend.mixed_step(chunk_payload, dec_payload)
-            self._step_device_s += time.perf_counter() - t_dev
+        acct = self.backend.step_accounting
         dur = time.perf_counter() - t0
+        with TRACER.span("emit", cat="engine", step=self._cur_step, program="mixed"):
+            self._mixed_settle(chunk_rows, decode_rows, tokens, acct, dur, finished)
+
+    def _mixed_settle(self, chunk_rows, decode_rows, tokens, acct, dur: float,
+                      finished: List[Request]):
+        """The ``emit`` phase of a mixed step: accounting, then settle."""
         # goodput accounting BEFORE settle mutates prefilled_len/total_len:
         # chunk tokens + the one fed token per decode row are useful (minus
         # re-fed positions); the padded launch remainder is padding
-        acct = self.backend.step_accounting
         g_useful = g_rework = 0
         g_by: Dict[str, int] = {}
         for _slot, req, n in chunk_rows:
@@ -1582,7 +1646,7 @@ class InferenceEngine:
         self.ledger.record(
             "mixed", acct["fed"], g_useful,
             padding=acct["fed"] - g_useful - g_rework,
-            rework=g_rework, rework_by=g_by or None)
+            rework=g_rework, rework_by=g_by or None, geometry=acct)
         if chunk_rows:
             # every decode token in this step waited out the chunk work: the
             # step duration is each riding request's decode-stall share
@@ -1767,44 +1831,52 @@ class InferenceEngine:
         normalize(max(p_i - q_i, 0))), which emits EXACT target-distribution
         samples. 1..K+1 tokens per sequence per forward either way."""
         K = self.spec_draft_len
-        # reserve capacity for all K+1 optimistic KV writes; preempt on OOM
-        active = [s for s in range(len(self.slots)) if self.slots[s] is not None]
-        for slot in sorted(active, key=lambda s: -self.slots[s].req_id):
-            req = self.slots[slot]
-            grow = req.total_len + K - self.mgr.lengths[req.req_id]
-            if grow > 0 and self.mgr.extend(req.req_id, grow) is None:
-                self._preempt(slot, cause="spec_reserve")
-        # the reservation pass may have popped LRU blocks: enqueue their D2H
-        # gather before the verify forward can overwrite them
-        self._drain_spills()
-        if not any(r is not None for r in self.slots):
-            return
+        with TRACER.span("launch_build", cat="engine", step=self._cur_step,
+                         program="verify") as build:
+            # reserve capacity for all K+1 optimistic KV writes; preempt on OOM
+            active = [s for s in range(len(self.slots)) if self.slots[s] is not None]
+            for slot in sorted(active, key=lambda s: -self.slots[s].req_id):
+                req = self.slots[slot]
+                grow = req.total_len + K - self.mgr.lengths[req.req_id]
+                if grow > 0 and self.mgr.extend(req.req_id, grow) is None:
+                    self._preempt(slot, cause="spec_reserve")
+            # the reservation pass may have popped LRU blocks: enqueue their D2H
+            # gather before the verify forward can overwrite them
+            self._drain_spills()
+            if not any(r is not None for r in self.slots):
+                build.discard()
+                return
 
-        B = self.max_batch_size
-        tokens = np.zeros((B, K + 1), np.int32)
-        tables = np.zeros((B, self.mgr.max_blocks_per_seq), np.int32)
-        start = np.zeros(B, np.int32)
-        for i, req in enumerate(self.slots):
-            if req is None:
-                drafts[i] = np.zeros(0, np.int32)
-                continue
-            d = drafts[i]
-            tokens[i, 0] = self._last_token[i]
-            tokens[i, 1 : 1 + len(d)] = d
-            tables[i] = self.mgr.table_array(req.req_id)
-            start[i] = req.total_len - 1  # position of the token being fed
-        with TRACER.span("spec_verify", cat="engine", mode=mode, step=self._cur_step,
-                         drafted=int(sum(len(d) for d in drafts))), \
-                compile_attribution(self.ledger, "verify"):
-            # greedy acceptance never reads the logits: need_logits=False keeps
-            # the [B, K+1, V] fp32 buffer from materializing at all
-            t_dev = time.perf_counter()
+            B = self.max_batch_size
+            tokens = np.zeros((B, K + 1), np.int32)
+            tables = np.zeros((B, self.mgr.max_blocks_per_seq), np.int32)
+            start = np.zeros(B, np.int32)
+            for i, req in enumerate(self.slots):
+                if req is None:
+                    drafts[i] = np.zeros(0, np.int32)
+                    continue
+                d = drafts[i]
+                tokens[i, 0] = self._last_token[i]
+                tokens[i, 1 : 1 + len(d)] = d
+                tables[i] = self.mgr.table_array(req.req_id)
+                start[i] = req.total_len - 1  # position of the token being fed
             extra = ({"adapter_table": [0 if r is None else r.adapter_slot
                                         for r in self.slots]}
                      if self.adapter_registry is not None else {})
+        with self._launch("spec_verify", "verify", mode=mode,
+                          drafted=int(sum(len(d) for d in drafts))):
+            # greedy acceptance never reads the logits: need_logits=False keeps
+            # the [B, K+1, V] fp32 buffer from materializing at all
             argmax, logits = self.backend.verify(
                 tokens, tables, start, need_logits=mode == "sample", **extra)
-            self._step_device_s += time.perf_counter() - t_dev
+        acct = self.backend.step_accounting
+        with TRACER.span("emit", cat="engine", step=self._cur_step, program="verify"):
+            self._spec_accept(finished, drafts, qprobs, mode, argmax, logits, acct)
+
+    def _spec_accept(self, finished: List[Request], drafts, qprobs, mode: str,
+                     argmax, logits, acct):
+        """The ``emit`` phase of a speculative step: host-side acceptance,
+        emission and the ledger entry."""
         self.spec_stats["verify_steps"] += 1
         # goodput: drafted-but-rejected positions are the spec_rejected waste
         # bucket; emitted (accepted + correction/bonus) positions are useful
@@ -1850,12 +1922,11 @@ class InferenceEngine:
                 # release the optimistic blocks past the accepted tokens
                 self.mgr.shrink(req.req_id, req.total_len)
         g_rejected = g_drafted - (self.spec_stats["accepted"] - g_acc0)
-        acct = self.backend.step_accounting
         self.ledger.note_shape(acct["shape"])
         self.ledger.record(
             "verify", acct["fed"], g_emitted,
             padding=acct["fed"] - g_emitted - g_rejected,
-            spec_rejected=g_rejected)
+            spec_rejected=g_rejected, geometry=acct)
 
     def _accept_rejection(self, slot: int, req, d: np.ndarray, logits_row: np.ndarray,
                           q: Optional[np.ndarray]) -> List[int]:
@@ -1915,49 +1986,57 @@ class InferenceEngine:
             if any(len(d) for d in drafts):
                 return self._decode_spec(finished, drafts, qprobs, mode)
         steps = self.decode_steps
-        # grow tables for up to `steps` tokens; preempt (recompute-requeue)
-        # youngest on exhaustion. Surplus is shrunk back after the device call.
-        start_len: Dict[int, int] = {}
-        active = [s for s in range(len(self.slots))
-                  if self.slots[s] is not None and self.slots[s].kv_stage == "decode"]
-        for slot in sorted(active, key=lambda s: -self.slots[s].req_id):
-            req = self.slots[slot]
-            needed = min(steps, req.remaining_new)
-            start_len[req.req_id] = self.mgr.lengths[req.req_id]
-            if self.mgr.extend(req.req_id, max(needed, 1)) is None:
-                start_len.pop(req.req_id, None)
-                self._preempt(slot)
-        # extends may have popped LRU blocks: enqueue their D2H gather before
-        # the decode forward can overwrite them
-        self._drain_spills()
+        with TRACER.span("launch_build", cat="engine", step=self._cur_step,
+                         program="decode") as build:
+            # grow tables for up to `steps` tokens; preempt (recompute-requeue)
+            # youngest on exhaustion. Surplus is shrunk back after the device call.
+            start_len: Dict[int, int] = {}
+            active = [s for s in range(len(self.slots))
+                      if self.slots[s] is not None and self.slots[s].kv_stage == "decode"]
+            for slot in sorted(active, key=lambda s: -self.slots[s].req_id):
+                req = self.slots[slot]
+                needed = min(steps, req.remaining_new)
+                start_len[req.req_id] = self.mgr.lengths[req.req_id]
+                if self.mgr.extend(req.req_id, max(needed, 1)) is None:
+                    start_len.pop(req.req_id, None)
+                    self._preempt(slot)
+            # extends may have popped LRU blocks: enqueue their D2H gather before
+            # the decode forward can overwrite them
+            self._drain_spills()
 
-        if not any(r is not None and r.kv_stage == "decode" for r in self.slots):
-            return
-        B = self.max_batch_size
-        tokens = np.array(self._last_token, np.int32)  # sync-ok: _last_token is a host array
-        tables = np.zeros((B, self.mgr.max_blocks_per_seq), np.int32)
-        ctx = np.zeros(B, np.int32)
-        done0 = np.ones(B, bool)
-        remaining = np.zeros(B, np.int32)
-        for i, req in enumerate(self.slots):
-            if req is None or req.kv_stage != "decode":
-                continue  # migrating rows stay frozen (done0) like empty slots
-            tables[i] = self.mgr.table_array(req.req_id)
-            ctx[i] = req.total_len - 1  # position of the token being fed
-            done0[i] = False
-            remaining[i] = req.remaining_new
-        with TRACER.span("decode", cat="engine", steps=steps, step=self._cur_step,
-                         active=int(sum(1 for r in self.slots if r is not None))), \
-                compile_attribution(self.ledger, "decode"):
-            # ONE host transfer of ids + validity flags (no logits)
-            t_dev = time.perf_counter()
+            if not any(r is not None and r.kv_stage == "decode" for r in self.slots):
+                build.discard()
+                return
+            B = self.max_batch_size
+            tokens = np.array(self._last_token, np.int32)  # sync-ok: _last_token is a host array
+            tables = np.zeros((B, self.mgr.max_blocks_per_seq), np.int32)
+            ctx = np.zeros(B, np.int32)
+            done0 = np.ones(B, bool)
+            remaining = np.zeros(B, np.int32)
+            for i, req in enumerate(self.slots):
+                if req is None or req.kv_stage != "decode":
+                    continue  # migrating rows stay frozen (done0) like empty slots
+                tables[i] = self.mgr.table_array(req.req_id)
+                ctx[i] = req.total_len - 1  # position of the token being fed
+                done0[i] = False
+                remaining[i] = req.remaining_new
             extra = ({"adapter_table": [0 if r is None else r.adapter_slot
                                         for r in self.slots]}
                      if self.adapter_registry is not None else {})
+        with self._launch("decode", "decode", steps=steps,
+                          active=int(sum(1 for r in self.slots if r is not None))):
+            # ONE host transfer of ids + validity flags (no logits)
             toks, valid = self.backend.decode(
                 tokens, tables, ctx, done0, remaining,
                 [None if r is None else r.sampling for r in self.slots], **extra)
-            self._step_device_s += time.perf_counter() - t_dev
+        acct = self.backend.step_accounting
+        with TRACER.span("emit", cat="engine", step=self._cur_step, program="decode"):
+            self._decode_settle(toks, valid, acct, start_len, finished)
+
+    def _decode_settle(self, toks, valid, acct, start_len: Dict[int, int],
+                       finished: List[Request]):
+        """The ``emit`` phase of a decode launch: stream the tokens out, book
+        the launch, retire or shrink."""
         n_emitted = 0
         for s in range(toks.shape[0]):
             for i, req in enumerate(self.slots):
@@ -1973,14 +2052,13 @@ class InferenceEngine:
         # goodput: the decode jit always burns B x decode_steps positions;
         # every emitted token is one useful fed position, the rest (idle
         # slots, post-EOS sub-steps, unconsumed budget) is padding
-        acct = self.backend.step_accounting
         for req in self.slots:
             if req is not None and req.kv_stage == "decode":
                 # the last emitted token was sampled, not fed: mark to total-1
                 req.fed_hwm = max(req.fed_hwm, req.total_len - 1)
         self.ledger.note_shape(acct["shape"])
         self.ledger.record("decode", acct["fed"], n_emitted,
-                           padding=acct["fed"] - n_emitted)
+                           padding=acct["fed"] - n_emitted, geometry=acct)
         for i, req in enumerate(self.slots):
             if req is None:
                 continue

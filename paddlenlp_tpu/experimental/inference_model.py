@@ -232,52 +232,64 @@ class PagedInferenceModel:
         cfg = self.config
         B, T, D = h.shape
 
-        x = _rms(h, lp["input_layernorm"]["scale"], self.eps)
+        # jax.named_scope is metadata only: each operation's op_name carries
+        # the scope, which is how a device profile names what a fusion is for
+        # (the serving programs get no scope from a module system)
+        with jax.named_scope("attn_norm"):
+            x = _rms(h, lp["input_layernorm"]["scale"], self.eps)
         attn = lp["self_attn"]
 
         def proj(p, x, heads, name):
             return self._lora_mm(p, x, lora_layer, adapter_idx, name) \
                 .reshape(B, T, heads, self.head_dim)
 
-        q = self._hint(proj(attn["q_proj"], x, self.n_heads, "q_proj"), "heads")
-        k = self._hint(proj(attn["k_proj"], x, self.n_kv, "k_proj"), "kv_heads")
-        v = self._hint(proj(attn["v_proj"], x, self.n_kv, "v_proj"), "kv_heads")
-        cos, sin = rope_tables(q_positions, self.inv_freq)
-        q, k = apply_rotary_pos_emb(q, k, cos, sin)
+        with jax.named_scope("qkv"):
+            q = self._hint(proj(attn["q_proj"], x, self.n_heads, "q_proj"), "heads")
+            k = self._hint(proj(attn["k_proj"], x, self.n_kv, "k_proj"), "kv_heads")
+            v = self._hint(proj(attn["v_proj"], x, self.n_kv, "v_proj"), "kv_heads")
+        with jax.named_scope("rope"):
+            cos, sin = rope_tables(q_positions, self.inv_freq)
+            q, k = apply_rotary_pos_emb(q, k, cos, sin)
 
         # scatter new K/V into the pool (per sequence)
-        for i in range(B):
-            written = write_kv_block(pool_layer, k[i], v[i], block_tables[i],
-                                     write_pos[i], scale_layer)
-            if scale_layer is not None:
-                pool_layer, scale_layer = written
-            else:
-                pool_layer = written
+        with jax.named_scope("kv_write"):
+            for i in range(B):
+                written = write_kv_block(pool_layer, k[i], v[i], block_tables[i],
+                                         write_pos[i], scale_layer)
+                if scale_layer is not None:
+                    pool_layer, scale_layer = written
+                else:
+                    pool_layer = written
         if self.use_paged_kernel:
-            attn_out = self._paged_attention(q, pool_layer, scale_layer, block_tables,
-                                             q_positions[:, 0], q_lens)
+            with jax.named_scope("paged_attn"):
+                attn_out = self._paged_attention(q, pool_layer, scale_layer, block_tables,
+                                                 q_positions[:, 0], q_lens)
         else:
-            k_all, v_all = gather_kv(pool_layer, block_tables, scale_layer)
-            attn_out = self._attend(q, k_all, v_all, q_positions, kv_len_mask)
-        attn_out = attn_out.reshape(B, T, self.n_heads * self.head_dim)
-        # gather before the contraction (o_proj stays column-parallel: full
-        # dot per output column, no cross-shard partial sums), gather after
-        # so the residual/norms see a replicated stream
-        attn_out = self._hint(attn_out, "full")
-        h = h + self._hint(
-            self._lora_mm(attn["o_proj"], attn_out, lora_layer, adapter_idx, "o_proj"),
-            "full")
+            with jax.named_scope("attn_gather"):
+                k_all, v_all = gather_kv(pool_layer, block_tables, scale_layer)
+                attn_out = self._attend(q, k_all, v_all, q_positions, kv_len_mask)
+        with jax.named_scope("o_proj"):
+            attn_out = attn_out.reshape(B, T, self.n_heads * self.head_dim)
+            # gather before the contraction (o_proj stays column-parallel: full
+            # dot per output column, no cross-shard partial sums), gather after
+            # so the residual/norms see a replicated stream
+            attn_out = self._hint(attn_out, "full")
+            h = h + self._hint(
+                self._lora_mm(attn["o_proj"], attn_out, lora_layer, adapter_idx, "o_proj"),
+                "full")
 
-        x = _rms(h, lp["post_attention_layernorm"]["scale"], self.eps)
-        mlp = lp["mlp"]
-        gate = self._hint(
-            self._lora_mm(mlp["gate_proj"], x, lora_layer, adapter_idx, "gate_proj"), "mlp")
-        up = self._hint(
-            self._lora_mm(mlp["up_proj"], x, lora_layer, adapter_idx, "up_proj"), "mlp")
-        act = self._hint(jax.nn.silu(gate) * up, "full")
-        h = h + self._hint(
-            self._lora_mm(mlp["down_proj"], act, lora_layer, adapter_idx, "down_proj"),
-            "full")
+        with jax.named_scope("mlp_norm"):
+            x = _rms(h, lp["post_attention_layernorm"]["scale"], self.eps)
+        with jax.named_scope("mlp"):
+            mlp = lp["mlp"]
+            gate = self._hint(
+                self._lora_mm(mlp["gate_proj"], x, lora_layer, adapter_idx, "gate_proj"), "mlp")
+            up = self._hint(
+                self._lora_mm(mlp["up_proj"], x, lora_layer, adapter_idx, "up_proj"), "mlp")
+            act = self._hint(jax.nn.silu(gate) * up, "full")
+            h = h + self._hint(
+                self._lora_mm(mlp["down_proj"], act, lora_layer, adapter_idx, "down_proj"),
+                "full")
         if scale_layer is not None:
             return h, (pool_layer, scale_layer)
         return h, pool_layer
@@ -305,9 +317,10 @@ class PagedInferenceModel:
             adapter_idx = jnp.zeros((input_ids.shape[0],), jnp.int32)
         m = params["model"]
         embed = m["embed_tokens"]["embedding"]
-        h = self._hint(embed[input_ids].astype(self.dtype), "full")
-        if getattr(self.config, "scale_embeddings", False):
-            h = h * jnp.asarray(self.config.hidden_size**0.5, h.dtype)
+        with jax.named_scope("embed"):
+            h = self._hint(embed[input_ids].astype(self.dtype), "full")
+            if getattr(self.config, "scale_embeddings", False):
+                h = h * jnp.asarray(self.config.hidden_size**0.5, h.dtype)
 
         def body(carry, scanned):
             return self._layer(carry, scanned, block_tables, q_positions, kv_len_mask,
@@ -321,18 +334,21 @@ class PagedInferenceModel:
             new_pool = PagedKVPool(kv=new_pool)
         else:
             new_pool = PagedKVPool(kv=new_pool[0], scale=new_pool[1])
-        h = _rms(h, m["norm"]["scale"], self.eps)
-        last = h if last_pos is None else h[jnp.arange(h.shape[0]), last_pos]
-        if "lm_head" in params:
-            logits = last @ params["lm_head"]["kernel"].astype(self.dtype)
-        else:
-            logits = last @ embed.T.astype(self.dtype)
+        with jax.named_scope("final_norm"):
+            h = _rms(h, m["norm"]["scale"], self.eps)
+        with jax.named_scope("lm_head"):
+            last = h if last_pos is None else h[jnp.arange(h.shape[0]), last_pos]
+            if "lm_head" in params:
+                logits = last @ params["lm_head"]["kernel"].astype(self.dtype)
+            else:
+                logits = last @ embed.T.astype(self.dtype)
         # logits stay in compute dtype: every consumer either casts to fp32
         # itself (sample_tokens) or explicitly opts out of the cast (greedy
         # verify reads only the argmax, sparing the [B, T, V] fp32 buffer).
         # Sharded layouts leave them vocab-sharded here; the gather to the
         # replicated sampler happens once at this anchor.
-        return self._hint(logits, "full"), new_pool
+            logits = self._hint(logits, "full")
+        return logits, new_pool
 
     # ------------------------------------------------------------------ entry points
     def _prefill_impl(self, params, pool, input_ids, block_tables, suffix_lens,
@@ -364,13 +380,16 @@ class PagedInferenceModel:
             q_lens=suffix_lens, lora=lora, adapter_idx=adapter_idx,
         )
         V = cached_counts.shape[-1]
-        valid = (jnp.arange(T)[None, :] < suffix_lens[:, None]).astype(jnp.int32)
-        # out-of-vocab ids one_hot to zero rows — same degrade as the old
-        # full-prompt device count
-        counts = cached_counts + (jax.nn.one_hot(input_ids, V, dtype=jnp.int32)
-                                  * valid[..., None]).sum(axis=1)
-        tokens = sample_tokens(logits, positions=total_lens, counts=counts, **samp)
-        counts = counts + jax.nn.one_hot(tokens, V, dtype=jnp.int32)
+        with jax.named_scope("bookkeeping"):
+            valid = (jnp.arange(T)[None, :] < suffix_lens[:, None]).astype(jnp.int32)
+            # out-of-vocab ids one_hot to zero rows — same degrade as the old
+            # full-prompt device count
+            counts = cached_counts + (jax.nn.one_hot(input_ids, V, dtype=jnp.int32)
+                                      * valid[..., None]).sum(axis=1)
+        with jax.named_scope("sample"):
+            tokens = sample_tokens(logits, positions=total_lens, counts=counts, **samp)
+        with jax.named_scope("bookkeeping"):
+            counts = counts + jax.nn.one_hot(tokens, V, dtype=jnp.int32)
         return tokens, counts, new_pool
 
     def _mixed_impl(self, params, pool, input_ids, block_tables, q_lens, q_start,
@@ -411,12 +430,15 @@ class PagedInferenceModel:
             lora=lora, adapter_idx=adapter_idx,
         )
         V = counts.shape[-1]
-        valid = (jnp.arange(T)[None, :] < q_lens[:, None]).astype(jnp.int32)
-        fed = (jax.nn.one_hot(input_ids, V, dtype=jnp.int32) * valid[..., None]).sum(axis=1)
-        counts = counts + fed * count_fed.astype(jnp.int32)[:, None]
-        tokens = sample_tokens(logits, positions=q_start + q_lens, counts=counts, **samp)
-        counts = counts + jax.nn.one_hot(tokens, V, dtype=jnp.int32) \
-            * emit.astype(jnp.int32)[:, None]
+        with jax.named_scope("bookkeeping"):
+            valid = (jnp.arange(T)[None, :] < q_lens[:, None]).astype(jnp.int32)
+            fed = (jax.nn.one_hot(input_ids, V, dtype=jnp.int32) * valid[..., None]).sum(axis=1)
+            counts = counts + fed * count_fed.astype(jnp.int32)[:, None]
+        with jax.named_scope("sample"):
+            tokens = sample_tokens(logits, positions=q_start + q_lens, counts=counts, **samp)
+        with jax.named_scope("bookkeeping"):
+            counts = counts + jax.nn.one_hot(tokens, V, dtype=jnp.int32) \
+                * emit.astype(jnp.int32)[:, None]
         return tokens, counts, new_pool
 
     def _mixed_flat_impl(self, params, pool, chunk_ids, chunk_tables, chunk_qlens,
@@ -461,16 +483,19 @@ class PagedInferenceModel:
             lora=lora, adapter_idx=dec_adapter,
         )
         V = counts.shape[-1]
-        valid = (jnp.arange(T)[None, :] < chunk_qlens[:, None]).astype(jnp.int32)
-        fed = (jax.nn.one_hot(chunk_ids, V, dtype=jnp.int32) * valid[..., None]).sum(axis=1)
-        counts = counts.at[chunk_slots].add(fed)
-        rows = jnp.concatenate([chunk_slots, dec_slots])
-        logits_all = jnp.concatenate([logits_c, logits_d], axis=0)
-        pos_all = jnp.concatenate([chunk_start + chunk_qlens, dec_start + 1])
-        tokens = sample_tokens(logits_all, positions=pos_all, counts=counts[rows], **samp)
-        emit_all = jnp.concatenate([chunk_emit, dec_live]).astype(jnp.int32)
-        counts = counts.at[rows].add(
-            jax.nn.one_hot(tokens, V, dtype=jnp.int32) * emit_all[:, None])
+        with jax.named_scope("bookkeeping"):
+            valid = (jnp.arange(T)[None, :] < chunk_qlens[:, None]).astype(jnp.int32)
+            fed = (jax.nn.one_hot(chunk_ids, V, dtype=jnp.int32) * valid[..., None]).sum(axis=1)
+            counts = counts.at[chunk_slots].add(fed)
+            rows = jnp.concatenate([chunk_slots, dec_slots])
+            logits_all = jnp.concatenate([logits_c, logits_d], axis=0)
+            pos_all = jnp.concatenate([chunk_start + chunk_qlens, dec_start + 1])
+        with jax.named_scope("sample"):
+            tokens = sample_tokens(logits_all, positions=pos_all, counts=counts[rows], **samp)
+        with jax.named_scope("bookkeeping"):
+            emit_all = jnp.concatenate([chunk_emit, dec_live]).astype(jnp.int32)
+            counts = counts.at[rows].add(
+                jax.nn.one_hot(tokens, V, dtype=jnp.int32) * emit_all[:, None])
         return tokens, counts, pool
 
     def _decode_impl(self, params, pool, tokens, block_tables, context_lens, done0,
@@ -495,15 +520,17 @@ class PagedInferenceModel:
                 kv_mask, ctx, jnp.zeros((B,), jnp.int32),
                 lora=lora, adapter_idx=adapter_idx,
             )
-            nxt = sample_tokens(logits, positions=ctx + 1, counts=counts, **samp)
-            emit = ~done
-            hit_eos = (nxt[:, None] == eos[None, :]).any(axis=-1)
-            newly_done = emit & (hit_eos | (n_out + 1 >= remaining))
-            nxt = jnp.where(done, tok, nxt)
-            counts = counts + jax.nn.one_hot(nxt, counts.shape[-1], dtype=counts.dtype) * emit[:, None]
-            ctx = jnp.where(done, ctx, ctx + 1)
-            n_out = n_out + emit
-            done = done | newly_done
+            with jax.named_scope("sample"):
+                nxt = sample_tokens(logits, positions=ctx + 1, counts=counts, **samp)
+            with jax.named_scope("bookkeeping"):
+                emit = ~done
+                hit_eos = (nxt[:, None] == eos[None, :]).any(axis=-1)
+                newly_done = emit & (hit_eos | (n_out + 1 >= remaining))
+                nxt = jnp.where(done, tok, nxt)
+                counts = counts + jax.nn.one_hot(nxt, counts.shape[-1], dtype=counts.dtype) * emit[:, None]
+                ctx = jnp.where(done, ctx, ctx + 1)
+                n_out = n_out + emit
+                done = done | newly_done
             return (pool_c, nxt, ctx, done, counts, n_out), (nxt, emit)
 
         init = (pool, tokens, context_lens, done0, counts,
@@ -542,7 +569,8 @@ class PagedInferenceModel:
             params, pool, tokens, block_tables, positions, kv_len_mask,
             start_pos, last_pos=None, lora=lora, adapter_idx=adapter_idx,
         )
-        argmax = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            argmax = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         if not need_logits:
             return argmax, None, new_pool
         return argmax, logits.astype(jnp.float32), new_pool

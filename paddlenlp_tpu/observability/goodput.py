@@ -65,6 +65,7 @@ from ..utils.env import device_peak_flops  # the one peak table; re-exported her
 
 __all__ = [
     "GoodputLedger",
+    "LAUNCH_GEOMETRY",
     "WASTE_KINDS",
     "REWORK_KINDS",
     "compile_attribution",
@@ -81,6 +82,17 @@ WASTE_KINDS = ("padding", "spec_rejected", "rework")
 #: rework sub-kinds (``/debug/efficiency`` detail; the metric folds them all
 #: under ``kind="rework"``)
 REWORK_KINDS = ("preempt_refill", "requeue_refill", "cow_token", "migration_reseed")
+
+#: what every launch records of what it was asked to do, beside ``fed``: live
+#: and padded rows, and the KV positions the attention had to read (a decode
+#: launch: the sum over sub-steps and rows still emitting of context + 1; a
+#: prefill or mixed launch: cached + fed tokens a row; counted on the host
+#: from what the launch returned). Backends stamp them into
+#: ``step_accounting``; they ride as args of the launch span and as monotone
+#: per-program totals in ``by_kind`` (``/debug/efficiency``): batch occupancy
+#: is rows_live / rows, and the bytes a paged-attention kernel has to read
+#: follow from kv_positions
+LAUNCH_GEOMETRY = ("rows_live", "rows", "kv_positions")
 
 #: step-program vocabulary the ledger accounts by (also the ``{program}``
 #: label of the serving compile counters)
@@ -106,9 +118,11 @@ class GoodputLedger:
         self.padding_by: Dict[str, int] = {k: 0 for k in STEP_KINDS}
         #: rework decomposed by cause
         self.rework_by: Dict[str, int] = {k: 0 for k in REWORK_KINDS}
-        #: per-program (kind -> [steps, fed]) launch accounting
+        #: per-program launch accounting: steps, fed, useful and the
+        #: LAUNCH_GEOMETRY totals
         self.by_kind: Dict[str, Dict[str, int]] = {
-            k: {"steps": 0, "fed": 0, "useful": 0} for k in STEP_KINDS}
+            k: dict({"steps": 0, "fed": 0, "useful": 0},
+                    **{g: 0 for g in LAUNCH_GEOMETRY}) for k in STEP_KINDS}
         #: per-program compile telemetry (jax.monitoring backend_compile)
         self.compiles: Dict[str, int] = {}
         self.compile_seconds: Dict[str, float] = {}
@@ -128,11 +142,14 @@ class GoodputLedger:
     # ------------------------------------------------------------- recording
     def record(self, kind: str, fed: int, useful: int, padding: int = 0,
                spec_rejected: int = 0, rework: int = 0,
-               rework_by: Optional[Dict[str, int]] = None):
+               rework_by: Optional[Dict[str, int]] = None,
+               geometry: Optional[Dict[str, int]] = None):
         """Account one device launch. Raises ``ValueError`` when the
         decomposition breaks conservation or goes negative — the invariant is
         enforced at record time, so an accounting bug is a loud step failure
-        the supervisor surfaces, never silent ledger drift."""
+        the supervisor surfaces, never silent ledger drift. ``geometry`` is
+        the launch's ``step_accounting``: its LAUNCH_GEOMETRY counts add to
+        the program's totals and take no part in the conservation."""
         if kind not in self.by_kind:
             raise ValueError(f"unknown step kind {kind!r} (want one of {STEP_KINDS})")
         parts = {"fed": fed, "useful": useful, "padding": padding,
@@ -166,6 +183,9 @@ class GoodputLedger:
         bk["steps"] += 1
         bk["fed"] += fed
         bk["useful"] += useful
+        if geometry:
+            for g in LAUNCH_GEOMETRY:
+                bk[g] += geometry[g]
         now = time.time()
         if self._first_record_t is None:
             self._first_record_t = now

@@ -32,13 +32,23 @@ after parsing the propagated traceparent header (:func:`parse_traceparent`).
 Trace-less spans (batch-level engine phases, trainer steps) are never sampled
 out.
 
+Profiler mirror: :meth:`SpanTracer.mirror_spans` installs ONE optional hook, a
+context-manager factory ``factory(name, **args)``. A *live* ``span(...)`` of a
+mirrored category then also enters that context manager for its duration, so
+one call site records the phase on both clocks: the engine installs
+``jax.profiler.TraceAnnotation`` for the engine and engine-loop categories
+(this module stays stdlib-only), which is a no-op TraceMe while no capture
+runs. Any mirrored span present in both records gives the offset between the
+tracer's timeline and the profiler's, so retrospective spans can be laid on
+the device timeline too. Retrospective ``add_span`` records are never mirrored.
+
 **Concurrency model.** Every public method may be called from any thread.
 The ring (``_buf``), the drop counter and the sampling-mark table are guarded
 by ``_lock`` (``# guarded-by:`` annotations, enforced by the
 ``tools/analyze`` lock-discipline checker); the one deliberate unguarded read
 (`trace_is_sampled`'s mark probe) is marked ``# lock-ok`` with its rationale.
 ``capacity``/``enabled``/``sample_every``/``_epoch0`` are set once at
-construction and read-only after.
+construction and read-only after, as is the profiler mirror once installed.
 """
 
 from __future__ import annotations
@@ -148,35 +158,54 @@ class Span:
 
 class _SpanCtx:
     """Context manager handed out by :meth:`SpanTracer.span`; records on exit.
-    ``set(key=value)`` attaches args discovered mid-span (e.g. tokens emitted)."""
+    ``set(key=value)`` attaches args discovered mid-span (e.g. tokens emitted);
+    ``discard()`` keeps an uneventful span out of the ring (its profiler mirror,
+    if any, still brackets the time); ``dur`` holds the measured seconds after
+    exit, so a caller that needs the duration reads the span's own."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_trace", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_trace", "_args", "_t0", "_mirror",
+                 "_keep", "dur")
 
     def __init__(self, tracer: "SpanTracer", name: str, cat: str,
-                 trace: Optional[str], args: Optional[Dict]):
+                 trace: Optional[str], args: Optional[Dict], mirror=None):
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._trace = trace
         self._args = args
         self._t0 = 0.0
+        self._mirror = mirror
+        self._keep = True
+        self.dur = 0.0
 
     def set(self, **kw):
         if self._args is None:
             self._args = {}
         self._args.update(kw)
+        annotate = getattr(self._mirror, "set_metadata", None)
+        if annotate is not None:
+            annotate(**kw)
+        return self
+
+    def discard(self):
+        self._keep = False
         return self
 
     def __enter__(self):
+        if self._mirror is not None:
+            self._mirror.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur = time.perf_counter() - self._t0
+        self.dur = time.perf_counter() - self._t0
+        if self._mirror is not None:
+            self._mirror.__exit__(exc_type, exc, tb)
         if exc_type is not None:
-            self.set(error=repr(exc)[:200])
-        self._tracer._record(self._name, self._cat, self._tracer._to_epoch(self._t0),
-                             dur, self._trace, self._args)
+            self._args = dict(self._args or {}, error=repr(exc)[:200])
+        if self._keep or exc_type is not None:
+            self._tracer._record(self._name, self._cat, self._tracer._to_epoch(self._t0),
+                                 self.dur, self._trace, self._args)
         return False
 
 
@@ -184,8 +213,12 @@ class _NullCtx:
     """No-op span for a disabled tracer (keeps call sites unconditional)."""
 
     __slots__ = ()
+    dur = 0.0  # nothing was measured
 
     def set(self, **kw):
+        return self
+
+    def discard(self):
         return self
 
     def __enter__(self):
@@ -221,6 +254,17 @@ class SpanTracer:
         # anchor perf_counter to the epoch once so spans from all threads share
         # one monotonic-but-absolute timeline (time.time() can step backwards)
         self._epoch0 = time.time() - time.perf_counter()
+        # the profiler mirror (see the module docstring): installed at most
+        # once, before traffic, and read-only after
+        self._mirror = None
+        self._mirror_cats: frozenset = frozenset()
+
+    def mirror_spans(self, factory, cats: Sequence[str]):
+        """Install the profiler mirror: live spans whose ``cat`` is in ``cats``
+        also enter ``factory(name, **args)`` for their duration. ``set()`` args
+        reach the mirror through its ``set_metadata(**kw)`` where it has one."""
+        self._mirror = factory
+        self._mirror_cats = frozenset(cats)
 
     def _to_epoch(self, perf_t: float) -> float:
         return self._epoch0 + perf_t
@@ -264,7 +308,8 @@ class SpanTracer:
         t = trace if trace is not None else current_trace()
         if not self.trace_is_sampled(t):
             return _NULL
-        return _SpanCtx(self, name, cat, t, args or None)
+        mirror = self._mirror(name, **args) if cat in self._mirror_cats else None
+        return _SpanCtx(self, name, cat, t, args or None, mirror)
 
     def instant(self, name: str, cat: str = "", trace: Optional[str] = None, **args):
         """Zero-duration marker (preemption, eviction, window edges)."""

@@ -55,7 +55,6 @@ import time
 from typing import Callable, Optional
 
 from ..observability.flight_recorder import RECORDER
-from ..observability.tracer import TRACER
 from ..utils.log import logger
 
 __all__ = ["BrownoutController", "BrownoutPolicy", "PRIORITIES",
@@ -257,8 +256,6 @@ class BrownoutController:
         else:
             RECORDER.record("brownout.step", prev=before, level=after,
                             direction="up" if after > before else "down")
-        TRACER.instant("brownout", cat="scheduler", prev=before, level=after,
-                       reason=reason)
         logger.warning(
             f"brownout: {BROWNOUT_LEVELS[before]} -> {BROWNOUT_LEVELS[after]} "
             f"({reason})")

@@ -73,14 +73,16 @@ __all__ = ["EngineLoop", "RequestHandle", "ServingMetrics", "SupervisorPolicy",
            "CANARY_PROMPT_IDS"]
 
 #: the per-request latency-attribution phase vocabulary. Non-overlapping by
-#: construction: queue + admission_gate span arrival -> first admission, the
-#: admission -> first-token window splits into promote_wait (waiting on a
+#: construction: inbox (submission on the HTTP thread -> the loop thread put it
+#: on the engine's waiting queue: the time on the command inbox while the loop
+#: was inside a step) + queue + admission_gate span submission -> first
+#: admission, the admission -> first-token window splits into promote_wait (waiting on a
 #: host-tier KV promotion copy) + prefill remainder, and the decode window
 #: (first token -> finish) splits into chunk_stall + migration_wait + decode
 #: remainder — so the phases always sum to e2e exactly when the timeline is
 #: complete. The router adds an eighth phase, ``hedge_race``, to the same
 #: histogram family for its first-token races.
-ATTRIBUTION_PHASES = ("queue", "admission_gate", "promote_wait", "prefill",
+ATTRIBUTION_PHASES = ("inbox", "queue", "admission_gate", "promote_wait", "prefill",
                       "chunk_stall", "migration_wait", "decode")
 
 
@@ -98,14 +100,21 @@ def request_attribution(req) -> Optional[Dict[str, float]]:
     gated = getattr(req, "gated_t", None)
     out = {p: 0.0 for p in ATTRIBUTION_PHASES}
     end_queue = sched if sched is not None else finish
-    if sched is not None and gated is not None and arrival <= gated <= sched:
+    enqueued = getattr(req, "enqueued_t", None)
+    if enqueued is not None and arrival <= enqueued <= end_queue:
+        # the loop thread took the submission in only after the step that was
+        # running when it came: that wait is the loop's, not the queue's
+        out["inbox"] = enqueued - arrival
+    else:
+        enqueued = arrival
+    if sched is not None and gated is not None and enqueued <= gated <= sched:
         # the engine marked the moment the request hit an admission gate at
         # the head of the queue: waiting *behind* others vs waiting *on a
         # gate* are different operator actions (scale out vs retune gates)
-        out["queue"] = gated - arrival
+        out["queue"] = gated - enqueued
         out["admission_gate"] = sched - gated
     else:
-        out["queue"] = max(end_queue - arrival, 0.0)
+        out["queue"] = max(end_queue - enqueued, 0.0)
     if sched is not None:
         end_prefill = first if first is not None else finish
         prefill_raw = max(end_prefill - sched, 0.0)
@@ -119,7 +128,10 @@ def request_attribution(req) -> Optional[Dict[str, float]]:
         out["promote_wait"] = promote
         out["prefill"] = prefill_raw - promote
     if first is not None:
-        decode_raw = max(finish - first, 0.0)
+        # a request requeued across an engine rebuild keeps its first token's
+        # instant, which then predates its last admission: that stretch is in
+        # the queue phase already, so its decode window opens at the admission
+        decode_raw = max(finish - max(first, sched if sched is not None else first), 0.0)
         stall = min(max(getattr(req, "chunk_stall_s", 0.0), 0.0), decode_raw)
         mig = max(getattr(req, "migration_wait_s", 0.0), 0.0)
         open_mig = getattr(req, "migrate_start_t", None)
@@ -253,6 +265,7 @@ class _FailedRequest:
         self.done = True
         self.finish_reason = finish_reason
         self.arrival_t = arrival_t
+        self.enqueued_t = None
         self.sched_t = None
         self.first_token_t = None
         self.finish_t = time.time()
@@ -276,7 +289,13 @@ class RequestHandle:
         self.adapter_id = adapter_id  # LoRA adapter this request decodes with
         self.depth_at_submit = 0  # engine backlog when submitted (queue-wait norm)
         self.deadline_t = deadline_t
+        # the request clock starts here, on the submitting (HTTP) thread; the
+        # loop thread stamps enqueued_t when it first hands the request to the
+        # engine, and inbox_step, the number of the engine step it found
+        # finished then (the launch the request waited out, if it waited)
         self.submitted_t = time.time()
+        self.enqueued_t: Optional[float] = None
+        self.inbox_step: Optional[int] = None
         self.timed_out = False
         self.max_retries = max_retries  # None = supervisor policy default
         self.retries = 0  # engine rebuilds this request rode through
@@ -415,18 +434,18 @@ class ServingMetrics:
             "best-effort, 2 conserve, 3 clamp max_tokens)")
         self.latency_attribution = r.histogram(
             "paddlenlp_serving_latency_attribution_seconds",
-            "Per-request e2e latency decomposed by phase (queue/"
+            "Per-request e2e latency decomposed by phase (inbox/queue/"
             "admission_gate/promote_wait/prefill/chunk_stall/migration_wait/"
             "decode on replicas; hedge_race on the router) — phases sum to e2e",
             labelnames=("phase",))
         self.ttft = r.histogram(
-            "paddlenlp_serving_ttft_seconds", "Time from arrival to first token")
+            "paddlenlp_serving_ttft_seconds", "Time from submission to first token")
         self.queue_wait = r.histogram(
-            "paddlenlp_serving_queue_wait_seconds", "Time from arrival to slot admission")
+            "paddlenlp_serving_queue_wait_seconds", "Time from submission to slot admission")
         self.inter_token = r.histogram(
             "paddlenlp_serving_inter_token_seconds", "Latency between consecutive tokens")
         self.e2e = r.histogram(
-            "paddlenlp_serving_e2e_seconds", "Time from arrival to completion")
+            "paddlenlp_serving_e2e_seconds", "Time from submission to completion")
         self.queue_depth = r.gauge(
             "paddlenlp_serving_queue_depth", "Requests waiting for a slot")
         self.running = r.gauge(
@@ -810,6 +829,10 @@ class EngineLoop:
         self._started = False
         self._state = "stopped"  # stopped | running | degraded
         self._phase = "init"  # last loop phase (join-failure diagnostics)
+        # loop-phase spans (loop-thread only): whether the last engine step
+        # launched anything, and when the open idle episode began
+        self._step_launched = False
+        self._idle_t0: Optional[float] = None
         self._consecutive_failures = 0
         self._last_failure_t = 0.0
         # slot-level quarantine accounting (loop-thread only, like the above):
@@ -1015,10 +1038,21 @@ class EngineLoop:
             self._shutdown_cleanup()
 
     def _run_iteration(self):
-        self._phase = "drain_cmds"
-        self._drain_cmds()
-        self._phase = "deadlines"
-        self._enforce_deadlines()
+        # loop phases are spans only around steps that launch: an idle or
+        # re-polling loop must not flood the ring with empty iterations
+        busy = self._step_launched or not self._cmds.empty()
+        if busy and self._idle_t0 is not None:
+            # one retrospective span an idle episode, closed when work comes
+            TRACER.add_span("loop_idle", TRACER.epoch_time(self._idle_t0),
+                            time.perf_counter() - self._idle_t0, cat="engine_loop")
+            self._idle_t0 = None
+        with TRACER.span("loop_intake", cat="engine_loop") as intake:
+            if not busy:
+                intake.discard()
+            self._phase = "drain_cmds"
+            self._drain_cmds()
+            self._phase = "deadlines"
+            self._enforce_deadlines()
         if self._pending_swap is not None:
             swap = self._pending_swap
             # finish_old waits for the engine to run dry at a step boundary
@@ -1031,12 +1065,20 @@ class EngineLoop:
         if self.engine.has_work():
             self._phase = "step"
             stats_before = self.engine.num_preemptions
-            for req in self.engine.step():
-                self._finish(req)
-            self.metrics.on_step(
-                self.engine.stats(), self.engine.num_preemptions - stats_before)
+            finished = self.engine.step()
+            self._step_launched = self.engine.last_step_device_s > 0
+            with TRACER.span("loop_finish", cat="engine_loop", finished=len(finished)) as finish:
+                if not (self._step_launched or finished):
+                    finish.discard()
+                for req in finished:
+                    self._finish(req)
+                self.metrics.on_step(
+                    self.engine.stats(), self.engine.num_preemptions - stats_before)
         else:
             self._phase = "idle"
+            self._step_launched = False
+            if self._idle_t0 is None:
+                self._idle_t0 = time.perf_counter()
             self._wake.wait(timeout=self.idle_wait_s)
             self._wake.clear()
 
@@ -1060,9 +1102,6 @@ class EngineLoop:
         RECORDER.record("supervisor.degraded", error=repr(exc)[:200],
                         consecutive=self._consecutive_failures,
                         inflight=len(self._handles))
-        TRACER.instant("engine_failure", cat="engine_loop", error=repr(exc),
-                       consecutive=self._consecutive_failures,
-                       inflight=len(self._handles))
         n_failed = self._triage(exc)
         # black box: snapshot the incident AFTER triage so the bundle's
         # health/events already reflect the dispositions (rate-limited;
@@ -1270,6 +1309,7 @@ class EngineLoop:
                              finish_reason=finish_reason, tenant=handle.tenant,
                              adapter_id=handle.adapter_id)
         req.aborted = finish_reason == "abort"
+        req.enqueued_t = handle.enqueued_t
         req.priority = handle.priority  # requests_total{priority} label
         if handle._first_token_t is not None:
             req.first_token_t = handle._first_token_t
@@ -1499,8 +1539,13 @@ class EngineLoop:
         """One engine submission. ``priority`` / ``rework_hwm`` / ``tenant`` /
         ``adapter_id`` are forwarded only when non-default so engine stand-ins
         (chaos-test stubs, older backends) with the narrower ``add_request``
-        signature keep working."""
-        kw = {}
+        signature keep working. ``arrival_t`` is the handle's ``submitted_t``
+        on every path, a requeue after a rebuild included: queue wait, TTFT
+        and e2e are then what the client waited."""
+        kw = {"arrival_t": handle.submitted_t}
+        if handle.enqueued_t is None:
+            handle.enqueued_t = time.time()
+            handle.inbox_step = self.engine.cur_step
         if handle.priority != "interactive":
             kw["priority"] = handle.priority
         if rework_hwm > 0:
@@ -1591,9 +1636,12 @@ class EngineLoop:
             # not report a fresh fast request)
             req.output_ids = list(handle._retry_prefix) + list(req.output_ids)
             req.prompt_ids = req.prompt_ids[: handle.prompt_len]
-            req.arrival_t = handle.submitted_t
             if handle._first_token_t is not None:
                 req.first_token_t = handle._first_token_t
+        if handle is not None and handle.enqueued_t is not None:
+            # the FIRST time the loop took the request in: a requeue re-adds
+            # it, and the inbox phase must not swallow the pre-crash stint
+            req.enqueued_t = handle.enqueued_t
         self.metrics.on_finished(req)
         self._last_token_t.pop(req.req_id, None)
         self._trace_finished(req, handle)
@@ -1602,13 +1650,22 @@ class EngineLoop:
 
     def _trace_finished(self, req, handle: Optional[RequestHandle]):
         """Retrospective per-request phase spans (the engine's timing fields
-        become a queue → prefill → decode timeline under the request's trace)
-        plus a summary row for /debug/requests."""
+        become an inbox → queue → prefill → decode timeline under the
+        request's trace) plus a summary row for /debug/requests."""
         trace = handle.trace if handle is not None else getattr(req, "trace", None)
         phases = {}
         meta = dict(req_id=req.req_id, prompt_len=len(req.prompt_ids))
+        enqueued = getattr(req, "enqueued_t", None)
+        if enqueued is None or not req.arrival_t <= enqueued:
+            enqueued = req.arrival_t
+        elif handle is not None:
+            # step= names what caused the wait: the engine step whose launch
+            # was running while the request sat on the loop's inbox
+            TRACER.add_span("inbox", req.arrival_t, enqueued - req.arrival_t,
+                            cat="request", trace=trace, wall=True,
+                            step=handle.inbox_step, **meta)
         if req.sched_t is not None:
-            phases["queue"] = (req.arrival_t, req.sched_t)
+            phases["queue"] = (enqueued, req.sched_t)
         if req.sched_t is not None and req.first_token_t is not None:
             phases["prefill"] = (req.sched_t, req.first_token_t)
         if req.first_token_t is not None and req.finish_t is not None:

@@ -279,7 +279,6 @@ class ReplicaPool:
             self._removed.pop(rid, None)
         if self.metrics is not None:
             self.metrics.replica_healthy.set(1.0, replica=rid)
-        self.tracer.instant("membership", cat="router", op="add", replica=rid)
         return replica
 
     def start_drain(self, replica_id: str, deadline_s: float = 30.0) -> Dict:
@@ -300,8 +299,6 @@ class ReplicaPool:
             replica.drain_expired_notified = False
         logger.warning(f"router: replica {replica_id} draining "
                        f"(deadline {deadline_s:.1f}s)")
-        self.tracer.instant("membership", cat="router", op="drain",
-                            replica=replica_id, deadline_s=deadline_s)
         return self.drain_status(replica_id)
 
     def cancel_drain(self, replica_id: str) -> Dict:
@@ -319,8 +316,6 @@ class ReplicaPool:
             replica.drain_deadline_t = None
             replica.drain_expired_notified = False
         logger.warning(f"router: replica {replica_id} drain cancelled (rejoining)")
-        self.tracer.instant("membership", cat="router", op="undrain",
-                            replica=replica_id)
         return self.drain_status(replica_id)
 
     def remove(self, replica_id: str, force: bool = False) -> Dict:
@@ -356,8 +351,6 @@ class ReplicaPool:
             self.metrics.replica_healthy.remove_series(replica=replica_id)
         logger.warning(f"router: replica {replica_id} removed from the pool"
                        + (" (forced)" if force else ""))
-        self.tracer.instant("membership", cat="router", op="remove",
-                            replica=replica_id, forced=force)
         return dict(tomb)
 
     def removed(self) -> List[Dict]:
@@ -482,8 +475,6 @@ class ReplicaPool:
                     replica.drained = True
                 logger.warning(f"router: replica {replica.id} drained "
                                "(no live streams); safe to remove")
-                self.tracer.instant("membership", cat="router", op="drained",
-                                    replica=replica.id)
             elif (replica.drain_deadline_t is not None
                   and now >= replica.drain_deadline_t):
                 # check-and-set under the pool lock: poll_once may be driven
@@ -496,8 +487,6 @@ class ReplicaPool:
                 logger.warning(
                     f"router: drain of {replica.id} outlived its deadline with "
                     f"{live} live stream(s); failing stuck streams over")
-                self.tracer.instant("membership", cat="router", op="drain_expired",
-                                    replica=replica.id, live=live)
                 if self.on_drain_deadline is not None:
                     try:
                         self.on_drain_deadline(replica.id)
@@ -631,8 +620,6 @@ class ReplicaPool:
         if new != prev:
             logger.warning(f"router: replica {replica.id} {prev} -> {new}"
                            + (f" ({result.error})" if result.error else ""))
-            self.tracer.instant("replica_state", cat="router", replica=replica.id,
-                                prev=prev, state=new, error=result.error)
 
     # ------------------------------------------------------------- proxy feedback
     def note_forward_failure(self, replica_id: str):

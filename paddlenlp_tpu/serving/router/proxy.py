@@ -517,8 +517,6 @@ class RouterServer:
                         daemon=True, name=f"drain-abort-{st.rid}").start()
             evicted += 1
             RECORDER.record("router.drain_evict", trace=st.rid, replica=replica_id)
-            self.tracer.instant("membership", cat="router", op="drain_evict",
-                                trace=st.rid, replica=replica_id)
         if evicted:
             # a drain that had to break streams is an incident worth a black
             # box (rate-limited; opt-in via PDNLP_TPU_POSTMORTEM_DIR)

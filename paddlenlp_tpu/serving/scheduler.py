@@ -153,15 +153,12 @@ class Scheduler:
         if self._draining or not self.loop.running:
             self.rejected_draining += 1
             RECORDER.record("sched.reject", trace=trace, reason="draining")
-            TRACER.instant("admission_rejected", cat="scheduler", reason="draining")
             raise ShuttingDownError("server is draining; retry against another replica")
         if self.loop.degraded:
             self.rejected_degraded += 1
             retry_after = self.loop.retry_after_hint()
             RECORDER.record("sched.reject", trace=trace, reason="degraded",
                             retry_after_s=retry_after)
-            TRACER.instant("admission_rejected", cat="scheduler", reason="degraded",
-                           retry_after_s=retry_after)
             raise DegradedError(
                 "engine is recovering from a failure; retry shortly",
                 retry_after_s=retry_after)
@@ -218,8 +215,6 @@ class Scheduler:
             retry_after = self.loop.queue_wait_estimate()
             RECORDER.record("sched.reject", trace=trace, reason="shed",
                             level=level)
-            TRACER.instant("admission_rejected", cat="scheduler", reason="shed",
-                           level=level)
             raise ShedError(
                 f"replica browned out (level {level}); {priority} traffic is "
                 "being shed — retry later or elsewhere",
@@ -232,8 +227,6 @@ class Scheduler:
                                            tenant=tenant)
                 RECORDER.record("sched.reject", trace=trace, reason="deadline",
                                 estimate_s=round(estimate, 4))
-                TRACER.instant("admission_rejected", cat="scheduler",
-                               reason="deadline", estimate_s=estimate)
                 raise DeadlineUnmetError(
                     f"queue-wait estimate {estimate:.3f}s already exceeds the "
                     f"{deadline_s:.3f}s deadline; rejecting on arrival",
@@ -255,8 +248,6 @@ class Scheduler:
                 retry_after = self.loop.queue_wait_estimate()
                 RECORDER.record("sched.reject", trace=trace, reason="saturated",
                                 inflight=self._inflight)
-                TRACER.instant("admission_rejected", cat="scheduler", reason="saturated",
-                               inflight=self._inflight)
                 raise SaturatedError(
                     f"in-flight window full ({self._inflight}/{cfg.max_inflight}); retry later",
                     retry_after_s=retry_after)
@@ -271,8 +262,6 @@ class Scheduler:
                 retry_after = self.loop.queue_wait_estimate()
                 RECORDER.record("sched.reject", trace=trace, reason="tenant_quota",
                                 tenant=tenant, inflight=self._tenant_inflight.get(tenant, 0))
-                TRACER.instant("admission_rejected", cat="scheduler",
-                               reason="tenant_quota", tenant=tenant)
                 raise TenantQuotaError(
                     f"tenant {tenant!r} at its max_inflight quota "
                     f"({self._tenant_inflight.get(tenant, 0)}/{tcap}); retry later",
@@ -371,7 +360,6 @@ class Scheduler:
         immediately while accepted streams keep finishing."""
         with self._lock:
             self._draining = True
-        TRACER.instant("membership", cat="scheduler", op="drain_direct")
 
     def stop_drain(self):
         """Undo :meth:`start_drain`: resume admitting new work. The rejoin
@@ -380,7 +368,6 @@ class Scheduler:
         process restart."""
         with self._lock:
             self._draining = False
-        TRACER.instant("membership", cat="scheduler", op="undrain_direct")
 
     def drain(self, timeout_s: Optional[float] = 30.0) -> bool:
         """Stop admitting; wait for in-flight work. Returns True if empty."""

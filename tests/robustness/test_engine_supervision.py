@@ -57,7 +57,7 @@ class StubMgr:
 
 
 class StubRequest:
-    def __init__(self, req_id, prompt_ids, sampling, stream_cb, trace):
+    def __init__(self, req_id, prompt_ids, sampling, stream_cb, trace, arrival_t=None):
         self.req_id = req_id
         self.prompt_ids = list(prompt_ids)
         self.sampling = sampling or Sampling()
@@ -67,7 +67,8 @@ class StubRequest:
         self.done = False
         self.aborted = False
         self.finish_reason = None
-        self.arrival_t = time.time()
+        self.enqueued_t = time.time()
+        self.arrival_t = self.enqueued_t if arrival_t is None else arrival_t
         self.sched_t = None
         self.first_token_t = None
         self.finish_t = None
@@ -97,8 +98,11 @@ class StubEngine:
         self._ids = iter(range(10_000))
 
     # ----------------------------------------------------------- engine api
-    def add_request(self, prompt_ids, sampling=None, stream_cb=None, trace=None):
-        req = StubRequest(next(self._ids), prompt_ids, sampling, stream_cb, trace)
+    cur_step = property(lambda self: self.step_count)
+    last_step_device_s = 1e-3  # every stub step launches
+
+    def add_request(self, prompt_ids, sampling=None, stream_cb=None, trace=None, arrival_t=None):
+        req = StubRequest(next(self._ids), prompt_ids, sampling, stream_cb, trace, arrival_t)
         self.mgr.lengths[req.req_id] = len(req.prompt_ids)
         self.waiting.append(req)
         return req.req_id

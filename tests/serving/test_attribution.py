@@ -88,7 +88,7 @@ class TestAttributionParity:
             e2e = row["finish_t"] - row["arrival_t"]
             assert abs(sum(attr.values()) - e2e) <= 0.05 * e2e + 1e-6, (attr, e2e)
             # parity with the pre-existing request timing fields
-            assert attr["queue"] + attr["admission_gate"] == \
+            assert attr["inbox"] + attr["queue"] + attr["admission_gate"] == \
                 pytest.approx(row["queue_wait_s"], rel=0.05, abs=1e-6)
             assert attr["prefill"] == \
                 pytest.approx(row["ttft_s"] - row["queue_wait_s"], rel=0.05, abs=1e-6)

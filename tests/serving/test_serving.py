@@ -73,15 +73,6 @@ class TestEngineHooks:
         assert eng.mgr.num_free == total  # KV fully reclaimed
         assert not eng.has_work()
 
-    def test_step_cb_stats(self, model):
-        eng = make_engine(model)
-        seen = []
-        eng.step_cb = seen.append
-        eng.add_request([5, 6, 7], SamplingParams(max_new_tokens=4))
-        while eng.has_work():
-            eng.step()
-        assert seen and {"queue_depth", "running", "free_blocks", "num_preemptions"} <= set(seen[0])
-
 
 # --------------------------------------------------------------------- engine loop
 class TestEngineLoop:

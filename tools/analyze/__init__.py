@@ -74,9 +74,12 @@ DEFAULT_CONFIG: Dict = {
     "host_sync_paths": {
         "paddlenlp_tpu/experimental/engine.py": [
             "InferenceEngine.step", "InferenceEngine._admit",
-            "InferenceEngine._admit_slots", "InferenceEngine._admit_chunked",
-            "InferenceEngine._mixed_step", "InferenceEngine._decode_running",
-            "InferenceEngine._decode_spec", "InferenceEngine._settle_sampled",
+            "InferenceEngine._admit_slots", "InferenceEngine._bind_waiting",
+            "InferenceEngine._admit_chunked", "InferenceEngine._prefill_batch",
+            "InferenceEngine._mixed_step", "InferenceEngine._mixed_rows",
+            "InferenceEngine._mixed_settle", "InferenceEngine._decode_running",
+            "InferenceEngine._decode_settle", "InferenceEngine._decode_spec",
+            "InferenceEngine._spec_accept", "InferenceEngine._settle_sampled",
             "InferenceEngine._advance_migrations",
             "InferenceEngine._advance_promotions",
             "InferenceEngine._drain_spills",
@@ -88,7 +91,7 @@ DEFAULT_CONFIG: Dict = {
         ],
         "paddlenlp_tpu/experimental/backend.py": [
             "ModelBackend.migration_ready", "ModelBackend.kv_writeback",
-            "SingleDeviceBackend.prefill", "SingleDeviceBackend.decode",
+            "launch_geometry", "SingleDeviceBackend.prefill", "SingleDeviceBackend.decode",
             "SingleDeviceBackend.verify", "SingleDeviceBackend.mixed_step",
             "SingleDeviceBackend.mixed_step_begin",
             "SingleDeviceBackend._mixed_padded_launch",

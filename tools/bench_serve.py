@@ -114,7 +114,7 @@ Usage::
                                                  # flight_recorder (on/off) +
                                                  # flight_events, and an
                                                  # `attribution` record with
-                                                 # per-phase p50/p99 (queue/
+                                                 # per-phase p50/p99 (inbox/queue/
                                                  # admission_gate/prefill/
                                                  # chunk_stall/migration_wait/
                                                  # decode) so a BENCH_r*
@@ -1034,7 +1034,7 @@ def run() -> None:
 
     attr_name = "paddlenlp_serving_latency_attribution_seconds"
     attribution = {}
-    for phase in ("queue", "admission_gate", "promote_wait", "prefill",
+    for phase in ("inbox", "queue", "admission_gate", "promote_wait", "prefill",
                   "chunk_stall", "migration_wait", "decode"):
         p50 = max([histogram_quantile(f[attr_name], 0.5, phase=phase)
                    for f in replica_fams if attr_name in f] or [0.0])
