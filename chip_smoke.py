@@ -333,8 +333,9 @@ def compare_logits(name, got, want):
 
 
 def kernel_alone(seed, tokens):
-    """The ragged kernel at Qwen2-1.5B's attention shape against plain fp32
-    jax.numpy attention over the gathered blocks, same bf16 inputs. Rows: a
+    """The ragged kernel at Qwen2-1.5B's attention shape, aimed at layer 1 of a
+    three-layer pool of token-major rows, against plain fp32 jax.numpy
+    attention over that layer's gathered blocks, same bf16 inputs. Rows: a
     full one, one that ends early, one dead, one at a late start. 1024 tokens
     are two query tiles of the kernel, 256 one, 1 a decode step."""
     import jax
@@ -346,18 +347,18 @@ def kernel_alone(seed, tokens):
     rows, heads, kv_heads, head_dim, blocks = 4, 12, 2, 128, MAX_BLOCKS_PER_SEQ
     bf16 = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
     q = bf16(rows, tokens, heads, head_dim)
-    pool_k = bf16(rows * blocks + 1, kv_heads, BLOCK_SIZE, head_dim)
-    pool_v = bf16(rows * blocks + 1, kv_heads, BLOCK_SIZE, head_dim)
+    layers, layer = 3, 1
+    pool = bf16(layers, 2, rows * blocks + 1, BLOCK_SIZE, kv_heads * head_dim)
     tables = jnp.asarray(1 + rng.permutation(rows * blocks).reshape(rows, blocks), jnp.int32)
     span = blocks * BLOCK_SIZE
     start = jnp.asarray([0, 37, 5, span - tokens], jnp.int32)
     lens = jnp.asarray([tokens, (tokens + 1) // 2, 0, tokens], jnp.int32)
 
-    def reference(q, pool_k, pool_v, tables, start, lens):
-        flat = lambda pool: pool[tables].transpose(0, 1, 3, 2, 4).reshape(
+    def reference(q, pool, tables, start, lens):
+        flat = lambda plane: plane[tables].reshape(
             rows, span, kv_heads, head_dim).astype(jnp.float32)
-        k = jnp.repeat(flat(pool_k), heads // kv_heads, axis=2)
-        v = jnp.repeat(flat(pool_v), heads // kv_heads, axis=2)
+        k = jnp.repeat(flat(pool[layer, 0]), heads // kv_heads, axis=2)
+        v = jnp.repeat(flat(pool[layer, 1]), heads // kv_heads, axis=2)
         s = jnp.einsum("btnh,bsnh->bnts", q.astype(jnp.float32), k,
                        precision="highest") * head_dim ** -0.5
         q_pos = start[:, None] + jnp.arange(tokens)[None, :]
@@ -367,9 +368,9 @@ def kernel_alone(seed, tokens):
         live = jnp.arange(tokens)[None, :] < lens[:, None]
         return jnp.where(live[:, :, None, None], out, 0.0)
 
-    got = np.asarray(jax.jit(ragged_paged_attention)(q, pool_k, pool_v, tables, start, lens),
+    got = np.asarray(jax.jit(ragged_paged_attention)(q, pool, tables, start, lens, layer),
                      np.float32)
-    want = np.asarray(jax.jit(reference)(q, pool_k, pool_v, tables, start, lens))
+    want = np.asarray(jax.jit(reference)(q, pool, tables, start, lens))
     assert np.isfinite(got).all()
     assert (got[2] == 0).all() and (got[1, (tokens + 1) // 2:] == 0).all(), "dead rows not zero"
     ratio = float(np.abs(got - want).max() / np.abs(want).max())

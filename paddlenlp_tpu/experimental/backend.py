@@ -197,7 +197,7 @@ class ModelBackend:
     def kv_spill(self, block_ids):
         """Gather ``block_ids`` out of the pool and start their D2H copy
         (hierarchical prefix cache, kv_host_tier.py). Returns ``(kv, scale)``
-        gathered [L, 2, n_padded, K, bs, H] planes with
+        gathered [L, 2, n_padded, bs, K*H] planes with
         ``copy_to_host_async`` dispatched — the engine hands them straight to
         :meth:`HostKVTier.put`. Must be called BEFORE any launch that writes
         the (just-recycled) blocks; dispatch order then guarantees the gather

@@ -64,6 +64,7 @@ import numpy as np
 from ..observability.goodput import LAUNCH_GEOMETRY
 from ..utils.log import logger
 from .backend import MixedRow, ModelBackend
+from .kv_host_tier import gather_blocks, pool_block_bytes, scatter_blocks
 from .paged_cache import PagedKVPool
 from .sharded_backend import ShardedBackend
 
@@ -79,21 +80,6 @@ def _normalize_stages(stages) -> Tuple[int, int]:
     raise ValueError(
         f"disagg stages must be a (prefill_devices, decode_devices) pair of "
         f"positive ints; got {stages!r}")
-
-
-def _gather_blocks(src, ids):
-    """Pull whole blocks (all layers, K and V planes) out of one stage's pool."""
-    return src[:, :, ids]
-
-
-def _scatter_blocks(dst, data, ids):
-    """Land migrated blocks in the destination pool. The second output is a
-    tiny marker scalar data-dependent on the scatter result: it completes
-    exactly when the copy has landed and — unlike the (donated-away-next-step)
-    pool tensor itself — stays safe to poll with ``is_ready()``."""
-    out = dst.at[:, :, ids].set(data)
-    marker = (out[0, 0, 0, 0, 0, 0] * 0).astype(jnp.int32) + ids.shape[0]
-    return out, marker
 
 
 @dataclasses.dataclass
@@ -140,14 +126,8 @@ class DisaggBackend(ModelBackend):
             model, mesh_shape=(1, d_devs), devices=devices[p_devs:p_devs + d_devs],
             stage="decode", **kw)
         self._build_migration_jits()
-        kv = self.decode_stage.pool.kv
-        # bytes one block carries across the wire: [L, 2, K, bs, H] (+ scale)
-        self._block_bytes = int(
-            kv.dtype.itemsize * kv.shape[0] * 2 * kv.shape[3] * kv.shape[4] * kv.shape[5])
-        if self.decode_stage.pool.scale is not None:
-            s = self.decode_stage.pool.scale
-            self._block_bytes += int(
-                s.dtype.itemsize * s.shape[0] * 2 * s.shape[3] * s.shape[4] * s.shape[5])
+        # bytes one block carries across the wire (+ scale)
+        self._block_bytes = pool_block_bytes(self.decode_stage.pool)
         # monotone migration accounting + a bounded (seq, blocks, bytes) event
         # ring the metrics plane drains by sequence number (same contract as
         # the engine's chunk rings: stats() reads never consume events)
@@ -169,10 +149,10 @@ class DisaggBackend(ModelBackend):
         d_kv_s = d_inf.pool_shardings.kv
         self._kv_data_sharding = d_kv_s  # block-slice layout == pool layout
         self._gather_kv = jax.jit(
-            _gather_blocks, donate_argnums=(),
+            gather_blocks, donate_argnums=(),
             in_shardings=(p_kv_s, p_inf._repl), out_shardings=p_kv_s)
         self._scatter_kv = jax.jit(
-            _scatter_blocks, donate_argnums=(0,),
+            scatter_blocks, donate_argnums=(0,),
             in_shardings=(d_kv_s, d_kv_s, d_inf._repl),
             out_shardings=(d_kv_s, d_inf._repl))
         # the reverse direction (decode→prefill) serves kv_writeback:
@@ -180,10 +160,10 @@ class DisaggBackend(ModelBackend):
         # reads (chunk attention, host-tier spills) happen on the prefill
         # stage — registering generated blocks requires carrying them back
         self._gather_kv_back = jax.jit(
-            _gather_blocks, donate_argnums=(),
+            gather_blocks, donate_argnums=(),
             in_shardings=(d_kv_s, d_inf._repl), out_shardings=d_kv_s)
         self._scatter_kv_back = jax.jit(
-            _scatter_blocks, donate_argnums=(0,),
+            scatter_blocks, donate_argnums=(0,),
             in_shardings=(p_kv_s, p_kv_s, p_inf._repl),
             out_shardings=(p_kv_s, p_inf._repl))
         self._kv_back_sharding = p_kv_s
@@ -192,17 +172,17 @@ class DisaggBackend(ModelBackend):
             d_s = d_inf.pool_shardings.scale
             self._scale_data_sharding = d_s
             self._gather_scale = jax.jit(
-                _gather_blocks, donate_argnums=(),
+                gather_blocks, donate_argnums=(),
                 in_shardings=(p_s, p_inf._repl), out_shardings=p_s)
             self._scatter_scale = jax.jit(
-                _scatter_blocks, donate_argnums=(0,),
+                scatter_blocks, donate_argnums=(0,),
                 in_shardings=(d_s, d_s, d_inf._repl),
                 out_shardings=(d_s, d_inf._repl))
             self._gather_scale_back = jax.jit(
-                _gather_blocks, donate_argnums=(),
+                gather_blocks, donate_argnums=(),
                 in_shardings=(d_s, d_inf._repl), out_shardings=d_s)
             self._scatter_scale_back = jax.jit(
-                _scatter_blocks, donate_argnums=(0,),
+                scatter_blocks, donate_argnums=(0,),
                 in_shardings=(p_s, p_s, p_inf._repl),
                 out_shardings=(p_s, p_inf._repl))
             self._scale_back_sharding = p_s

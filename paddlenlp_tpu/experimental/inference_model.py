@@ -6,9 +6,10 @@ a decode-optimized forward that REUSES the training params (scanned [L] layout)
 but runs its own fused loop — mirroring the reference's split between training
 models and the experimental inference runtime.
 
-TPU-native: one ``lax.scan`` over the stacked layer params + the [L]-leading paged
-pool; block-table gathers/scatters instead of CUDA append-attention kernels; the
-whole prefill/decode step is a single jit.
+TPU-native: one ``lax.scan`` over the stacked layer params with the whole paged
+pool in its carry, written and read in place by layer index; block-table
+gathers/scatters instead of CUDA append-attention kernels; the whole
+prefill/decode step is a single jit.
 """
 
 from __future__ import annotations
@@ -103,19 +104,21 @@ class PagedInferenceModel:
         self.max_blocks_per_seq = max_blocks_per_seq
         self.decode_steps = decode_steps
         # Pallas ragged paged kernel: default-on for TPU when the tile shapes
-        # are Mosaic-safe; otherwise the XLA gather path. On TPU a default
+        # are Mosaic-safe (one head's (block_size, head_dim) tile is cut out
+        # of the pool's n_kv * head_dim lane rows, so head_dim must fill whole
+        # 128-lane tiles); otherwise the XLA gather path. On TPU a default
         # that comes out off is said once, so it is never a silent choice.
         if use_paged_kernel is None:
             on_tpu = jax.default_backend() == "tpu"
             use_paged_kernel = (
-                on_tpu and self.config.head_dim % 64 == 0 and block_size % 8 == 0)
+                on_tpu and self.config.head_dim % 128 == 0 and block_size % 8 == 0)
             if on_tpu and not use_paged_kernel:
                 from ..utils.log import logger
 
                 logger.warning_once(
                     f"paged attention kernel off for {type(model).__name__} "
                     f"(head_dim={self.config.head_dim}, block_size={block_size}): needs "
-                    "head_dim % 64 == 0 and block_size % 8 == 0; using the XLA gather path")
+                    "head_dim % 128 == 0 and block_size % 8 == 0; using the XLA gather path")
         self.use_paged_kernel = use_paged_kernel
         # [-1] sentinel when no eos: never matches a sampled id
         self.eos_arr = jnp.asarray(sorted(eos_ids) or [-1], jnp.int32)
@@ -206,29 +209,25 @@ class PagedInferenceModel:
         out = jnp.einsum("bnts,bsnh->btnh", probs, v.astype(jnp.float32))
         return out.astype(q.dtype)
 
-    def _paged_attention(self, q, pool_layer, scale_layer, block_tables, q_start, q_lens):
-        """Fused block-table walk + attend over one layer's pool: the Pallas
-        ragged kernel streams addressed KV blocks instead of materializing the
-        gathered cache (dequant rides in-kernel for int8/fp8 pools). One
-        launch covers the whole ragged batch — decode rows (q_lens=1), prefill
-        chunks (q_lens up to T), and inactive padding (q_lens=0) together.
-        The sharded subclass runs it under ``shard_map``."""
+    def _paged_attention(self, q, kv, kv_scale, block_tables, q_start, q_lens, layer):
+        """Fused block-table walk + attend over layer ``layer`` of the whole
+        pool: the Pallas ragged kernel streams addressed KV blocks instead of
+        materializing the gathered cache (dequant rides in-kernel for int8/fp8
+        pools). One launch covers the whole ragged batch — decode rows
+        (q_lens=1), prefill chunks (q_lens up to T), and inactive padding
+        (q_lens=0) together. The sharded subclass runs it under ``shard_map``."""
         from ..ops.pallas.paged_attention import ragged_paged_attention
 
-        return ragged_paged_attention(
-            q, pool_layer[0], pool_layer[1], block_tables,
-            q_start=q_start, q_lens=q_lens,
-            k_scale=None if scale_layer is None else scale_layer[0],
-            v_scale=None if scale_layer is None else scale_layer[1],
-        )
+        return ragged_paged_attention(q, kv, block_tables, q_start=q_start, q_lens=q_lens,
+                                      layer=layer, kv_scale=kv_scale)
 
     def _layer(self, carry, scanned, block_tables, q_positions, kv_len_mask, write_pos,
                q_lens, adapter_idx):
-        """One decoder layer inside lax.scan: scanned = (layer_params, pool_layer,
-        scale_layer-or-None for quantized caches, lora_layer-or-None for
-        multi-LoRA batches)."""
-        h = carry
-        lp, pool_layer, scale_layer, lora_layer = scanned
+        """One decoder layer inside lax.scan: carry = (h, whole pool), written
+        and read in place at this layer's index; scanned = (layer_params,
+        lora_layer-or-None for multi-LoRA batches, layer index)."""
+        h, pool = carry
+        lp, lora_layer, layer = scanned
         cfg = self.config
         B, T, D = h.shape
 
@@ -251,22 +250,15 @@ class PagedInferenceModel:
             cos, sin = rope_tables(q_positions, self.inv_freq)
             q, k = apply_rotary_pos_emb(q, k, cos, sin)
 
-        # scatter new K/V into the pool (per sequence)
         with jax.named_scope("kv_write"):
-            for i in range(B):
-                written = write_kv_block(pool_layer, k[i], v[i], block_tables[i],
-                                         write_pos[i], scale_layer)
-                if scale_layer is not None:
-                    pool_layer, scale_layer = written
-                else:
-                    pool_layer = written
+            pool = write_kv_block(pool, k, v, block_tables, write_pos, layer)
         if self.use_paged_kernel:
             with jax.named_scope("paged_attn"):
-                attn_out = self._paged_attention(q, pool_layer, scale_layer, block_tables,
-                                                 q_positions[:, 0], q_lens)
+                attn_out = self._paged_attention(q, pool.kv, pool.scale, block_tables,
+                                                 q_positions[:, 0], q_lens, layer)
         else:
             with jax.named_scope("attn_gather"):
-                k_all, v_all = gather_kv(pool_layer, block_tables, scale_layer)
+                k_all, v_all = gather_kv(pool, block_tables, layer, self.n_kv)
                 attn_out = self._attend(q, k_all, v_all, q_positions, kv_len_mask)
         with jax.named_scope("o_proj"):
             attn_out = attn_out.reshape(B, T, self.n_heads * self.head_dim)
@@ -290,9 +282,7 @@ class PagedInferenceModel:
             h = h + self._hint(
                 self._lora_mm(mlp["down_proj"], act, lora_layer, adapter_idx, "down_proj"),
                 "full")
-        if scale_layer is not None:
-            return h, (pool_layer, scale_layer)
-        return h, pool_layer
+        return (h, pool), None
 
     def _forward(self, params, pool: PagedKVPool, input_ids, block_tables, q_positions,
                  kv_len_mask, write_pos, last_pos, q_lens=None, lora=None,
@@ -307,10 +297,11 @@ class PagedInferenceModel:
 
         ``lora`` is the adapter pool tree ``{proj: {"A": [L, P, d_in, r],
         "B": [L, P, r, d_out]}}`` (or None for an adapter-free program);
-        ``adapter_idx`` [B] maps each row to a pool slot (0 = identity). Both
-        ride the layer scan: the pool's [L] axis slices per layer alongside
-        the params, and None is a valid empty pytree — the adapter-free
-        program carries no extra operands at all."""
+        ``adapter_idx`` [B] maps each row to a pool slot (0 = identity). The
+        adapter pool rides the layer scan as xs: its [L] axis slices per layer
+        alongside the params, and None is a valid empty pytree — the
+        adapter-free program carries no extra operands at all. The KV pool
+        does NOT: it rides the carry whole, addressed by layer index."""
         if q_lens is None:
             q_lens = jnp.full((input_ids.shape[0],), input_ids.shape[1], jnp.int32)
         if lora is not None and adapter_idx is None:
@@ -326,14 +317,11 @@ class PagedInferenceModel:
             return self._layer(carry, scanned, block_tables, q_positions, kv_len_mask,
                                write_pos, q_lens, adapter_idx)
 
-        # uniform 4-tuple xs: None entries are empty pytrees lax.scan slices
-        # to None per layer — the quant-off / lora-off programs are unchanged
-        scanned = (m["layers"], pool.kv, pool.scale, lora)
-        h, new_pool = jax.lax.scan(body, h, scanned)
-        if pool.scale is None:
-            new_pool = PagedKVPool(kv=new_pool)
-        else:
-            new_pool = PagedKVPool(kv=new_pool[0], scale=new_pool[1])
+        # the pool rides the carry, addressed by the scanned layer index: as
+        # xs/ys every layer would slice its pool out and stack it back. A None
+        # lora is an empty pytree lax.scan slices to None per layer
+        scanned = (m["layers"], lora, jnp.arange(pool.kv.shape[0], dtype=jnp.int32))
+        (h, new_pool), _ = jax.lax.scan(body, (h, pool), scanned)
         with jax.named_scope("final_norm"):
             h = _rms(h, m["norm"]["scale"], self.eps)
         with jax.named_scope("lm_head"):

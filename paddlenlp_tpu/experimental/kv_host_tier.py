@@ -60,19 +60,15 @@ def scatter_blocks(dst, data, ids):
     when the copy has landed and — unlike the (donated-away-next-step) pool
     tensor itself — stays safe to poll with ``is_ready()``."""
     out = dst.at[:, :, ids].set(data)
-    marker = (out[0, 0, 0, 0, 0, 0] * 0).astype(jnp.int32) + ids.shape[0]
+    marker = (out[0, 0, 0, 0, 0] * 0).astype(jnp.int32) + ids.shape[0]
     return out, marker
 
 
 def pool_block_bytes(pool) -> int:
-    """Bytes one block carries across the host boundary: [L, 2, K, bs, H]
-    (+ the scale plane for quantized pools)."""
-    kv = pool.kv
-    n = int(kv.dtype.itemsize * kv.shape[0] * 2 * kv.shape[3] * kv.shape[4] * kv.shape[5])
-    if pool.scale is not None:
-        s = pool.scale
-        n += int(s.dtype.itemsize * s.shape[0] * 2 * s.shape[3] * s.shape[4] * s.shape[5])
-    return n
+    """Bytes one block carries across the host boundary: [L, 2, bs, K*H]
+    (+ the [L, 2, bs, K] scale plane for quantized pools)."""
+    planes = [pool.kv] if pool.scale is None else [pool.kv, pool.scale]
+    return sum(int(a.dtype.itemsize * a.shape[0] * 2 * a.shape[3] * a.shape[4]) for a in planes)
 
 
 @dataclasses.dataclass
@@ -89,7 +85,7 @@ class HostPromoteTicket:
 
 @dataclasses.dataclass
 class _SpillBatch:
-    """One batched spill's payload: gathered [L, 2, n, K, bs, H] planes,
+    """One batched spill's payload: gathered [L, 2, n, bs, K*H] planes,
     device-resident until settled (D2H already in flight), then numpy."""
 
     kv: object
@@ -158,7 +154,7 @@ class HostKVTier:
 
     def put(self, hashes: List[bytes], kv, scale=None):
         """Register one spill batch: ``kv``/``scale`` are the gathered
-        [L, 2, n, K, bs, H] planes (rows beyond ``len(hashes)`` are pow2
+        [L, 2, n, bs, K*H] planes (rows beyond ``len(hashes)`` are pow2
         padding and never referenced) with their D2H copies already in
         flight. Earlier batches settle to numpy here — one batch of deferral
         means the async copy has had a full engine step to land."""
@@ -186,7 +182,7 @@ class HostKVTier:
     def take(self, hashes: List[bytes]):
         """Pop ``hashes`` (resident-XOR invariant: a promoted hash leaves the
         tier — the engine re-registers it in the device index) and return
-        their stacked planes ``(kv [L, 2, m, K, bs, H], scale | None,
+        their stacked planes ``(kv [L, 2, m, bs, K*H], scale | None,
         nbytes)`` ready for the H2D scatter."""
         kv_rows, scale_rows = [], []
         for h in hashes:

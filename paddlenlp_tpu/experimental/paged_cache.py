@@ -5,12 +5,15 @@ Counterpart of the reference's block-attention machinery: the CUDA block pool in
 allocator ``csrc/gpu/step.cu`` (op ``step_paddle`` :316 — free/dispatch blocks,
 preempt + recover). TPU-native split:
 
-- device side: ONE pool tensor ``[L, 2, num_blocks, n_kv, block_size, H]``
-  (kv-head-major so a Pallas BlockSpec can DMA one head's ``[block_size, H]``
-  tile — the last two dims must be TPU-tileable);
-  prefill/decode scatter new K/V into table-addressed slots
-  (``lax`` scatter via ``.at[]``) and attention gathers whole block rows — static
-  shapes, jit-compiled once;
+- device side: ONE pool tensor ``[L, 2, num_blocks, block_size, n_kv * H]``:
+  token-major rows, a token's K (or V) for all its kv heads is one contiguous
+  row. Every consumer addresses that one donated buffer in place, by layer
+  index: prefill/decode scatter whole rows at ``[l, plane, block, offset]``
+  and the Pallas kernel DMAs one head's ``[block_size, H]`` tile out of the
+  rows of block ``tables[b, j]``. Writer and reader agree on the layout, so
+  no step program slices, stacks or re-lays out a pool-sized array (the pool rides
+  the layer scan's carry, never its xs/ys). The block axis is axis 2 for
+  whole-block copies (prefix-cache COW, host tier, stage migration);
 - host side: ``BlockManager`` does the step.cu bookkeeping (free list, per-seq
   tables, allocate/extend/free, preemption candidates) in plain Python — the
   allocator runs between device steps, so there is no launch-latency reason to
@@ -35,12 +38,13 @@ __all__ = ["PagedKVPool", "BlockManager", "init_paged_pool", "write_kv_block", "
 
 @dataclasses.dataclass
 class PagedKVPool:
-    """Device-side pool: kv [L, 2, num_blocks, n_kv, block_size, head_dim].
+    """Device-side pool: kv [L, 2, num_blocks, block_size, n_kv * head_dim].
 
     Quantized caches (the reference's c8/fp8 cache, ``csrc/gpu/append_attn/``
     c8 impls + ``predictor.py:775-791`` cachekv_int8) store ``kv`` as int8 /
-    float8_e4m3 plus per-token-per-head ``scale`` [L, 2, nb, n_kv, bs, 1] —
-    dequant happens at the attention read (in-kernel for the Pallas path)."""
+    float8_e4m3 plus per-token-per-head ``scale`` [L, 2, nb, bs, n_kv], laid
+    out and addressed like ``kv`` — dequant happens at the attention read
+    (in-kernel for the Pallas path)."""
 
     kv: jnp.ndarray
     scale: Optional[jnp.ndarray] = None
@@ -51,7 +55,7 @@ class PagedKVPool:
 
     @property
     def block_size(self) -> int:
-        return self.kv.shape[4]
+        return self.kv.shape[3]
 
     @property
     def quantized(self) -> bool:
@@ -67,7 +71,7 @@ def init_paged_pool(config, num_blocks: int, block_size: int = 16, dtype=jnp.bfl
                     quant: Optional[str] = None) -> PagedKVPool:
     n_kv = getattr(config, "num_key_value_heads", config.num_attention_heads)
     head_dim = getattr(config, "head_dim", config.hidden_size // config.num_attention_heads)
-    shape = (config.num_hidden_layers, 2, num_blocks, n_kv, block_size, head_dim)
+    shape = (config.num_hidden_layers, 2, num_blocks, block_size, n_kv * head_dim)
     if quant is None:
         return PagedKVPool(kv=jnp.zeros(shape, dtype=dtype))
     if quant not in _QMAX:
@@ -75,7 +79,7 @@ def init_paged_pool(config, num_blocks: int, block_size: int = 16, dtype=jnp.bfl
     qdtype = jnp.int8 if quant == "int8" else jnp.float8_e4m3fn
     return PagedKVPool(
         kv=jnp.zeros(shape, dtype=qdtype),
-        scale=jnp.zeros(shape[:-1] + (1,), dtype=jnp.float32),
+        scale=jnp.zeros(shape[:-1] + (n_kv,), dtype=jnp.float32),
     )
 
 
@@ -92,57 +96,57 @@ def quantize_kv(x: jnp.ndarray, qdtype) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return q.astype(qdtype), scale
 
 
-def write_kv_block(pool_layer: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                   block_table: jnp.ndarray, start_pos,
-                   scale_layer: Optional[jnp.ndarray] = None):
-    """Scatter new tokens' K/V into the pool (one layer).
+def write_kv_block(pool: PagedKVPool, k: jnp.ndarray, v: jnp.ndarray,
+                   block_tables: jnp.ndarray, start_pos: jnp.ndarray, layer) -> PagedKVPool:
+    """Scatter a batch's new K/V rows into layer ``layer`` of the whole pool.
 
-    pool_layer [2, num_blocks, K, bs, H]; k/v [T, K, H] for ONE sequence;
-    block_table [max_blocks]; start_pos scalar — token i lands at logical position
-    start_pos+i -> (block_table[(start_pos+i)//bs], (start_pos+i)%bs).
-    With ``scale_layer`` [2, num_blocks, K, bs, 1] the pool is quantized: K/V are
-    range-compressed per token+head on write. Returns pool_layer or
-    (pool_layer, scale_layer)."""
-    T = k.shape[0]
-    bs = pool_layer.shape[3]
-    pos = start_pos + jnp.arange(T)
-    blocks = block_table[pos // bs]
-    offs = pos % bs
-    if scale_layer is not None:
-        k, ks = quantize_kv(k, pool_layer.dtype)
-        v, vs = quantize_kv(v, pool_layer.dtype)
-        scale_layer = scale_layer.at[0, blocks, :, offs].set(ks)
-        scale_layer = scale_layer.at[1, blocks, :, offs].set(vs)
-    # advanced indices (blocks, offs) split by the kv-head slice: result rows
-    # are [T, K, H], matching k/v
-    pool_layer = pool_layer.at[0, blocks, :, offs].set(k.astype(pool_layer.dtype))
-    pool_layer = pool_layer.at[1, blocks, :, offs].set(v.astype(pool_layer.dtype))
-    if scale_layer is not None:
-        return pool_layer, scale_layer
-    return pool_layer
+    pool.kv [L, 2, num_blocks, bs, K*H] (updated in place when the caller
+    donated it); k/v [B, T, K, H]; block_tables [B, max_blocks]; start_pos [B]
+    — token i of row b lands at logical position start_pos[b]+i ->
+    (block_tables[b, (start_pos[b]+i)//bs], (start_pos[b]+i)%bs).
+    One scatter per plane for the whole batch: each update is a token's whole
+    row, minor in the pool, so XLA keeps the pool's layout and aliases the
+    operand to the output. Padded rows land in the sentinel block.
+    A quantized pool (``pool.scale`` [L, 2, num_blocks, bs, K]) range-compresses
+    K/V per token+head on write and scatters the scales the same way."""
+    B, T, K, H = k.shape
+    kv, scale = pool.kv, pool.scale
+    pos = start_pos[:, None] + jnp.arange(T)[None, :]  # [B, T]
+    blocks = block_tables[jnp.arange(B)[:, None], pos // pool.block_size]
+    offs = pos % pool.block_size
+    if scale is not None:
+        k, ks = quantize_kv(k, kv.dtype)
+        v, vs = quantize_kv(v, kv.dtype)
+        scale = scale.at[layer, 0, blocks, offs].set(ks[..., 0])
+        scale = scale.at[layer, 1, blocks, offs].set(vs[..., 0])
+    kv = kv.at[layer, 0, blocks, offs].set(k.reshape(B, T, K * H).astype(kv.dtype))
+    kv = kv.at[layer, 1, blocks, offs].set(v.reshape(B, T, K * H).astype(kv.dtype))
+    return PagedKVPool(kv=kv, scale=scale)
 
 
-def gather_kv(pool_layer: jnp.ndarray, block_tables: jnp.ndarray,
-              scale_layer: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Gather per-sequence K/V views (one layer).
+def gather_kv(pool: PagedKVPool, block_tables: jnp.ndarray, layer,
+              n_kv: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Gather per-sequence K/V views of layer ``layer`` from the whole pool.
 
-    pool_layer [2, num_blocks, K, bs, H]; block_tables [B, max_blocks] ->
-    (k, v) each [B, max_blocks*bs, K, H]. Out-of-range table entries must point at
-    a zeroed sentinel block; masking by context length happens in attention.
-    Quantized pools dequantize on the gathered (per-sequence) view."""
-    k = pool_layer[0][block_tables]  # [B, max_blocks, K, bs, H]
-    v = pool_layer[1][block_tables]
-    B, M, K, bs, H = k.shape
-    if scale_layer is not None:
-        ks = scale_layer[0][block_tables]  # [B, M, K, bs, 1]
-        vs = scale_layer[1][block_tables]
+    pool.kv [L, 2, num_blocks, bs, K*H] with K = ``n_kv``; block_tables
+    [B, max_blocks] -> (k, v) each [B, max_blocks*bs, K, H]. Out-of-range
+    table entries must point at a zeroed sentinel block; masking by context
+    length happens in attention. The layer is one more gather index: nothing
+    is sliced out of the pool first. Quantized pools (``pool.scale``
+    [L, 2, num_blocks, bs, K]) dequantize on the gathered view."""
+    B, M = block_tables.shape
+
+    def view(plane):
+        rows = pool.kv[layer, plane, block_tables]  # [B, max_blocks, bs, K*H]
+        rows = rows.reshape(B, M * pool.block_size, n_kv, -1)
+        if pool.scale is None:
+            return rows
+        scales = pool.scale[layer, plane, block_tables].reshape(B, M * pool.block_size, n_kv, 1)
         # dequantize to bf16: the quantized cache must not carry a LARGER
         # working set than the bf16 pool it replaces
-        k = (k.astype(jnp.float32) * ks).astype(jnp.bfloat16)
-        v = (v.astype(jnp.float32) * vs).astype(jnp.bfloat16)
-    k = k.transpose(0, 1, 3, 2, 4).reshape(B, M * bs, K, H)
-    v = v.transpose(0, 1, 3, 2, 4).reshape(B, M * bs, K, H)
-    return k, v
+        return (rows.astype(jnp.float32) * scales).astype(jnp.bfloat16)
+
+    return view(0), view(1)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

@@ -134,7 +134,9 @@ class ShardedPagedInferenceModel(PagedInferenceModel):
         self.param_specs = spec_tree_from_rules(model.params, rules, mesh, _IDENTITY_RULES)
         self.param_shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), self.param_specs)
         n_kv = getattr(model.config, "num_key_value_heads", model.config.num_attention_heads)
-        self.pool_spec = (P(None, None, None, "tp", None, None)
+        # token-major rows [L, 2, nb, bs, K*H] (scales [.., K]): splitting the
+        # last axis tp ways puts whole kv heads on each shard
+        self.pool_spec = (P(None, None, None, None, "tp")
                           if n_kv % self.tp == 0 else P())
         pool_ns = NamedSharding(mesh, self.pool_spec)
         self.pool_shardings = PagedKVPool(kv=pool_ns, scale=pool_ns if kv_quantized else None)
@@ -184,7 +186,7 @@ class ShardedPagedInferenceModel(PagedInferenceModel):
             return x
         return jax.lax.with_sharding_constraint(x, NamedSharding(self.mesh, spec))
 
-    def _paged_attention(self, q, pool_layer, scale_layer, block_tables, q_start, q_lens):
+    def _paged_attention(self, q, kv, kv_scale, block_tables, q_start, q_lens, layer):
         # GSPMD cannot partition a Mosaic kernel ("wrap the call in a
         # shard_map"): run it manually per shard — each tp shard attends its
         # own kv heads and their query groups against its slice of the pool;
@@ -193,12 +195,11 @@ class ShardedPagedInferenceModel(PagedInferenceModel):
         # single-device kernel holds.
         heads = "tp" if self.pool_spec != P() else None
         q_spec = P(None, None, heads, None)  # [B, T, N, H]
-        layer_spec = P(None, None, heads, None, None)  # [2, nb, K, bs, H|1]
         return jax.shard_map(
             super()._paged_attention, mesh=self.mesh,
-            in_specs=(q_spec, layer_spec, layer_spec, P(), P(), P()),
+            in_specs=(q_spec, self.pool_spec, self.pool_spec, P(), P(), P(), P()),
             out_specs=q_spec, check_vma=False,
-        )(q, pool_layer, scale_layer, block_tables, q_start, q_lens)
+        )(q, kv, kv_scale, block_tables, q_start, q_lens, layer)
 
     def _build_jits(self):
         # every step's trailing args are the multi-LoRA pair(s): the adapter
@@ -300,10 +301,10 @@ class ShardedBackend(SingleDeviceBackend):
     def _build_host_tier_jits(self):
         # host-tier spill/promote with the step programs' explicit-placement
         # contract: gather/scatter on the pool's sharding (the block-slice
-        # layout equals the pool layout — the kv-heads axis shards, blocks
-        # replicate), ids and the marker replicated, scatter pool donated.
-        # The kv sharding serves the scale plane too: same NamedSharding,
-        # same axis-3 split.
+        # layout equals the pool layout — the kv heads split the last axis,
+        # blocks replicate), ids and the marker replicated, scatter pool
+        # donated. The kv sharding serves the scale plane too: same
+        # NamedSharding, same last-axis split.
         kv_s = self.infer.pool_shardings.kv
         r = self.infer._repl
         gather = jax.jit(gather_blocks, donate_argnums=(),

@@ -17,11 +17,15 @@ Design:
   The query rows are tiled (``_MAX_Q_ROWS``) because the chip's compiler gives
   a kernel 16 MiB of scoped VMEM: a whole 2048-token prefill bucket at GQA
   group 6 (12288 rows x 128) needed 18 MiB and was refused;
-- the block table plus per-sequence ``q_start``/``q_lens`` ride scalar
-  prefetch (``pltpu.PrefetchScalarGridSpec``): the KV BlockSpec index map
-  reads ``tables[b, j]`` to aim the DMA at the right pool block — the table
-  gather IS the address computation, exactly like the CUDA kernel's block
-  walk;
+- the operand is the WHOLE pool ``[L, 2, num_blocks, bs, K*H]`` (token-major
+  rows, passed once for K and once for V), never a layer or a plane cut out
+  of it: the block table, per-sequence ``q_start``/``q_lens`` and the layer
+  index ride scalar prefetch (``pltpu.PrefetchScalarGridSpec``), and the KV
+  BlockSpec index map aims the DMA of one head's ``(bs, H)`` tile at
+  ``(layer, plane, tables[b, j], 0, kh)`` — the table gather IS the address
+  computation, exactly like the CUDA kernel's block walk. The tile is ``H``
+  lanes out of a ``K*H``-lane row, so on the chip ``H`` must be a multiple
+  of 128 (or the row itself);
 - causal masking is per query ROW: query token t of sequence b sits at
   absolute position ``q_start[b] + t`` and sees kv positions ``<= q_start+t``
   — correct across chunk boundaries (a chunk's first token attends over the
@@ -70,7 +74,7 @@ def _q_tile_tokens(T: int, group: int) -> int:
     return tq
 
 
-def _kernel(tables_ref, start_ref, len_ref, q_ref, k_ref, v_ref, *rest,
+def _kernel(tables_ref, start_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
             bs, scale, use_kv_scale, group, tq):
     if use_kv_scale:
         ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = rest
@@ -78,6 +82,7 @@ def _kernel(tables_ref, start_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         o_ref, m_s, l_s, acc_s = rest
         ks_ref = vs_ref = None
     b = pl.program_id(0)
+    kh = pl.program_id(1)
     j = pl.program_id(3)
     nj = pl.num_programs(3)
 
@@ -97,11 +102,14 @@ def _kernel(tables_ref, start_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when((live > 0) & (j * bs <= hi))
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)  # [tq*group, H]
-        k = k_ref[0, 0].astype(jnp.float32)  # [bs, H]
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)  # [bs, H]
+        v = v_ref[...].astype(jnp.float32)
         if use_kv_scale:  # int8/fp8 cache: dequant the streamed block in VMEM
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
+            # the scale tile is the block's [bs, K] rows: keep this head's column
+            ks, vs = ks_ref[...], vs_ref[...]
+            mine = jax.lax.broadcasted_iota(jnp.int32, ks.shape, 1) == kh
+            k = k * jnp.sum(jnp.where(mine, ks, 0.0), axis=-1, keepdims=True)
+            v = v * jnp.sum(jnp.where(mine, vs, 0.0), axis=-1, keepdims=True)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # [tq*group, bs]
         kv_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         t = t0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group  # query token idx
@@ -123,48 +131,54 @@ def _kernel(tables_ref, start_ref, len_ref, q_ref, k_ref, v_ref, *rest,
 
 def ragged_paged_attention(
     q: jnp.ndarray,  # [B, T, N, H] new-token queries (rows past q_lens ignored)
-    pool_k: jnp.ndarray,  # [num_blocks, K, bs, H] (kv-head-major: TPU-tileable DMA)
-    pool_v: jnp.ndarray,
+    kv: jnp.ndarray,  # [L, 2, num_blocks, bs, K*H] the whole pool
     block_tables: jnp.ndarray,  # [B, max_blocks] int32
     q_start: jnp.ndarray,  # [B] absolute position of q[:, 0]
     q_lens: jnp.ndarray,  # [B] valid new tokens per sequence (0 = inactive row)
+    layer,  # int32 scalar: the pool layer to read
     scale: Optional[float] = None,
     interpret: Optional[bool] = None,
-    k_scale: Optional[jnp.ndarray] = None,  # [num_blocks, K, bs, 1] quantized-pool scales
-    v_scale: Optional[jnp.ndarray] = None,
+    kv_scale: Optional[jnp.ndarray] = None,  # [L, 2, num_blocks, bs, K] quantized-pool scales
 ) -> jnp.ndarray:
     """One-launch attention for a ragged mixed prefill/decode batch.
 
-    Query token t of row b attends kv positions ``[0, q_start[b] + t]`` read
-    through ``block_tables[b]`` — the KV for positions ``< q_start`` was
-    written by earlier chunks/steps, the chunk's own KV by this step's scatter
-    (ordered before the kernel by jit data dependence on the pool). Returns
-    ``[B, T, N, H]`` with rows ``t >= q_lens[b]`` zeroed.
+    Query token t of row b attends kv positions ``[0, q_start[b] + t]`` of
+    pool layer ``layer``, read through ``block_tables[b]`` — the KV for
+    positions ``< q_start`` was written by earlier chunks/steps, the chunk's
+    own KV by this step's scatter (ordered before the kernel by jit data
+    dependence on the pool). Returns ``[B, T, N, H]`` with rows
+    ``t >= q_lens[b]`` zeroed.
     """
     B, T, N, H = q.shape
-    nb, K, bs, _ = pool_k.shape
+    bs, K = kv.shape[3], kv.shape[4] // H
     group = N // K
     max_blocks = block_tables.shape[1]
     scale = scale if scale is not None else H**-0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    use_kv_scale = k_scale is not None
+    use_kv_scale = kv_scale is not None
     tq = _q_tile_tokens(T, group)
     rows = tq * group
 
     # [B, T, N, H] -> [B, K, T*group, H]: head n = kh*group + g, so T and group
     # interleave as rows (t, g) -> row t*group + g of kv head kh
     qf = q.reshape(B, T, K, group, H).transpose(0, 2, 1, 3, 4).reshape(B, K, T * group, H)
-    q_spec = pl.BlockSpec((1, 1, rows, H), lambda b, kh, qt, j, t, s, l: (b, kh, qt, 0))
-    kv_spec = pl.BlockSpec((1, 1, bs, H), lambda b, kh, qt, j, t, s, l: (t[b, j], kh, 0, 0))
-    sc_spec = pl.BlockSpec((1, 1, bs, 1), lambda b, kh, qt, j, t, s, l: (t[b, j], kh, 0, 0))
-    in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [qf, pool_k, pool_v]
+    q_spec = pl.BlockSpec((1, 1, rows, H), lambda b, kh, qt, j, t, s, n, l: (b, kh, qt, 0))
+
+    def pool_spec(plane, width, per_head):
+        # rows of block tables[b, j] in one plane of layer l[0], in place in the
+        # pool: kv head kh's H lanes of the KV rows, or the whole scale row
+        return pl.BlockSpec(
+            (None, None, None, bs, width),
+            lambda b, kh, qt, j, t, s, n, l: (l[0], plane, t[b, j], 0, kh if per_head else 0))
+
+    in_specs = [q_spec, pool_spec(0, H, True), pool_spec(1, H, True)]
+    operands = [qf, kv, kv]
     if use_kv_scale:
-        in_specs += [sc_spec, sc_spec]
-        operands += [k_scale, v_scale]
+        in_specs += [pool_spec(0, K, False), pool_spec(1, K, False)]
+        operands += [kv_scale, kv_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, K, T // tq, max_blocks),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -184,27 +198,26 @@ def ragged_paged_attention(
         interpret=interpret,
         name="ragged_paged_attention",
     )(block_tables.astype(jnp.int32), q_start.astype(jnp.int32),
-      q_lens.astype(jnp.int32), *operands)
+      q_lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     return out.reshape(B, K, T, group, H).transpose(0, 2, 1, 3, 4).reshape(B, T, N, H)
 
 
 def paged_decode_attention(
     q: jnp.ndarray,  # [B, N, H] one query token per sequence
-    pool_k: jnp.ndarray,  # [num_blocks, K, bs, H]
-    pool_v: jnp.ndarray,
+    kv: jnp.ndarray,  # [L, 2, num_blocks, bs, K*H] the whole pool
     block_tables: jnp.ndarray,  # [B, max_blocks] int32
     context_lens: jnp.ndarray,  # [B] int32 (position of the current token)
+    layer,  # int32 scalar: the pool layer to read
     scale: Optional[float] = None,
     interpret: Optional[bool] = None,
-    k_scale: Optional[jnp.ndarray] = None,
-    v_scale: Optional[jnp.ndarray] = None,
+    kv_scale: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Decode-only wrapper: every sequence contributes exactly one query token
     at position ``context_lens[b]`` (the ragged kernel with ``q_lens = 1``)."""
     B = q.shape[0]
     out = ragged_paged_attention(
-        q[:, None], pool_k, pool_v, block_tables,
-        q_start=context_lens, q_lens=jnp.ones((B,), jnp.int32),
-        scale=scale, interpret=interpret, k_scale=k_scale, v_scale=v_scale,
+        q[:, None], kv, block_tables,
+        q_start=context_lens, q_lens=jnp.ones((B,), jnp.int32), layer=layer,
+        scale=scale, interpret=interpret, kv_scale=kv_scale,
     )
     return out[:, 0]

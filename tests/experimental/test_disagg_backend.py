@@ -87,7 +87,7 @@ class TestLayout:
         assert b.prefill_stage.pool.kv.shape == b.decode_stage.pool.kv.shape
         # each stage's pool is laid out on its own tp axis
         assert tuple(b.prefill_stage.pool.kv.sharding.spec) == (
-            None, None, None, "tp", None, None)
+            None, None, None, None, "tp")
 
     def test_insufficient_devices_raises(self, model):
         with pytest.raises(ValueError, match="devices"):

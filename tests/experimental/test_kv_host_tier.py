@@ -266,7 +266,7 @@ class TestHostTierUnit:
 
     def _batch(self, n, seed):
         rng = np.random.default_rng(seed)
-        return rng.standard_normal((2, 2, n, 2, BS, 8)).astype(np.float32)
+        return rng.standard_normal((2, 2, n, BS, 2 * 8)).astype(np.float32)
 
     def test_put_take_roundtrip_bitwise(self):
         tier = HostKVTier(8, block_bytes=2 * 2 * 2 * BS * 8 * 4)

@@ -10,6 +10,27 @@ from paddlenlp_tpu.experimental import BlockManager, InferenceEngine, SamplingPa
 from paddlenlp_tpu.transformers import LlamaConfig, LlamaForCausalLM
 
 
+def whole_pool(pk, pv, layer=0, layers=1, seed=0):
+    """K and V planes of one layer, [nb, K, bs, H] as the references read them,
+    laid into the pool the program keeps: [L, 2, nb, bs, K*H] token-major rows,
+    every other layer filled with different values."""
+    nb, K, bs, H = pk.shape
+    rows = lambda plane: plane.transpose(0, 2, 1, 3).reshape(nb, bs, K * H)
+    if jnp.issubdtype(pk.dtype, jnp.floating):
+        pool = jax.random.normal(jax.random.PRNGKey(seed), (layers, 2, nb, bs, K * H)).astype(pk.dtype)
+    else:
+        pool = jax.random.randint(jax.random.PRNGKey(seed), (layers, 2, nb, bs, K * H), -127, 128
+                                  ).astype(pk.dtype)
+    return pool.at[layer, 0].set(rows(pk)).at[layer, 1].set(rows(pv))
+
+
+def whole_scale(ks, vs, layer=0, layers=1):
+    """Per-token-per-head scales [nb, K, bs, 1] laid into [L, 2, nb, bs, K]."""
+    rows = lambda plane: plane[..., 0].transpose(0, 2, 1)
+    scale = jnp.full((layers, 2) + rows(ks).shape, 7.0, jnp.float32)
+    return scale.at[layer, 0].set(rows(ks)).at[layer, 1].set(rows(vs))
+
+
 @pytest.fixture(scope="module")
 def model():
     cfg = LlamaConfig(vocab_size=96, hidden_size=64, intermediate_size=112, num_hidden_layers=2,
@@ -159,7 +180,7 @@ class TestPagedKernel:
         pv = jnp.asarray(rng.standard_normal((nb, K, bs, H)), jnp.float32)
         tables = jnp.asarray(rng.permutation(np.arange(1, nb))[: B * mb].reshape(B, mb), jnp.int32)
         ctx = jnp.asarray([7, 22], jnp.int32)
-        out = paged_decode_attention(q, pk, pv, tables, ctx, interpret=True)
+        out = paged_decode_attention(q, whole_pool(pk, pv), tables, ctx, 0, interpret=True)
 
         def flat(pool):  # [nb,K,bs,H] gathered -> [B, mb*bs, K, H]
             return pool[tables].transpose(0, 1, 3, 2, 4).reshape(B, mb * bs, K, H)
@@ -222,7 +243,8 @@ class TestRaggedKernel:
                              jnp.int32)
         q_start = jnp.asarray([9, 22, 0], jnp.int32)  # chunk @9, decode @22, dead
         q_lens = jnp.asarray([8, 1, 0], jnp.int32)
-        out = ragged_paged_attention(q, pk, pv, tables, q_start, q_lens, interpret=True)
+        out = ragged_paged_attention(q, whole_pool(pk, pv), tables, q_start, q_lens, 0,
+                                     interpret=True)
         ref = self._ref(q, pk, pv, tables, q_start, q_lens)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
         assert np.all(np.asarray(out)[2] == 0.0)  # dead row is exact zeros
@@ -241,9 +263,32 @@ class TestRaggedKernel:
         tables = jnp.asarray([[3, 7, 1, 5]], jnp.int32)
         q_start = jnp.asarray([8], jnp.int32)  # exactly one full block prefilled
         q_lens = jnp.asarray([8], jnp.int32)
-        out = ragged_paged_attention(q, pk, pv, tables, q_start, q_lens, interpret=True)
+        out = ragged_paged_attention(q, whole_pool(pk, pv), tables, q_start, q_lens, 0,
+                                     interpret=True)
         ref = self._ref(q, pk, pv, tables, q_start, q_lens)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+    @pytest.mark.parametrize("layer", [0, 13, 27])
+    def test_kernel_is_aimed_by_layer_index(self, layer):
+        """The operand is the whole 28-layer pool, every layer holding other
+        values: the kernel aimed at ``layer`` equals plain attention over that
+        layer's K and V, and over no other layer's."""
+        from paddlenlp_tpu.ops.pallas.paged_attention import ragged_paged_attention
+
+        rng = np.random.default_rng(6)
+        L, B, T, N, K, H, nb, bs, mb = 28, 3, 4, 4, 2, 16, 14, 4, 4
+        q = jnp.asarray(rng.standard_normal((B, T, N, H)), jnp.float32)
+        pool = jnp.asarray(rng.standard_normal((L, 2, nb, bs, K * H)), jnp.float32)
+        tables = jnp.asarray(rng.permutation(np.arange(1, nb))[: B * mb].reshape(B, mb),
+                             jnp.int32)
+        q_start = jnp.asarray([5, 11, 0], jnp.int32)
+        q_lens = jnp.asarray([4, 1, 0], jnp.int32)
+        planes = lambda l: [pool[l, p].reshape(nb, bs, K, H).transpose(0, 2, 1, 3) for p in (0, 1)]
+        out = ragged_paged_attention(q, pool, tables, q_start, q_lens, layer, interpret=True)
+        ref = self._ref(q, *planes(layer), tables, q_start, q_lens)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+        other = self._ref(q, *planes((layer + 1) % L), tables, q_start, q_lens)
+        assert np.abs(np.asarray(out) - np.asarray(other)).max() > 1e-2
 
     def test_kernel_default_off_on_tpu_is_said(self, model, monkeypatch):
         """On a TPU the kernel is on by default; where the shape gate turns
@@ -280,10 +325,11 @@ class TestRaggedKernel:
         q_start = jnp.asarray([9, 22, 0, 3], jnp.int32)
         q_lens = jnp.asarray([11, 1, 0, 16], jnp.int32)
         assert pa._q_tile_tokens(T, N // K) == T  # default bound: one tile
-        whole = pa.ragged_paged_attention(q, pk, pv, tables, q_start, q_lens, interpret=True)
+        pool = whole_pool(pk, pv)
+        whole = pa.ragged_paged_attention(q, pool, tables, q_start, q_lens, 0, interpret=True)
         monkeypatch.setattr(pa, "_MAX_Q_ROWS", max_rows)
         assert pa._q_tile_tokens(T, N // K) == max_rows // (N // K)
-        tiled = pa.ragged_paged_attention(q, pk, pv, tables, q_start, q_lens, interpret=True)
+        tiled = pa.ragged_paged_attention(q, pool, tables, q_start, q_lens, 0, interpret=True)
         np.testing.assert_array_equal(np.asarray(tiled), np.asarray(whole))
         np.testing.assert_allclose(
             np.asarray(tiled), np.asarray(self._ref(q, pk, pv, tables, q_start, q_lens)),
@@ -301,10 +347,59 @@ class TestRaggedKernel:
         tables = jnp.asarray(rng.permutation(np.arange(1, nb))[: B * mb].reshape(B, mb),
                              jnp.int32)
         ctx = jnp.asarray([7, 22], jnp.int32)
-        a = paged_decode_attention(q, pk, pv, tables, ctx, interpret=True)
-        b = ragged_paged_attention(q[:, None], pk, pv, tables, ctx,
-                                   jnp.ones((B,), jnp.int32), interpret=True)[:, 0]
+        pool = whole_pool(pk, pv)
+        a = paged_decode_attention(q, pool, tables, ctx, 0, interpret=True)
+        b = ragged_paged_attention(q[:, None], pool, tables, ctx,
+                                   jnp.ones((B,), jnp.int32), 0, interpret=True)[:, 0]
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=0)
+
+
+class TestPoolWrite:
+    @pytest.mark.parametrize("quant", [None, "int8"])
+    def test_write_then_gather_round_trips_in_place(self, quant):
+        """A ragged batch written at layer 2 of 4, across a block boundary,
+        reads back through ``gather_kv``; every other layer, every block the
+        batch does not own and every row it did not reach stay bit for bit."""
+        from paddlenlp_tpu.experimental.paged_cache import (
+            PagedKVPool, gather_kv, quantize_kv, write_kv_block)
+
+        rng = np.random.default_rng(7)
+        L, B, T, K, H, nb, bs, mb, layer = 4, 3, 6, 2, 8, 12, 4, 3, 2
+        dtype = jnp.float32 if quant is None else jnp.int8
+        kv = jnp.asarray(rng.integers(-100, 100, (L, 2, nb, bs, K * H)), dtype)
+        scale = None if quant is None else jnp.asarray(
+            rng.uniform(0.5, 2.0, (L, 2, nb, bs, K)), jnp.float32)
+        k = jnp.asarray(rng.standard_normal((B, T, K, H)), jnp.float32)
+        v = jnp.asarray(rng.standard_normal((B, T, K, H)), jnp.float32)
+        tables = jnp.asarray([[3, 7, 1], [5, 2, 9], [0, 0, 0]], jnp.int32)  # row 2: padding
+        start = jnp.asarray([2, 5, 0], jnp.int32)  # tokens 2..7 span blocks 3 and 7; 5..10 all three
+        written = write_kv_block(PagedKVPool(kv, scale), k, v, tables, start, layer)
+        new_kv, new_scale = written.kv, written.scale
+
+        k_all, v_all = gather_kv(written, tables, layer, K)
+        assert k_all.shape == (B, mb * bs, K, H)
+        for b in (0, 1):
+            span = slice(int(start[b]), int(start[b]) + T)
+            for got, want in ((k_all, k), (v_all, v)):
+                if quant:
+                    q, s = quantize_kv(want[b], jnp.int8)
+                    want_b = (q.astype(jnp.float32) * s).astype(jnp.bfloat16)
+                else:
+                    want_b = want[b]
+                np.testing.assert_array_equal(np.asarray(got[b, span], np.float32),
+                                              np.asarray(want_b, np.float32))
+
+        touched = np.zeros((nb, bs), bool)
+        for b in range(B):
+            for t in range(T):
+                pos = int(start[b]) + t
+                touched[int(tables[b, pos // bs]), pos % bs] = True
+        for before, after in ((kv, new_kv),) + (((scale, new_scale),) if quant else ()):
+            before, after = np.asarray(before), np.asarray(after)
+            others = [l for l in range(L) if l != layer]
+            np.testing.assert_array_equal(after[others], before[others])
+            np.testing.assert_array_equal(after[layer][:, ~touched], before[layer][:, ~touched])
+            assert (after[layer][:, touched] != before[layer][:, touched]).any()
 
 
 class TestPreemption:
@@ -376,8 +471,9 @@ class TestQuantizedKVCache:
         pv_q, pv_s = quantize_kv(pv, jnp.int8)
         tables = jnp.asarray(rng.permutation(np.arange(1, nb))[: B * mb].reshape(B, mb), jnp.int32)
         ctx = jnp.asarray([7, 22], jnp.int32)
-        out = paged_decode_attention(q, pk_q, pv_q, tables, ctx, interpret=True,
-                                     k_scale=pk_s, v_scale=pv_s)
+        # layer 1 of 3: the scale planes are addressed by layer like the pool
+        out = paged_decode_attention(q, whole_pool(pk_q, pv_q, 1, 3), tables, ctx, 1,
+                                     interpret=True, kv_scale=whole_scale(pk_s, pv_s, 1, 3))
 
         def flat(pool):
             return pool[tables].transpose(0, 1, 3, 2, 4).reshape(B, mb * bs, K, H)

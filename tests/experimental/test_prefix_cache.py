@@ -185,7 +185,7 @@ class TestBlockManagerPrefixCache:
 
         from paddlenlp_tpu.experimental.paged_cache import PagedKVPool, copy_blocks
 
-        kv = jnp.arange(2 * 2 * 6 * 1 * BS * 2, dtype=jnp.float32).reshape(2, 2, 6, 1, BS, 2)
+        kv = jnp.arange(2 * 2 * 6 * BS * 2, dtype=jnp.float32).reshape(2, 2, 6, BS, 2)
         kv = kv.at[:, :, 0].set(0.0)  # zero sentinel
         before = np.asarray(kv)
         pool = copy_blocks(PagedKVPool(kv=kv), [(1, 4), (2, 5), (3, 1)])  # 3 -> pads to 4

@@ -54,7 +54,7 @@ def eng_tp8_chunked(model):
 class TestLayout:
     def test_kv_pool_sharded_on_tp(self, eng_tp8):
         spec = eng_tp8.pool.kv.sharding.spec
-        assert tuple(spec) == (None, None, None, "tp", None, None)
+        assert tuple(spec) == (None, None, None, None, "tp")
         assert len(eng_tp8.pool.kv.devices()) == 8
 
     def test_params_sharded(self, eng_tp8):
@@ -69,7 +69,7 @@ class TestLayout:
     def test_jits_carry_explicit_shardings(self, eng_tp8):
         infer = eng_tp8.infer
         # the sharding trees the jits were compiled with are non-trivial
-        assert infer.pool_shardings.kv.spec == P(None, None, None, "tp", None, None)
+        assert infer.pool_shardings.kv.spec == P(None, None, None, None, "tp")
         import jax
         leaves = jax.tree.leaves(infer.param_shardings)
         assert any("tp" in tuple(ns.spec) for ns in leaves)
@@ -116,7 +116,7 @@ class TestTokenIdentity:
         # the jitted steps' out_shardings hold: after real prefill/mixed/decode
         # traffic (and COW copies) the pool is still laid out on tp
         assert tuple(eng_tp8_chunked.pool.kv.sharding.spec) == (
-            None, None, None, "tp", None, None)
+            None, None, None, None, "tp")
 
     def test_seeded_sampling_chunked(self, eng_ref, eng_tp8_chunked):
         sp = SamplingParams(max_new_tokens=6, do_sample=True, temperature=1.1,

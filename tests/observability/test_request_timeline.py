@@ -74,7 +74,7 @@ def _scoped(text):
 def test_step_programs_carry_every_scope(model, program, paged_kernel):
     infer = PagedInferenceModel(model, 4, 32, 8, dtype=jnp.float32, decode_steps=2,
                                 use_paged_kernel=paged_kernel)
-    pool = PagedKVPool(kv=jax.ShapeDtypeStruct((2, 2, 32, 2, 4, 16), jnp.float32))
+    pool = PagedKVPool(kv=jax.ShapeDtypeStruct((2, 2, 32, 4, 2 * 16), jnp.float32))
     found = _scoped(_program_text(infer, program, pool=pool))
     want = set(PROGRAM_SCOPES) | {"paged_attn" if paged_kernel else "attn_gather"}
     assert want <= found, sorted(want - found)
@@ -106,7 +106,7 @@ def test_scopes_and_the_kernel_s_name_survive_the_chip_s_compiler(monkeypatch):
         infer = PagedInferenceModel(qwen, 16, 64, 8, dtype=jnp.bfloat16, decode_steps=2, use_paged_kernel=True)
         aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
         text = _program_text(infer, "decode", batch=4, vocab=1024, aval=aval,
-                             pool=PagedKVPool(kv=aval((2, 2, 64, 1, 16, 128), jnp.bfloat16)))
+                             pool=PagedKVPool(kv=aval((2, 2, 64, 16, 1 * 128), jnp.bfloat16)))
     finally:
         jax.config.update("jax_enable_compilation_cache", before)
         compilation_cache.reset_cache()

@@ -8,13 +8,17 @@ query tile (18 MiB of scoped VMEM against a limit of 16) and GSPMD refused to
 partition it at all. The shapes are the ones ``chip_smoke.py`` runs: Qwen2-1.5B
 serving (12 query / 2 kv heads, head_dim 128, 9600-block pool of 16-token
 blocks, 160-block tables) and Qwen2-0.5B training (14 / 2 heads, head_dim 64)
-at sequence 2048. Nothing runs; a compile that passes says nothing about
-results or times.
+at sequence 2048; the step programs are the benchmark cell's (28 layers, 16
+slots, 192-block tables). Nothing runs; a compile that passes says nothing
+about results or times.
 """
 
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +59,7 @@ def compiled_text(fn, *avals):
 
 
 NUM_BLOCKS, BLOCK, TABLE = 9600, 16, 160
+LAYERS = 28
 
 
 @pytest.mark.parametrize("batch,tokens,kv_heads,pool_dtype", [
@@ -62,21 +67,24 @@ NUM_BLOCKS, BLOCK, TABLE = 9600, 16, 160
     (8, 1, 12, jnp.bfloat16),  # decode without GQA: a 1-row query tile
     (8, 512, 2, jnp.bfloat16),  # one prefill chunk: 3072 rows, the largest single tile
     (2, 2048, 2, jnp.bfloat16),  # largest monolithic prefill bucket: 12288 rows in 4 tiles
-    (8, 1, 2, jnp.int8),  # quantized pools: (16, 128) int8/fp8 blocks + (16, 1) scales
+    (8, 1, 2, jnp.int8),  # quantized pools: (16, 128) int8/fp8 tiles + (16, kv_heads) scale rows
     (8, 512, 2, jnp.float8_e4m3fn),
 ], ids=["decode", "decode-mha", "chunk512", "prefill2048", "int8-decode", "fp8-chunk512"])
 def test_ragged_paged_attention_compiles(chip, batch, tokens, kv_heads, pool_dtype):
+    """One head's (16, 128) tile cut out of the pool's token-major rows of
+    ``kv_heads * 128`` lanes, at ``(layer, plane, table[b, j], 0, kv head)``."""
     aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-    heads, head_dim = 12, 128
-    pool = aval((NUM_BLOCKS, kv_heads, BLOCK, head_dim), pool_dtype)
-    avals = [aval((batch, tokens, heads, head_dim), jnp.bfloat16), pool, pool,
-             aval((batch, TABLE), jnp.int32), aval((batch,), jnp.int32), aval((batch,), jnp.int32)]
+    heads, head_dim, layers = 12, 128, 4  # 28 layers of 12 kv heads would not fit the chip
+    avals = [aval((batch, tokens, heads, head_dim), jnp.bfloat16),
+             aval((layers, 2, NUM_BLOCKS, BLOCK, kv_heads * head_dim), pool_dtype),
+             aval((batch, TABLE), jnp.int32), aval((batch,), jnp.int32), aval((batch,), jnp.int32),
+             aval((), jnp.int32)]
     if pool_dtype != jnp.bfloat16:
-        avals += [aval((NUM_BLOCKS, kv_heads, BLOCK, 1), jnp.float32)] * 2
+        avals.append(aval((layers, 2, NUM_BLOCKS, BLOCK, kv_heads), jnp.float32))
 
-    def attend(q, pool_k, pool_v, tables, start, lens, k_scale=None, v_scale=None):
-        return ragged_paged_attention(q, pool_k, pool_v, tables, start, lens,
-                                      interpret=False, k_scale=k_scale, v_scale=v_scale)
+    def attend(q, kv, tables, start, lens, layer, kv_scale=None):
+        return ragged_paged_attention(q, kv, tables, start, lens, layer,
+                                      interpret=False, kv_scale=kv_scale)
 
     assert "tpu_custom_call" in compiled_text(attend, *avals)
 
@@ -106,7 +114,7 @@ def test_flash_attention_compiles(chip, heads, kv_heads, head_dim, backward):
 def test_paged_kernel_on_a_mesh_compiles(topology, monkeypatch):
     """dp 2 x tp 2 serving: GSPMD refuses a bare Mosaic kernel ("cannot be
     automatically partitioned"), so the sharded model runs it under shard_map,
-    each tp shard on its own kv head."""
+    each tp shard on its own kv head: its 128 lanes of every pool row."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from paddlenlp_tpu.experimental.sharded_backend import ShardedPagedInferenceModel
@@ -124,12 +132,12 @@ def test_paged_kernel_on_a_mesh_compiles(topology, monkeypatch):
         shape, dtype, sharding=NamedSharding(mesh, spec))
     batch = 8
     avals = (aval((batch, 1, 12, 128), jnp.bfloat16, P(None, None, "tp", None)),
-             aval((2, NUM_BLOCKS, 2, BLOCK, 128), jnp.bfloat16, P(*infer.pool_spec[1:])),
+             aval((1, 2, NUM_BLOCKS, BLOCK, 2 * 128), jnp.bfloat16, infer.pool_spec),
              aval((batch, TABLE), jnp.int32, P()), aval((batch,), jnp.int32, P()),
-             aval((batch,), jnp.int32, P()))
+             aval((batch,), jnp.int32, P()), aval((), jnp.int32, P()))
 
-    def attend(q, pool_layer, tables, start, lens):
-        return infer._paged_attention(q, pool_layer, None, tables, start, lens)
+    def attend(q, kv, tables, start, lens, layer):
+        return infer._paged_attention(q, kv, None, tables, start, lens, layer)
 
     # the kernel asks jax.default_backend() whether to interpret; a described
     # chip does not change that answer, so the test gives it
@@ -137,3 +145,70 @@ def test_paged_kernel_on_a_mesh_compiles(topology, monkeypatch):
     text = compiled_text(attend, *avals)
     # per shard: one kv head, its 6 query heads
     assert "tpu_custom_call" in text and "bf16[8,1,6,128]" in text
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill16x512"])
+def test_step_programs_copy_no_pool(chip, monkeypatch, program):
+    """The serving step programs at the benchmark cell's shapes (Qwen2-1.5B,
+    28 layers, 9600 blocks of 16, 16 slots, 192-block tables, abstract weights)
+    address the donated pool in place. While the pool rode the layer scan as
+    xs / ys in a kv-head-major layout, the same compiles held 4.53 GiB (decode)
+    and 4.79 GiB (prefill) of temporaries: each layer's pool sliced out, relaid
+    for the scatter, relaid for the kernel and stacked back."""
+    from paddlenlp_tpu.experimental.backend import samp_arrays
+    from paddlenlp_tpu.experimental.inference_model import PagedInferenceModel
+    from paddlenlp_tpu.experimental.paged_cache import init_paged_pool
+    from paddlenlp_tpu.transformers import Qwen2Config, Qwen2ForCausalLM
+
+    slots, table, vocab = 16, 192, 151936
+    config = Qwen2Config(vocab_size=vocab, hidden_size=1536, intermediate_size=8960,
+                         num_hidden_layers=LAYERS, num_attention_heads=12,
+                         num_key_value_heads=2, tie_word_embeddings=True)
+    model = Qwen2ForCausalLM(config, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    model.params = model.param_shapes  # shapes only: there is no device to hold arrays
+    # the kernel asks jax.default_backend() whether to interpret; a described
+    # chip does not change that answer, so the test gives it
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    infer = PagedInferenceModel(model, BLOCK, NUM_BLOCKS, table, dtype=jnp.bfloat16, decode_steps=8)
+    assert infer.use_paged_kernel
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree)
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    params = on_chip(model.params)
+    pool = on_chip(jax.eval_shape(lambda: init_paged_pool(config, NUM_BLOCKS, BLOCK)))
+    samp = lambda n: on_chip(jax.eval_shape(lambda: samp_arrays([None] * n, n)))
+    rows = lambda *shape: aval((slots,) + shape, jnp.int32)
+    if program == "decode":
+        step, args = infer._decode_impl, (
+            params, pool, rows(), rows(table), rows(), aval((slots,), jnp.bool_), rows(),
+            rows(vocab), samp(slots))
+    else:
+        step, args = infer._prefill_impl, (
+            params, pool, rows(512), rows(table), rows(), rows(), rows(vocab), samp(slots))
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(*args).compile()
+
+    pool_bytes = pool.kv.size * pool.kv.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.25 * pool_bytes
+
+    # every instruction with an output of one layer of pool or more either
+    # passes a buffer on or updates the donated pool in place (the bound on
+    # temporaries above is what says the update's operand is aliased)
+    text = compiled.as_text()
+    fusion_roots = dict(re.findall(
+        r"^%?(fused_computation[\w.\-]*) [^\n]*\{\n(?:[^}][^\n]*\n)*?\s*ROOT %?[\w.\-]+ = \S+ ([a-z\-]+)\(",
+        text, flags=re.M))
+    weights = {a.shape for a in jax.tree.leaves(model.params)}  # only ever read
+    layer_elements = pool.kv.size // LAYERS
+    in_place = {"scatter", "dynamic-update-slice"}
+    passed_on = {"parameter", "get-tuple-element", "bitcast"}
+    found = set()
+    for name, dims, op, callee in re.findall(
+            r"^\s*(?:ROOT )?%?([\w.\-]+) = bf16\[([\d,]+)\]\S* ([a-z\-]+)\((?:[^\n]*calls=%?([\w.\-]+))?",
+            text, flags=re.M):
+        shape = tuple(int(d) for d in dims.split(","))
+        if shape in weights or math.prod(shape) < layer_elements:
+            continue
+        op = fusion_roots.get(callee, "fusion") if op == "fusion" else op
+        assert op in in_place | passed_on, f"{name}: a pool-sized {op} {shape}"
+        found.add(op)
+    assert found & in_place, "no write into the pool was found: the scan reads nothing"
