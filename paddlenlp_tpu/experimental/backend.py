@@ -80,9 +80,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..observability.tracer import TRACER
-from .inference_model import PagedInferenceModel
+from .inference_model import PagedInferenceModel, inference_model_class
 from .kv_host_tier import HostPromoteTicket, gather_blocks, scatter_blocks
-from .paged_cache import PagedKVPool, copy_blocks, init_paged_pool
+from .paged_cache import PagedKVPool, copy_blocks
 
 __all__ = ["ModelBackend", "SingleDeviceBackend", "MixedRow", "samp_arrays",
            "launch_geometry"]
@@ -157,6 +157,8 @@ class ModelBackend:
     #: the PagedInferenceModel (or subclass) holding the jitted programs —
     #: exposed because tests and tools flip ``infer.use_paged_kernel``
     infer: PagedInferenceModel
+    #: how many prefill-chunk rows one mixed step may carry (None: as many as the budget feeds)
+    max_chunk_rows: Optional[int] = None
 
     #: True for stage-split (disaggregated) backends: the engine then routes
     #: finished prefills through kv_migrate/migration_ready before treating
@@ -253,9 +255,12 @@ class SingleDeviceBackend(ModelBackend):
                  max_blocks_per_seq: int, dtype, decode_steps: int, eos_ids,
                  kv_cache_quant: Optional[str] = None,
                  token_flatten: Optional[bool] = None,
-                 adapter_registry=None):
+                 adapter_registry=None,
+                 prefill_chunk_tokens: Optional[int] = None):
         self.model = model
         self.max_batch_size = max_batch_size
+        # read by a kind whose mixed program has one fixed shape (see _build_infer)
+        self.prefill_chunk_tokens = prefill_chunk_tokens
         self.step_accounting = {"fed": 0, "shape": ()}
         # multi-LoRA: with a registry attached, EVERY step passes the device
         # adapter pool + a per-row slot index (identity rows gather slot 0's
@@ -278,15 +283,21 @@ class SingleDeviceBackend(ModelBackend):
     # ---------------------------------------------------------------- setup
     def _build_infer(self, model, block_size, num_blocks, max_blocks_per_seq,
                      dtype, decode_steps, eos_ids) -> PagedInferenceModel:
-        return PagedInferenceModel(
+        """The inference model that computes the configuration's layer kinds
+        (``inference_model.inference_model_class``); the llama kind's is built as ever."""
+        return inference_model_class(model.config)(
             model, block_size, num_blocks, max_blocks_per_seq, dtype=dtype,
             decode_steps=decode_steps, eos_ids=eos_ids,
-        )
+            max_batch_size=self.max_batch_size, prefill_chunk_tokens=self.prefill_chunk_tokens)
 
     def _init_pool(self, config, num_blocks, block_size, dtype, quant):
-        return init_paged_pool(config, num_blocks, block_size,
-                               dtype=jnp.bfloat16 if dtype == jnp.bfloat16 else jnp.float32,
-                               quant=quant)
+        return self.infer.init_pool(num_blocks, block_size,
+                                    jnp.bfloat16 if dtype == jnp.bfloat16 else jnp.float32, quant)
+
+    @property
+    def max_chunk_rows(self) -> Optional[int]:
+        shape = self.infer.fixed_mixed_shape
+        return shape[0] if shape else None
 
     def _init_counts(self):
         return jnp.zeros((self.max_batch_size, self.model.config.vocab_size), jnp.int32)
@@ -410,6 +421,8 @@ class SingleDeviceBackend(ModelBackend):
         sub = np.arange(1, valid.shape[0] + 1, dtype=np.int64)[:, None]
         self.step_accounting = dict(
             acct, kv_positions=int(((ctx[None, :] + sub) * valid).sum()))  # sync-ok: valid already host
+        if self.infer.launch_counts:
+            self.step_accounting.update(self.infer.launch_counts(self.pool))
         return toks, valid
 
     def verify(self, tokens, block_tables, start_pos, need_logits: bool,
@@ -520,13 +533,18 @@ class SingleDeviceBackend(ModelBackend):
         flat = self.token_flatten
         if flat is None:
             flat = not self.infer.use_paged_kernel
+        if self.infer.fixed_mixed_shape:
+            flat = True  # the one shape such a model compiles is the flat layout's
         launch = self._mixed_flat_launch if flat else self._mixed_padded_launch
         with TRACER.span("dispatch", cat="engine", program="mixed"):
             tokens_dev, mapper = launch(chunk_rows, decode_rows)
 
         def collect() -> np.ndarray:
             with TRACER.span("wait", cat="engine", program="mixed"):
-                return mapper(np.asarray(tokens_dev))  # sync-ok: THE mixed-step sync point — sampled int32 ids only
+                out = mapper(np.asarray(tokens_dev))  # sync-ok: THE mixed-step sync point — sampled int32 ids only
+                if self.infer.launch_counts:
+                    self.step_accounting = dict(self.step_accounting, **self.infer.launch_counts(self.pool))
+                return out
 
         return collect
 
@@ -576,24 +594,25 @@ class SingleDeviceBackend(ModelBackend):
         segments run in ONE jit; token-identical to the padded layout (each
         live row's math is a row-slice of the padded program's). Returns
         (device tokens, host-order mapper)."""
-        C = _bucket(len(chunk_rows), minimum=1)
-        T = _bucket(max([len(r.tokens) for r in chunk_rows], default=1), minimum=1)
-        D = _bucket(len(decode_rows), minimum=1)
-        M = (chunk_rows[0].table.shape[0] if chunk_rows else decode_rows[0].table.shape[0])
+        C, T, D = self.infer.fixed_mixed_shape or (
+            _bucket(len(chunk_rows), minimum=1),
+            _bucket(max([len(r.tokens) for r in chunk_rows], default=1), minimum=1),
+            _bucket(len(decode_rows), minimum=1))
+        M = (chunk_rows[0].table.shape if chunk_rows else decode_rows[0].table.shape)
         rows = chunk_rows + decode_rows
         self.step_accounting = dict(
             {"fed": C * T + D, "shape": ("mixed_flat", C, T, D)},
             **launch_geometry(C + D, [len(r.tokens) for r in rows],
                               [r.start + len(r.tokens) for r in rows]))
         c_ids = np.zeros((C, T), np.int32)
-        c_tables = np.zeros((C, M), np.int32)
+        c_tables = np.zeros((C,) + M, np.int32)
         c_qlens = np.zeros(C, np.int32)
         c_start = np.zeros(C, np.int32)
         c_slots = np.zeros(C, np.int32)
         c_emit = np.zeros(C, bool)
         c_adapter = np.zeros(C, np.int32)
         d_tokens = np.zeros(D, np.int32)
-        d_tables = np.zeros((D, M), np.int32)
+        d_tables = np.zeros((D,) + M, np.int32)
         d_start = np.zeros(D, np.int32)
         d_slots = np.zeros(D, np.int32)
         d_live = np.zeros(D, bool)

@@ -45,6 +45,7 @@ import numpy as np
 
 from ..observability.flight_recorder import RECORDER
 from ..observability.goodput import (
+    KIND_COUNTERS,
     LAUNCH_GEOMETRY,
     GoodputLedger,
     compile_attribution,
@@ -59,6 +60,7 @@ from ..serving.tenancy.quotas import DEFAULT_TENANT, TenantQuotas, tenant_goodpu
 from ..utils.faults import FaultPoint
 from ..utils.log import logger
 from .backend import MixedRow, ModelBackend, SingleDeviceBackend, _bucket
+from .inference_model import inference_model_class
 from .kv_host_tier import HostKVTier, pool_block_bytes
 from .paged_cache import BlockManager
 
@@ -297,8 +299,15 @@ class InferenceEngine:
             max_batch_size=max_batch_size, block_size=block_size, num_blocks=num_blocks,
             max_blocks_per_seq=max_blocks_per_seq, dtype=dtype, decode_steps=decode_steps,
             eos_ids=self.eos_ids, kv_cache_quant=kv_cache_quant, token_flatten=token_flatten,
-            adapter_registry=adapter_registry,
+            adapter_registry=adapter_registry, prefill_chunk_tokens=prefill_chunk_tokens,
         )
+        # every kind's door: the class that computes the configuration's layer
+        # kinds says what of the engine it does not serve, here, by name
+        inference_model_class(model.config).refuse_engine_features(
+            kv_cache_quant=kv_cache_quant, adapter_registry=adapter_registry,
+            use_speculative=use_speculative or draft_model is not None, mesh_shape=mesh_shape,
+            disagg_stages=disagg_stages, host_kv_blocks=host_kv_blocks,
+            enable_prefix_cache=enable_prefix_cache, prefill_chunk_tokens=prefill_chunk_tokens)
         if disagg_stages is not None and mesh_shape is not None:
             raise ValueError(
                 "mesh_shape and disagg_stages are mutually exclusive: a disagg "
@@ -343,8 +352,7 @@ class InferenceEngine:
         # (one migrate.defer event per wait, not one per engine step)
         self._migrate_defer_noted: set = set()
         self.enable_prefix_cache = enable_prefix_cache
-        self.mgr = BlockManager(num_blocks, block_size, max_blocks_per_seq,
-                                enable_prefix_cache=enable_prefix_cache)
+        self.mgr = self._new_block_manager(num_blocks, block_size, max_blocks_per_seq)
         # hierarchical KV: the optional host-RAM tier under the BlockManager,
         # plus the engine-held in-flight promotion tickets (req_id -> ticket;
         # the same marker-poll scheduling gate as stage migrations)
@@ -408,6 +416,13 @@ class InferenceEngine:
         self._last_step_end: Optional[float] = None
         self._prev_step_busy = False
         self._step_device_s = 0.0
+
+    def _new_block_manager(self, num_blocks: int, block_size: int, max_blocks_per_seq: int) -> BlockManager:
+        """The allocator for this engine's backend: with a second table a
+        sequence where the model's cache kinds keep a window."""
+        return BlockManager(num_blocks, block_size, max_blocks_per_seq,
+                            enable_prefix_cache=self.enable_prefix_cache,
+                            **(getattr(self.backend.infer, "window_spec", None) or {}))
 
     # device state lives in the backend; these stay as read paths for tests,
     # tools and the metrics plane that predate the backend split
@@ -843,9 +858,8 @@ class InferenceEngine:
         retry/abort disposition and must triage before calling reset."""
         self.waiting.clear()
         self.slots = [None] * self.max_batch_size
-        self.mgr = BlockManager(self.mgr.total_usable_blocks + 1, self.mgr.block_size,
-                                self.mgr.max_blocks_per_seq,
-                                enable_prefix_cache=self.enable_prefix_cache)
+        self.mgr = self._new_block_manager(self.mgr.total_usable_blocks + 1, self.mgr.block_size,
+                                           self.mgr.max_blocks_per_seq)
         self._last_token[:] = 0
         self.backend.reset_counts()
         if self.adapter_registry is not None:
@@ -1082,6 +1096,7 @@ class InferenceEngine:
                 yield span
                 acct = self.backend.step_accounting
                 span.set(**{g: acct[g] for g in LAUNCH_GEOMETRY})
+                span.set(**{g: acct[g] for g in KIND_COUNTERS if g in acct})
         finally:
             self._step_device_s += span.dur
 
@@ -1571,8 +1586,9 @@ class InferenceEngine:
         # the OLDEST mid-prefill request drinks the chunk budget first: slot
         # order would let a newly-admitted prompt landing in a lower slot
         # starve an older one indefinitely under sustained admissions
+        most_rows = self.backend.max_chunk_rows
         for slot in sorted(prefilling, key=lambda s: self.slots[s].req_id):
-            if budget <= 0:
+            if budget <= 0 or (most_rows and len(chunk_rows) >= most_rows):
                 break
             req = self.slots[slot]
             n = min(budget, len(req.prompt_ids) - req.prefilled_len)
@@ -1585,12 +1601,15 @@ class InferenceEngine:
         chunk_payload = []
         for slot, req, n in chunk_rows:
             p0 = req.prefilled_len
+            self.mgr.window_span(req.req_id, p0, n)
             chunk_payload.append(MixedRow(
                 slot=slot, tokens=req.prompt_ids[p0 : p0 + n], start=p0,
                 table=self.mgr.table_array(req.req_id),
                 emit=p0 + n == len(req.prompt_ids),  # sampler on last chunk
                 sampling=req.sampling, is_chunk=True,
                 adapter=req.adapter_slot))
+        for slot, req in decode_rows:
+            self.mgr.window_span(req.req_id, req.total_len - 1, 1)
         dec_payload = [
             MixedRow(slot=slot, tokens=np.asarray([self._last_token[slot]], np.int32),  # sync-ok: _last_token is a host array
                      start=req.total_len - 1,  # position of the token being fed
@@ -2009,13 +2028,14 @@ class InferenceEngine:
                 return
             B = self.max_batch_size
             tokens = np.array(self._last_token, np.int32)  # sync-ok: _last_token is a host array
-            tables = np.zeros((B, self.mgr.max_blocks_per_seq), np.int32)
+            tables = np.zeros((B,) + self.mgr.table_shape, np.int32)
             ctx = np.zeros(B, np.int32)
             done0 = np.ones(B, bool)
             remaining = np.zeros(B, np.int32)
             for i, req in enumerate(self.slots):
                 if req is None or req.kv_stage != "decode":
                     continue  # migrating rows stay frozen (done0) like empty slots
+                self.mgr.window_span(req.req_id, req.total_len - 1, steps)
                 tables[i] = self.mgr.table_array(req.req_id)
                 ctx[i] = req.total_len - 1  # position of the token being fed
                 done0[i] = False

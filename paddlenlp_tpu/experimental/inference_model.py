@@ -1,4 +1,14 @@
-"""Paged-attention inference forward for llama-family models.
+"""Paged-attention inference forward, by layer kind.
+
+A model's configuration yields its layer kinds (``config.layer_kinds()``) and
+names the class whose step programs compute them (``config.inference_model``);
+a configuration that says neither is all ``llama`` layers, computed by this
+file's :class:`PagedInferenceModel`: identical layers under one ``lax.scan``,
+one pool of per-head K and V. A class of other kinds subclasses it and owns its
+parameter names, its pool (``init_pool``), its forward (``_run_layers``) and
+its door: what of the engine it does not serve (``refuse_engine_features``)
+and what of a configuration it does not compute (``refuse_unserved``), each
+refused by the mechanism's name. No model's name is tested here.
 
 Counterpart of ``paddlenlp/experimental/transformers/fused_transformer_layers.py``
 (``FusedBlockMultiTransformer`` :2192) + per-model ``*BlockInferenceModel`` classes:
@@ -21,9 +31,54 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.rope import apply_rotary_pos_emb, rope_frequencies, rope_tables
-from .paged_cache import PagedKVPool, gather_kv, write_kv_block
+from .paged_cache import PagedKVPool, gather_kv, init_paged_pool, write_kv_block
 
-__all__ = ["PagedInferenceModel", "sample_tokens"]
+__all__ = ["PagedInferenceModel", "sample_tokens", "layer_kinds", "refuse_unserved", "inference_model_class"]
+
+
+def layer_kinds(config):
+    """The kind of every layer of ``config``, first to last: what the
+    configuration yields, ``llama`` throughout where it yields nothing."""
+    own = getattr(config, "layer_kinds", None)
+    return list(own()) if callable(own) else ["llama"] * config.num_hidden_layers
+
+
+def refuse_unserved(config, max_context: int):
+    """The door of the ``llama`` kind: a configuration whose layers need a
+    mechanism this kind does not compute is refused by that mechanism's name,
+    never served with something else in its place. ``max_context`` is the
+    longest sequence the engine's tables can hold: a window at least that long
+    is not in use."""
+    name = type(config).__name__
+    other = sorted(set(layer_kinds(config)) - {"llama"})
+    if other:
+        raise ValueError(f"{name}: layer kinds {other} are not computed by the llama kind's step programs "
+                         "(the configuration names the class that does: config.inference_model)")
+    window = getattr(config, "sliding_window", None)
+    if window is not None and window < max_context:
+        raise ValueError(
+            f"{name}: sliding_window={window} is in use (sequences reach {max_context} positions) and the "
+            "llama layer kind attends the whole context; a kind with a window cache is needed (paged_cache.LatentKVPool)")
+    if getattr(config, "kv_lora_rank", None):
+        raise ValueError(f"{name}: kv_lora_rank={config.kv_lora_rank} (latent attention) is not computed by the "
+                         "llama layer kind: its pool holds per-head K and V")
+    for key in ("n_routed_experts", "num_local_experts", "num_experts"):
+        if getattr(config, key, None):
+            raise ValueError(f"{name}: {key}={getattr(config, key)} (routed experts) is not computed by the "
+                             "llama layer kind: its MLP is one dense SwiGLU")
+
+
+def inference_model_class(config):
+    """The class whose step programs compute ``config``'s layer kinds: the one
+    the configuration names by its dotted path (``config.inference_model``; a
+    path, so that a configuration imports no serving code), else the llama kind's."""
+    path = getattr(config, "inference_model", None)
+    if path is None:
+        return PagedInferenceModel
+    import importlib
+
+    module, _, attr = path.rpartition(".")
+    return getattr(importlib.import_module(module), attr)
 
 
 def sample_tokens(
@@ -89,20 +144,56 @@ def _rms(x, scale, eps):
 
 
 class PagedInferenceModel:
-    """Holds jitted prefill/decode over (params, pool). Llama-family only
-    (llama/qwen2/mistral: config-driven biases + GQA + rope)."""
+    """Holds jitted prefill/decode over (params, pool): the ``llama`` layer kind
+    (llama/qwen2/mistral: config-driven biases + GQA + rope), every layer alike."""
+
+    #: (chunk rows, chunk tokens, decode rows) of the one mixed program a
+    #: model compiles, or None: the backend buckets each to a power of two
+    fixed_mixed_shape = None
+    #: a window cache's needs (``BlockManager``), or None
+    window_spec = None
+    #: a class whose layers count on the device gives the counts after a launch's
+    #: sync, as launch-span args (``launch_counts(pool)``)
+    launch_counts = None
+
+    @classmethod
+    def refuse_engine_features(cls, **features):
+        """The engine's door, called for every kind with what the engine was
+        asked for (``kv_cache_quant``, ``adapter_registry``, ``use_speculative``,
+        ``mesh_shape``, ``disagg_stages``, ``host_kv_blocks``,
+        ``enable_prefix_cache``, ``prefill_chunk_tokens``): a class raises
+        ``ValueError`` naming each mechanism its programs do not serve. The
+        llama kind serves them all."""
 
     def __init__(self, model, block_size: int = 16, num_blocks: int = 512, max_blocks_per_seq: int = 64,
-                 dtype=jnp.bfloat16, decode_steps: int = 8, eos_ids=(), use_paged_kernel=None):
+                 dtype=jnp.bfloat16, decode_steps: int = 8, eos_ids=(), use_paged_kernel=None,
+                 max_batch_size: Optional[int] = None, prefill_chunk_tokens: Optional[int] = None):
         self.model = model
         self.config = model.config
-        if "layers" not in model.params.get("model", {}):
-            raise ValueError("PagedInferenceModel requires the scanned-layer param layout (use_scan_layers)")
+        # the engine's geometry, for a kind whose mixed program has one fixed
+        # shape (``fixed_mixed_shape``); the llama kind buckets and reads neither
+        self.max_batch_size = max_batch_size
+        self.prefill_chunk_tokens = prefill_chunk_tokens
         self.dtype = dtype
         self.block_size = block_size
         self.num_blocks = num_blocks
         self.max_blocks_per_seq = max_blocks_per_seq
         self.decode_steps = decode_steps
+        # [-1] sentinel when no eos: never matches a sampled id
+        self.eos_arr = jnp.asarray(sorted(eos_ids) or [-1], jnp.int32)
+        self.eps = self.config.rms_norm_eps
+        self._setup_kind(use_paged_kernel)
+        self._build_jits()
+
+    def init_pool(self, num_blocks: int, block_size: int, dtype, quant=None):
+        return init_paged_pool(self.config, num_blocks, block_size, dtype=dtype, quant=quant)
+
+    def _setup_kind(self, use_paged_kernel):
+        """What the llama kind needs beside the common fields; first its door."""
+        model, block_size = self.model, self.block_size
+        refuse_unserved(self.config, block_size * self.max_blocks_per_seq)
+        if "layers" not in model.params.get("model", {}):
+            raise ValueError("PagedInferenceModel requires the scanned-layer param layout (use_scan_layers)")
         # Pallas ragged paged kernel: default-on for TPU when the tile shapes
         # are Mosaic-safe (one head's (block_size, head_dim) tile is cut out
         # of the pool's n_kv * head_dim lane rows, so head_dim must fill whole
@@ -120,10 +211,7 @@ class PagedInferenceModel:
                     f"(head_dim={self.config.head_dim}, block_size={block_size}): needs "
                     "head_dim % 128 == 0 and block_size % 8 == 0; using the XLA gather path")
         self.use_paged_kernel = use_paged_kernel
-        # [-1] sentinel when no eos: never matches a sampled id
-        self.eos_arr = jnp.asarray(sorted(eos_ids) or [-1], jnp.int32)
         cfg = self.config
-        self.eps = cfg.rms_norm_eps
         self.n_heads = cfg.num_attention_heads
         self.n_kv = cfg.num_key_value_heads
         self.head_dim = cfg.head_dim
@@ -132,7 +220,6 @@ class PagedInferenceModel:
         # (stacked [L, ...] — lax.scan slices per layer); _mm dispatches per
         # projection (reference int8_gemm_with_cutlass serving path)
         self.quant_cfg = getattr(model, "quantization_config", None)
-        self._build_jits()
 
     def _build_jits(self):
         """Compile the step entry points. The sharded subclass overrides this
@@ -313,15 +400,8 @@ class PagedInferenceModel:
             if getattr(self.config, "scale_embeddings", False):
                 h = h * jnp.asarray(self.config.hidden_size**0.5, h.dtype)
 
-        def body(carry, scanned):
-            return self._layer(carry, scanned, block_tables, q_positions, kv_len_mask,
-                               write_pos, q_lens, adapter_idx)
-
-        # the pool rides the carry, addressed by the scanned layer index: as
-        # xs/ys every layer would slice its pool out and stack it back. A None
-        # lora is an empty pytree lax.scan slices to None per layer
-        scanned = (m["layers"], lora, jnp.arange(pool.kv.shape[0], dtype=jnp.int32))
-        (h, new_pool), _ = jax.lax.scan(body, (h, pool), scanned)
+        h, new_pool = self._run_layers(m, h, pool, block_tables, q_positions, kv_len_mask,
+                                       write_pos, q_lens, lora, adapter_idx)
         with jax.named_scope("final_norm"):
             h = _rms(h, m["norm"]["scale"], self.eps)
         with jax.named_scope("lm_head"):
@@ -337,6 +417,20 @@ class PagedInferenceModel:
         # replicated sampler happens once at this anchor.
             logits = self._hint(logits, "full")
         return logits, new_pool
+
+    def _run_layers(self, m, h, pool, block_tables, q_positions, kv_len_mask, write_pos,
+                    q_lens, lora, adapter_idx):
+        """Every layer of the stack, by kind. Here all are ``llama``: one scan."""
+        def body(carry, scanned):
+            return self._layer(carry, scanned, block_tables, q_positions, kv_len_mask,
+                               write_pos, q_lens, adapter_idx)
+
+        # the pool rides the carry, addressed by the scanned layer index: as
+        # xs/ys every layer would slice its pool out and stack it back. A None
+        # lora is an empty pytree lax.scan slices to None per layer
+        scanned = (m["layers"], lora, jnp.arange(pool.kv.shape[0], dtype=jnp.int32))
+        (h, new_pool), _ = jax.lax.scan(body, (h, pool), scanned)
+        return h, new_pool
 
     # ------------------------------------------------------------------ entry points
     def _prefill_impl(self, params, pool, input_ids, block_tables, suffix_lens,
@@ -486,6 +580,12 @@ class PagedInferenceModel:
                 jax.nn.one_hot(tokens, V, dtype=jnp.int32) * emit_all[:, None])
         return tokens, counts, pool
 
+    def _decode_q_lens(self, done):
+        """What a decode sub-step tells the forward about its rows. The llama
+        kind says nothing (finished rows rewrite their slot in place); a kind
+        that counts or routes its live tokens is told which rows are."""
+        return None
+
     def _decode_impl(self, params, pool, tokens, block_tables, context_lens, done0,
                      remaining, counts, samp, lora=None, adapter_idx=None):
         """Multi-step decode: advance every slot up to ``decode_steps`` tokens in ONE
@@ -506,7 +606,7 @@ class PagedInferenceModel:
             logits, pool_c = self._forward(
                 params, pool_c, tok[:, None], block_tables, ctx[:, None],
                 kv_mask, ctx, jnp.zeros((B,), jnp.int32),
-                lora=lora, adapter_idx=adapter_idx,
+                q_lens=self._decode_q_lens(done), lora=lora, adapter_idx=adapter_idx,
             )
             with jax.named_scope("sample"):
                 nxt = sample_tokens(logits, positions=ctx + 1, counts=counts, **samp)

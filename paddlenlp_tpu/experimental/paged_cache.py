@@ -14,6 +14,10 @@ preempt + recover). TPU-native split:
   no step program slices, stacks or re-lays out a pool-sized array (the pool rides
   the layer scan's carry, never its xs/ys). The block axis is axis 2 for
   whole-block copies (prefix-cache COW, host tier, stage migration);
+- beside it, for layer kinds that keep something else (``latent_model.py``):
+  ``LatentKVPool``, planes of one latent row a token for full-attention
+  layers, their indexer keys, and window layers' rows under a second table
+  that holds the window only;
 - host side: ``BlockManager`` does the step.cu bookkeeping (free list, per-seq
   tables, allocate/extend/free, preemption candidates) in plain Python — the
   allocator runs between device steps, so there is no launch-latency reason to
@@ -32,9 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["PagedKVPool", "BlockManager", "init_paged_pool", "write_kv_block", "gather_kv",
-           "copy_blocks"]
-
+__all__ = ["PagedKVPool", "LatentKVPool", "BlockManager", "init_paged_pool", "init_latent_pool",
+           "write_kv_block", "write_rows", "gather_kv", "copy_blocks"]
 
 @dataclasses.dataclass
 class PagedKVPool:
@@ -63,6 +66,65 @@ class PagedKVPool:
 
 
 jax.tree_util.register_dataclass(PagedKVPool, data_fields=["kv", "scale"], meta_fields=[])
+
+
+@dataclasses.dataclass
+class LatentKVPool:
+    """Three planes side by side, each ``[layers of its kind, blocks, block_size,
+    width]`` with a token's row minor, donated and carried whole like
+    :class:`PagedKVPool`:
+
+    - ``kv``   the full-attention layers' latent row ``(c_kv | roped k_pe)``,
+    - ``idx``  the same layers' indexer key,
+      both addressed by a sequence's block table (``tables[:, 0]``);
+    - ``win``  the window layers' latent row, under a second table
+      (``tables[:, 1]``) that holds blocks for the window only: the
+      ``BlockManager`` gives back what falls behind it, so this plane has
+      its own, much smaller block count.
+
+    ``stats`` int32 [n] rides along: what the last launch's layers counted on
+    the device (``LatentInferenceModel.STATS``), read at the sync point."""
+
+    kv: jnp.ndarray
+    idx: jnp.ndarray
+    win: jnp.ndarray
+    stats: jnp.ndarray
+    scale = None  # no quantized form of the latent planes
+
+    @property
+    def num_blocks(self) -> int:
+        return self.kv.shape[1]
+
+    @property
+    def block_size(self) -> int:
+        return self.kv.shape[2]
+
+    @property
+    def quantized(self) -> bool:
+        return False
+
+
+jax.tree_util.register_dataclass(LatentKVPool, data_fields=["kv", "idx", "win", "stats"], meta_fields=[])
+
+
+def init_latent_pool(n_full: int, n_window: int, num_blocks: int, num_window_blocks: int, block_size: int,
+                     widths: Dict[str, int], n_stats: int, dtype=jnp.bfloat16) -> LatentKVPool:
+    plane = lambda layers, blocks, width: jnp.zeros((max(layers, 1), blocks, block_size, width), dtype)
+    return LatentKVPool(kv=plane(n_full, num_blocks, widths["kv"]), idx=plane(n_full, num_blocks, widths["idx"]),
+                        win=plane(n_window, num_window_blocks, widths["win"]), stats=jnp.zeros((n_stats,), jnp.int32))
+
+
+def write_rows(plane: jnp.ndarray, rows: jnp.ndarray, table: jnp.ndarray, positions: jnp.ndarray,
+               valid: jnp.ndarray, layer) -> jnp.ndarray:
+    """Scatter a batch's new rows into ``plane[layer]``: rows [B, T, width] at
+    absolute ``positions`` [B, T] through ``table`` [B, max_blocks]. One
+    scatter of whole rows, in place on a donated plane. Rows that are not
+    ``valid`` [B, T] (padding of a chunk or of the batch) land in the sentinel
+    block 0 and never in a block some sequence owns."""
+    bs = plane.shape[2]
+    slot = jnp.minimum(positions // bs, table.shape[1] - 1)
+    blocks = jnp.where(valid, jnp.take_along_axis(table, slot, axis=1), 0)
+    return plane.at[layer, blocks, positions % bs].set(rows.astype(plane.dtype))
 
 _QMAX = {"int8": 127.0, "fp8": 448.0}  # float8_e4m3 max normal
 
@@ -207,7 +269,18 @@ class BlockManager:
     """
 
     def __init__(self, num_blocks: int, block_size: int, max_blocks_per_seq: int,
-                 enable_prefix_cache: bool = False):
+                 enable_prefix_cache: bool = False, window_back: Optional[int] = None,
+                 num_window_blocks: int = 0):
+        # a second table a sequence for layer kinds that keep a window only
+        # (``LatentKVPool.win``): ``window_back`` = how many
+        # positions behind a query its layers still read; logical block ->
+        # block of the window plane, given back once wholly behind the window
+        self.window_back = window_back
+        self.window_free: List[int] = list(range(1, num_window_blocks))  # block 0 = sentinel
+        self.window_tables: Dict[int, Dict[int, int]] = {}
+        if window_back is not None and enable_prefix_cache:
+            raise ValueError("prefix caching is refused beside a window cache: a shared prefix's window "
+                             "planes are gone by the time a second request could reuse its blocks")
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq
         self.total_usable_blocks = num_blocks - 1
@@ -469,6 +542,44 @@ class BlockManager:
         self.lengths[seq_id] = new_len
         return new_blocks
 
+    # ------------------------------------------------------------- window table
+    @property
+    def table_shape(self) -> Tuple[int, ...]:
+        """Shape of one sequence's :meth:`table_array`."""
+        return (self.max_blocks_per_seq,) if self.window_back is None else (2, self.max_blocks_per_seq)
+
+    def window_span(self, seq_id: int, start: int, n: int) -> int:
+        """Before a launch feeds positions ``[start, start + n)`` of ``seq_id``:
+        give back the window blocks that lie wholly behind ``start -
+        window_back`` and take blocks up to the last position fed. Returns how
+        many came back. A no-op (0) without a window cache. The window plane is
+        sized so that every slot can hold its window plus one launch's tokens
+        (``SingleDeviceBackend``), so running out is a fault, not pressure."""
+        if self.window_back is None:
+            return 0
+        table = self.window_tables.setdefault(seq_id, {})
+        bs = self.block_size
+        first = max(0, start - self.window_back) // bs
+        behind = [b for b in table if b < first]
+        for b in behind:
+            self.window_free.append(table.pop(b))
+        for b in range(first, (start + n - 1) // bs + 1):
+            if b not in table:
+                if not self.window_free:
+                    raise RuntimeError("out of window-cache blocks: the window plane is smaller than "
+                                       "slots x (window + tokens a launch feeds)")
+                table[b] = self.window_free.pop()
+        return len(behind)
+
+    def _drop_window(self, seq_id: int, keep_below: int = 0):
+        table = self.window_tables.get(seq_id)
+        if table is None:
+            return
+        for b in [b for b in table if b >= keep_below]:
+            self.window_free.append(table.pop(b))
+        if not keep_below:
+            del self.window_tables[seq_id]
+
     def shrink(self, seq_id: int, new_len: int):
         """Release blocks beyond ``new_len`` tokens (undo speculative multi-step
         extension after a sequence finished early). Refcount-aware: a shared
@@ -476,6 +587,7 @@ class BlockManager:
         if seq_id not in self.tables:
             return
         keep = max(self.blocks_needed(new_len), 1)
+        self._drop_window(seq_id, keep_below=keep)
         blocks = self.tables[seq_id]
         if keep < len(blocks):
             for b in blocks[keep:]:
@@ -488,6 +600,7 @@ class BlockManager:
         blocks = self.tables.pop(seq_id, [])
         self.lengths.pop(seq_id, None)
         self._seq_epoch.pop(seq_id, None)
+        self._drop_window(seq_id)
         for b in blocks:
             self._release_block(b)
 
@@ -505,6 +618,7 @@ class BlockManager:
         blocks = self.tables.pop(seq_id, None)
         self.lengths.pop(seq_id, None)
         epoch = self._seq_epoch.pop(seq_id, None)
+        self._drop_window(seq_id)
         if blocks is None:
             return
         if self.enable_prefix_cache and token_ids is not None and epoch == self._cache_epoch:
@@ -548,10 +662,17 @@ class BlockManager:
             self.host_tier.clear()
 
     def table_array(self, seq_id: int) -> np.ndarray:
-        """Padded table row (sentinel block 0 for unused slots)."""
-        out = np.zeros(self.max_blocks_per_seq, dtype=np.int32)
+        """Padded table row (sentinel block 0 for unused slots); with a window
+        cache, two rows: the block table and the window table, the latter
+        holding blocks at the logical blocks inside the window and 0 elsewhere."""
+        out = np.zeros(self.table_shape, dtype=np.int32)
         blocks = self.tables.get(seq_id, [])
-        out[: len(blocks)] = blocks
+        if self.window_back is None:
+            out[: len(blocks)] = blocks
+            return out
+        out[0, : len(blocks)] = blocks
+        for logical, block in self.window_tables.get(seq_id, {}).items():
+            out[1, logical] = block
         return out
 
     def longest_seq(self) -> Optional[int]:

@@ -66,6 +66,7 @@ from ..utils.env import device_peak_flops  # the one peak table; re-exported her
 __all__ = [
     "GoodputLedger",
     "LAUNCH_GEOMETRY",
+    "KIND_COUNTERS",
     "WASTE_KINDS",
     "REWORK_KINDS",
     "compile_attribution",
@@ -93,6 +94,16 @@ REWORK_KINDS = ("preempt_refill", "requeue_refill", "cow_token", "migration_rese
 #: is rows_live / rows, and the bytes a paged-attention kernel has to read
 #: follow from kv_positions
 LAUNCH_GEOMETRY = ("rows_live", "rows", "kv_positions")
+
+#: what a launch of layer kinds that count on the device reports beside the
+#: geometry (``experimental/latent_model.py:LatentInferenceModel.STATS``):
+#: positions the indexer scored and kept, routed choices that landed on held
+#: experts and all of them, the busiest held expert's tokens (summed over the
+#: launch's expert layers and decode sub-steps, so that x experts held /
+#: assignments_local is max over mean). Launch-span args, and monotone
+#: ``totals`` where a launch carries them (a program without such layers never does)
+KIND_COUNTERS = ("index_candidates", "index_selected", "expert_assignments_local",
+                 "expert_assignments", "expert_tokens_max")
 
 #: step-program vocabulary the ledger accounts by (also the ``{program}``
 #: label of the serving compile counters)
@@ -186,6 +197,9 @@ class GoodputLedger:
         if geometry:
             for g in LAUNCH_GEOMETRY:
                 bk[g] += geometry[g]
+            for g in KIND_COUNTERS:
+                if g in geometry:
+                    self.totals[g] = self.totals.get(g, 0) + geometry[g]
         now = time.time()
         if self._first_record_t is None:
             self._first_record_t = now

@@ -20,7 +20,7 @@ static-analysis suite loads it by file path without executing
 
 from __future__ import annotations
 
-__all__ = ["SPAN_CATALOG"]
+__all__ = ["SPAN_CATALOG", "DEVICE_SCOPES", "LAUNCH_ARGS"]
 
 SPAN_CATALOG = {
     # ------------------------------------------------------------- engine (cat="engine")
@@ -70,4 +70,41 @@ SPAN_CATALOG = {
     # ------------------------------------------------------------- profiler
     "profiler_window_start": "instant: jax.profiler capture window opened",
     "profiler_window_stop": "instant: jax.profiler capture window closed",
+}
+
+#: ``jax.named_scope`` names the serving step programs put on their operations
+#: (a device profile shows them in each operation's ``tf_op``): the llama kind's
+#: (``experimental/inference_model.py``) and the latent kinds'
+#: (``experimental/latent_model.py``, ``transformers/latent_layers.py``).
+#: ``bench/harness/program_spans.py`` and ``bench/harness/latent_scopes.py`` read them.
+DEVICE_SCOPES = {
+    "embed": "token embedding lookup", "attn_norm": "the layer's input RMS norm",
+    "qkv": "llama kind: q/k/v projections", "rope": "llama kind: rotary embedding of q and k",
+    "kv_write": "scatter of the fed tokens' rows into the pool (latent kinds: nested latent_plane / index_plane / window_plane)",
+    "paged_attn": "llama kind: the Pallas ragged paged attention kernel", "attn_gather": "llama kind: XLA gather + attend",
+    "o_proj": "attention output projection", "mlp_norm": "post-attention RMS norm", "mlp": "dense SwiGLU MLP",
+    "final_norm": "final RMS norm", "lm_head": "output head", "sample": "on-device sampler", "bookkeeping": "counts, stops, positions",
+    "mla_proj": "latent kinds: low-rank q and kv chains (norm, rescale, RoPE of the pe slices)",
+    "indexer": "latent_full: indexer projections and the scores of cached positions against a query",
+    "index_topk": "latent_full: the k-th largest score a query (counting passes) or top_k of a decode row",
+    "latent_gather": "latent_full decode: gather of the kept positions' latent rows through the block table",
+    "mla_attn": "latent_full: attention over the kept positions (absorbed for one token, expanded tiles for a chunk)",
+    "window_attn": "latent_window: gather of the window's blocks and attention over them",
+    "attn_gate": "latent kinds: headwise sigmoid gate on the attention output",
+    "router": "expert layer: float32 sigmoid scores, top-k of score + bias, per-expert counts",
+    "experts": "expert layer: the held experts' tiles (rows ranked by expert, one tile of one expert a loop turn)",
+    "shared_expert": "expert layer: the shared expert on every token",
+}
+
+#: args a launch span (``prefill`` / ``decode`` / ``mixed_step`` / ``spec_verify``) carries once the
+#: launch has returned: the geometry (``goodput.LAUNCH_GEOMETRY``) and, from programs whose layers
+#: count them, ``goodput.KIND_COUNTERS``
+LAUNCH_ARGS = {
+    "rows_live": "rows that fed at least one real token", "rows": "padded rows of the launch",
+    "kv_positions": "cached positions the live rows' attention had to cover",
+    "index_candidates": "cached positions the sparse indexer scored for live queries, over full layers (device count)",
+    "index_selected": "positions its selection kept for attention: the top index_topk a query, ties with the last included (device count)",
+    "expert_assignments_local": "routed choices of live tokens that landed on experts held here (device count)",
+    "expert_assignments": "all routed choices of live tokens: tokens x experts a token x expert layers",
+    "expert_tokens_max": "the busiest held expert's tokens, summed over expert layers and decode sub-steps",
 }

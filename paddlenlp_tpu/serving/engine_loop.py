@@ -536,6 +536,16 @@ class ServingMetrics:
             "paddlenlp_serving_useful_tokens_total",
             "Fed positions that built new KV or emitted a kept token "
             "(the goodput numerator)")
+        self.expert_assignments = r.counter(
+            "paddlenlp_serving_expert_assignments_total",
+            "Routed expert choices of live tokens (held=local: those that "
+            "landed on experts this process holds; held=all: every choice)",
+            labelnames=("held",))
+        self.index_positions = r.counter(
+            "paddlenlp_serving_index_positions_total",
+            "Cached positions the sparse indexer scored (kind=candidates) and "
+            "kept for attention (kind=selected), over full-attention layers",
+            labelnames=("kind",))
         self.wasted_tokens = r.counter(
             "paddlenlp_serving_wasted_tokens_total",
             "Non-useful fed positions by waste kind (padding = bucket pads + "
@@ -747,6 +757,14 @@ class ServingMetrics:
                 delta = totals.get(kind, 0) - self._gp_last.get(kind, 0)
                 if delta > 0:
                     self.wasted_tokens.inc(delta, kind=kind)
+            for key, counter, label in (
+                    ("expert_assignments_local", self.expert_assignments, {"held": "local"}),
+                    ("expert_assignments", self.expert_assignments, {"held": "all"}),
+                    ("index_candidates", self.index_positions, {"kind": "candidates"}),
+                    ("index_selected", self.index_positions, {"kind": "selected"})):
+                delta = totals.get(key, 0) - self._gp_last.get(key, 0)
+                if delta > 0:
+                    counter.inc(delta, **label)
             self._gp_last = dict(totals)
             for program, n in gp.get("compiles", {}).items():
                 delta = n - self._compile_last.get(program, 0)

@@ -37,6 +37,7 @@ def _populate():
     from ..qwen2_moe.configuration import Qwen2MoeConfig
     from ..bart.configuration import BartConfig
     from ..deepseek_v2.configuration import DeepseekV2Config
+    from ..dots3_note.configuration import Dots3NoteConfig
     from ..mamba.configuration import MambaConfig
     from ..rw.configuration import RWConfig
     from ..chatglm.configuration import ChatGLMConfig
@@ -69,7 +70,7 @@ def _populate():
 
     for cfg in (LlamaConfig, GPTConfig, Qwen2Config, MistralConfig, GemmaConfig, BertConfig,
                 ErnieConfig, MixtralConfig, Qwen2MoeConfig, BaichuanConfig, BloomConfig,
-                OPTConfig, QWenConfig, ChatGLMv2Config, T5Config, BartConfig, DeepseekV2Config,
+                OPTConfig, QWenConfig, ChatGLMv2Config, T5Config, BartConfig, DeepseekV2Config, Dots3NoteConfig,
                 MambaConfig, RWConfig, ChatGLMConfig, YuanConfig, JambaConfig,
                 AlbertConfig, ElectraConfig, RobertaConfig,
                 MT5Config, MBartConfig, PegasusConfig,

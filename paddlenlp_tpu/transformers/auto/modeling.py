@@ -100,6 +100,10 @@ def _populate_models():
 
     register_model("deepseek_v2", "base", deepseek_v2.DeepseekV2Model)
     register_model("deepseek_v2", "causal_lm", deepseek_v2.DeepseekV2ForCausalLM)
+    from ..dots3_note import modeling as dots3_note
+
+    register_model("dots3_note", "base", dots3_note.Dots3NoteModel)
+    register_model("dots3_note", "causal_lm", dots3_note.Dots3NoteForCausalLM)
     from ..mamba import modeling as mamba
 
     register_model("mamba", "base", mamba.MambaModel)

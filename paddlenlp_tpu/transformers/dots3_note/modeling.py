@@ -1,0 +1,194 @@
+"""dots3_note text decoder: latent attention of two kinds, a sparse indexer,
+sigmoid-routed experts that know which they hold.
+
+The layer mathematics is ``transformers/latent_layers.py``'s: plain functions
+over one layer's parameter tree, so that the whole-sequence module below
+(``AutoModel``, no cache) and the serving step programs the configuration names
+(``Dots3NoteConfig.inference_model``) compute the same thing from the same
+code. Here: the parameter tree, the whole-sequence forward, the flax modules,
+checkpoint names and partition rules.
+
+Left out: the vision and audio towers and the MTP module."""
+
+from __future__ import annotations
+
+from typing import Dict
+
+import jax.numpy as jnp
+from flax import linen as nn
+
+from ...parallel.partition import P
+from ..conversion_utils import StackedLayerMapping, auto_name_mappings
+from ..model_utils import PretrainedModel
+from ..latent_layers import LATENT_FULL, attention_dense, mlp, rms_norm
+from .configuration import Dots3NoteConfig
+
+__all__ = ["Dots3NoteModel", "Dots3NoteForCausalLM", "Dots3NotePretrainedModel", "param_tree_shapes"]
+
+FLOAT32_LEAVES = ("scale", "bias", "e_score_correction_bias")  # kept float32 whatever the weights' dtype
+
+
+# ------------------------------------------------------------------ the parameter tree
+def _attn_shapes(cfg, kind):
+    d, hidden = cfg.attention_dims(kind), cfg.hidden_size
+    heads = d["heads"]
+    out = {
+        "q_a_proj": {"kernel": (hidden, d["q_lora"])},
+        "q_a_layernorm": {"scale": (d["q_lora"],)},
+        "q_b_proj": {"kernel": (d["q_lora"], heads * (d["nope"] + d["rope"]))},
+        "kv_a_proj_with_mqa": {"kernel": (hidden, d["kv_lora"] + d["rope"])},
+        "kv_a_layernorm": {"scale": (d["kv_lora"],)},
+        "kv_b_proj": {"kernel": (d["kv_lora"], heads * (d["nope"] + d["v"]))},
+        "o_proj": {"kernel": (heads * d["v"], hidden)},
+        "gate_proj": {"kernel": (hidden, heads)},
+    }
+    if kind == LATENT_FULL:
+        out["indexer"] = {
+            "wq_b": {"kernel": (d["q_lora"], cfg.index_n_heads * cfg.index_head_dim)},
+            "wk": {"kernel": (hidden, cfg.index_head_dim)},
+            "k_norm": {"scale": (cfg.index_head_dim,), "bias": (cfg.index_head_dim,)},
+            "weights_proj": {"kernel": (hidden, cfg.index_n_heads)},
+        }
+    return out
+
+
+def _swiglu_shapes(hidden, width):
+    return {"gate_proj": {"kernel": (hidden, width)}, "up_proj": {"kernel": (hidden, width)},
+            "down_proj": {"kernel": (width, hidden)}}
+
+
+def _mlp_shapes(cfg, layer):
+    hidden = cfg.hidden_size
+    if layer < cfg.first_k_dense_replace:
+        return _swiglu_shapes(hidden, cfg.intermediate_size)
+    held, width = cfg.n_routed_experts, cfg.moe_intermediate_size
+    return {
+        "gate": {"kernel": (hidden, cfg.n_routed_experts_total)},
+        "e_score_correction_bias": (cfg.n_routed_experts_total,),
+        "experts": {"gate_proj": (held, hidden, width), "up_proj": (held, hidden, width),
+                    "down_proj": (held, width, hidden)},
+        "shared_experts": _swiglu_shapes(hidden, width * cfg.n_shared_experts),
+    }
+
+
+def param_tree_shapes(cfg, causal_lm: bool = True) -> Dict:
+    """{path: shape} nested as the module's parameters are."""
+    hidden = cfg.hidden_size
+    model = {"embed_tokens": {"embedding": (cfg.vocab_size, hidden)}, "norm": {"scale": (hidden,)}}
+    for i, kind in enumerate(cfg.layer_kinds()):
+        model[f"layers_{i}"] = {
+            "input_layernorm": {"scale": (hidden,)},
+            "post_attention_layernorm": {"scale": (hidden,)},
+            "self_attn": _attn_shapes(cfg, kind),
+            "mlp": _mlp_shapes(cfg, i),
+        }
+    out = {"model": model}
+    if causal_lm:
+        out["lm_head"] = {"kernel": (hidden, cfg.vocab_size)}
+    return out
+
+
+# ------------------------------------------------------------------ whole-sequence forward (no cache)
+def decoder_forward(cfg, params, input_ids, positions=None, dtype=jnp.float32):
+    """Whole-sequence forward of the text decoder: hidden states [B, T, hidden] after the final norm."""
+    m = params["model"] if "model" in params else params
+    b, t = input_ids.shape
+    if positions is None:
+        positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None, :], (b, t))
+    h = m["embed_tokens"]["embedding"][input_ids].astype(dtype)
+    for i, kind in enumerate(cfg.layer_kinds()):
+        lp = m[f"layers_{i}"]
+        x = rms_norm(h, lp["input_layernorm"]["scale"], cfg.rms_norm_eps)
+        h = h + attention_dense(lp["self_attn"], x, positions, cfg, kind)[0]
+        x = rms_norm(h, lp["post_attention_layernorm"]["scale"], cfg.rms_norm_eps)
+        h = h + mlp(lp["mlp"], x, cfg, i)[0]
+    return rms_norm(h, m["norm"]["scale"], cfg.rms_norm_eps)
+
+
+# ------------------------------------------------------------------ flax modules
+class _Params(nn.Module):
+    """Declares a nested tree of parameters from its shapes and returns it."""
+
+    shapes: Dict
+    std: float
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self):
+        out = {}
+        for name, shape in self.shapes.items():
+            if isinstance(shape, dict) or hasattr(shape, "items"):
+                out[name] = _Params(dict(shape), self.std, self.param_dtype, name=name)()
+            elif name in FLOAT32_LEAVES:
+                init = nn.initializers.ones if name == "scale" else nn.initializers.zeros
+                out[name] = self.param(name, init, tuple(shape), jnp.float32)
+            else:
+                out[name] = self.param(name, nn.initializers.normal(self.std), tuple(shape), self.param_dtype)
+        return out
+
+
+class Dots3NoteModule(nn.Module):
+    config: Dots3NoteConfig
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+    causal_lm = False
+
+    @nn.compact
+    def __call__(self, input_ids, position_ids=None, deterministic: bool = True):
+        cfg = self.config
+        shapes = param_tree_shapes(cfg, self.causal_lm)
+        params = {k: _Params(v, cfg.initializer_range, self.param_dtype, name=k)() for k, v in shapes.items()}
+        h = decoder_forward(cfg, params, input_ids, position_ids, self.dtype)
+        if not self.causal_lm:
+            return h
+        return (h @ params["lm_head"]["kernel"].astype(self.dtype)).astype(jnp.float32)
+
+
+class Dots3NoteForCausalLMModule(Dots3NoteModule):
+    causal_lm = True
+
+
+class Dots3NotePretrainedModel(PretrainedModel):
+    config_class = Dots3NoteConfig
+    base_model_prefix = "model"
+
+    @classmethod
+    def get_partition_rules(cls, config=None):
+        return [
+            (r"embed_tokens/embedding$", P("vocab", "embed")),
+            (r"self_attn/(q_a_proj|kv_a_proj_with_mqa|gate_proj)/kernel$", P("embed", None)),
+            (r"self_attn/(q_b_proj|kv_b_proj)/kernel$", P(None, "heads")),
+            (r"self_attn/o_proj/kernel$", P("heads", "embed")),
+            (r"indexer/(wq_b|wk|weights_proj)/kernel$", P()),
+            (r"mlp/gate/kernel$", P("embed", None)),
+            (r"mlp/experts/(gate_proj|up_proj)$", P("expert", "embed", "mlp")),
+            (r"mlp/experts/down_proj$", P("expert", "mlp", "embed")),
+            (r"(mlp|shared_experts)/(gate_proj|up_proj)/kernel$", P("embed", "mlp")),
+            (r"(mlp|shared_experts)/down_proj/kernel$", P("mlp", "embed")),
+            (r"lm_head/kernel$", P("embed", "vocab")),
+            (r"(scale|bias|e_score_correction_bias)$", P()),
+        ]
+
+    @classmethod
+    def _get_name_mappings(cls, config, flat_shapes):
+        """Checkpoint names: the stacked experts are ``mlp.experts.<n>.<proj>.weight``
+        of the held range; everything else maps by its own path."""
+        mappings, plain = [], {}
+        for path, leaf in flat_shapes.items():
+            tail = path.rsplit("/", 1)[-1]
+            if "/mlp/experts/" in path and tail in ("gate_proj", "up_proj", "down_proj"):
+                layer = path.split("/layers_")[1].split("/")[0]
+                tpl = f"model.layers.{layer}.mlp.experts.{{}}.{tail}.weight"
+                mappings.append(StackedLayerMapping(tpl, path, action="transpose", dims=(config.n_routed_experts,)))
+            else:
+                plain[path] = leaf
+        mappings.extend(auto_name_mappings(plain))
+        return mappings
+
+
+class Dots3NoteModel(Dots3NotePretrainedModel):
+    module_class = Dots3NoteModule
+
+
+class Dots3NoteForCausalLM(Dots3NotePretrainedModel):
+    module_class = Dots3NoteForCausalLMModule

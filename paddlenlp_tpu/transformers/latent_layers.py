@@ -1,0 +1,253 @@
+"""Layer mathematics of the latent layer kinds, as plain functions over one
+layer's parameter tree: what a whole-sequence module (``transformers/<model>/
+modeling.py``, no cache) and the serving step programs
+(``experimental/latent_model.py``, paged planes) both compute, from the same code.
+
+Two kinds of layer, named as a configuration's ``layer_kinds()`` yields them:
+
+- ``latent_full``    latent attention (low-rank q and kv chains, one roped key
+                     head shared by all) over a learned top-k selection of the
+                     cached positions (the indexer);
+- ``latent_window``  latent attention with sizes of its own over a window.
+
+Either kind's MLP is dense SwiGLU or sigmoid-routed experts of which this
+process holds a share. The functions:
+
+- ``mla_project``     the q and kv chains of one attention kind: the normed,
+                      rescaled q latent, per-head q (nope | roped pe) and the
+                      cached row ``(c_kv | roped k_pe)``;
+- ``indexer_query`` / ``indexer_key`` / ``index_scores`` / ``kth_largest``
+                      the learned selection: which ``index_topk`` cached
+                      positions a query may attend;
+- ``route``           sigmoid scores in float32, top-k of score + bias over the
+                      router's full width, weights normalised over the chosen;
+- ``attention_dense`` one attention kind over whole sequences, expanded, causal;
+- ``experts_held``    the held experts' part of the result: assignments are
+                      sorted by expert into tile-aligned groups and a loop of
+                      data-dependent length runs one tile of one expert at a
+                      time, so no token is dropped and an expert nobody chose
+                      is never read.
+
+What they read of a configuration ``cfg``: ``attention_dims(kind)`` (heads,
+q_lora, kv_lora, nope, rope, v, theta, window, s_q, s_kv), ``index_n_heads``,
+``index_head_dim``, ``index_topk``, ``qk_rope_head_dim``, ``rms_norm_eps``,
+``num_experts_per_tok``, ``routed_scaling_factor``, ``experts_held`` (first,
+count) and ``first_k_dense_replace``."""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+LATENT_FULL, LATENT_WINDOW = "latent_full", "latent_window"
+NEG = -1e30
+
+def rms_norm(x, scale, eps):
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def layer_norm(x, scale, bias, eps):
+    x32 = x.astype(jnp.float32)
+    mean = jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x32 - mean), axis=-1, keepdims=True)
+    y = (x32 - mean) * jax.lax.rsqrt(var + eps)
+    return (y * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+def rope(x, positions, theta):
+    """Rotate-half RoPE over the last axis of ``x`` [..., d] at ``positions``
+    (broadcast against ``x``'s leading axes), in float32."""
+    d = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = positions.astype(jnp.float32)[..., None] * inv
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)
+    x32 = x.astype(jnp.float32)
+    rot = jnp.concatenate([-x32[..., d // 2:], x32[..., : d // 2]], -1)
+    return (x32 * cos + rot * sin).astype(x.dtype)
+
+
+def _mm(p, x):
+    return x @ p["kernel"].astype(x.dtype)
+
+
+def swiglu(p, x):
+    return _mm(p["down_proj"], jax.nn.silu(_mm(p["gate_proj"], x)) * _mm(p["up_proj"], x))
+
+
+def mla_project(p, x, positions, d, eps):
+    """x [..., hidden] at ``positions`` [...] -> (c_q [..., q_lora], q_nope
+    [..., H, nope], q_pe [..., H, rope] roped, row [..., kv_lora + rope]): the
+    row is what a latent cache holds for the token, ``(c_kv | roped k_pe)``."""
+    c_q = rms_norm(_mm(p["q_a_proj"], x), p["q_a_layernorm"]["scale"], eps) * jnp.asarray(d["s_q"], x.dtype)
+    q = _mm(p["q_b_proj"], c_q).reshape(x.shape[:-1] + (d["heads"], d["nope"] + d["rope"]))
+    q_nope, q_pe = q[..., : d["nope"]], rope(q[..., d["nope"]:], positions[..., None], d["theta"])
+    kv_a = _mm(p["kv_a_proj_with_mqa"], x)
+    c_kv = rms_norm(kv_a[..., : d["kv_lora"]], p["kv_a_layernorm"]["scale"], eps) * jnp.asarray(d["s_kv"], x.dtype)
+    k_pe = rope(kv_a[..., d["kv_lora"]:], positions, d["theta"])
+    return c_q, q_nope, q_pe, jnp.concatenate([c_kv, k_pe], -1)
+
+
+def kv_b_split(p, d):
+    """``kv_b_proj`` as (W^K [kv_lora, H, nope], W^V [kv_lora, H, v])."""
+    w = p["kv_b_proj"]["kernel"].reshape(d["kv_lora"], d["heads"], d["nope"] + d["v"])
+    return w[..., : d["nope"]], w[..., d["nope"]:]
+
+
+def indexer_query(p, c_q, x, positions, cfg, theta):
+    """(q_I [..., heads, dim] with RoPE on its first ``qk_rope_head_dim`` dims,
+    w [..., heads] float32, scaled by heads^-1/2 dim^-1/2)."""
+    n, dim, r = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+    q = _mm(p["wq_b"], c_q).reshape(x.shape[:-1] + (n, dim))
+    q = jnp.concatenate([rope(q[..., :r], positions[..., None], theta), q[..., r:]], -1)
+    w = _mm(p["weights_proj"], x).astype(jnp.float32) * (n ** -0.5 * dim ** -0.5)
+    return q, w
+
+
+def indexer_key(p, x, positions, cfg, theta):
+    """k_I [..., dim]: LayerNorm(x W_Ik), RoPE on the first ``qk_rope_head_dim`` dims: the indexer plane's row."""
+    r = cfg.qk_rope_head_dim
+    k = layer_norm(_mm(p["wk"], x), p["k_norm"]["scale"], p["k_norm"]["bias"], cfg.rms_norm_eps)
+    return jnp.concatenate([rope(k[..., :r], positions, theta), k[..., r:]], -1)
+
+
+def index_scores(q_i, w, k_i):
+    """I[..., t, s] = sum_h w[t, h] relu(q_I[t, h] . k_I[s]): inputs in their own
+    dtype, products accumulated and everything after them in float32.
+    q_i [..., T, n, dim], w [..., T, n], k_i [..., S, dim] -> [..., T, S]."""
+    qk = jnp.einsum("...tnd,...sd->...tns", q_i, k_i, preferred_element_type=jnp.float32)
+    # highest: on a TPU a float32 product is otherwise rounded to bfloat16 first, and this sum decides a top-k
+    return jnp.einsum("...tns,...tn->...ts", jax.nn.relu(qk), w, precision="highest")
+
+
+def _sortable(x):
+    """float32 -> uint32 whose unsigned order is the floats' order."""
+    u = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
+
+
+def kth_largest(scores, valid, k):
+    """Per row of ``scores`` [..., S], a key such that ``sortable(score) >= key``
+    holds for exactly the k largest valid scores (ties with the k-th included;
+    every valid score where there are at most k). Exact: the key is built bit
+    by bit, each bit one compare-and-count pass, so no sort of S values is made.
+    Returns (keys [..., S] uint32 with invalid entries lowest, threshold [..., 1])."""
+    keys = jnp.where(valid, _sortable(scores), jnp.uint32(0))
+
+    def bit(i, thr):
+        cand = thr | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        enough = jnp.sum(keys >= cand, axis=-1, keepdims=True, dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, thr)
+
+    thr = jax.lax.fori_loop(0, 32, bit, jnp.zeros(keys.shape[:-1] + (1,), jnp.uint32))
+    return keys, thr
+
+
+def route(p, x2d, cfg):
+    """x2d [N, hidden] -> (experts [N, k] int32 over the router's full width,
+    weights [N, k] float32). Scores are float32 whatever the weights' dtype."""
+    logits = jnp.matmul(x2d.astype(jnp.float32), p["gate"]["kernel"].astype(jnp.float32), precision="highest")
+    s = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(s + p["e_score_correction_bias"].astype(jnp.float32), cfg.num_experts_per_tok)
+    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    return idx.astype(jnp.int32), chosen / jnp.sum(chosen, -1, keepdims=True) * cfg.routed_scaling_factor
+
+
+def held_counts(idx, first, count):
+    """How many of the routed choices ``idx`` [N, k] fell on each held expert: [count] int32."""
+    local = idx - first
+    ok = (local >= 0) & (local < count)
+    return jnp.sum(jax.nn.one_hot(jnp.where(ok, local, count), count + 1, dtype=jnp.int32), axis=(0, 1))[:count]
+
+
+def experts_held(p, x2d, idx, w, first, count, live=None):
+    """The held experts' part of ``sum_k w_k E_k(x)``: x2d [N, hidden], idx/w
+    [N, k] as ``route`` gives them, experts ``first .. first + count - 1`` held
+    in ``p`` ([count, ...] stacked). ``live`` [N] bool leaves rows out (padding).
+
+    No capacity and no drop: the N*k assignments are ranked within their expert
+    and laid into a buffer where each expert's group starts on a tile boundary;
+    the loop runs as many tiles as the groups fill (known only on the device),
+    each one expert's weights against one tile of rows."""
+    n, k = idx.shape
+    hidden = x2d.shape[-1]
+    tile = 128 if n * k >= 1024 else 16
+    local = idx - first
+    ok = (local >= 0) & (local < count)
+    if live is not None:
+        ok &= live[:, None]
+    flat = jnp.where(ok, local, count).reshape(-1)  # [A], count = not held here
+    onehot = jax.nn.one_hot(flat, count + 1, dtype=jnp.int32)
+    sizes = jnp.sum(onehot, 0)[:count]
+    rank = jnp.take_along_axis(jnp.cumsum(onehot, 0) - onehot, flat[:, None], 1)[:, 0]
+    tiles = (sizes + tile - 1) // tile
+    tile_end = jnp.cumsum(tiles)  # expert e owns tiles [tile_end[e] - tiles[e], tile_end[e])
+    group_start = (tile_end - tiles) * tile
+    rows = n * k + count * tile  # every assignment held, every group padded: the static bound
+    dest = jnp.where(flat < count, jnp.take(group_start, jnp.minimum(flat, count - 1)) + rank, rows)
+    token_of_row = jnp.full((rows,), n, jnp.int32).at[dest].set(
+        jnp.repeat(jnp.arange(n, dtype=jnp.int32), k), mode="drop")
+    x_rows = jnp.concatenate([x2d, jnp.zeros((1, hidden), x2d.dtype)], 0)[token_of_row]
+    w_gate, w_up, w_down = p["gate_proj"], p["up_proj"], p["down_proj"]
+
+    def one_tile(i, y_rows):
+        e = jnp.sum(tile_end <= i).astype(jnp.int32)
+        xs = jax.lax.dynamic_slice_in_dim(x_rows, i * tile, tile, 0)
+        act = jax.nn.silu(xs @ w_gate[e].astype(xs.dtype)) * (xs @ w_up[e].astype(xs.dtype))
+        return jax.lax.dynamic_update_slice_in_dim(y_rows, act @ w_down[e].astype(xs.dtype), i * tile, 0)
+
+    y_rows = jax.lax.fori_loop(0, tile_end[-1], one_tile, jnp.zeros((rows, hidden), x2d.dtype))
+    picked = y_rows[jnp.minimum(dest, rows - 1)].reshape(n, k, hidden)
+    weight = jnp.where(ok, w, 0.0).astype(jnp.float32)
+    return jnp.einsum("nkd,nk->nd", picked.astype(jnp.float32), weight).astype(x2d.dtype)
+
+
+def moe(p, x, cfg, live=None):
+    """The expert layer on x [..., hidden]: routed part of the held experts plus the shared expert."""
+    x2d = x.reshape(-1, x.shape[-1])
+    with jax.named_scope("router"):
+        idx, w = route(p, x2d, cfg)
+    first, count = cfg.experts_held
+    with jax.named_scope("experts"):
+        y = experts_held(p["experts"], x2d, idx, w, first, count, live)
+    with jax.named_scope("shared_expert"):
+        y = y + swiglu(p["shared_experts"], x2d)
+    return y.reshape(x.shape), idx
+
+
+def mlp(p, x, cfg, layer, live=None):
+    if layer < cfg.first_k_dense_replace:
+        with jax.named_scope("mlp"):
+            return swiglu(p, x), None
+    return moe(p, x, cfg, live)
+
+
+# ------------------------------------------------------------------ whole-sequence attention (no cache)
+def attention_dense(p, x, positions, cfg, kind):
+    """One attention kind over whole sequences x [B, T, hidden], causal, in the
+    expanded form: the module's forward. Returns (out [B, T, hidden], selected
+    [B, T, T] bool: which positions each query attended)."""
+    d, eps = cfg.attention_dims(kind), cfg.rms_norm_eps
+    b, t, _ = x.shape
+    c_q, q_nope, q_pe, row = mla_project(p, x, positions, d, eps)
+    c_kv, k_pe = row[..., : d["kv_lora"]], row[..., d["kv_lora"]:]
+    w_k, w_v = kv_b_split(p, d)
+    k_nope = jnp.einsum("bsc,chn->bshn", c_kv, w_k.astype(x.dtype))
+    v = jnp.einsum("bsc,chv->bshv", c_kv, w_v.astype(x.dtype))
+    allowed = positions[:, None, :] <= positions[:, :, None]  # [B, T, S]
+    if kind == LATENT_WINDOW:
+        allowed &= positions[:, None, :] > positions[:, :, None] - d["window"]
+    else:
+        q_i, w_i = indexer_query(p["indexer"], c_q, x, positions, cfg, d["theta"])
+        k_i = indexer_key(p["indexer"], x, positions, cfg, d["theta"])
+        keys, thr = kth_largest(index_scores(q_i, w_i, k_i), allowed, cfg.index_topk)
+        allowed &= keys >= thr
+    s = (jnp.einsum("bthn,bshn->bhts", q_nope, k_nope, preferred_element_type=jnp.float32)
+         + jnp.einsum("bthr,bsr->bhts", q_pe, k_pe, preferred_element_type=jnp.float32))
+    s = s * (d["nope"] + d["rope"]) ** -0.5
+    prob = jax.nn.softmax(jnp.where(allowed[:, None], s, NEG), axis=-1)
+    o = jnp.einsum("bhts,bshv->bthv", prob.astype(x.dtype), v)
+    o = o * jax.nn.sigmoid(_mm(p["gate_proj"], x).astype(jnp.float32)).astype(x.dtype)[..., None]
+    return _mm(p["o_proj"], o.reshape(b, t, -1)), allowed
