@@ -9,7 +9,6 @@ Structure (classic flash-attention-2 schedule):
 - forward: grid = (batch*heads, T/block_q, S/block_kv); the kv axis is innermost
   and sequential ("arbitrary"), carrying VMEM scratch accumulators (m, l, acc);
   emits the per-row logsumexp L = m + log(l) as a residual for the backward;
-- fully-invisible blocks are skipped under causal/window masking (@pl.when);
 - GQA maps query-head blocks onto shared kv heads in the BlockSpec index maps —
   no materialized repeat;
 - backward: two kernels re-streaming K/V — dq (kv innermost) and dk/dv
@@ -18,9 +17,33 @@ Structure (classic flash-attention-2 schedule):
   the kernel: the grid batch axis is B*K and the innermost sequential axis
   walks (query-head-in-group, q-block) pairs, so for an N/K = g GQA model the
   dk/dv output traffic and K/V re-streaming drop by g× versus the per-query-head
-  scheme (outputs were [B*N, S, H] + an XLA group-sum pass; now [B*K, S, H]);
+  scheme. The dk/dv kernel works on the transposed score tile [block_kv, block_q],
+  so P^T dO and dS^T Q are plain matmuls and no tile is transposed;
 - ``segment_ids`` restricts attention to same-segment tokens (ZeroPadding packed
   batches); ``window`` adds the mistral sliding-window lower bound.
+
+What one grid step does. A step costs about 0.35 us whatever it does, so it
+has to do a tile's worth of work:
+- the tile comes from the shape (``_blocks``): ``_TILE`` on both axes, cut to
+  the sequence, with half the chip's VMEM asked for (``_vmem_share``);
+- a step the causal mask (or the window) skips names the block already resident:
+  the index maps clip to the first / last block the row of tiles needs
+  (``_kv_blocks``, ``_q_blocks``), so Pallas issues no copy for it;
+- every tile that runs builds the element mask (``_visible`` drops the tests
+  the call rules out); a second body without it for tiles wholly below the
+  diagonal bought nothing on the chip;
+- the MXU takes the operands in the inputs' precision with float32 accumulation
+  (bf16 q, k, v, dO as they are; p and dS cast to the inputs' dtype); running
+  maximum, sum, logsumexp, delta and every accumulator stay float32;
+- per-row statistics never have the shape [rows, 1]: logsumexp and delta cross
+  HBM as lane-dense [1, T] rows (a [T, 1] array is padded to 128 lanes there)
+  and live in VMEM as [rows, 128] with every lane alike (``_across``), since
+  arithmetic on a [rows, 1] value costs by the row.
+
+Left for later (sizes: PERF.md section 5, ROADMAP S3): the second forward kernel
+that remat runs (``save_qkv_attn`` saves the output but not the logsumexp
+residual); the part of a tile above the diagonal (strips inside a step would
+skip it); two 64-wide heads in one 128-lane tile.
 
 Off-TPU (tests), the kernels run in Pallas interpret mode.
 """
@@ -40,39 +63,137 @@ __all__ = ["flash_attention"]
 
 NEG_INF = -1e30
 
+_NT = (((1,), (1,)), ((), ()))  # [m, h] x [n, h] -> [m, n]
 
-def _visible(s_shape, q_start, k_start, causal, window, q_len, kv_len, seg_q, seg_k):
-    """Element-level visibility mask for one [block_q, block_kv] tile.
 
-    ``seg_q`` is [block_q, 1] and ``seg_k`` is [1, block_kv] (the trailing/leading
-    unit dims come from the TPU-tileable [B, T, 1] / [B, 1, S] segment layouts).
-    """
-    rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s_shape, 0)
-    cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s_shape, 1)
-    valid = (cols < kv_len) & (rows < q_len)
+def _nt(a, b):
+    return jax.lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+
+
+def _dot(a, b):
+    return jax.lax.dot(a, b, preferred_element_type=jnp.float32)
+
+
+# ---------------------------------------------------------------- tiles
+# Edge of the tile a grid step aims for, on both axes and in all three kernels
+# (chip sweeps on v5e), and the most scoped VMEM the chip's compiler needs for
+# such a step (head_dim 256, float32, segments and a window, compiled for v5p;
+# v5e and v6e fit every case into their defaults). PERF.md section 6, PR 28.
+_TILE = 1024
+_VMEM_STEP = 24 << 20
+
+
+def _vmem_share():
+    """Half the chip's VMEM, which a step with a tile over 512 x 512 asks for:
+    64 MiB on v5e, where the training step around the kernels is 5% faster for
+    it than with 32 MiB or the compiler's default of 16 (the kernels alone are
+    3% slower). None in a process without a chip (interpret mode, a compile for
+    a described chip): the compiler's default."""
+    try:
+        return pltpu.get_tpu_info().vmem_capacity_bytes // 2
+    except ValueError:
+        return None
+
+
+def _blocks(block_q, block_kv, q_len, kv_len):
+    """(block_q, block_kv): the caller's where given, else ``_TILE``, halved on a
+    chip whose share of VMEM does not hold such a step (v2 to v4 have 16 MiB in
+    all, a 1024 x 1024 step needs 17 to 21 MB there and a 512 x 512 one compiles
+    in every case); cut to the sequence. A block is then the whole axis or a
+    multiple of 128 for every shape the dispatcher's gate (``T % 128 == 0``)
+    lets through; an axis it does not divide ends in a partial block, which
+    the mask covers."""
+    share = _vmem_share()
+    tile = _TILE if share is None or share >= _VMEM_STEP else _TILE // 2
+    return min(block_q or tile, q_len), min(block_kv or tile, kv_len)
+
+
+def _compiler_params(block_q, block_kv):
+    large = block_q * block_kv > (_TILE // 2) ** 2
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"),
+                                vmem_limit_bytes=_vmem_share() if large else None)
+
+
+# ---------------------------------------------------------------- which tiles run
+def _kv_blocks(qi, block_q, block_kv, n_k, causal, window):
+    """First and last kv block the q block ``qi`` attends to."""
+    lo, hi = 0, n_k - 1
     if causal:
-        valid &= cols <= rows
+        hi = jnp.minimum((qi * block_q + block_q - 1) // block_kv, hi)
     if window is not None:
-        valid &= cols > rows - window
+        lo = jnp.maximum(qi * block_q - window + 1, 0) // block_kv
+    return lo, hi
+
+
+def _q_blocks(ki, block_q, block_kv, n_q, causal, window):
+    """First and last q block that attends to the kv block ``ki``."""
+    lo, hi = 0, n_q - 1
+    if causal:
+        lo = ki * block_kv // block_q
+    if window is not None:
+        hi = jnp.minimum((ki * block_kv + block_kv + window - 2) // block_q, hi)
+    return lo, hi
+
+
+# A tile's geometry, as ``_visible`` takes it:
+# (q_start, k_start, block_q, block_kv, causal, window, q_len, kv_len)
+def _visible(q_start, k_start, block_q, block_kv, causal, window, q_len, kv_len,
+             seg_q, seg_k, q_axis=0):
+    """Element-level visibility mask for one score tile, [block_q, block_kv] or,
+    with ``q_axis=1``, its transpose. ``seg_q`` / ``seg_k`` broadcast against
+    each other along the tile ([block_q, 1] and [1, block_kv], or transposed).
+    None where nothing masks."""
+    s_shape = (block_q, block_kv) if q_axis == 0 else (block_kv, block_q)
+    rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s_shape, q_axis)
+    cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s_shape, 1 - q_axis)
+    tests = []
+    if kv_len % block_kv:
+        tests.append(cols < kv_len)
+    if q_len % block_q:
+        tests.append(rows < q_len)
+    if causal:
+        tests.append(cols <= rows)
+    if window is not None:
+        tests.append(cols > rows - window)
     if seg_q is not None:
-        valid &= seg_q == seg_k
-    return valid
+        tests.append(seg_q == seg_k)
+    return functools.reduce(jnp.logical_and, tests) if tests else None
 
 
-def _zero_oob(x, start, limit):
-    """Zero rows past ``limit`` (Pallas pads partial edge blocks with garbage —
-    even p=0 coefficients turn garbage into NaN via 0*NaN)."""
-    idx = start + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
-    return jnp.where(idx < limit, x, 0.0)
+# Per-row statistics (running maximum and sum, logsumexp, delta) live in VMEM as
+# [rows, 128] with every lane alike: arithmetic on them is dense, and meeting a
+# [rows, width] tile is a repeat of whole registers, not a broadcast across lanes.
+_LANES = 128
 
 
-def _block_runs(q_start, k_start, block_q, block_kv, causal, window):
-    run = True
-    if causal:
-        run = k_start <= q_start + block_q - 1
-    if window is not None:
-        run = jnp.logical_and(run, k_start + block_kv - 1 > q_start - window) if causal else run
-    return run
+def _across(stat, width):
+    """[rows, 128] lane-replicated statistic against a [rows, width] tile."""
+    if width % _LANES == 0:
+        return jnp.tile(stat, (1, width // _LANES))
+    if width < _LANES:
+        return stat[:, :width]
+    return jnp.broadcast_to(stat[:, :1], (stat.shape[0], width))
+
+
+def _replicated(row):
+    """[1, n] lane-dense row of statistics -> [n, 128], through the XLU.
+    Logsumexp and delta cross HBM as rows: a [T, 1] array there is padded to
+    128 lanes, 128 times what the numbers take."""
+    return jnp.transpose(jnp.broadcast_to(row, (_LANES, row.shape[1])))
+
+
+def _row(stat):
+    """[n, 128] lane-replicated statistic -> [1, n] lane-dense row."""
+    return jnp.transpose(stat)[0:1, :]
+
+
+def _zero_oob(x, start, limit, block, axis=0):
+    """Zero what lies past ``limit`` along ``axis`` (Pallas pads partial edge
+    blocks with garbage — even p=0 coefficients turn garbage into NaN via 0*NaN)."""
+    if limit % block == 0:
+        return x
+    idx = start + jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    return jnp.where(idx < limit, x.astype(jnp.float32), 0.0).astype(x.dtype)
 
 
 # ---------------------------------------------------------------- forward
@@ -91,39 +212,47 @@ def _fa_kernel(q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, lse_ref,
 
     q_start = qi * block_q
     k_start = ki * block_kv
-    run = _block_runs(q_start, k_start, block_q, block_kv, causal, window)
+    geometry = (q_start, k_start, block_q, block_kv, causal, window, q_len, kv_len)
+    lo, hi = _kv_blocks(qi, block_q, block_kv, n_k, causal, window)
 
-    @pl.when(run)
-    def _compute():
-        q = _zero_oob(q_ref[0].astype(jnp.float32), q_start, q_len)
-        k = _zero_oob(k_ref[0].astype(jnp.float32), k_start, kv_len)
-        v = _zero_oob(v_ref[0].astype(jnp.float32), k_start, kv_len)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-        seg_q = sq_ref[0] if use_segments else None
-        seg_k = sk_ref[0] if use_segments else None
-        valid = _visible(s.shape, q_start, k_start, causal, window, q_len, kv_len, seg_q, seg_k)
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_scratch[...]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(valid, p, 0.0)  # exp(NEG-NEG)=1 on fully-masked rows
+    @pl.when(jnp.logical_and(lo <= ki, ki <= hi))
+    def _tile():
+        q = _zero_oob(q_ref[0], q_start, q_len, block_q)
+        k = _zero_oob(k_ref[0], k_start, kv_len, block_kv)
+        v = _zero_oob(v_ref[0], k_start, kv_len, block_kv)
+        valid = _visible(*geometry, sq_ref[0] if use_segments else None,
+                         sk_ref[0] if use_segments else None)
+        s = _nt(q, k) * scale
+        if valid is not None:
+            s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_scratch[...]  # [block_q, 128]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _across(m_new, block_kv))
+        if valid is not None:
+            p = jnp.where(valid, p, 0.0)  # exp(NEG-NEG)=1 on fully-masked rows
         alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_scratch[...] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scratch[...] = acc_scratch[...] * alpha + jax.lax.dot(p, v)
+        l_scratch[...] = alpha * l_scratch[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scratch[...] = acc_scratch[...] * _across(alpha, acc_scratch.shape[1]) + _dot(p.astype(v.dtype), v)
         m_scratch[...] = m_new
-        l_scratch[...] = l_new
 
     @pl.when(ki == n_k - 1)
     def _finalize():
         l = jnp.maximum(l_scratch[...], 1e-37)
-        o_ref[0] = (acc_scratch[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_scratch[...] + jnp.log(l)  # [block_q, 1]
+        o_ref[0] = (acc_scratch[...] / _across(l, acc_scratch.shape[1])).astype(o_ref.dtype)
+        lse_ref[0] = _row(m_scratch[...] + jnp.log(l))  # [1, block_q]
 
 
 def _fold(x):  # [B, T, N, H] -> [B*N, T, H]
     B, T, N, H = x.shape
     return x.transpose(0, 2, 1, 3).reshape(B * N, T, H)
+
+
+def _segments(segments, B, T):
+    """[B, T, 1] and [B, 1, T] layouts of the segment ids (zeros without): TPU
+    tiling requires the last two block dims divisible by (8, 128) or equal to the
+    array dims, so per-position data rides a trailing or a middle unit dim."""
+    seg = segments if segments is not None else jnp.zeros((B, T), jnp.int32)
+    return seg[:, :, None], seg[:, None, :]
 
 
 def _flash_fwd(q, k, v, segments, scale, causal, window, block_q, block_kv, interpret):
@@ -139,14 +268,12 @@ def _flash_fwd(q, k, v, segments, scale, causal, window, block_q, block_kv, inte
     group = N // K
     qf, kf, vf = _fold(q), _fold(k), _fold(v)
     use_seg = segments is not None
-    seg = segments if use_seg else jnp.zeros((B, T), jnp.int32)
-    # TPU tiling requires the last two block dims divisible by (8, 128) or equal
-    # to the array dims — per-row 1D data rides a trailing/middle unit dim.
-    seg_q3 = seg[:, :, None]  # [B, T, 1] -> block (1, block_q, 1)
-    seg_k3 = seg[:, None, :]  # [B, 1, S] -> block (1, 1, block_kv)
-    block_q = min(block_q, T)
-    block_kv = min(block_kv, S)
-    grid = (B * N, pl.cdiv(T, block_q), pl.cdiv(S, block_kv))
+    seg_col, seg_row = _segments(segments, B, T)
+    block_q, block_kv = _blocks(block_q, block_kv, T, S)
+    n_q, n_k = pl.cdiv(T, block_q), pl.cdiv(S, block_kv)
+
+    def kv_block(qi, ki):  # a skipped step names the nearest block its row of tiles needs: no copy
+        return jnp.clip(ki, *_kv_blocks(qi, block_q, block_kv, n_k, causal, window))
 
     kernel = functools.partial(
         _fa_kernel, scale=scale, block_q=block_q, block_kv=block_kv,
@@ -154,38 +281,37 @@ def _flash_fwd(q, k, v, segments, scale, causal, window, block_q, block_kv, inte
     )
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B * N, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, H), lambda bn, qi, ki: (bn, qi, 0)),
-            pl.BlockSpec((1, block_kv, H), lambda bn, qi, ki, g=group: (bn // g, ki, 0)),
-            pl.BlockSpec((1, block_kv, H), lambda bn, qi, ki, g=group: (bn // g, ki, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bn, qi, ki, n=N: (bn // n, qi, 0)),
-            pl.BlockSpec((1, 1, block_kv), lambda bn, qi, ki, n=N: (bn // n, 0, ki)),
+            pl.BlockSpec((1, block_kv, H), lambda bn, qi, ki: (bn // group, kv_block(qi, ki), 0)),
+            pl.BlockSpec((1, block_kv, H), lambda bn, qi, ki: (bn // group, kv_block(qi, ki), 0)),
+            pl.BlockSpec((1, block_q, 1), lambda bn, qi, ki: (bn // N, qi, 0)),
+            pl.BlockSpec((1, 1, block_kv), lambda bn, qi, ki: (bn // N, 0, kv_block(qi, ki))),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, H), lambda bn, qi, ki: (bn, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bn, qi, ki: (bn, qi, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda bn, qi, ki: (bn, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * N, T, H), q.dtype),
-            jax.ShapeDtypeStruct((B * N, T, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B * N, 1, T), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),  # m
-            pltpu.VMEM((block_q, 1), jnp.float32),  # l
+            pltpu.VMEM((block_q, _LANES), jnp.float32),  # m
+            pltpu.VMEM((block_q, _LANES), jnp.float32),  # l
             pltpu.VMEM((block_q, H), jnp.float32),  # acc
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(block_q, block_kv),
         interpret=interpret,
         name="flash_attention_fwd",
-    )(qf, kf, vf, seg_q3, seg_k3)
-    return out.reshape(B, N, T, H).transpose(0, 2, 1, 3), lse[..., 0]
+    )(qf, kf, vf, seg_col, seg_row)
+    return out.reshape(B, N, T, H).transpose(0, 2, 1, 3), lse  # lse: [B*N, 1, T]
 
 
 # ---------------------------------------------------------------- backward
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_ref,
-                   dq_ref, dq_scratch, *,
+                   dq_ref, dq_scratch, lse_scratch, delta_scratch, *,
                    scale, block_q, block_kv, causal, window, q_len, kv_len, use_segments):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -194,40 +320,43 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_r
     @pl.when(ki == 0)
     def _init():
         dq_scratch[...] = jnp.zeros_like(dq_scratch)
+        lse_scratch[...] = _replicated(lse_ref[0])  # once a row of tiles
+        delta_scratch[...] = _replicated(delta_ref[0])
 
     q_start = qi * block_q
     k_start = ki * block_kv
-    run = _block_runs(q_start, k_start, block_q, block_kv, causal, window)
+    geometry = (q_start, k_start, block_q, block_kv, causal, window, q_len, kv_len)
+    lo, hi = _kv_blocks(qi, block_q, block_kv, n_k, causal, window)
 
-    @pl.when(run)
-    def _compute():
-        q = _zero_oob(q_ref[0].astype(jnp.float32), q_start, q_len)
-        k = _zero_oob(k_ref[0].astype(jnp.float32), k_start, kv_len)
-        v = _zero_oob(v_ref[0].astype(jnp.float32), k_start, kv_len)
-        do = _zero_oob(do_ref[0].astype(jnp.float32), q_start, q_len)
-        lse = lse_ref[0]  # [block_q, 1]
+    @pl.when(jnp.logical_and(lo <= ki, ki <= hi))
+    def _tile():
+        q = _zero_oob(q_ref[0], q_start, q_len, block_q)
+        k = _zero_oob(k_ref[0], k_start, kv_len, block_kv)
+        v = _zero_oob(v_ref[0], k_start, kv_len, block_kv)
+        do = _zero_oob(do_ref[0], q_start, q_len, block_q)
+        lse = lse_scratch[...]  # [block_q, 128]
         # delta rows past q_len are Pallas edge-block garbage; p=0 there cannot
-        # save ds (0 * NaN = NaN), and dkv's column reduction would spread it
-        row_idx = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
-        delta = jnp.where(row_idx < q_len, delta_ref[0], 0.0)  # [block_q, 1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-        seg_q = sq_ref[0] if use_segments else None
-        seg_k = sk_ref[0] if use_segments else None
-        valid = _visible(s.shape, q_start, k_start, causal, window, q_len, kv_len, seg_q, seg_k)
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))  # [bq, bkv]
-        ds = p * (dp - delta) * scale
-        dq_scratch[...] += jax.lax.dot(ds, k)
+        # save ds (0 * NaN = NaN)
+        delta = _zero_oob(delta_scratch[...], q_start, q_len, block_q)
+        valid = _visible(*geometry, sq_ref[0] if use_segments else None,
+                         sk_ref[0] if use_segments else None)
+        p = jnp.exp(_nt(q, k) * scale - _across(lse, block_kv))
+        if valid is not None:
+            p = jnp.where(valid, p, 0.0)
+        ds = p * (_nt(do, v) - _across(delta, block_kv))  # times scale, once, in _finalize
+        dq_scratch[...] += _dot(ds.astype(k.dtype), k)
 
     @pl.when(ki == n_k - 1)
     def _finalize():
-        dq_ref[0] = dq_scratch[...].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scratch[...] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_ref,
                     dk_ref, dv_ref, dk_scratch, dv_scratch, *,
                     scale, block_q, block_kv, causal, window, q_len, kv_len, use_segments,
                     n_q):
+    """Works on the transposed tile: scores [block_kv, block_q], logsumexp,
+    delta and the q-side segment ids as [1, block_q] rows."""
     ki = pl.program_id(1)
     j = pl.program_id(2)  # walks (query-head-in-group, q-block) pairs
     n_j = pl.num_programs(2)
@@ -240,32 +369,30 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_
 
     q_start = qi * block_q
     k_start = ki * block_kv
-    run = _block_runs(q_start, k_start, block_q, block_kv, causal, window)
+    geometry = (q_start, k_start, block_q, block_kv, causal, window, q_len, kv_len)
+    lo, hi = _q_blocks(ki, block_q, block_kv, n_q, causal, window)
 
-    @pl.when(run)
-    def _compute():
-        q = _zero_oob(q_ref[0].astype(jnp.float32), q_start, q_len)
-        k = _zero_oob(k_ref[0].astype(jnp.float32), k_start, kv_len)
-        v = _zero_oob(v_ref[0].astype(jnp.float32), k_start, kv_len)
-        do = _zero_oob(do_ref[0].astype(jnp.float32), q_start, q_len)
-        lse = lse_ref[0]  # [block_q, 1]
-        # delta rows past q_len are Pallas edge-block garbage; p=0 there cannot
-        # save ds (0 * NaN = NaN), and dkv's column reduction would spread it
-        row_idx = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
-        delta = jnp.where(row_idx < q_len, delta_ref[0], 0.0)  # [block_q, 1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-        seg_q = sq_ref[0] if use_segments else None
-        seg_k = sk_ref[0] if use_segments else None
-        valid = _visible(s.shape, q_start, k_start, causal, window, q_len, kv_len, seg_q, seg_k)
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)  # [bq, bkv]
-        dv_scratch[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))  # p^T @ do
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-        ds = p * (dp - delta) * scale
-        dk_scratch[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())))  # ds^T @ q
+    @pl.when(jnp.logical_and(lo <= qi, qi <= hi))
+    def _tile():
+        q = _zero_oob(q_ref[0], q_start, q_len, block_q)
+        k = _zero_oob(k_ref[0], k_start, kv_len, block_kv)
+        v = _zero_oob(v_ref[0], k_start, kv_len, block_kv)
+        do = _zero_oob(do_ref[0], q_start, q_len, block_q)
+        lse = lse_ref[0]  # [1, block_q]
+        # as in dq; here the reduction over q would spread the NaN over dk
+        delta = _zero_oob(delta_ref[0], q_start, q_len, block_q, axis=1)
+        valid = _visible(*geometry, sq_ref[0] if use_segments else None,
+                         sk_ref[0] if use_segments else None, q_axis=1)
+        pt = jnp.exp(_nt(k, q) * scale - lse)  # p^T
+        if valid is not None:
+            pt = jnp.where(valid, pt, 0.0)
+        dv_scratch[...] += _dot(pt.astype(do.dtype), do)
+        dst = pt * (_nt(v, do) - delta)  # dS^T; times scale, once, in _finalize
+        dk_scratch[...] += _dot(dst.astype(q.dtype), q)
 
     @pl.when(j == n_j - 1)
     def _finalize():
-        dk_ref[0] = dk_scratch[...].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_scratch[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scratch[...].astype(dv_ref.dtype)
 
 
@@ -275,75 +402,79 @@ def _flash_bwd(q, k, v, segments, out, lse, g, scale, causal, window, block_q, b
     group = N // K
     qf, kf, vf, dof = _fold(q), _fold(k), _fold(v), _fold(g)
     of = _fold(out)
-    # [B*N, T, 1]: trailing unit dim keeps the block TPU-tileable (see _flash_fwd)
-    delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1, keepdims=True)
-    lse3 = lse[..., None]
+    delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)[:, None, :]  # [B*N, 1, T]
     use_seg = segments is not None
-    seg = segments if use_seg else jnp.zeros((B, T), jnp.int32)
-    seg_q3 = seg[:, :, None]  # [B, T, 1]
-    seg_k3 = seg[:, None, :]  # [B, 1, S]
-    block_q = min(block_q, T)
-    block_kv = min(block_kv, S)
-    n_q, n_k = pl.cdiv(T, block_q), pl.cdiv(S, block_kv)
+    seg_col, seg_row = _segments(segments, B, T)
+    common = dict(scale=scale, causal=causal, window=window, q_len=T, kv_len=S, use_segments=use_seg)
 
-    common = dict(scale=scale, block_q=block_q, block_kv=block_kv, causal=causal,
-                  window=window, q_len=T, kv_len=S, use_segments=use_seg)
-    params = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"))
+    # dq: as the forward. Logsumexp and delta are [B*N, 1, T] rows in both kernels
+    bq, bkv = _blocks(block_q, block_kv, T, S)
+    n_q, n_k = pl.cdiv(T, bq), pl.cdiv(S, bkv)
+
+    def kv_block(qi, ki):
+        return jnp.clip(ki, *_kv_blocks(qi, bq, bkv, n_k, causal, window))
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **common),
+        functools.partial(_bwd_dq_kernel, **common, block_q=bq, block_kv=bkv),
         grid=(B * N, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, H), lambda bn, qi, ki: (bn, qi, 0)),
-            pl.BlockSpec((1, block_kv, H), lambda bn, qi, ki, g_=group: (bn // g_, ki, 0)),
-            pl.BlockSpec((1, block_kv, H), lambda bn, qi, ki, g_=group: (bn // g_, ki, 0)),
-            pl.BlockSpec((1, block_q, H), lambda bn, qi, ki: (bn, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bn, qi, ki: (bn, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bn, qi, ki: (bn, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bn, qi, ki, n=N: (bn // n, qi, 0)),
-            pl.BlockSpec((1, 1, block_kv), lambda bn, qi, ki, n=N: (bn // n, 0, ki)),
+            pl.BlockSpec((1, bq, H), lambda bn, qi, ki: (bn, qi, 0)),
+            pl.BlockSpec((1, bkv, H), lambda bn, qi, ki: (bn // group, kv_block(qi, ki), 0)),
+            pl.BlockSpec((1, bkv, H), lambda bn, qi, ki: (bn // group, kv_block(qi, ki), 0)),
+            pl.BlockSpec((1, bq, H), lambda bn, qi, ki: (bn, qi, 0)),
+            pl.BlockSpec((1, 1, bq), lambda bn, qi, ki: (bn, 0, qi)),
+            pl.BlockSpec((1, 1, bq), lambda bn, qi, ki: (bn, 0, qi)),
+            pl.BlockSpec((1, bq, 1), lambda bn, qi, ki: (bn // N, qi, 0)),
+            pl.BlockSpec((1, 1, bkv), lambda bn, qi, ki: (bn // N, 0, kv_block(qi, ki))),
         ],
-        out_specs=pl.BlockSpec((1, block_q, H), lambda bn, qi, ki: (bn, qi, 0)),
+        out_specs=pl.BlockSpec((1, bq, H), lambda bn, qi, ki: (bn, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * N, T, H), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, H), jnp.float32)],
-        compiler_params=params,
+        scratch_shapes=[pltpu.VMEM((bq, H), jnp.float32),
+                        pltpu.VMEM((bq, _LANES), jnp.float32), pltpu.VMEM((bq, _LANES), jnp.float32)],
+        compiler_params=_compiler_params(bq, bkv),
         interpret=interpret,
         name="flash_attention_bwd_dq",
-    )(qf, kf, vf, dof, lse3, delta, seg_q3, seg_k3)
+    )(qf, kf, vf, dof, lse, delta, seg_col, seg_row)
 
     # dk/dv: grid batch axis is the B*K kv heads; the sequential axis walks the
     # group*n_q (query-head-in-group, q-block) pairs so dk/dv for a kv head
     # accumulate in VMEM across its whole query group (no outside group-sum).
-    qhead = lambda bk, j, g_=group, nq=n_q: bk * g_ + j // nq
+    # The tile is transposed: q-side segment ids a [.., 1, T] row, k-side a column.
+    def q_head(bk, j):
+        return bk * group + j // n_q
+
+    def q_block(ki, j):
+        return jnp.clip(j % n_q, *_q_blocks(ki, bq, bkv, n_q, causal, window))
+
     dk_p, dv_p = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **common, n_q=n_q),
+        functools.partial(_bwd_dkv_kernel, **common, block_q=bq, block_kv=bkv, n_q=n_q),
         grid=(B * K, n_k, group * n_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, H), lambda bk, ki, j, nq=n_q: (qhead(bk, j), j % nq, 0)),
-            pl.BlockSpec((1, block_kv, H), lambda bk, ki, j: (bk, ki, 0)),
-            pl.BlockSpec((1, block_kv, H), lambda bk, ki, j: (bk, ki, 0)),
-            pl.BlockSpec((1, block_q, H), lambda bk, ki, j, nq=n_q: (qhead(bk, j), j % nq, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bk, ki, j, nq=n_q: (qhead(bk, j), j % nq, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bk, ki, j, nq=n_q: (qhead(bk, j), j % nq, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bk, ki, j, kk=K, nq=n_q: (bk // kk, j % nq, 0)),
-            pl.BlockSpec((1, 1, block_kv), lambda bk, ki, j, kk=K: (bk // kk, 0, ki)),
+            pl.BlockSpec((1, bq, H), lambda bk, ki, j: (q_head(bk, j), q_block(ki, j), 0)),
+            pl.BlockSpec((1, bkv, H), lambda bk, ki, j: (bk, ki, 0)),
+            pl.BlockSpec((1, bkv, H), lambda bk, ki, j: (bk, ki, 0)),
+            pl.BlockSpec((1, bq, H), lambda bk, ki, j: (q_head(bk, j), q_block(ki, j), 0)),
+            pl.BlockSpec((1, 1, bq), lambda bk, ki, j: (q_head(bk, j), 0, q_block(ki, j))),
+            pl.BlockSpec((1, 1, bq), lambda bk, ki, j: (q_head(bk, j), 0, q_block(ki, j))),
+            pl.BlockSpec((1, 1, bq), lambda bk, ki, j: (bk // K, 0, q_block(ki, j))),
+            pl.BlockSpec((1, bkv, 1), lambda bk, ki, j: (bk // K, ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_kv, H), lambda bk, ki, j: (bk, ki, 0)),
-            pl.BlockSpec((1, block_kv, H), lambda bk, ki, j: (bk, ki, 0)),
+            pl.BlockSpec((1, bkv, H), lambda bk, ki, j: (bk, ki, 0)),
+            pl.BlockSpec((1, bkv, H), lambda bk, ki, j: (bk, ki, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * K, S, H), jnp.float32),
             jax.ShapeDtypeStruct((B * K, S, H), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_kv, H), jnp.float32),
-            pltpu.VMEM((block_kv, H), jnp.float32),
+            pltpu.VMEM((bkv, H), jnp.float32),
+            pltpu.VMEM((bkv, H), jnp.float32),
         ],
-        compiler_params=params,
+        compiler_params=_compiler_params(bq, bkv),
         interpret=interpret,
         name="flash_attention_bwd_dkv",
-    )(qf, kf, vf, dof, lse3, delta, seg_q3, seg_k3)
+    )(qf, kf, vf, dof, lse, delta, seg_row, seg_col)
 
     dq = dq.reshape(B, N, T, H).transpose(0, 2, 1, 3)
     dk = dk_p.reshape(B, K, S, H).transpose(0, 2, 1, 3).astype(k.dtype)
@@ -361,8 +492,8 @@ def flash_attention(
     scale: Optional[float] = None,
     causal: bool = True,
     window: Optional[int] = None,
-    block_q: int = 128,
-    block_kv: int = 128,
+    block_q: Optional[int] = None,  # None: the tile comes from the shape (_blocks)
+    block_kv: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     scale = scale if scale is not None else q.shape[-1] ** -0.5

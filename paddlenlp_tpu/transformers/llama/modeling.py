@@ -342,8 +342,9 @@ def _remat_policy(granularity: str):
     - ``full``          recompute the whole decoder layer (save nothing)
     - ``full_attn``     save everything except attention internals (qkv + core)
     - ``core_attn``     save everything except the attention core (softmax(qk)v)
-    - ``save_core_attn``  save ONLY the attention core output (cheap memory,
-                          skips the attention-core recompute in backward)
+    - ``save_core_attn``  save ONLY the attention core output (cheap memory;
+                          meant to skip the attention-core recompute in
+                          backward, which on the chip it does not: see below)
     - ``save_qkv_attn``   save only q/k/v + attention core output
     - ``save_attn_mlp``   save q/k/v + attention core + mlp activation
     - ``save_dots``       XLA classic: save all non-batch matmul outputs
@@ -353,6 +354,12 @@ def _remat_policy(granularity: str):
     The save_only_* tiers are the 16 GB-HBM middle ground between ``full``
     (recomputes the whole layer) and ``core_attn`` (save-everything-except,
     which does not fit). Their step-time cost on the chip is not measured.
+
+    Not true on the chip for the Pallas flash path: saving "core_attn" does not
+    skip the attention-core recompute. The kernel's backward also needs its
+    logsumexp residual, which carries no name, so the traced training step runs
+    the forward kernel twice a layer (PERF.md section 5, ``seq2k``: the second
+    ``flash_attention_fwd`` is remat's). ROADMAP S3 holds what is left.
     """
     if granularity == "full":
         return None

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from paddlenlp_tpu.ops.flash_attention import dot_product_attention
+from paddlenlp_tpu.ops.pallas import flash_attention as kernel_file
 from paddlenlp_tpu.ops.pallas.flash_attention import flash_attention
 
 
@@ -172,3 +173,91 @@ class TestPallasFlash:
         _, k, v = qkv(T=128)
         with pytest.raises(ValueError, match="requires T == S"):
             flash_attention(q, k, v, causal=True, interpret=True)
+
+
+def packed_segments(B, T, seed=0):
+    """Three documents a row, cut at seeded places (not at tile edges)."""
+    cuts = np.sort(np.random.default_rng(seed).integers(1, T, (B, 2)), axis=1)
+    pos = np.arange(T)[None]
+    return jnp.asarray((pos >= cuts[:, :1]).astype(np.int32) + (pos >= cuts[:, 1:]))
+
+
+# (T, query heads, kv heads, head_dim, dtype, segments, window): the tiles are the rule's own
+# (block_q / block_kv not passed), so the rule and the clamped index maps are what is under
+# test. 1024 x 1024 tiles at these T:
+DEFAULT_TILE_CASES = {
+    "one-tile-h64-gqa7": (128, 7, 1, 64, jnp.float32, False, None),
+    "one-tile-h128-mha-bf16": (128, 2, 2, 128, jnp.bfloat16, False, None),
+    "several-tiles-h64-gqa7": (1536, 7, 1, 64, jnp.float32, False, None),  # 1024 + 512, a partial last block
+    "cell-2048-h64-gqa7-bf16": (2048, 7, 1, 64, jnp.bfloat16, False, None),
+    "cell-2048-h128-mha": (2048, 2, 2, 128, jnp.float32, False, None),
+    "segments-h64-gqa7": (1536, 7, 1, 64, jnp.float32, True, None),
+    "segments-h128-mha-bf16": (2048, 2, 2, 128, jnp.bfloat16, True, None),
+    "window-h64-gqa7": (2048, 7, 1, 64, jnp.float32, False, 300),
+    "window-skips-tiles-h64": (3072, 2, 1, 64, jnp.float32, False, 300),  # the last rows' first tile lies below it
+    "window-h128-mha-bf16": (2048, 2, 2, 128, jnp.bfloat16, False, 640),
+    "segments-and-window": (1536, 2, 1, 64, jnp.float32, True, 200),
+    "ragged-every-kernel-h128": (1152, 2, 1, 128, jnp.float32, False, None),  # 1024 + 128
+    "ragged-h64-bf16": (1664, 2, 2, 64, jnp.bfloat16, False, None),
+}
+
+
+@pytest.mark.parametrize("case", DEFAULT_TILE_CASES)
+def test_default_tiles_match_the_xla_path(case):
+    """Forward and all three gradients at the tiles the rule picks, every row of
+    tiles (the first, whose later steps clamp to its one block, and the last)."""
+    T, N, K, H, dtype, segmented, window = DEFAULT_TILE_CASES[case]
+    q, k, v = qkv(B=1, T=T, N=N, K=K, H=H, seed=T + N, dtype=dtype)
+    w = qkv(B=1, T=T, N=N, K=K, H=H, seed=1)[0]  # float32 weights of the scalar that is differentiated
+    seg = packed_segments(1, T) if segmented else None
+
+    def f_pallas(q, k, v):
+        out = flash_attention(q, k, v, seg, None, True, window, interpret=True)
+        return (out.astype(jnp.float32) * w).sum(), out
+
+    def f_ref(q, k, v):
+        out = dot_product_attention(q, k, v, causal=True, segment_ids=seg, window=window, use_pallas=False)
+        return (out.astype(jnp.float32) * w).sum(), out
+
+    (_, out), grads = jax.value_and_grad(f_pallas, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, ref), ref_grads = jax.value_and_grad(f_ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    f32 = lambda a: np.asarray(a, dtype=np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(f32(out), f32(ref), atol=2e-5)
+        for name, a, b in zip("qkv", grads, ref_grads):
+            np.testing.assert_allclose(f32(a), f32(b), atol=5e-4, rtol=1e-3, err_msg=f"d{name}")
+    else:  # both sides round to bf16, in different places: compare in the norm
+        rel = lambda a, b: np.linalg.norm(f32(a) - f32(b)) / np.linalg.norm(f32(b))
+        np.testing.assert_allclose(f32(out), f32(ref), atol=3e-2)
+        for name, a, b in zip("qkv", grads, ref_grads):
+            assert np.isfinite(f32(a)).all() and rel(a, b) < 1.5e-2, f"d{name}: {rel(a, b)}"
+
+
+def test_non_causal_default_tiles():
+    """Nothing to clamp and no diagonal: the mask is the ragged kv end alone."""
+    q, k, v = qkv(B=1, T=1536, N=2, K=1)
+    ref = dot_product_attention(q, k, v, causal=False, use_pallas=False)
+    out = flash_attention(q, k, v, causal=False, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("block_q,block_kv,window", [
+    (128, 128, None), (512, 1024, None), (1024, 1024, None), (384, 256, None),
+    (128, 128, 100), (512, 1024, 300), (1024, 1024, 640), (256, 384, 1), (512, 512, 5000),
+])
+def test_tile_spans_against_the_mask(block_q, block_kv, window):
+    """``_kv_blocks`` / ``_q_blocks`` (which steps run, and what a skipped step
+    names) against the element mask itself, for every tile of a ragged sequence."""
+    T = 2048 + 128
+    rows, cols = np.arange(T)[:, None], np.arange(T)[None, :]
+    visible = cols <= rows
+    if window is not None:
+        visible &= cols > rows - window
+    n_q, n_k = -(-T // block_q), -(-T // block_kv)
+    for qi in range(n_q):
+        lo, hi = (int(x) for x in kernel_file._kv_blocks(qi, block_q, block_kv, n_k, True, window))
+        for ki in range(n_k):
+            tile = visible[qi * block_q:(qi + 1) * block_q, ki * block_kv:(ki + 1) * block_kv]
+            assert (lo <= ki <= hi) == bool(tile.any()), (qi, ki)
+            q_lo, q_hi = (int(x) for x in kernel_file._q_blocks(ki, block_q, block_kv, n_q, True, window))
+            assert (q_lo <= qi <= q_hi) == bool(tile.any()), (qi, ki)

@@ -7,8 +7,8 @@ interpret-mode test while the compiler refused the paged kernel's whole-prompt
 query tile (18 MiB of scoped VMEM against a limit of 16) and GSPMD refused to
 partition it at all. The shapes are the ones ``chip_smoke.py`` runs: Qwen2-1.5B
 serving (12 query / 2 kv heads, head_dim 128, 9600-block pool of 16-token
-blocks, 160-block tables) and Qwen2-0.5B training (14 / 2 heads, head_dim 64)
-at sequence 2048; the step programs are the benchmark cell's (28 layers, 16
+blocks, 160-block tables) and Qwen2-0.5B training (14 / 2 heads, head_dim 64,
+4 rows of 2048: the benchmark's training cell); the step programs are the benchmark cell's (28 layers, 16
 slots, 192-block tables). Nothing runs; a compile that passes says nothing
 about results or times.
 """
@@ -49,8 +49,19 @@ def topology():
     compilation_cache.reset_cache()
 
 
+def tell_vmem(monkeypatch, mib):
+    """The flash kernel asks ``pltpu.get_tpu_info`` for the chip's VMEM; a
+    described chip does not answer, so the test does (v5e: 128 MiB)."""
+    import types
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(pltpu, "get_tpu_info", lambda: types.SimpleNamespace(vmem_capacity_bytes=mib << 20))
+
+
 @pytest.fixture
-def chip(topology):
+def chip(topology, monkeypatch):
+    tell_vmem(monkeypatch, 128)
     return SingleDeviceSharding(topology.devices[0])
 
 
@@ -89,18 +100,29 @@ def test_ragged_paged_attention_compiles(chip, batch, tokens, kv_heads, pool_dty
     assert "tpu_custom_call" in compiled_text(attend, *avals)
 
 
+FLASH_SHAPES = {  # batch, tokens, query heads, kv heads, head_dim
+    "cell-4x2048-h64-group7": (4, 2048, 14, 2, 64),  # qwen2-0.5b-pretrain.seq2k, exactly
+    "h128-group6-4096": (2, 4096, 12, 2, 128),  # Qwen2-1.5B's heads
+}
+
+
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
-@pytest.mark.parametrize("heads,kv_heads,head_dim", [(14, 2, 64), (12, 2, 128)],
-                         ids=["h64-group7", "h128-group6"])
-def test_flash_attention_compiles(chip, heads, kv_heads, head_dim, backward):
+@pytest.mark.parametrize("masking", ["causal", "segments", "window", "segments-128x128"])
+@pytest.mark.parametrize("shape", FLASH_SHAPES)
+def test_flash_attention_compiles(chip, shape, masking, backward):
+    """At the tiles the kernel file's rule picks (no block argument) and with the
+    64 MiB of scoped VMEM it asks for on this chip, and with a caller's own
+    128 x 128 blocks under segments, as before the rule."""
+    batch, seq, heads, kv_heads, head_dim = FLASH_SHAPES[shape]
+    blocks = (128, 128) if masking.endswith("128x128") else (None, None)
     aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-    batch, seq = 2, 2048
     q = aval((batch, seq, heads, head_dim), jnp.bfloat16)
     kv = aval((batch, seq, kv_heads, head_dim), jnp.bfloat16)
     segments = aval((batch, seq), jnp.int32)  # packed batches: [B,T,1] / [B,1,S] tiles
 
     def forward(q, k, v, seg):
-        return flash_attention(q, k, v, seg, None, True, None, 128, 128, False)
+        return flash_attention(q, k, v, seg if masking.startswith("segments") else None, None, True,
+                               1024 if masking == "window" else None, *blocks, interpret=False)
 
     def loss(q, k, v, seg):
         return forward(q, k, v, seg).astype(jnp.float32).sum()
@@ -109,6 +131,80 @@ def test_flash_attention_compiles(chip, heads, kv_heads, head_dim, backward):
                          q, kv, kv, segments)
     # forward alone is one kernel; the gradient adds dq and dk/dv
     assert text.count('custom_call_target="tpu_custom_call"') == (3 if backward else 1)
+    # half the chip's VMEM for a 1024 x 1024 step, the compiler's default for the caller's small blocks
+    assert text.count('"size":"67108864"}],"custom_call_config"') == (0 if blocks[0] else 3 if backward else 1)
+
+
+def test_flash_attention_on_a_mesh_compiles(topology, monkeypatch):
+    """dp 2 x tp 2 training: the dispatcher runs the kernel under shard_map, each
+    shard on 2 of 4 rows and on 7 query heads over one kv head, tiles by the rule."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddlenlp_tpu.ops.flash_attention import dot_product_attention
+    from paddlenlp_tpu.parallel import MeshConfig, create_mesh, use_mesh
+
+    mesh = create_mesh(MeshConfig(dp=2, tp=2), devices=topology.devices)
+    spec = NamedSharding(mesh, P("dp", None, "tp", None))
+    q = jax.ShapeDtypeStruct((4, 2048, 14, 64), jnp.bfloat16, sharding=spec)
+    kv = jax.ShapeDtypeStruct((4, 2048, 2, 64), jnp.bfloat16, sharding=spec)
+
+    def loss(q, k, v):
+        return dot_product_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    # the dispatcher and the kernel ask jax.default_backend(); a described chip
+    # does not change that answer, so the test gives it
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tell_vmem(monkeypatch, 128)
+    with use_mesh(mesh):
+        text = compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert "bf16[2,2048,7,64]" in text  # per shard
+
+
+def test_flash_attention_compiles_for_16_mib_of_vmem(topology, monkeypatch):
+    """A chip of 16 MiB VMEM (v4) gets 512 x 512 tiles and the compiler's default:
+    a 1024 x 1024 step of this call needs 17.2 MB there."""
+    from jax.experimental import topologies
+
+    from paddlenlp_tpu.ops.pallas import flash_attention as kernel_file
+
+    try:
+        v4 = topologies.get_topology_desc(platform="tpu", topology_name="v4:2x2x1")
+    except Exception as e:
+        pytest.skip(f"cannot describe a v4 topology here: {e!r}")
+    tell_vmem(monkeypatch, 16)
+    assert kernel_file._blocks(None, None, 2048, 2048) == (512, 512)
+    assert kernel_file._compiler_params(512, 512).vmem_limit_bytes is None
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=SingleDeviceSharding(v4.devices[0]))
+    q, kv = aval((2, 2048, 12, 128), jnp.bfloat16), aval((2, 2048, 2, 128), jnp.bfloat16)
+
+    def loss(q, k, v, seg):
+        return flash_attention(q, k, v, seg, interpret=False).astype(jnp.float32).sum()
+
+    text = compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, aval((2, 2048), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert "f32[24,1,2048]" in text  # the logsumexp rows: the kernels are these
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+def test_flash_tile_rule(kernel):
+    """The rule alone, no compiler: at the cell's shape a call is under 1,000 grid
+    steps (it was 14,336), and for every ``T`` the dispatcher's gate lets through
+    (``T % 128 == 0``) a block is the whole axis or a multiple of 128 (sublanes
+    of the [block, head_dim] operands, lanes of the [1, block] rows)."""
+    from paddlenlp_tpu.ops.pallas import flash_attention as kernel_file
+
+    block_q, block_kv = kernel_file._blocks(None, None, 2048, 2048)
+    rows, q_tiles, kv_tiles = 4 * (2 if kernel == "dkv" else 14), 2048 // block_q, 2048 // block_kv
+    steps = rows * kv_tiles * q_tiles * (7 if kernel == "dkv" else 1)
+    assert 100 <= steps < 1000, (block_q, block_kv, steps)
+
+    for tokens in list(range(128, 4096 + 1, 128)) + [8192, 32768, 131072]:
+        for block in kernel_file._blocks(None, None, tokens, tokens):
+            assert block == tokens or (block % 128 == 0 and 0 < block < tokens), (tokens, block)
+    assert kernel_file._compiler_params(block_q, block_kv).vmem_limit_bytes is None  # no chip here: the default
+    assert kernel_file._blocks(128, 256, 2048, 2048) == (128, 256)  # the caller's are honoured
+    assert kernel_file._blocks(512, 512, 256, 384) == (256, 384)  # and cut to the sequence
 
 
 def test_paged_kernel_on_a_mesh_compiles(topology, monkeypatch):
