@@ -137,7 +137,6 @@ class MixedRow:
     table: np.ndarray
     emit: bool
     sampling: object
-    is_chunk: bool
     #: adapter-pool slot this row's LoRA delta gathers from (0 = identity —
     #: the no-adapter row); the engine fills it from Request.adapter_slot
     adapter: int = 0
@@ -254,7 +253,6 @@ class SingleDeviceBackend(ModelBackend):
     def __init__(self, model, *, max_batch_size: int, block_size: int, num_blocks: int,
                  max_blocks_per_seq: int, dtype, decode_steps: int, eos_ids,
                  kv_cache_quant: Optional[str] = None,
-                 token_flatten: Optional[bool] = None,
                  adapter_registry=None,
                  prefill_chunk_tokens: Optional[int] = None):
         self.model = model
@@ -275,10 +273,6 @@ class SingleDeviceBackend(ModelBackend):
                                        dtype, decode_steps, eos_ids)
         self.pool = self._init_pool(model.config, num_blocks, block_size, dtype, kv_cache_quant)
         self.counts = self._init_counts()
-        # None = auto: flatten on the XLA fallback (where decode rows padded to
-        # the chunk bucket dominate the mixed-step cost), keep the single
-        # padded launch when the Pallas ragged kernel is active
-        self.token_flatten = token_flatten
 
     # ---------------------------------------------------------------- setup
     def _build_infer(self, model, block_size, num_blocks, max_blocks_per_seq,
@@ -530,14 +524,8 @@ class SingleDeviceBackend(ModelBackend):
         prefill-stage and decode-stage programs back to back and only then
         collect, so the two device groups compute concurrently instead of the
         host serializing them at the first sync."""
-        flat = self.token_flatten
-        if flat is None:
-            flat = not self.infer.use_paged_kernel
-        if self.infer.fixed_mixed_shape:
-            flat = True  # the one shape such a model compiles is the flat layout's
-        launch = self._mixed_flat_launch if flat else self._mixed_padded_launch
         with TRACER.span("dispatch", cat="engine", program="mixed"):
-            tokens_dev, mapper = launch(chunk_rows, decode_rows)
+            tokens_dev, mapper = self._mixed_flat_launch(chunk_rows, decode_rows)
 
         def collect() -> np.ndarray:
             with TRACER.span("wait", cat="engine", program="mixed"):
@@ -548,52 +536,12 @@ class SingleDeviceBackend(ModelBackend):
 
         return collect
 
-    def _mixed_padded_launch(self, chunk_rows, decode_rows):
-        """Legacy layout: one [B, T] launch, every row padded to the chunk
-        bucket — what the Pallas ragged kernel wants (a single grid covers
-        chunks, decodes and dead rows). Returns (device tokens, host-order
-        mapper)."""
-        B = self.max_batch_size
-        T = _bucket(max([len(r.tokens) for r in chunk_rows], default=1), minimum=1)
-        rows = chunk_rows + decode_rows
-        ids = np.zeros((B, T), np.int32)
-        tables = np.zeros((B, chunk_rows[0].table.shape[0] if chunk_rows
-                           else decode_rows[0].table.shape[0]), np.int32)
-        self.step_accounting = dict(
-            {"fed": B * T, "shape": ("mixed_padded", B, T)},
-            **launch_geometry(B, [len(r.tokens) for r in rows],
-                              [r.start + len(r.tokens) for r in rows]))
-        q_lens = np.zeros(B, np.int32)
-        q_start = np.zeros(B, np.int32)
-        count_fed = np.zeros(B, bool)
-        emit = np.zeros(B, bool)
-        adapter = np.zeros(B, np.int32)
-        sampling: List = [None] * B
-        for r in rows:
-            n = len(r.tokens)
-            ids[r.slot, :n] = r.tokens
-            tables[r.slot] = r.table
-            q_lens[r.slot] = n
-            q_start[r.slot] = r.start
-            count_fed[r.slot] = r.is_chunk  # chunk tokens accumulate into counts
-            emit[r.slot] = r.emit
-            adapter[r.slot] = r.adapter
-            sampling[r.slot] = r.sampling
-        tokens, self.counts, self.pool = self.infer.mixed_step(
-            self.params, self.pool, jnp.asarray(ids), jnp.asarray(tables),
-            jnp.asarray(q_lens), jnp.asarray(q_start), self.counts,
-            jnp.asarray(count_fed), jnp.asarray(emit), samp_arrays(sampling, B),
-            lora=self._lora_tree(), adapter_idx=self._adapter_idx(adapter, B),
-        )
-        return tokens, lambda host: np.asarray([host[r.slot] for r in rows])  # sync-ok: host reshuffle of already-synced ids
-
     def _mixed_flat_launch(self, chunk_rows, decode_rows):
-        """Token-flattened layout: chunk rows keep their [C, T] matrix, decode
-        rows collapse to a [D, 1] segment — per-step cost scales with the
-        tokens actually fed (bucketed per segment), not B x chunk. Both
-        segments run in ONE jit; token-identical to the padded layout (each
-        live row's math is a row-slice of the padded program's). Returns
-        (device tokens, host-order mapper)."""
+        """Build and dispatch the mixed launch: chunk rows keep their [C, T]
+        matrix, decode rows collapse to a [D, 1] segment, each bucketed on its
+        own (or ``fixed_mixed_shape``), so per-step cost scales with the
+        tokens actually fed. Both segments run in ONE jit. Returns (device
+        tokens, host-order mapper)."""
         C, T, D = self.infer.fixed_mixed_shape or (
             _bucket(len(chunk_rows), minimum=1),
             _bucket(max([len(r.tokens) for r in chunk_rows], default=1), minimum=1),

@@ -227,6 +227,21 @@ class Request:
 
 
 class InferenceEngine:
+    # migration scheduling (staged backends only): at most this many block
+    # migrations in flight at once ...
+    migration_inflight_limit = 4
+    # ... and new migrations are deferred while the decode stage's share of KV
+    # blocks exceeds this fraction (decode pressure gates handoff)
+    decode_pressure_gate = 0.92
+    # stage-aware admission: new prompts stop admitting while the prefill
+    # stage's share of KV blocks (mid-prefill + migrating sequences) would
+    # exceed this fraction
+    prefill_pressure_gate = 0.95
+    # n-gram (prompt-lookup) proposer: length of the matched suffix
+    spec_ngram = 2
+    # engine leg of the per-request rejection-sampling seed (_req_rng)
+    spec_seed = 0
+
     def __init__(
         self,
         model,
@@ -241,9 +256,7 @@ class InferenceEngine:
         kv_cache_quant: Optional[str] = None,  # None | "int8" | "fp8" (cachekv_int8 knob)
         use_speculative: bool = False,
         spec_draft_len: int = 4,
-        spec_ngram: int = 2,
         draft_model=None,  # small causal LM proposer (reference speculate_method=draft_model)
-        spec_seed: int = 0,
         # share KV blocks across common prompt prefixes. Content-addressed:
         # only valid while params are frozen — callers that update weights
         # between requests must disable this or call clear_prefix_cache()
@@ -260,19 +273,6 @@ class InferenceEngine:
         # stage, KV blocks migrating between the stage pools. Overrides
         # mesh_shape. None = single-stage.
         disagg_stages=None,
-        # migration scheduling knobs (staged backends only): at most this many
-        # block migrations in flight at once ...
-        migration_inflight_limit: int = 4,
-        # ... and new migrations are deferred while the decode stage's share
-        # of KV blocks exceeds this fraction (decode pressure gates handoff)
-        decode_pressure_gate: float = 0.92,
-        # stage-aware admission: new prompts stop admitting while the prefill
-        # stage's share of KV blocks (mid-prefill + migrating sequences)
-        # would exceed this fraction
-        prefill_pressure_gate: float = 0.95,
-        # mixed-step layout: True = token-flattened segments, False = one
-        # padded [B, chunk] launch, None = auto (flatten on the XLA fallback)
-        token_flatten: Optional[bool] = None,
         # a prebuilt ModelBackend instance overrides mesh_shape (tests /
         # future MPMD stage-split backends plug in here)
         backend: Optional[ModelBackend] = None,
@@ -298,7 +298,7 @@ class InferenceEngine:
         backend_kw = dict(
             max_batch_size=max_batch_size, block_size=block_size, num_blocks=num_blocks,
             max_blocks_per_seq=max_blocks_per_seq, dtype=dtype, decode_steps=decode_steps,
-            eos_ids=self.eos_ids, kv_cache_quant=kv_cache_quant, token_flatten=token_flatten,
+            eos_ids=self.eos_ids, kv_cache_quant=kv_cache_quant,
             adapter_registry=adapter_registry, prefill_chunk_tokens=prefill_chunk_tokens,
         )
         # every kind's door: the class that computes the configuration's layer
@@ -340,9 +340,6 @@ class InferenceEngine:
         # blocks): req_id -> in-flight MigrationTicket, plus the deferred
         # queue migrations wait on while the decode stage is under pressure
         self.staged = bool(getattr(self.backend, "staged", False))
-        self.migration_inflight_limit = migration_inflight_limit
-        self.decode_pressure_gate = decode_pressure_gate
-        self.prefill_pressure_gate = prefill_pressure_gate
         # is_ready-less runtimes: force-land a migration after this many polls
         # (the functional pool threading already guarantees correctness)
         self.migration_force_land_polls = 8
@@ -378,9 +375,7 @@ class InferenceEngine:
         # batched verify; greedy acceptance or rejection sampling
         self.use_speculative = use_speculative or draft_model is not None
         self.spec_draft_len = spec_draft_len
-        self.spec_ngram = spec_ngram
         self.draft_model = draft_model
-        self._spec_seed = spec_seed
         self._spec_rngs: Dict[int, np.random.Generator] = {}
         self.spec_stats = {"verify_steps": 0, "tokens_emitted": 0, "drafted": 0, "accepted": 0}
         self.num_preemptions = 0
@@ -1606,16 +1601,14 @@ class InferenceEngine:
                 slot=slot, tokens=req.prompt_ids[p0 : p0 + n], start=p0,
                 table=self.mgr.table_array(req.req_id),
                 emit=p0 + n == len(req.prompt_ids),  # sampler on last chunk
-                sampling=req.sampling, is_chunk=True,
-                adapter=req.adapter_slot))
+                sampling=req.sampling, adapter=req.adapter_slot))
         for slot, req in decode_rows:
             self.mgr.window_span(req.req_id, req.total_len - 1, 1)
         dec_payload = [
             MixedRow(slot=slot, tokens=np.asarray([self._last_token[slot]], np.int32),  # sync-ok: _last_token is a host array
                      start=req.total_len - 1,  # position of the token being fed
                      table=self.mgr.table_array(req.req_id), emit=True,
-                     sampling=req.sampling, is_chunk=False,
-                     adapter=req.adapter_slot)
+                     sampling=req.sampling, adapter=req.adapter_slot)
             for slot, req in decode_rows]
         return chunk_rows, decode_rows, chunk_payload, dec_payload
 
@@ -1837,7 +1830,7 @@ class InferenceEngine:
         with the same seed, matching the device sampler's per-request contract."""
         if req.req_id not in self._spec_rngs:
             self._spec_rngs[req.req_id] = np.random.default_rng(
-                (self._spec_seed, req.sampling.seed, req.req_id))
+                (self.spec_seed, req.sampling.seed, req.req_id))
         return self._spec_rngs[req.req_id]
 
     def _decode_spec(self, finished: List[Request], drafts: List[np.ndarray],

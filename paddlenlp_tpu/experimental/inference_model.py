@@ -230,7 +230,6 @@ class PagedInferenceModel:
         # need_logits is static BY POSITION: a jit with in_shardings (the
         # sharded subclass) takes no keyword arguments
         self._verify = jax.jit(self._verify_impl, donate_argnums=(1,), static_argnums=(7,))
-        self._mixed = jax.jit(self._mixed_impl, donate_argnums=(1,))
         self._mixed_flat = jax.jit(self._mixed_flat_impl, donate_argnums=(1,))
 
     def _hint(self, x, kind: str):
@@ -474,75 +473,33 @@ class PagedInferenceModel:
             counts = counts + jax.nn.one_hot(tokens, V, dtype=jnp.int32)
         return tokens, counts, new_pool
 
-    def _mixed_impl(self, params, pool, input_ids, block_tables, q_lens, q_start,
-                    counts, count_fed, emit, samp, lora=None, adapter_idx=None):
-        """One ragged mixed prefill/decode step: every row feeds ``q_lens[j]``
-        new tokens starting at absolute position ``q_start[j]`` — a prefill
-        CHUNK (``q_start`` = tokens already prefilled, ``q_lens`` up to the
-        chunk size), a decode step (``q_lens = 1``, ``q_start`` = position of
-        the last sampled token), or nothing (``q_lens = 0``, padded slot). KV
-        for every fed token is written into the paged pool at its absolute
-        position; attention covers ``[0, q_start + t]`` per fed token t —
-        causal across chunk boundaries because earlier chunks' KV is already
-        in the pool.
-
-        Sampling fires for EVERY row at position ``q_start + q_lens`` (the
-        next position) from the logits after the last valid fed token; the
-        caller keeps the token only where ``emit`` is set (final prefill
-        chunks and decode rows) — non-final chunks discard it, exactly the
-        "sampler fires only when the last chunk lands" contract.
-
-        Penalty-count accumulation across chunks: ``counts`` [B, V] is the
-        running per-row token count. Rows with ``count_fed`` add their fed
-        tokens on device (prefill chunks — the count survives to the next
-        chunk through the returned array); decode rows don't (their fed token
-        was counted when it was sampled). Rows with ``emit`` add the sampled
-        token. Penalties see counts INCLUDING the fed tokens, matching the
-        monolithic prefill exactly.
-
-        Returns (tokens [B], counts' [B, V], new pool).
-        """
-        n, T = input_ids.shape
-        positions = q_start[:, None] + jnp.arange(T)[None, :]
-        S = block_tables.shape[1] * self.block_size
-        kv_len_mask = jnp.arange(S)[None, :] < (q_start + q_lens)[:, None]
-        logits, new_pool = self._forward(
-            params, pool, input_ids, block_tables, positions, kv_len_mask,
-            q_start, jnp.maximum(q_lens - 1, 0), q_lens=q_lens,
-            lora=lora, adapter_idx=adapter_idx,
-        )
-        V = counts.shape[-1]
-        with jax.named_scope("bookkeeping"):
-            valid = (jnp.arange(T)[None, :] < q_lens[:, None]).astype(jnp.int32)
-            fed = (jax.nn.one_hot(input_ids, V, dtype=jnp.int32) * valid[..., None]).sum(axis=1)
-            counts = counts + fed * count_fed.astype(jnp.int32)[:, None]
-        with jax.named_scope("sample"):
-            tokens = sample_tokens(logits, positions=q_start + q_lens, counts=counts, **samp)
-        with jax.named_scope("bookkeeping"):
-            counts = counts + jax.nn.one_hot(tokens, V, dtype=jnp.int32) \
-                * emit.astype(jnp.int32)[:, None]
-        return tokens, counts, new_pool
-
+    # the one mixed layout; "flat" stays in the name because the benchmark keys on jit__mixed_flat_impl
     def _mixed_flat_impl(self, params, pool, chunk_ids, chunk_tables, chunk_qlens,
                          chunk_start, chunk_slots, chunk_emit, dec_tokens, dec_tables,
                          dec_start, dec_slots, dec_live, counts, samp, lora=None,
                          chunk_adapter=None, dec_adapter=None):
-        """Token-flattened ragged mixed step (the XLA-fallback layout).
+        """One ragged mixed prefill/decode step: two packed segments in one jit,
+        ``C x T + D`` positions. A chunk row feeds ``chunk_qlens[j]`` prompt
+        tokens starting at absolute position ``chunk_start[j]`` (= tokens
+        already prefilled), in a [C, T] matrix; a decode row feeds its last
+        sampled token at ``dec_start[j]``, in a [D, 1] segment. KV for every
+        fed token is written into the paged pool at its absolute position;
+        attention covers ``[0, start + t]`` per fed token t, causal across
+        chunk boundaries because earlier chunks' KV is already in the pool. A
+        padding row (``chunk_qlens = 0`` / ``~dec_live``) writes only into the
+        sentinel block and adds zeros.
 
-        :meth:`_mixed_impl` pads EVERY row — decode rows included — to the
-        chunk bucket, so a mixed step costs B x chunk query positions on the
-        XLA path however few tokens are actually fed. Here the step is two
-        packed segments inside one jit: prefill chunks keep their [C, T]
-        matrix (C = rows actually mid-prefill, bucketed) and decode rows
-        collapse to [D, 1]; cost scales with the tokens fed. Rows map to
-        engine slots through ``chunk_slots``/``dec_slots`` — the penalty-count
-        tensor stays slot-indexed, updated by scatter instead of dense adds.
+        Sampling fires for EVERY row at its next position from the logits
+        after the last valid fed token; the caller keeps the token only where
+        the row emits (``chunk_emit``: final chunks; live decode rows): the
+        sampler fires only when the last chunk lands.
 
-        Token-identical to the padded layout: each live row's computation is
-        a row-slice of the padded program (same contraction lengths, same
-        sampling keys ``(seed, position)``), and the count updates are the
-        same integers. Dead padding rows (``chunk_qlens = 0`` /
-        ``~dec_live``) write only into the sentinel block and add zeros.
+        ``counts`` [B, V] is the running per-SLOT token count, reached through
+        ``chunk_slots``/``dec_slots`` and updated by scatter. Chunk rows add
+        their fed tokens (the count survives to the next chunk through the
+        returned array); decode rows don't (theirs was counted when sampled).
+        Emitting rows add the sampled token. Penalties see counts INCLUDING
+        the fed tokens, matching the monolithic prefill exactly.
 
         Returns (tokens [C + D], counts', new pool) — tokens in segment
         order, the caller slices live rows back out.
@@ -679,11 +636,6 @@ class PagedInferenceModel:
             params, pool, tokens, block_tables, context_lens, done0, remaining, counts,
             samp, lora, adapter_idx
         )
-
-    def mixed_step(self, params, pool: PagedKVPool, input_ids, block_tables, q_lens,
-                   q_start, counts, count_fed, emit, samp, lora=None, adapter_idx=None):
-        return self._mixed(params, pool, input_ids, block_tables, q_lens, q_start,
-                           counts, count_fed, emit, samp, lora, adapter_idx)
 
     def mixed_step_flat(self, params, pool: PagedKVPool, chunk_ids, chunk_tables,
                         chunk_qlens, chunk_start, chunk_slots, chunk_emit, dec_tokens,

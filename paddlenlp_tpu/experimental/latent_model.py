@@ -130,9 +130,6 @@ class LatentInferenceModel(PagedInferenceModel):
     def _mixed_flat_impl(self, params, pool, *args, **kwargs):
         return super()._mixed_flat_impl(params, _zero_stats(pool), *args, **kwargs)
 
-    def _mixed_impl(self, params, pool, *args, **kwargs):
-        return super()._mixed_impl(params, _zero_stats(pool), *args, **kwargs)
-
     def _decode_impl(self, params, pool, *args, **kwargs):
         return super()._decode_impl(params, _zero_stats(pool), *args, **kwargs)
 

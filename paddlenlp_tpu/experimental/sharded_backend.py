@@ -220,10 +220,6 @@ class ShardedPagedInferenceModel(PagedInferenceModel):
             self._verify_impl, donate_argnums=(1,), static_argnums=(7,),
             in_shardings=(ps, pool_s) + (r,) * 3 + (lora_s, r),
             out_shardings=(r, r, pool_s))
-        self._mixed = jax.jit(
-            self._mixed_impl, donate_argnums=(1,),
-            in_shardings=(ps, pool_s) + (r,) * 8 + (lora_s, r),
-            out_shardings=(r, r, pool_s))
         self._mixed_flat = jax.jit(
             self._mixed_flat_impl, donate_argnums=(1,),
             in_shardings=(ps, pool_s) + (r,) * 13 + (lora_s, r, r),

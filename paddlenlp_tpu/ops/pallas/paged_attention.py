@@ -37,10 +37,7 @@ Design:
   not change any row's result;
 - GQA: queries fold to [B, K, T*group, H]; each grid cell attends its kv
   head's whole query group for every token of its query tile at once;
-- ``q_lens = 1`` everywhere reduces to the classic paged decode kernel —
-  :func:`paged_decode_attention` is that wrapper, kept as the stable
-  decode-only API (``_layer`` now always dispatches the ragged kernel; the
-  wrapper has no library call sites, only external/test callers).
+- ``q_lens = 1`` everywhere reduces to the classic paged decode kernel.
 
 Off-TPU (tests), the kernel runs in Pallas interpret mode.
 """
@@ -55,7 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["paged_decode_attention", "ragged_paged_attention"]
+__all__ = ["ragged_paged_attention"]
 
 NEG_INF = -1e30
 
@@ -200,24 +197,3 @@ def ragged_paged_attention(
     )(block_tables.astype(jnp.int32), q_start.astype(jnp.int32),
       q_lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     return out.reshape(B, K, T, group, H).transpose(0, 2, 1, 3, 4).reshape(B, T, N, H)
-
-
-def paged_decode_attention(
-    q: jnp.ndarray,  # [B, N, H] one query token per sequence
-    kv: jnp.ndarray,  # [L, 2, num_blocks, bs, K*H] the whole pool
-    block_tables: jnp.ndarray,  # [B, max_blocks] int32
-    context_lens: jnp.ndarray,  # [B] int32 (position of the current token)
-    layer,  # int32 scalar: the pool layer to read
-    scale: Optional[float] = None,
-    interpret: Optional[bool] = None,
-    kv_scale: Optional[jnp.ndarray] = None,
-) -> jnp.ndarray:
-    """Decode-only wrapper: every sequence contributes exactly one query token
-    at position ``context_lens[b]`` (the ragged kernel with ``q_lens = 1``)."""
-    B = q.shape[0]
-    out = ragged_paged_attention(
-        q[:, None], kv, block_tables,
-        q_start=context_lens, q_lens=jnp.ones((B,), jnp.int32), layer=layer,
-        scale=scale, interpret=interpret, kv_scale=kv_scale,
-    )
-    return out[:, 0]

@@ -8,6 +8,8 @@ tests (each fresh engine pays several jit compiles); every test uses distinct
 prompts so runs stay independent — and any cross-test prefix-cache hit must
 leave outputs identical anyway, which is exactly the property under test."""
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -35,6 +37,33 @@ def eng_mono(model):
 @pytest.fixture(scope="module")
 def eng_chunk(model):
     return InferenceEngine(model, prefill_chunk_tokens=8, **KW)
+
+
+@pytest.fixture(scope="module")
+def eng_chunk_kernel(model):
+    """eng_chunk's twin through the Pallas ragged kernel (interpret mode on the
+    CPU). The flag is read at trace time, so it is set before the first step."""
+    eng = InferenceEngine(model, prefill_chunk_tokens=8, **KW)
+    eng.infer.use_paged_kernel = True
+    return eng
+
+
+@contextlib.contextmanager
+def spy_mixed_launches(eng):
+    """Records (chunk rows, decode rows, stamped launch shape) of every mixed
+    launch of ``eng`` while the block runs; the backend's method is restored."""
+    seen, orig = [], eng.backend._mixed_flat_launch
+
+    def spy(chunk_rows, decode_rows):
+        out = orig(chunk_rows, decode_rows)
+        seen.append((len(chunk_rows), len(decode_rows), eng.backend.step_accounting["shape"]))
+        return out
+
+    eng.backend._mixed_flat_launch = spy
+    try:
+        yield seen
+    finally:
+        eng.backend._mixed_flat_launch = orig
 
 
 @pytest.fixture(scope="module")
@@ -83,14 +112,11 @@ class TestChunkedParity:
         want = eng_mono.generate(prompts, SamplingParams(max_new_tokens=6))
         assert eng_chunk.generate(prompts, SamplingParams(max_new_tokens=6)) == want
 
-    def test_chunked_with_ragged_kernel(self, eng_mono, model):
+    def test_chunked_with_ragged_kernel(self, eng_mono, eng_chunk_kernel):
         """Whole-engine chunked decode through the Pallas ragged kernel
-        (interpret) must equal the XLA gather path. Fresh engine: the kernel
-        flag is read at trace time, so it cannot flip on a warm engine."""
+        (interpret) must equal the XLA gather path."""
         want = eng_mono.generate(PROMPTS, SamplingParams(max_new_tokens=6))
-        eng = InferenceEngine(model, prefill_chunk_tokens=8, **KW)
-        eng.infer.use_paged_kernel = True  # interpret mode on CPU
-        assert eng.generate(PROMPTS, SamplingParams(max_new_tokens=6)) == want
+        assert eng_chunk_kernel.generate(PROMPTS, SamplingParams(max_new_tokens=6)) == want
 
     def test_prefix_cache_fed_suffix_chunked(self, model, eng_mono):
         """Warm admissions start chunking at the cached length; outputs match
@@ -154,26 +180,27 @@ class TestChunkedInterleave:
         assert interleaved > 0  # decode advanced while the long prompt filled
         assert len(eng.recent_decode_stalls) > stalls0  # stall events recorded
 
-    def test_preempt_half_prefilled_folds_state(self, model, eng_mono):
-        """Pool pressure evicts the youngest slot mid-prefill; after requeue +
-        re-admission the stream is token-exact and no KV block leaks. The
-        reference run rides the shared monolithic engine — both requests fit
-        its batch at once, so the outputs are batch-capacity-independent."""
-        long_p = list(range(10, 34))  # 24 tokens
-        want = eng_mono.generate([[5, 6, 7], long_p], SamplingParams(max_new_tokens=10))
-
-        eng = InferenceEngine(model, prefill_chunk_tokens=4, max_batch_size=2,
-                              block_size=4, num_blocks=11, max_blocks_per_seq=32)
-        streams = {0: [], 1: []}
-        eng.add_request([5, 6, 7], SamplingParams(max_new_tokens=10),
-                        stream_cb=lambda t, d: streams[0].append(t))
-        eng.add_request(long_p, SamplingParams(max_new_tokens=10),
-                        stream_cb=lambda t, d: streams[1].append(t))
+    @pytest.mark.parametrize("kw,prompts,max_new", [
+        (dict(prefill_chunk_tokens=4, max_batch_size=2, num_blocks=11), [[5, 6, 7], list(range(10, 34))], 10),
+        (dict(prefill_chunk_tokens=8, max_batch_size=4, num_blocks=18), [list(range(5, 25)), list(range(30, 50))], 20),
+    ], ids=["youngest_mid_prefill", "two_side_by_side"])
+    def test_preempt_half_prefilled_folds_state(self, model, eng_mono, kw, prompts, max_new):
+        """Pool pressure evicts the youngest slot mid-flight (a 24-token prompt
+        mid-prefill beside a short one; two 20-token prompts chunking side by
+        side, 10 blocks each where 17 are usable); after requeue + re-admission
+        the streams are token-exact and no KV block leaks. The reference run
+        rides the shared monolithic engine — the requests fit its batch at
+        once, so the outputs are batch-capacity-independent."""
+        want = eng_mono.generate(prompts, SamplingParams(max_new_tokens=max_new))
+        eng = InferenceEngine(model, block_size=4, max_blocks_per_seq=32, **kw)
+        streams = [[] for _ in prompts]
+        for prompt, stream in zip(prompts, streams):
+            eng.add_request(prompt, SamplingParams(max_new_tokens=max_new),
+                            stream_cb=lambda t, d, stream=stream: stream.append(t))
         while eng.has_work():
             eng.step()
         assert eng.num_preemptions > 0
-        assert streams[0] == want[0]
-        assert streams[1] == want[1]
+        assert streams == [list(w) for w in want]
         assert eng.mgr.num_free == eng.mgr.total_usable_blocks  # no leak
 
     def test_oldest_prefill_gets_budget_first(self, eng_chunk4):
@@ -247,69 +274,59 @@ class TestChunkedMetrics:
         assert metrics2.prefill_chunk_tokens.count() == 0
 
 
-class TestTokenFlattenedLayout:
-    """The token-flattened mixed-step layout (PR 7 follow-up): decode rows no
-    longer pad to the chunk bucket on the XLA fallback. It must be
-    token-identical to the padded layout AND to monolithic prefill, and it is
-    the auto default off-TPU (``token_flatten=None`` -> flat when the Pallas
-    ragged kernel is inactive)."""
+class TestMixedLaunch:
+    """What the mixed step launches: two packed segments, chunk rows [C, T]
+    and decode rows [D, 1], each bucketed on its own, with the ragged kernel
+    as without it."""
 
-    def test_flat_is_auto_default_off_tpu(self, eng_chunk):
-        assert not eng_chunk.infer.use_paged_kernel
-        assert eng_chunk.backend.token_flatten is None  # auto -> flat
+    @pytest.mark.parametrize("sp,prompts", [
+        (SamplingParams(max_new_tokens=7),
+         [list(range(8, 31)), [88, 89], list(range(61, 74))]),
+        (SamplingParams(max_new_tokens=7, do_sample=True, temperature=0.8, top_p=0.9, seed=3,
+                        repetition_penalty=1.2, presence_penalty=0.1, frequency_penalty=0.05),
+         [list(range(9, 32)), [90, 91], list(range(62, 75))]),
+    ], ids=["greedy", "sampled_with_penalties"])
+    def test_mixed_step_with_the_kernel_equals_without(self, eng_chunk, eng_chunk_kernel, sp, prompts):
+        """The mixed step through the Pallas ragged kernel (interpret) against
+        the XLA gather path, row for row: chunk rows whose counts accumulate
+        across chunks beside decode rows, seeded sampling under penalties."""
+        assert eng_chunk_kernel.generate(prompts, sp) == eng_chunk.generate(prompts, sp)
 
-    def test_flat_vs_padded_token_identical(self, model, eng_chunk):
-        """eng_chunk runs the flat layout (auto); a token_flatten=False twin
-        runs the padded [B, chunk] launch — greedy + seeded sampling with
-        penalties must agree row for row."""
-        eng_pad = InferenceEngine(model, prefill_chunk_tokens=8,
-                                  token_flatten=False, **KW)
-        prompts = [list(range(8, 31)), [88, 89], list(range(61, 74))]
-        for sp in (SamplingParams(max_new_tokens=7),
-                   SamplingParams(max_new_tokens=7, do_sample=True, temperature=0.8,
-                                  top_p=0.9, seed=3, repetition_penalty=1.2,
-                                  presence_penalty=0.1, frequency_penalty=0.05)):
-            assert eng_chunk.generate(prompts, sp) == eng_pad.generate(prompts, sp)
-
-    def test_flat_preemption_parity(self, model):
-        """Preemption pressure mid-prefill behaves identically under both
-        layouts (the capacity pass is engine-side and layout-blind)."""
-        kw = dict(max_batch_size=4, block_size=4, num_blocks=18, max_blocks_per_seq=32)
-        prompts = [list(range(5, 25)), list(range(30, 50))]
-        outs = {}
-        for flat in (True, False):
-            eng = InferenceEngine(model, prefill_chunk_tokens=8, token_flatten=flat, **kw)
-            outs[flat] = eng.generate(prompts, SamplingParams(max_new_tokens=10))
-        assert outs[True] == outs[False]
+    def test_launch_shape_is_the_same_with_the_kernel(self, eng_chunk, eng_chunk_kernel):
+        """The kernel does not pick the layout: with it on as with it off a
+        mixed step is stamped ``("mixed_flat", C, T, D)``, one chunk row of 8
+        beside the decode rows' own bucket."""
+        shapes = {}
+        for name, eng in (("xla", eng_chunk), ("kernel", eng_chunk_kernel)):
+            with spy_mixed_launches(eng) as seen:
+                for i in range(3):
+                    eng.add_request([80 + i, 3, 4], SamplingParams(max_new_tokens=16))
+                for _ in range(2):
+                    eng.step()
+                eng.add_request(list(range(72, 92)), SamplingParams(max_new_tokens=2))
+                while eng.has_work():
+                    eng.step()
+            shapes[name] = [shape for _c, _d, shape in seen]
+        assert shapes["kernel"] == shapes["xla"]
+        assert ("mixed_flat", 1, 8, 4) in shapes["xla"]  # a full chunk beside 3 decode rows
+        assert all(s[0] == "mixed_flat" and len(s) == 4 for s in shapes["xla"])
 
     def test_flat_feeds_fewer_padded_rows(self, eng_chunk):
-        """The point of the layout: with one long prompt chunking while three
-        short requests decode, the flat step's chunk segment holds 1 row, not
-        max_batch_size — assert via the backend's segment shapes. Rides the
-        shared chunk engine (the spy is restored); the long prompt's leading
-        block is unique to this test so no cache hit shortens the chunk walk."""
+        """With one long prompt chunking while three short requests decode,
+        the step's chunk segment holds 1 row, not max_batch_size — assert via
+        the backend's segment shapes. Rides the shared chunk engine; the long
+        prompt's leading block is unique to this test so no cache hit shortens
+        the chunk walk."""
         eng = eng_chunk
-        seen = []
-        orig = eng.backend._mixed_flat_launch
-
-        def spy(chunk_rows, decode_rows):
-            seen.append((len(chunk_rows), len(decode_rows)))
-            return orig(chunk_rows, decode_rows)
-
-        eng.backend._mixed_flat_launch = spy
-        try:
+        with spy_mixed_launches(eng) as seen:
             for p in ([40 + i] for i in range(3)):
                 eng.add_request(list(p) + [7, 8], SamplingParams(max_new_tokens=24))
             for _ in range(3):
                 eng.step()  # the shorties admit + start decoding
             eng.add_request(list(range(41, 73)), SamplingParams(max_new_tokens=4))
-            for _ in range(4):
-                eng.step()
             while eng.has_work():
                 eng.step()
-        finally:
-            eng.backend._mixed_flat_launch = orig
-        mixed = [s for s in seen if s[0] and s[1]]
+        mixed = [(c, d) for c, d, _shape in seen if c and d]
         assert mixed, "no step carried chunks and decodes together"
         # every mixed step fed exactly the live rows: 1 chunk row + <=3 decodes
         assert all(c == 1 and 1 <= d <= 3 for c, d in mixed), mixed

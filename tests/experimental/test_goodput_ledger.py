@@ -1,8 +1,8 @@
 """Goodput-ledger conservation parity across every engine step path.
 
 The invariant under test: ``fed == useful + padding + spec_rejected + rework``
-holds EXACTLY on monolithic, chunked, token-flattened, padded-mixed, sharded
-and disaggregated steps — and ``useful`` is identical across all of them for
+holds EXACTLY on monolithic, chunked, sharded and disaggregated steps — and
+``useful`` is identical across all of them for
 the same greedy workload (token identity implies work identity; only the
 padding/rework decomposition may differ per layout). Plus the rework
 accounting: preemption recompute, supervisor-requeue hints, prefix-cache COW
@@ -36,10 +36,6 @@ def engines(model):
     return {
         "mono": InferenceEngine(model, **KW),
         "chunked": InferenceEngine(model, prefill_chunk_tokens=4, **KW),
-        "flat": InferenceEngine(model, prefill_chunk_tokens=4,
-                                token_flatten=True, **KW),
-        "padded": InferenceEngine(model, prefill_chunk_tokens=4,
-                                  token_flatten=False, **KW),
         "sharded": InferenceEngine(model, mesh_shape=(1, 2), **KW),
         "disagg": InferenceEngine(model, disagg_stages=(1, 1),
                                   prefill_chunk_tokens=4, **KW),
@@ -165,8 +161,7 @@ class TestReworkAccounting:
 
 class TestSpeculative:
     def test_spec_rejected_matches_engine_stats(self, model):
-        eng = InferenceEngine(model, use_speculative=True, spec_draft_len=3,
-                              spec_ngram=2, **KW)
+        eng = InferenceEngine(model, use_speculative=True, spec_draft_len=3, **KW)
         # constant prompt: the model repeats, the n-gram proposer drafts,
         # greedy verify accepts some and rejects the rest — the ledger's
         # spec_rejected bucket must equal the engine's drafted - accepted

@@ -171,7 +171,7 @@ class TestDeviceSampling:
 
 class TestPagedKernel:
     def test_kernel_matches_gather_path(self):
-        from paddlenlp_tpu.ops.pallas.paged_attention import paged_decode_attention
+        from paddlenlp_tpu.ops.pallas.paged_attention import ragged_paged_attention
 
         rng = np.random.default_rng(1)
         B, N, K, H, nb, bs, mb = 2, 4, 2, 64, 12, 8, 4
@@ -180,7 +180,8 @@ class TestPagedKernel:
         pv = jnp.asarray(rng.standard_normal((nb, K, bs, H)), jnp.float32)
         tables = jnp.asarray(rng.permutation(np.arange(1, nb))[: B * mb].reshape(B, mb), jnp.int32)
         ctx = jnp.asarray([7, 22], jnp.int32)
-        out = paged_decode_attention(q, whole_pool(pk, pv), tables, ctx, 0, interpret=True)
+        out = ragged_paged_attention(q[:, None], whole_pool(pk, pv), tables, ctx,
+                                     jnp.ones((B,), jnp.int32), 0, interpret=True)[:, 0]
 
         def flat(pool):  # [nb,K,bs,H] gathered -> [B, mb*bs, K, H]
             return pool[tables].transpose(0, 1, 3, 2, 4).reshape(B, mb * bs, K, H)
@@ -335,23 +336,23 @@ class TestRaggedKernel:
             np.asarray(tiled), np.asarray(self._ref(q, pk, pv, tables, q_start, q_lens)),
             atol=2e-5)
 
-    def test_decode_wrapper_matches_ragged(self):
-        from paddlenlp_tpu.ops.pallas.paged_attention import (
-            paged_decode_attention, ragged_paged_attention)
+    def test_decode_rows_match_reference(self):
+        """``q_lens`` of ones is the classic paged decode kernel: one query
+        token a sequence, at the position its context has reached."""
+        from paddlenlp_tpu.ops.pallas.paged_attention import ragged_paged_attention
 
         rng = np.random.default_rng(4)
         B, N, K, H, nb, bs, mb = 2, 4, 2, 64, 12, 8, 4
-        q = jnp.asarray(rng.standard_normal((B, N, H)), jnp.float32)
+        q = jnp.asarray(rng.standard_normal((B, 1, N, H)), jnp.float32)
         pk = jnp.asarray(rng.standard_normal((nb, K, bs, H)), jnp.float32)
         pv = jnp.asarray(rng.standard_normal((nb, K, bs, H)), jnp.float32)
         tables = jnp.asarray(rng.permutation(np.arange(1, nb))[: B * mb].reshape(B, mb),
                              jnp.int32)
         ctx = jnp.asarray([7, 22], jnp.int32)
-        pool = whole_pool(pk, pv)
-        a = paged_decode_attention(q, pool, tables, ctx, 0, interpret=True)
-        b = ragged_paged_attention(q[:, None], pool, tables, ctx,
-                                   jnp.ones((B,), jnp.int32), 0, interpret=True)[:, 0]
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=0)
+        ones = jnp.ones((B,), jnp.int32)
+        out = ragged_paged_attention(q, whole_pool(pk, pv), tables, ctx, ones, 0, interpret=True)
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(self._ref(q, pk, pv, tables, ctx, ones)), atol=2e-5)
 
 
 class TestPoolWrite:
@@ -460,7 +461,7 @@ class TestQuantizedKVCache:
 
     def test_paged_kernel_dequant_matches_gather(self):
         from paddlenlp_tpu.experimental.paged_cache import quantize_kv
-        from paddlenlp_tpu.ops.pallas.paged_attention import paged_decode_attention
+        from paddlenlp_tpu.ops.pallas.paged_attention import ragged_paged_attention
 
         rng = np.random.default_rng(3)
         B, N, K, H, nb, bs, mb = 2, 4, 2, 64, 12, 8, 4
@@ -472,8 +473,9 @@ class TestQuantizedKVCache:
         tables = jnp.asarray(rng.permutation(np.arange(1, nb))[: B * mb].reshape(B, mb), jnp.int32)
         ctx = jnp.asarray([7, 22], jnp.int32)
         # layer 1 of 3: the scale planes are addressed by layer like the pool
-        out = paged_decode_attention(q, whole_pool(pk_q, pv_q, 1, 3), tables, ctx, 1,
-                                     interpret=True, kv_scale=whole_scale(pk_s, pv_s, 1, 3))
+        out = ragged_paged_attention(q[:, None], whole_pool(pk_q, pv_q, 1, 3), tables, ctx,
+                                     jnp.ones((B,), jnp.int32), 1, interpret=True,
+                                     kv_scale=whole_scale(pk_s, pv_s, 1, 3))[:, 0]
 
         def flat(pool):
             return pool[tables].transpose(0, 1, 3, 2, 4).reshape(B, mb * bs, K, H)

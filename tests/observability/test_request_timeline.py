@@ -339,9 +339,8 @@ def test_prefill_launch_geometry_with_a_cached_prefix(model):
 
 
 @pytest.mark.parametrize("kw,kinds", [({"prefill_chunk_tokens": 8}, ("mixed", "decode")),
-                                      ({"prefill_chunk_tokens": 8, "token_flatten": False}, ("mixed", "decode")),
                                       ({"use_speculative": True}, ("prefill", "verify"))],
-                         ids=["chunked-flat", "chunked-padded", "speculative"])
+                         ids=["chunked", "speculative"])
 def test_every_program_records_geometry_and_conserves(model, kw, kinds):
     eng = make_engine(model, **kw)
     eng.generate([[5, 6, 7, 8, 9], list(range(10, 30)), [30] * 12], SamplingParams(max_new_tokens=10))

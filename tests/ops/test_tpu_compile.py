@@ -243,14 +243,16 @@ def test_paged_kernel_on_a_mesh_compiles(topology, monkeypatch):
     assert "tpu_custom_call" in text and "bf16[8,1,6,128]" in text
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill16x512"])
+@pytest.mark.parametrize("program", ["decode", "prefill16x512", "mixed_flat1x512+16"])
 def test_step_programs_copy_no_pool(chip, monkeypatch, program):
     """The serving step programs at the benchmark cell's shapes (Qwen2-1.5B,
     28 layers, 9600 blocks of 16, 16 slots, 192-block tables, abstract weights)
     address the donated pool in place. While the pool rode the layer scan as
     xs / ys in a kv-head-major layout, the same compiles held 4.53 GiB (decode)
     and 4.79 GiB (prefill) of temporaries: each layer's pool sliced out, relaid
-    for the scatter, relaid for the kernel and stacked back."""
+    for the scatter, relaid for the kernel and stacked back. The mixed step (one
+    chunk row of 512 tokens beside 16 decode rows: what a chunked engine of this
+    geometry launches) runs two forwards in series over the one donated pool."""
     from paddlenlp_tpu.experimental.backend import samp_arrays
     from paddlenlp_tpu.experimental.inference_model import PagedInferenceModel
     from paddlenlp_tpu.experimental.paged_cache import init_paged_pool
@@ -278,9 +280,14 @@ def test_step_programs_copy_no_pool(chip, monkeypatch, program):
         step, args = infer._decode_impl, (
             params, pool, rows(), rows(table), rows(), aval((slots,), jnp.bool_), rows(),
             rows(vocab), samp(slots))
-    else:
+    elif program == "prefill16x512":
         step, args = infer._prefill_impl, (
             params, pool, rows(512), rows(table), rows(), rows(), rows(vocab), samp(slots))
+    else:
+        chunk = lambda *shape, dtype=jnp.int32: aval((1,) + shape, dtype)
+        step, args = infer._mixed_flat_impl, (
+            params, pool, chunk(512), chunk(table), chunk(), chunk(), chunk(), chunk(dtype=jnp.bool_),
+            rows(), rows(table), rows(), rows(), aval((slots,), jnp.bool_), rows(vocab), samp(1 + slots))
     compiled = jax.jit(step, donate_argnums=(1,)).lower(*args).compile()
 
     pool_bytes = pool.kv.size * pool.kv.dtype.itemsize

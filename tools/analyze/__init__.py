@@ -94,7 +94,6 @@ DEFAULT_CONFIG: Dict = {
             "launch_geometry", "SingleDeviceBackend.prefill", "SingleDeviceBackend.decode",
             "SingleDeviceBackend.verify", "SingleDeviceBackend.mixed_step",
             "SingleDeviceBackend.mixed_step_begin",
-            "SingleDeviceBackend._mixed_padded_launch",
             "SingleDeviceBackend._mixed_flat_launch",
             "SingleDeviceBackend._cached_counts", "SingleDeviceBackend.seed_counts",
             "SingleDeviceBackend.reset_counts", "SingleDeviceBackend.apply_cow",

@@ -38,13 +38,9 @@ Usage::
                                                  # one flag flip to compare.
                                                  # (64 is the CPU-smoke sweet
                                                  # spot; 256-512 suits real TPU
-                                                 # runs. Mixed steps default to
-                                                 # the token-flattened layout
-                                                 # off-TPU — cost scales with
-                                                 # tokens actually fed;
-                                                 # --token-flatten 0 forces the
-                                                 # old padded B*chunk launch
-                                                 # for an A/B)
+                                                 # runs. A mixed step's cost
+                                                 # scales with the tokens
+                                                 # actually fed)
     python tools/bench_serve.py --mesh-shape 2,4 # tensor-parallel sharded
                                                  # engine on a dp=2 x tp=4 mesh
                                                  # of virtual CPU devices —
@@ -355,8 +351,6 @@ def run() -> None:
     prefill_chunk = _arg("--prefill-chunk", 0)
     mesh_shape = _parse_mesh_shape()
     disagg = _parse_disagg()
-    token_flatten = (bool(_arg("--token-flatten", 1))
-                     if "--token-flatten" in sys.argv else None)
     if not 0.0 <= prefix_share <= 1.0:
         _fail(f"--prefix-share must be in [0, 1], got {prefix_share}")
     # 24 tokens = 6 full blocks at block_size=4: a warm hit skips all of them
@@ -412,8 +406,6 @@ def run() -> None:
         eng_kw["mesh_shape"] = mesh_shape
     if disagg:
         eng_kw["disagg_stages"] = disagg
-    if token_flatten is not None:
-        eng_kw["token_flatten"] = token_flatten
     # which stream positions carry a long prompt (spread through the run so
     # chatty decodes are always in flight when one lands)
     long_every = max(n_requests // max(n_long, 1), 1)
@@ -574,9 +566,9 @@ def run() -> None:
         # compile the long-prefill path (mixed-step jit / long prefill bucket)
         # outside the measured window: the tail comparison is about steady-state
         # scheduling, not one-time XLA compiles. Short chatty streams ride along
-        # so mixed-step shapes with 1..3 concurrent decode rows (every
-        # token-flattened segment bucket the measured window will see) compile
-        # here too, not inside a measured decode gap
+        # so mixed-step shapes with 1..3 concurrent decode rows (every segment
+        # bucket the measured window will see) compile here too, not inside a
+        # measured decode gap
         riders = [threading.Thread(
             target=one_request, args=(-2 - r, {"ttft": [], "tokens": 0, "gaps_short": []}))
             for r in range(3)]
@@ -1158,10 +1150,6 @@ def run() -> None:
             "long_prompts": n_long_issued,
             "long_prompt_tokens": long_tokens,
             "prefill_chunk": prefill_chunk,
-            # which mixed-step layout ran: flat segments (cost ~ fed tokens)
-            # vs the padded B x chunk launch (--token-flatten 0)
-            "token_flatten": token_flatten if token_flatten is not None
-                             else bool(prefill_chunk),
             # client-observed tails: the chatty requests' inter-token gaps are
             # the decode stalls the chunked prefill bounds
             "client_p99_inter_token_ms": round(gp(0.99) * 1e3, 1),
