@@ -21,6 +21,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 __all__ = ["dot_product_attention", "make_causal_mask", "make_segment_mask"]
 
@@ -157,8 +158,13 @@ def dot_product_attention(
             bias = ab if bias is None else bias + ab
 
     if dropout_rate == 0.0:
-        return jax.nn.dot_product_attention(query, key, value, bias=bias, mask=mask, scale=scale)
-    return _math_attention(query, key, value, mask, scale, dropout_rate, dropout_rng, bias=bias)
+        out = jax.nn.dot_product_attention(query, key, value, bias=bias, mask=mask, scale=scale)
+    else:
+        out = _math_attention(query, key, value, mask, scale, dropout_rate, dropout_rng, bias=bias)
+    # one value, one name: the kernel names its own output where its forward rule
+    # makes it ("flash_out"), so the callers name nothing. A second name on the
+    # same array is a second saved copy a layer under a scanned remat.
+    return checkpoint_name(out, "core_attn")
 
 
 def _pallas_dispatch(query, key, value, segment_ids, scale, window):

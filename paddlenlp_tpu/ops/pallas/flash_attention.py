@@ -40,10 +40,13 @@ has to do a tile's worth of work:
   and live in VMEM as [rows, 128] with every lane alike (``_across``), since
   arithmetic on a [rows, 1] value costs by the row.
 
-Left for later (sizes: PERF.md section 5, ROADMAP S3): the second forward kernel
-that remat runs (``save_qkv_attn`` saves the output but not the logsumexp
-residual); the part of a tile above the diagonal (strips inside a step would
-skip it); two 64-wide heads in one 128-lane tile.
+Under remat the forward rule names the two residuals its backward reads and no
+caller can name (``flash_out``, ``flash_lse``: ``_fwd``); a policy that saves
+both (llama/modeling.py:_remat_policy) runs the forward kernel once a layer.
+
+Left for later (sizes: PERF.md section 5, ROADMAP S3): the part of a tile above
+the diagonal (strips inside a step would skip it); two 64-wide heads in one
+128-lane tile; the softmax of one strip over the matmul of the next.
 
 Off-TPU (tests), the kernels run in Pallas interpret mode.
 """
@@ -56,6 +59,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -401,8 +405,10 @@ def _flash_bwd(q, k, v, segments, out, lse, g, scale, causal, window, block_q, b
     S, K = k.shape[1], k.shape[2]
     group = N // K
     qf, kf, vf, dof = _fold(q), _fold(k), _fold(v), _fold(g)
-    of = _fold(out)
-    delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)[:, None, :]  # [B*N, 1, T]
+    # from ``out`` and ``g`` as they come, the small result folded: ``out`` is read
+    # nowhere else, so a saved ``out`` (remat) is never relaid for the kernels
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [B, T, N]
+    delta = delta.transpose(0, 2, 1).reshape(B * N, 1, T)
     use_seg = segments is not None
     seg_col, seg_row = _segments(segments, B, T)
     common = dict(scale=scale, causal=causal, window=window, q_len=T, kv_len=S, use_segments=use_seg)
@@ -508,6 +514,12 @@ def _fwd(q, k, v, segment_ids, scale, causal, window, block_q, block_kv, interpr
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     out, lse = _flash_fwd(q, k, v, segment_ids, scale_v, causal, window, block_q, block_kv, interpret)
+    # What the backward reads and no caller can name: the residual ``out`` is the
+    # value before the caller's name for the result ("core_attn"), and ``lse``
+    # never leaves the rule. A remat policy that saves both names keeps the
+    # backward from running this kernel again (llama/modeling.py:_remat_policy).
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, segment_ids, out, lse)
 
 

@@ -175,8 +175,7 @@ class DeepseekV2Attention(nn.Module):
             dropout_rate=dropout_rate,
             dropout_rng=dropout_rng,
         )
-        attn_out = checkpoint_name(attn_out, "core_attn")[..., :d_v]
-        attn_out = attn_out.reshape(B, T, n_heads * d_v)
+        attn_out = attn_out[..., :d_v].reshape(B, T, n_heads * d_v)  # named "core_attn" by the call
         out = _dense(cfg.hidden_size, cfg.attention_bias, cfg, self.dtype, self.param_dtype, "o_proj")(attn_out)
         return out, new_kv
 

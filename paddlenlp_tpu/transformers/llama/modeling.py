@@ -256,8 +256,8 @@ class LlamaAttention(nn.Module):
         ):
             from ...ops.ring_attention import ring_self_attention
 
-            attn_out = ring_self_attention(q, k, v, mesh, positions=position_ids)
-        else:
+            attn_out = checkpoint_name(ring_self_attention(q, k, v, mesh, positions=position_ids), "core_attn")
+        else:  # names its result itself: "core_attn", or the kernel's own "flash_out"
             attn_out = dot_product_attention(
                 q,
                 k,
@@ -272,7 +272,6 @@ class LlamaAttention(nn.Module):
                 positions=position_ids if (cp_active and kv is None) else None,
                 use_alibi=use_alibi,
             )
-        attn_out = checkpoint_name(attn_out, "core_attn")
         attn_out = attn_out.reshape(B, T, n_heads * head_dim)
         out_bias = getattr(cfg, "attention_out_bias", cfg.attention_bias)
         out = _dense(cfg.hidden_size, out_bias, cfg, self.dtype, self.param_dtype, "o_proj")(attn_out)
@@ -335,16 +334,15 @@ class LlamaDecoderLayer(nn.Module):
 
 def _remat_policy(granularity: str):
     """Map the reference's recompute_granularity (training_args) onto jax.checkpoint
-    policies via named checkpoints tagged inside the decoder layer
-    ("attn_qkv" post-rope q/k/v, "core_attn" attention output, "mlp_act" the
-    silu(gate)*up product):
+    policies via named checkpoints ("attn_qkv" post-rope q/k/v and "mlp_act" the
+    silu(gate)*up product, tagged inside the decoder layer; the attention core's
+    output, tagged where it is made, see below):
 
     - ``full``          recompute the whole decoder layer (save nothing)
     - ``full_attn``     save everything except attention internals (qkv + core)
     - ``core_attn``     save everything except the attention core (softmax(qk)v)
     - ``save_core_attn``  save ONLY the attention core output (cheap memory;
-                          meant to skip the attention-core recompute in
-                          backward, which on the chip it does not: see below)
+                          the backward skips the attention-core recompute)
     - ``save_qkv_attn``   save only q/k/v + attention core output
     - ``save_attn_mlp``   save q/k/v + attention core + mlp activation
     - ``save_dots``       XLA classic: save all non-batch matmul outputs
@@ -353,32 +351,40 @@ def _remat_policy(granularity: str):
 
     The save_only_* tiers are the 16 GB-HBM middle ground between ``full``
     (recomputes the whole layer) and ``core_attn`` (save-everything-except,
-    which does not fit). Their step-time cost on the chip is not measured.
+    which does not fit).
 
-    Not true on the chip for the Pallas flash path: saving "core_attn" does not
-    skip the attention-core recompute. The kernel's backward also needs its
-    logsumexp residual, which carries no name, so the traced training step runs
-    the forward kernel twice a layer (PERF.md section 5, ``seq2k``: the second
-    ``flash_attention_fwd`` is remat's). ROADMAP S3 holds what is left.
+    "The attention core" has one name a path, given where the value is made: the
+    XLA path's result is "core_attn" (ops/flash_attention.py:dot_product_attention;
+    ring attention's is named so by the layer), the Pallas kernel's forward rule
+    names the two residuals its backward reads, "flash_out" and "flash_lse" (the
+    fp32 logsumexp, 4 B a query head a token: ops/pallas/flash_attention.py:_fwd).
+    Every tier keeps or drops the three together, so a tier that saves the core
+    runs the forward kernel once a layer (tests/transformers/test_remat_policies.py
+    counts it). No caller names the result again: under a scanned remat a second
+    name on one array is a second saved copy a layer. The two "except" tiers keep
+    the kernel's raw outputs whatever they list, since jax's
+    save_anything_except_these_names saves every value that has no name.
     """
+    core = ("core_attn", "flash_out", "flash_lse")
     if granularity == "full":
         return None
     if granularity == "full_attn":
-        return jax.checkpoint_policies.save_anything_except_these_names("attn_qkv", "core_attn")
+        return jax.checkpoint_policies.save_anything_except_these_names("attn_qkv", *core)
     if granularity == "core_attn":
-        return jax.checkpoint_policies.save_anything_except_these_names("core_attn")
+        return jax.checkpoint_policies.save_anything_except_these_names(*core)
     if granularity == "save_core_attn":
-        return jax.checkpoint_policies.save_only_these_names("core_attn")
+        return jax.checkpoint_policies.save_only_these_names(*core)
     if granularity == "save_qkv_attn":
-        return jax.checkpoint_policies.save_only_these_names("attn_qkv", "core_attn")
+        return jax.checkpoint_policies.save_only_these_names("attn_qkv", *core)
     if granularity == "save_attn_mlp":
-        return jax.checkpoint_policies.save_only_these_names("attn_qkv", "core_attn", "mlp_act")
+        return jax.checkpoint_policies.save_only_these_names("attn_qkv", *core, "mlp_act")
     if granularity == "save_dots":
         return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
     if granularity == "offload_attn":
+        # the logsumexp is small and stays on the device
         return jax.checkpoint_policies.save_and_offload_only_these_names(
-            names_which_can_be_saved=[],
-            names_which_can_be_offloaded=["attn_qkv", "core_attn"],
+            names_which_can_be_saved=["flash_lse"],
+            names_which_can_be_offloaded=["attn_qkv", "core_attn", "flash_out"],
             offload_src="device",
             offload_dst="pinned_host",
         )

@@ -261,3 +261,35 @@ def test_tile_spans_against_the_mask(block_q, block_kv, window):
             assert (lo <= ki <= hi) == bool(tile.any()), (qi, ki)
             q_lo, q_hi = (int(x) for x in kernel_file._q_blocks(ki, block_q, block_kv, n_q, True, window))
             assert (q_lo <= qi <= q_hi) == bool(tile.any()), (qi, ki)
+
+
+REMAT_CASES = {  # query heads, kv heads, packed segments
+    "mha": (2, 2, False),
+    "gqa": (4, 2, False),
+    "gqa-segments": (4, 1, True),
+}
+
+
+@pytest.mark.parametrize("case", REMAT_CASES)
+def test_saved_residuals_are_the_recomputed_ones(case):
+    """Under ``jax.checkpoint`` with a policy that saves the two names the
+    forward rule gives (``flash_out``, ``flash_lse``) the backward reads what the
+    forward left; with no policy it runs the forward kernel again. Both give,
+    bit for bit, the gradients of the call without remat, and the jaxpr says
+    which of the two happened."""
+    N, K, segmented = REMAT_CASES[case]
+    q, k, v = qkv(B=1, T=256, N=N, K=K, H=64, seed=7)
+    w = qkv(B=1, T=256, N=N, K=K, H=64, seed=1)[0]
+    seg = packed_segments(1, 256) if segmented else None
+
+    def f(q, k, v):
+        return (flash_attention(q, k, v, seg, None, True, None, 128, 128, True) * w).sum()
+
+    saving = jax.checkpoint_policies.save_only_these_names("flash_out", "flash_lse")
+    grad = lambda fn: jax.grad(fn, argnums=(0, 1, 2))
+    plain = grad(f)(q, k, v)
+    for policy, forward_kernels in ((saving, 1), (None, 2)):
+        remat = grad(jax.checkpoint(f, policy=policy))
+        for name, a, b in zip("qkv", remat(q, k, v), plain):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=f"d{name}")
+        assert str(jax.make_jaxpr(remat)(q, k, v)).count("name=flash_attention_fwd") == forward_kernels

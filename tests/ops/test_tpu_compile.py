@@ -161,6 +161,50 @@ def test_flash_attention_on_a_mesh_compiles(topology, monkeypatch):
     assert "bf16[2,2048,7,64]" in text  # per shard
 
 
+@pytest.mark.parametrize("placement", ["one-chip", "dp2-tp2"])
+def test_remat_step_runs_the_forward_kernel_once_a_layer(topology, monkeypatch, placement):
+    """The training cell's layer (14 / 2 heads of 64, 4 rows of 2048, remat
+    ``save_qkv_attn``, scanned) twice, differentiated: three flash kernels in the
+    program (forward, dq, dk/dv; a scan's body is compiled once for both layers),
+    the backward reading the output and logsumexp the forward left. Four meant
+    remat ran the forward kernel again. On the mesh the kernel sits under
+    shard_map. The unrolled stack: tests/transformers/test_remat_policies.py."""
+    import contextlib
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddlenlp_tpu.parallel import MeshConfig, create_mesh, use_mesh
+    from paddlenlp_tpu.parallel.partition import sharding_tree
+    from paddlenlp_tpu.transformers import Qwen2Config, Qwen2ForCausalLM
+
+    config = Qwen2Config(vocab_size=1024, hidden_size=896, intermediate_size=4864, num_hidden_layers=2,
+                         num_attention_heads=14, num_key_value_heads=2, tie_word_embeddings=True,
+                         recompute=True, recompute_granularity="save_qkv_attn", use_scan_layers=True)
+    model = Qwen2ForCausalLM(config, dtype=jnp.bfloat16, param_dtype=jnp.float32)
+    shapes = model.param_shapes  # shapes only: there is no device to hold arrays
+    if placement == "one-chip":
+        mesh, rows = contextlib.nullcontext(), SingleDeviceSharding(topology.devices[0])
+        placed = jax.tree.map(lambda a: rows, shapes)
+    else:
+        mesh = create_mesh(MeshConfig(dp=2, tp=2), devices=topology.devices)
+        rows = NamedSharding(mesh, P("dp", None))
+        placed = sharding_tree(shapes, model.get_partition_rules(config), mesh)
+        mesh = use_mesh(mesh)
+    params = jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), shapes, placed)
+    ids = jax.ShapeDtypeStruct((4, 2048), jnp.int32, sharding=rows)
+
+    def loss(params, ids):
+        return jnp.mean(model.apply(params, input_ids=ids).logits.astype(jnp.float32) ** 2)
+
+    # the dispatcher and the kernel ask jax.default_backend(); a described chip
+    # does not change that answer, so the test gives it
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tell_vmem(monkeypatch, 128)
+    with mesh:
+        text = compiled_text(jax.grad(loss), params, ids)
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
 def test_flash_attention_compiles_for_16_mib_of_vmem(topology, monkeypatch):
     """A chip of 16 MiB VMEM (v4) gets 512 x 512 tiles and the compiler's default:
     a 1024 x 1024 step of this call needs 17.2 MB there."""
