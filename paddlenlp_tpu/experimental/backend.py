@@ -363,8 +363,12 @@ class SingleDeviceBackend(ModelBackend):
         return jnp.asarray(counts_in)
 
     def seed_counts(self, slot_idx, cached_entries):
-        rows = self._cached_counts(cached_entries, len(slot_idx))
-        self.counts = self.counts.at[jnp.asarray(np.asarray(slot_idx))].set(rows)  # sync-ok: slot_idx is a host int list
+        # one shape whatever the group's size: the index is padded past the last slot and those rows are dropped,
+        # so an admission group of a size no warm-up saw compiles nothing
+        n = self.counts.shape[0]
+        idx = np.full(n, n, np.int32)
+        idx[: len(slot_idx)] = slot_idx
+        self.counts = self.counts.at[jnp.asarray(idx)].set(self._cached_counts(cached_entries, n), mode="drop")
 
     def reset_counts(self):
         self.counts = jnp.zeros_like(self.counts)

@@ -24,6 +24,7 @@ prefill/decode step is a single jit.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional, Tuple
 
 import jax
@@ -33,7 +34,7 @@ import numpy as np
 from ..ops.rope import apply_rotary_pos_emb, rope_frequencies, rope_tables
 from .paged_cache import PagedKVPool, gather_kv, init_paged_pool, write_kv_block
 
-__all__ = ["PagedInferenceModel", "sample_tokens", "layer_kinds", "refuse_unserved", "inference_model_class"]
+__all__ = ["PagedInferenceModel", "LaunchCounts", "sample_tokens", "layer_kinds", "refuse_unserved", "inference_model_class"]
 
 
 def layer_kinds(config):
@@ -50,6 +51,10 @@ def refuse_unserved(config, max_context: int):
     longest sequence the engine's tables can hold: a window at least that long
     is not in use."""
     name = type(config).__name__
+    if getattr(config, "ssm_state_size", None) or getattr(config, "state_size", None):
+        raise ValueError(f"{name}: state-space layers (a recurrent state a sequence, ssm_state_size / state_size) "
+                         "are not computed by the llama layer kind: its pool holds blocks of tokens, and a scan "
+                         "layer's past is in no block (paged_cache.StatePool keeps state rows by slot)")
     other = sorted(set(layer_kinds(config)) - {"llama"})
     if other:
         raise ValueError(f"{name}: layer kinds {other} are not computed by the llama kind's step programs "
@@ -143,6 +148,41 @@ def _rms(x, scale, eps):
     return (x32 * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)).astype(x.dtype)
 
 
+class LaunchCounts:
+    """For a kind whose layers count on the device and whose prompts enter in
+    chunks only (mix in ahead of :class:`PagedInferenceModel`): ``STATS`` names
+    what rides the pool's ``stats`` [n] int32, zeroed as a launch begins, added
+    to by ``_count`` and read back after the launch's own sync as launch-span
+    args and ledger totals (``goodput.KIND_COUNTERS``)."""
+
+    STATS = ()
+
+    def launch_counts(self, pool) -> dict:
+        """What the layers of the launch just synced counted on the device."""
+        return dict(zip(self.STATS, (int(x) for x in np.asarray(pool.stats))))  # sync-ok: a few ints, after the launch's own sync
+
+    def _count(self, pool, **counts):
+        add = jnp.stack([jnp.asarray(counts.get(name, 0), jnp.int32) for name in self.STATS])
+        return dataclasses.replace(pool, stats=pool.stats + add)
+
+    def _prefill_impl(self, *args, **kwargs):
+        raise NotImplementedError(f"{type(self).__name__} prefills in chunks only (prefill_chunk_tokens)")
+
+    def _verify_impl(self, *args, **kwargs):
+        raise NotImplementedError(f"{type(self).__name__} has no speculative verify program")
+
+    def _mixed_flat_impl(self, params, pool, *args, **kwargs):
+        return super()._mixed_flat_impl(params, dataclasses.replace(pool, stats=jnp.zeros_like(pool.stats)),
+                                        *args, **kwargs)
+
+    def _decode_impl(self, params, pool, *args, **kwargs):
+        return super()._decode_impl(params, dataclasses.replace(pool, stats=jnp.zeros_like(pool.stats)),
+                                    *args, **kwargs)
+
+    def _decode_q_lens(self, done):
+        return (~done).astype(jnp.int32)
+
+
 class PagedInferenceModel:
     """Holds jitted prefill/decode over (params, pool): the ``llama`` layer kind
     (llama/qwen2/mistral: config-driven biases + GQA + rope), every layer alike."""
@@ -190,10 +230,23 @@ class PagedInferenceModel:
 
     def _setup_kind(self, use_paged_kernel):
         """What the llama kind needs beside the common fields; first its door."""
-        model, block_size = self.model, self.block_size
-        refuse_unserved(self.config, block_size * self.max_blocks_per_seq)
+        model = self.model
+        refuse_unserved(self.config, self.block_size * self.max_blocks_per_seq)
         if "layers" not in model.params.get("model", {}):
             raise ValueError("PagedInferenceModel requires the scanned-layer param layout (use_scan_layers)")
+        self._setup_attention(use_paged_kernel)
+        # serving a QuantizedModel: its params carry qweight/scales leaves
+        # (stacked [L, ...] — lax.scan slices per layer); _mm dispatches per
+        # projection (reference int8_gemm_with_cutlass serving path)
+        self.quant_cfg = getattr(model, "quantization_config", None)
+
+    def _setup_attention(self, use_paged_kernel):
+        """What ``_attention`` reads: the head counts, the kernel switch and
+        whether q and k are rotated. A configuration's class says where its
+        attention carries no position embedding (``rotary_attention = False``);
+        the llama kind's do."""
+        cfg = self.config
+        self.n_heads, self.n_kv, self.head_dim = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
         # Pallas ragged paged kernel: default-on for TPU when the tile shapes
         # are Mosaic-safe (one head's (block_size, head_dim) tile is cut out
         # of the pool's n_kv * head_dim lane rows, so head_dim must fill whole
@@ -201,25 +254,18 @@ class PagedInferenceModel:
         # that comes out off is said once, so it is never a silent choice.
         if use_paged_kernel is None:
             on_tpu = jax.default_backend() == "tpu"
-            use_paged_kernel = (
-                on_tpu and self.config.head_dim % 128 == 0 and block_size % 8 == 0)
+            use_paged_kernel = on_tpu and self.head_dim % 128 == 0 and self.block_size % 8 == 0
             if on_tpu and not use_paged_kernel:
                 from ..utils.log import logger
 
                 logger.warning_once(
-                    f"paged attention kernel off for {type(model).__name__} "
-                    f"(head_dim={self.config.head_dim}, block_size={block_size}): needs "
+                    f"paged attention kernel off for {type(self.model).__name__} "
+                    f"(head_dim={self.head_dim}, block_size={self.block_size}): needs "
                     "head_dim % 128 == 0 and block_size % 8 == 0; using the XLA gather path")
         self.use_paged_kernel = use_paged_kernel
-        cfg = self.config
-        self.n_heads = cfg.num_attention_heads
-        self.n_kv = cfg.num_key_value_heads
-        self.head_dim = cfg.head_dim
-        self.inv_freq = jnp.asarray(rope_frequencies(self.head_dim, cfg.rope_theta, cfg.rope_scaling))
-        # serving a QuantizedModel: its params carry qweight/scales leaves
-        # (stacked [L, ...] — lax.scan slices per layer); _mm dispatches per
-        # projection (reference int8_gemm_with_cutlass serving path)
-        self.quant_cfg = getattr(model, "quantization_config", None)
+        self.rotary = getattr(cfg, "rotary_attention", True)
+        if self.rotary:
+            self.inv_freq = jnp.asarray(rope_frequencies(self.head_dim, cfg.rope_theta, cfg.rope_scaling))
 
     def _build_jits(self):
         """Compile the step entry points. The sharded subclass overrides this
@@ -307,23 +353,18 @@ class PagedInferenceModel:
         return ragged_paged_attention(q, kv, block_tables, q_start=q_start, q_lens=q_lens,
                                       layer=layer, kv_scale=kv_scale)
 
-    def _layer(self, carry, scanned, block_tables, q_positions, kv_len_mask, write_pos,
-               q_lens, adapter_idx):
-        """One decoder layer inside lax.scan: carry = (h, whole pool), written
-        and read in place at this layer's index; scanned = (layer_params,
-        lora_layer-or-None for multi-LoRA batches, layer index)."""
-        h, pool = carry
-        lp, lora_layer, layer = scanned
-        cfg = self.config
-        B, T, D = h.shape
+    def _attention(self, x, pool: PagedKVPool, attn, lora_layer, adapter_idx, block_tables, q_positions,
+                   kv_len_mask, write_pos, q_lens, layer):
+        """The attention mixer on the normed input ``x`` [B, T, D], through layer
+        ``layer`` of the per-head pool: projections, rotary embedding where the
+        configuration has one, the fed tokens' K and V written at their
+        positions, the ragged paged kernel (or the XLA gather), the output
+        projection. Returns (what the residual adds, the pool)."""
+        B, T, _ = x.shape
 
         # jax.named_scope is metadata only: each operation's op_name carries
         # the scope, which is how a device profile names what a fusion is for
         # (the serving programs get no scope from a module system)
-        with jax.named_scope("attn_norm"):
-            x = _rms(h, lp["input_layernorm"]["scale"], self.eps)
-        attn = lp["self_attn"]
-
         def proj(p, x, heads, name):
             return self._lora_mm(p, x, lora_layer, adapter_idx, name) \
                 .reshape(B, T, heads, self.head_dim)
@@ -332,9 +373,10 @@ class PagedInferenceModel:
             q = self._hint(proj(attn["q_proj"], x, self.n_heads, "q_proj"), "heads")
             k = self._hint(proj(attn["k_proj"], x, self.n_kv, "k_proj"), "kv_heads")
             v = self._hint(proj(attn["v_proj"], x, self.n_kv, "v_proj"), "kv_heads")
-        with jax.named_scope("rope"):
-            cos, sin = rope_tables(q_positions, self.inv_freq)
-            q, k = apply_rotary_pos_emb(q, k, cos, sin)
+        if self.rotary:
+            with jax.named_scope("rope"):
+                cos, sin = rope_tables(q_positions, self.inv_freq)
+                q, k = apply_rotary_pos_emb(q, k, cos, sin)
 
         with jax.named_scope("kv_write"):
             pool = write_kv_block(pool, k, v, block_tables, write_pos, layer)
@@ -352,9 +394,24 @@ class PagedInferenceModel:
             # dot per output column, no cross-shard partial sums), gather after
             # so the residual/norms see a replicated stream
             attn_out = self._hint(attn_out, "full")
-            h = h + self._hint(
+            return self._hint(
                 self._lora_mm(attn["o_proj"], attn_out, lora_layer, adapter_idx, "o_proj"),
-                "full")
+                "full"), pool
+
+    def _layer(self, carry, scanned, block_tables, q_positions, kv_len_mask, write_pos,
+               q_lens, adapter_idx):
+        """One decoder layer inside lax.scan: carry = (h, whole pool), written
+        and read in place at this layer's index; scanned = (layer_params,
+        lora_layer-or-None for multi-LoRA batches, layer index)."""
+        h, pool = carry
+        lp, lora_layer, layer = scanned
+
+        with jax.named_scope("attn_norm"):
+            x = _rms(h, lp["input_layernorm"]["scale"], self.eps)
+        attn_out, pool = self._attention(x, pool, lp["self_attn"], lora_layer, adapter_idx, block_tables,
+                                         q_positions, kv_len_mask, write_pos, q_lens, layer)
+        with jax.named_scope("o_proj"):
+            h = h + attn_out
 
         with jax.named_scope("mlp_norm"):
             x = _rms(h, lp["post_attention_layernorm"]["scale"], self.eps)
@@ -372,8 +429,12 @@ class PagedInferenceModel:
 
     def _forward(self, params, pool: PagedKVPool, input_ids, block_tables, q_positions,
                  kv_len_mask, write_pos, last_pos, q_lens=None, lora=None,
-                 adapter_idx=None):
+                 adapter_idx=None, slots=None):
         """input_ids [B,T]; returns (logits at last_pos [B,V], new PagedKVPool).
+
+        ``slots`` [B] is the engine slot of each row, for a kind that keeps
+        something by slot (``StatePool``); None where the rows are the slots in
+        order (the decode program). The llama kind keeps nothing by slot.
 
         ``last_pos=None`` returns full-sequence logits [B,T,V] (the speculative
         verify step needs the model's prediction after EVERY draft position).
@@ -400,7 +461,7 @@ class PagedInferenceModel:
                 h = h * jnp.asarray(self.config.hidden_size**0.5, h.dtype)
 
         h, new_pool = self._run_layers(m, h, pool, block_tables, q_positions, kv_len_mask,
-                                       write_pos, q_lens, lora, adapter_idx)
+                                       write_pos, q_lens, lora, adapter_idx, slots)
         with jax.named_scope("final_norm"):
             h = _rms(h, m["norm"]["scale"], self.eps)
         with jax.named_scope("lm_head"):
@@ -418,7 +479,7 @@ class PagedInferenceModel:
         return logits, new_pool
 
     def _run_layers(self, m, h, pool, block_tables, q_positions, kv_len_mask, write_pos,
-                    q_lens, lora, adapter_idx):
+                    q_lens, lora, adapter_idx, slots=None):
         """Every layer of the stack, by kind. Here all are ``llama``: one scan."""
         def body(carry, scanned):
             return self._layer(carry, scanned, block_tables, q_positions, kv_len_mask,
@@ -511,7 +572,7 @@ class PagedInferenceModel:
         logits_c, pool = self._forward(
             params, pool, chunk_ids, chunk_tables, positions_c, kv_mask_c,
             chunk_start, jnp.maximum(chunk_qlens - 1, 0), q_lens=chunk_qlens,
-            lora=lora, adapter_idx=chunk_adapter,
+            lora=lora, adapter_idx=chunk_adapter, slots=chunk_slots,
         )
         D = dec_tokens.shape[0]
         positions_d = dec_start[:, None]
@@ -519,7 +580,7 @@ class PagedInferenceModel:
         logits_d, pool = self._forward(
             params, pool, dec_tokens[:, None], dec_tables, positions_d, kv_mask_d,
             dec_start, jnp.zeros((D,), jnp.int32), q_lens=dec_live.astype(jnp.int32),
-            lora=lora, adapter_idx=dec_adapter,
+            lora=lora, adapter_idx=dec_adapter, slots=dec_slots,
         )
         V = counts.shape[-1]
         with jax.named_scope("bookkeeping"):

@@ -38,12 +38,11 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..transformers import latent_layers as M
 from ..transformers.latent_layers import LATENT_FULL as FULL
 from ..transformers.latent_layers import LATENT_WINDOW as WINDOW
-from .inference_model import PagedInferenceModel, _rms, layer_kinds
+from .inference_model import LaunchCounts, PagedInferenceModel, _rms, layer_kinds
 from .paged_cache import LatentKVPool, init_latent_pool, write_rows
 
 __all__ = ["LatentInferenceModel"]
@@ -52,9 +51,10 @@ KEY_TILE = 512  # cached positions a chunk's attention takes in at a time
 NEG = M.NEG
 
 
-class LatentInferenceModel(PagedInferenceModel):
-    #: what a launch's layers count on the device, in ``pool.stats``'s order: launch-span args and
-    #: ledger totals (``goodput.KIND_COUNTERS``)
+class LatentInferenceModel(LaunchCounts, PagedInferenceModel):
+    #: what a launch's layers count on the device, in ``pool.stats``'s order (``LaunchCounts``): routed choices of
+    #: live tokens that landed on held experts and all of them, the busiest held expert's tokens summed over expert
+    #: layers and sub-steps, positions the indexer scored and kept for live queries over full layers
     STATS = ("expert_assignments_local", "expert_assignments", "expert_tokens_max",
              "index_candidates", "index_selected")
 
@@ -108,37 +108,9 @@ class LatentInferenceModel(PagedInferenceModel):
                                 (self.window_spec or {}).get("num_window_blocks", 1), block_size, widths,
                                 len(self.STATS), dtype)
 
-    def launch_counts(self, pool: LatentKVPool) -> dict:
-        """What the layers of the launch just synced counted on the device
-        (``STATS``): routed choices of live tokens that landed on held experts
-        and all of them, the busiest held expert's tokens summed over expert
-        layers and sub-steps, positions the indexer scored and kept for live
-        queries over full layers."""
-        return dict(zip(self.STATS, (int(x) for x in np.asarray(pool.stats))))  # sync-ok: 5 ints, after the launch's own sync
-
-    def _count(self, pool: LatentKVPool, **counts) -> LatentKVPool:
-        add = jnp.stack([jnp.asarray(counts.get(name, 0), jnp.int32) for name in self.STATS])
-        return dataclasses.replace(pool, stats=pool.stats + add)
-
-    # ------------------------------------------------------------------ entry points: the llama kind's
-    def _prefill_impl(self, *args, **kwargs):
-        raise NotImplementedError("the latent layer kinds prefill in chunks only (prefill_chunk_tokens)")
-
-    def _verify_impl(self, *args, **kwargs):
-        raise NotImplementedError("the latent layer kinds have no speculative verify program")
-
-    def _mixed_flat_impl(self, params, pool, *args, **kwargs):
-        return super()._mixed_flat_impl(params, _zero_stats(pool), *args, **kwargs)
-
-    def _decode_impl(self, params, pool, *args, **kwargs):
-        return super()._decode_impl(params, _zero_stats(pool), *args, **kwargs)
-
-    def _decode_q_lens(self, done):
-        return (~done).astype(jnp.int32)
-
     # ------------------------------------------------------------------ the stack
     def _run_layers(self, m, h, pool, block_tables, q_positions, kv_len_mask, write_pos,
-                    q_lens, lora, adapter_idx):
+                    q_lens, lora, adapter_idx, slots=None):
         """``block_tables`` [B, 2, M]: a row's block table and its window table
         ([B, M] where no layer keeps a window: the block table alone).
         ``kv_len_mask`` and ``write_pos`` are the llama kind's and unused: the
@@ -331,7 +303,3 @@ def _absorbed(attn, rows, allowed, q_nope, q_pe, d):
     p = jax.nn.softmax(sc, axis=-1)
     o_lat = jnp.einsum("bhts,bsc->bthc", p.astype(dtype), c_kv)
     return jnp.einsum("bthc,chv->bthv", o_lat, w_v.astype(dtype))
-
-
-def _zero_stats(pool: LatentKVPool) -> LatentKVPool:
-    return dataclasses.replace(pool, stats=jnp.zeros_like(pool.stats))

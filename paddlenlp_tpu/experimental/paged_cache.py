@@ -17,7 +17,11 @@ preempt + recover). TPU-native split:
 - beside it, for layer kinds that keep something else (``latent_model.py``):
   ``LatentKVPool``, planes of one latent row a token for full-attention
   layers, their indexer keys, and window layers' rows under a second table
-  that holds the window only;
+  that holds the window only; and ``StatePool`` (``state_model.py``): the
+  per-head planes of the attention layers, addressed by block table as
+  above, beside **state rows addressed by slot** for the scan layers, whose
+  whole past is one recurrent state and a few convolution inputs a sequence,
+  in no block of tokens;
 - host side: ``BlockManager`` does the step.cu bookkeeping (free list, per-seq
   tables, allocate/extend/free, preemption candidates) in plain Python — the
   allocator runs between device steps, so there is no launch-latency reason to
@@ -36,8 +40,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["PagedKVPool", "LatentKVPool", "BlockManager", "init_paged_pool", "init_latent_pool",
-           "write_kv_block", "write_rows", "gather_kv", "copy_blocks"]
+__all__ = ["PagedKVPool", "LatentKVPool", "StatePool", "BlockManager", "init_paged_pool", "init_latent_pool",
+           "init_state_pool", "write_kv_block", "write_rows", "read_state_rows", "write_state_rows", "gather_kv",
+           "copy_blocks"]
 
 @dataclasses.dataclass
 class PagedKVPool:
@@ -125,6 +130,77 @@ def write_rows(plane: jnp.ndarray, rows: jnp.ndarray, table: jnp.ndarray, positi
     slot = jnp.minimum(positions // bs, table.shape[1] - 1)
     blocks = jnp.where(valid, jnp.take_along_axis(table, slot, axis=1), 0)
     return plane.at[layer, blocks, positions % bs].set(rows.astype(plane.dtype))
+
+
+@dataclasses.dataclass
+class StatePool:
+    """Paged per-head K and V for the attention layers beside recurrent state
+    rows for the scan layers, donated and carried whole like :class:`PagedKVPool`:
+
+    - ``kv``    [attention layers, 2, blocks, block_size, n_kv * head_dim]:
+      :class:`PagedKVPool`'s layout, written by ``write_kv_block`` and read by
+      the ragged paged kernel, addressed by a sequence's block table;
+    - ``ssm``   [scan layers, slots + 1, groups, heads a group, head_dim,
+      state] **float32**: a sequence's recurrent state, one row an engine slot;
+    - ``conv``  [scan layers, slots + 1, conv_kernel - 1, conv_dim]: the last
+      inputs of its causal convolution.
+
+    A row belongs to the slot, not to a block: whoever feeds a slot's position
+    0 starts from zeros (an admission, a re-prefill after preemption), so a
+    slot is never cleared on the host and a preempted sequence rebuilds its
+    state by recompute, as its KV. Row ``slots`` is the sentinel that rows of
+    a launch that feed nothing write to. ``stats`` int32 [n] rides along:
+    what the last launch's layers counted on the device."""
+
+    kv: jnp.ndarray
+    ssm: jnp.ndarray
+    conv: jnp.ndarray
+    stats: jnp.ndarray
+    scale = None  # no quantized form
+
+    @property
+    def num_blocks(self) -> int:
+        return self.kv.shape[2]
+
+    @property
+    def block_size(self) -> int:
+        return self.kv.shape[3]
+
+    @property
+    def quantized(self) -> bool:
+        return False
+
+
+jax.tree_util.register_dataclass(StatePool, data_fields=["kv", "ssm", "conv", "stats"], meta_fields=[])
+
+
+def init_state_pool(n_attention: int, num_blocks: int, block_size: int, kv_width: int, n_scan: int, slots: int,
+                    state_shape: Tuple[int, ...], conv_shape: Tuple[int, ...], n_stats: int,
+                    dtype=jnp.bfloat16) -> StatePool:
+    return StatePool(kv=jnp.zeros((max(n_attention, 1), 2, num_blocks, block_size, kv_width), dtype),
+                     ssm=jnp.zeros((max(n_scan, 1), slots + 1) + tuple(state_shape), jnp.float32),
+                     conv=jnp.zeros((max(n_scan, 1), slots + 1) + tuple(conv_shape), dtype),
+                     stats=jnp.zeros((n_stats,), jnp.int32))
+
+
+def read_state_rows(plane: jnp.ndarray, layer: int, slots: Optional[jnp.ndarray], n: int) -> jnp.ndarray:
+    """The rows of ``plane[layer]`` a launch's ``n`` rows own: those of
+    ``slots`` [n], or rows ``0 .. n - 1`` where the launch's rows are the
+    slots in order (``slots`` None: a static slice, nothing gathered)."""
+    return plane[layer, :n] if slots is None else plane[layer, slots]
+
+
+def write_state_rows(plane: jnp.ndarray, layer: int, slots: Optional[jnp.ndarray], old: jnp.ndarray,
+                     new: jnp.ndarray, live: jnp.ndarray) -> jnp.ndarray:
+    """Put ``new`` [n, ...] back where ``read_state_rows`` took ``old`` from, in
+    place on the donated plane. A row that is not ``live`` [n] keeps what it
+    held: in slot order it writes ``old`` back, under ``slots`` it lands in the
+    sentinel row (padding rows all name slot 0, which may be someone's)."""
+    new = new.astype(plane.dtype)
+    if slots is None:
+        keep = live.reshape((-1,) + (1,) * (new.ndim - 1))
+        return plane.at[layer, : new.shape[0]].set(jnp.where(keep, new, old))
+    return plane.at[layer, jnp.where(live, slots, plane.shape[1] - 1)].set(new)
 
 _QMAX = {"int8": 127.0, "fp8": 448.0}  # float8_e4m3 max normal
 

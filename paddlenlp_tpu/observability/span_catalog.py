@@ -74,9 +74,11 @@ SPAN_CATALOG = {
 
 #: ``jax.named_scope`` names the serving step programs put on their operations
 #: (a device profile shows them in each operation's ``tf_op``): the llama kind's
-#: (``experimental/inference_model.py``) and the latent kinds'
-#: (``experimental/latent_model.py``, ``transformers/latent_layers.py``).
-#: ``bench/harness/program_spans.py`` and ``bench/harness/latent_scopes.py`` read them.
+#: (``experimental/inference_model.py``), the latent kinds'
+#: (``experimental/latent_model.py``, ``transformers/latent_layers.py``) and the
+#: state-space kinds' (``experimental/state_model.py``, ``transformers/state_layers.py``).
+#: ``bench/harness/program_spans.py``, ``bench/harness/latent_scopes.py`` and
+#: ``bench/harness/state_scopes.py`` read them.
 DEVICE_SCOPES = {
     "embed": "token embedding lookup", "attn_norm": "the layer's input RMS norm",
     "qkv": "llama kind: q/k/v projections", "rope": "llama kind: rotary embedding of q and k",
@@ -94,6 +96,11 @@ DEVICE_SCOPES = {
     "router": "expert layer: float32 sigmoid scores, top-k of score + bias, per-expert counts",
     "experts": "expert layer: the held experts' tiles (rows ranked by expert, one tile of one expert a loop turn)",
     "shared_expert": "expert layer: the shared expert on every token",
+    "ssm_proj": "scan layer: the in-projection (z | xBC | dt) and the out-projection",
+    "ssm_conv": "scan layer: causal depthwise convolution over xBC from the row's cached inputs, bias, SiLU",
+    "ssm_scan": "scan layer: the recurrence, chunk (SSD) form for a prompt chunk and one-step form for one token, and the D term",
+    "ssm_gate_norm": "scan layer: gate by SiLU(z), then RMS norm in groups",
+    "state_rw": "scan layer: read of the slots' state rows (zeros for a row at position 0) and the write back",
 }
 
 #: args a launch span (``prefill`` / ``decode`` / ``mixed_step`` / ``spec_verify``) carries once the
@@ -107,4 +114,7 @@ LAUNCH_ARGS = {
     "expert_assignments_local": "routed choices of live tokens that landed on experts held here (device count)",
     "expert_assignments": "all routed choices of live tokens: tokens x experts a token x expert layers",
     "expert_tokens_max": "the busiest held expert's tokens, summed over expert layers and decode sub-steps",
+    "state_rows": "rows whose recurrent state the scan layers read and wrote: rows x decode sub-steps, dead ones too (device count)",
+    "state_rows_live": "those of them that fed a token (device count)",
+    "state_resets": "those that fed a sequence's position 0 and so started from zeros: admissions and re-prefills (device count)",
 }

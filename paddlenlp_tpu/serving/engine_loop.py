@@ -546,6 +546,13 @@ class ServingMetrics:
             "Cached positions the sparse indexer scored (kind=candidates) and "
             "kept for attention (kind=selected), over full-attention layers",
             labelnames=("kind",))
+        self.state_rows = r.counter(
+            "paddlenlp_serving_state_rows_total",
+            "Rows whose recurrent state the scan layers read and wrote "
+            "(kind=computed: rows x decode sub-steps, dead ones too; "
+            "kind=live: those that fed a token; kind=reset: those that "
+            "started from zeros at a sequence's first token)",
+            labelnames=("kind",))
         self.wasted_tokens = r.counter(
             "paddlenlp_serving_wasted_tokens_total",
             "Non-useful fed positions by waste kind (padding = bucket pads + "
@@ -761,7 +768,10 @@ class ServingMetrics:
                     ("expert_assignments_local", self.expert_assignments, {"held": "local"}),
                     ("expert_assignments", self.expert_assignments, {"held": "all"}),
                     ("index_candidates", self.index_positions, {"kind": "candidates"}),
-                    ("index_selected", self.index_positions, {"kind": "selected"})):
+                    ("index_selected", self.index_positions, {"kind": "selected"}),
+                    ("state_rows", self.state_rows, {"kind": "computed"}),
+                    ("state_rows_live", self.state_rows, {"kind": "live"}),
+                    ("state_resets", self.state_rows, {"kind": "reset"})):
                 delta = totals.get(key, 0) - self._gp_last.get(key, 0)
                 if delta > 0:
                     counter.inc(delta, **label)

@@ -49,7 +49,11 @@ from .llama import (  # noqa: F401
     LlamaModel,
     LlamaPretrainingCriterion,
 )
+# state-space families: nemotron_h (Mamba-2 hybrid) is the one the serving engine computes (recurrent state rows
+# beside the paged KV pool, experimental/state_model.py); mamba and jamba (Mamba-1) are whole-sequence only, with
+# cache classes of their own for model.generate()
 from .mamba import MambaConfig, MambaForCausalLM, MambaModel  # noqa: F401
+from .nemotron_h import NemotronHConfig, NemotronHForCausalLM, NemotronHModel  # noqa: F401
 from .roberta import (  # noqa: F401
     RobertaConfig,
     RobertaForMaskedLM,

@@ -39,6 +39,7 @@ def _populate():
     from ..deepseek_v2.configuration import DeepseekV2Config
     from ..dots3_note.configuration import Dots3NoteConfig
     from ..mamba.configuration import MambaConfig
+    from ..nemotron_h.configuration import NemotronHConfig
     from ..rw.configuration import RWConfig
     from ..chatglm.configuration import ChatGLMConfig
     from ..yuan.configuration import YuanConfig
@@ -71,6 +72,7 @@ def _populate():
     for cfg in (LlamaConfig, GPTConfig, Qwen2Config, MistralConfig, GemmaConfig, BertConfig,
                 ErnieConfig, MixtralConfig, Qwen2MoeConfig, BaichuanConfig, BloomConfig,
                 OPTConfig, QWenConfig, ChatGLMv2Config, T5Config, BartConfig, DeepseekV2Config, Dots3NoteConfig,
+                NemotronHConfig,
                 MambaConfig, RWConfig, ChatGLMConfig, YuanConfig, JambaConfig,
                 AlbertConfig, ElectraConfig, RobertaConfig,
                 MT5Config, MBartConfig, PegasusConfig,

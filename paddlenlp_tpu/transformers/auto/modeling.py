@@ -104,6 +104,12 @@ def _populate_models():
 
     register_model("dots3_note", "base", dots3_note.Dots3NoteModel)
     register_model("dots3_note", "causal_lm", dots3_note.Dots3NoteForCausalLM)
+    # state-space families: nemotron_h is served by the engine (its configuration names the step programs,
+    # experimental/state_model.py); mamba and jamba are whole-sequence only (model.generate with their own caches)
+    from ..nemotron_h import modeling as nemotron_h
+
+    register_model("nemotron_h", "base", nemotron_h.NemotronHModel)
+    register_model("nemotron_h", "causal_lm", nemotron_h.NemotronHForCausalLM)
     from ..mamba import modeling as mamba
 
     register_model("mamba", "base", mamba.MambaModel)

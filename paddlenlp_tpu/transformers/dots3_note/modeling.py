@@ -20,6 +20,7 @@ from flax import linen as nn
 from ...parallel.partition import P
 from ..conversion_utils import StackedLayerMapping, auto_name_mappings
 from ..model_utils import PretrainedModel
+from ..param_tree import ParamTree
 from ..latent_layers import LATENT_FULL, attention_dense, mlp, rms_norm
 from .configuration import Dots3NoteConfig
 
@@ -106,25 +107,11 @@ def decoder_forward(cfg, params, input_ids, positions=None, dtype=jnp.float32):
 
 
 # ------------------------------------------------------------------ flax modules
-class _Params(nn.Module):
-    """Declares a nested tree of parameters from its shapes and returns it."""
-
-    shapes: Dict
-    std: float
-    param_dtype: jnp.dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self):
-        out = {}
-        for name, shape in self.shapes.items():
-            if isinstance(shape, dict) or hasattr(shape, "items"):
-                out[name] = _Params(dict(shape), self.std, self.param_dtype, name=name)()
-            elif name in FLOAT32_LEAVES:
-                init = nn.initializers.ones if name == "scale" else nn.initializers.zeros
-                out[name] = self.param(name, init, tuple(shape), jnp.float32)
-            else:
-                out[name] = self.param(name, nn.initializers.normal(self.std), tuple(shape), self.param_dtype)
-        return out
+def _float32_init(name):
+    """Norm scales start at 1, biases and the router's selection bias at 0; None: not a float32 leaf."""
+    if name not in FLOAT32_LEAVES:
+        return None
+    return nn.initializers.ones if name == "scale" else nn.initializers.zeros
 
 
 class Dots3NoteModule(nn.Module):
@@ -137,7 +124,8 @@ class Dots3NoteModule(nn.Module):
     def __call__(self, input_ids, position_ids=None, deterministic: bool = True):
         cfg = self.config
         shapes = param_tree_shapes(cfg, self.causal_lm)
-        params = {k: _Params(v, cfg.initializer_range, self.param_dtype, name=k)() for k, v in shapes.items()}
+        params = {k: ParamTree(v, cfg.initializer_range, _float32_init, self.param_dtype, name=k)()
+                  for k, v in shapes.items()}
         h = decoder_forward(cfg, params, input_ids, position_ids, self.dtype)
         if not self.causal_lm:
             return h

@@ -20,6 +20,10 @@ Counterpart of ``paddlenlp/transformers/jamba/modeling.py``
 
 Layer heterogeneity rules out lax.scan over layers; the stack is unrolled
 (``use_scan_layers`` raises).
+
+Whole-sequence only: ``JambaCache`` serves ``model.generate()`` and lives
+outside the serving engine, whose door refuses this family (Mamba-1 mixers).
+The hybrid the engine serves is ``nemotron_h`` (``experimental/state_model.py``).
 """
 
 from __future__ import annotations

@@ -11,7 +11,10 @@ Two kinds of layer, named as a configuration's ``layer_kinds()`` yields them:
 - ``latent_window``  latent attention with sizes of its own over a window.
 
 Either kind's MLP is dense SwiGLU or sigmoid-routed experts of which this
-process holds a share. The functions:
+process holds a share. The expert layer (``route``, ``experts_held``, ``moe``) is
+every layer kind's that routes so: the caller says what one expert computes
+(``ExpertBody``: ``SWIGLU``, three matrices and SiLU, or ``RELU2``, two and a
+squared ReLU), the routing and the held share are the same. The functions:
 
 - ``mla_project``     the q and kv chains of one attention kind: the normed,
                       rescaled q latent, per-head q (nope | roped pe) and the
@@ -25,16 +28,19 @@ process holds a share. The functions:
 - ``experts_held``    the held experts' part of the result: assignments are
                       sorted by expert into tile-aligned groups and a loop of
                       data-dependent length runs one tile of one expert at a
-                      time, so no token is dropped and an expert nobody chose
-                      is never read.
+                      time (the caller's ``ExpertBody``), so no token is
+                      dropped and an expert nobody chose is never read.
 
 What they read of a configuration ``cfg``: ``attention_dims(kind)`` (heads,
 q_lora, kv_lora, nope, rope, v, theta, window, s_q, s_kv), ``index_n_heads``,
 ``index_head_dim``, ``index_topk``, ``qk_rope_head_dim``, ``rms_norm_eps``,
 ``num_experts_per_tok``, ``routed_scaling_factor``, ``experts_held`` (first,
-count) and ``first_k_dense_replace``."""
+count) and ``first_k_dense_replace``; the expert layer (``moe``) reads only
+``num_experts_per_tok``, ``routed_scaling_factor`` and ``experts_held``."""
 
 from __future__ import annotations
+
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -75,6 +81,33 @@ def _mm(p, x):
 
 def swiglu(p, x):
     return _mm(p["down_proj"], jax.nn.silu(_mm(p["gate_proj"], x)) * _mm(p["up_proj"], x))
+
+
+def relu2(p, x):
+    return _mm(p["down_proj"], jnp.square(jax.nn.relu(_mm(p["up_proj"], x))))
+
+
+def _swiglu_tile(p, e, xs):
+    act = jax.nn.silu(xs @ p["gate_proj"][e].astype(xs.dtype)) * (xs @ p["up_proj"][e].astype(xs.dtype))
+    return act @ p["down_proj"][e].astype(xs.dtype)
+
+
+def _relu2_tile(p, e, xs):
+    # up_proj is stacked [count, width, hidden] (the checkpoint's own out x in): hidden is a whole number of
+    # 128-lane tiles where the published width (1856) is not, so neither stack has a padded minor axis
+    up = jnp.einsum("th,wh->tw", xs, p["up_proj"][e].astype(xs.dtype))
+    return jnp.square(jax.nn.relu(up)) @ p["down_proj"][e].astype(xs.dtype)
+
+
+class ExpertBody(NamedTuple):
+    """What one expert computes, in the two forms the expert layer needs."""
+
+    tile: Callable  #: (stacked held experts [count, ...], expert number, rows [tile, hidden]) -> rows
+    dense: Callable  #: (one expert's ``{proj: {"kernel"}}``, x [..., hidden]) -> [..., hidden]: the shared expert
+
+
+SWIGLU = ExpertBody(_swiglu_tile, swiglu)  # silu(x W_gate) * (x W_up) W_down
+RELU2 = ExpertBody(_relu2_tile, relu2)  # relu(x W_up)^2 W_down, no gate matrix; held up_proj [count, width, hidden]
 
 
 def mla_project(p, x, positions, d, eps):
@@ -162,10 +195,11 @@ def held_counts(idx, first, count):
     return jnp.sum(jax.nn.one_hot(jnp.where(ok, local, count), count + 1, dtype=jnp.int32), axis=(0, 1))[:count]
 
 
-def experts_held(p, x2d, idx, w, first, count, live=None):
+def experts_held(p, x2d, idx, w, first, count, live=None, body: ExpertBody = SWIGLU):
     """The held experts' part of ``sum_k w_k E_k(x)``: x2d [N, hidden], idx/w
     [N, k] as ``route`` gives them, experts ``first .. first + count - 1`` held
-    in ``p`` ([count, ...] stacked). ``live`` [N] bool leaves rows out (padding).
+    in ``p`` ([count, ...] stacked), each computing ``body.tile``. ``live`` [N]
+    bool leaves rows out (padding).
 
     No capacity and no drop: the N*k assignments are ranked within their expert
     and laid into a buffer where each expert's group starts on a tile boundary;
@@ -190,13 +224,11 @@ def experts_held(p, x2d, idx, w, first, count, live=None):
     token_of_row = jnp.full((rows,), n, jnp.int32).at[dest].set(
         jnp.repeat(jnp.arange(n, dtype=jnp.int32), k), mode="drop")
     x_rows = jnp.concatenate([x2d, jnp.zeros((1, hidden), x2d.dtype)], 0)[token_of_row]
-    w_gate, w_up, w_down = p["gate_proj"], p["up_proj"], p["down_proj"]
 
     def one_tile(i, y_rows):
         e = jnp.sum(tile_end <= i).astype(jnp.int32)
         xs = jax.lax.dynamic_slice_in_dim(x_rows, i * tile, tile, 0)
-        act = jax.nn.silu(xs @ w_gate[e].astype(xs.dtype)) * (xs @ w_up[e].astype(xs.dtype))
-        return jax.lax.dynamic_update_slice_in_dim(y_rows, act @ w_down[e].astype(xs.dtype), i * tile, 0)
+        return jax.lax.dynamic_update_slice_in_dim(y_rows, body.tile(p, e, xs), i * tile, 0)
 
     y_rows = jax.lax.fori_loop(0, tile_end[-1], one_tile, jnp.zeros((rows, hidden), x2d.dtype))
     picked = y_rows[jnp.minimum(dest, rows - 1)].reshape(n, k, hidden)
@@ -204,16 +236,17 @@ def experts_held(p, x2d, idx, w, first, count, live=None):
     return jnp.einsum("nkd,nk->nd", picked.astype(jnp.float32), weight).astype(x2d.dtype)
 
 
-def moe(p, x, cfg, live=None):
-    """The expert layer on x [..., hidden]: routed part of the held experts plus the shared expert."""
+def moe(p, x, cfg, live=None, body: ExpertBody = SWIGLU):
+    """The expert layer on x [..., hidden]: routed part of the held experts plus
+    the shared expert, every expert of the form ``body``."""
     x2d = x.reshape(-1, x.shape[-1])
     with jax.named_scope("router"):
         idx, w = route(p, x2d, cfg)
     first, count = cfg.experts_held
     with jax.named_scope("experts"):
-        y = experts_held(p["experts"], x2d, idx, w, first, count, live)
+        y = experts_held(p["experts"], x2d, idx, w, first, count, live, body)
     with jax.named_scope("shared_expert"):
-        y = y + swiglu(p["shared_experts"], x2d)
+        y = y + body.dense(p["shared_experts"], x2d)
     return y.reshape(x.shape), idx
 
 

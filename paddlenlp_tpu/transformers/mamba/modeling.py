@@ -16,6 +16,11 @@ TPU-first shape of the port:
   attention families, via the ``_init_decode_cache`` hook;
 - params keep HF mamba names (``backbone.layers.{i}.mixer.*``) for checkpoint
   interop; ``A_log``/``D``/``conv1d.weight`` get explicit mappings.
+
+Whole-sequence only: ``MambaCache`` serves ``model.generate()`` and lives
+outside the serving engine, whose door refuses this family. The state-space
+family ``InferenceEngine`` serves is ``nemotron_h`` (Mamba-2: recurrent state
+rows beside the paged KV pool, ``experimental/state_model.py``).
 """
 
 from __future__ import annotations

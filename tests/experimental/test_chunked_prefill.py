@@ -141,6 +141,19 @@ class TestChunkedParity:
         assert results["chunk_cache"] == results["mono_cache"]
         assert results["chunk_nocache"] == results["mono_cache"]
 
+    def test_cached_admission_groups_seed_each_rows_own_counts(self, model):
+        """Groups of one, two and three prompts whose prefix is cached, so their penalty counts come from the host
+        through ``seed_counts``' one padded scatter: each row's counts are its own prompt's, so a repetition penalty
+        samples what the cache-off engine samples."""
+        shared = list(range(5, 21))  # 4 full blocks
+        warm = [[shared + [70 + i, 71 + i] for i in range(n)] for n in (1, 2, 3)]
+        penal = SamplingParams(max_new_tokens=5, repetition_penalty=1.3)
+        plain = InferenceEngine(model, prefill_chunk_tokens=8, enable_prefix_cache=False, **KW)
+        eng = InferenceEngine(model, prefill_chunk_tokens=8, enable_prefix_cache=True, **KW)
+        eng.generate([shared + [50]], penal)
+        assert [eng.generate(g, penal) for g in warm] == [plain.generate(g, penal) for g in warm]
+        assert eng.mgr.cache_hits == 6
+
     def test_per_step_prefill_bounded(self, eng_chunk4):
         """No engine step feeds more prompt tokens than the chunk budget."""
         eng = eng_chunk4
