@@ -17,9 +17,12 @@ index among the layers of its kind.
 Two forms of each attention, picked by the static number of tokens a row
 feeds: one token (decode) runs absorbed, the query folded into the latent, the
 indexer's top-k gathered (``latent_gather``) or the window's blocks; a chunk
-of a prompt runs expanded over tiles of cached positions, as many tiles as the
-longest row needs (a loop whose length the device decides), dense under the
-selection mask.
+of a prompt runs expanded, dense under the selection mask. The full layers'
+chunk form is one Pallas kernel (``ops/pallas/latent_attention.py``: keys and
+values expanded from the latent rows tile by tile, the score tile in VMEM
+only, as many key tiles visited as the longest row needs, a number the device
+decides and ``attn_key_tiles`` counts); the window layers' is XLA over the
+window's gathered rows. Off the chip the kernel runs in interpret mode.
 
 The entry points, their jit names, the donated pool and the sampler are the
 ``llama`` kind's: this class only replaces what runs between the embedding and
@@ -39,6 +42,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas.latent_attention import latent_chunk_attention
 from ..transformers import latent_layers as M
 from ..transformers.latent_layers import LATENT_FULL as FULL
 from ..transformers.latent_layers import LATENT_WINDOW as WINDOW
@@ -47,16 +51,17 @@ from .paged_cache import LatentKVPool, init_latent_pool, write_rows
 
 __all__ = ["LatentInferenceModel"]
 
-KEY_TILE = 512  # cached positions a chunk's attention takes in at a time
+KEY_TILE = 512  # cached positions the chunk form's indexer loop and attention kernel take in at a time
 NEG = M.NEG
 
 
 class LatentInferenceModel(LaunchCounts, PagedInferenceModel):
     #: what a launch's layers count on the device, in ``pool.stats``'s order (``LaunchCounts``): routed choices of
     #: live tokens that landed on held experts and all of them, the busiest held expert's tokens summed over expert
-    #: layers and sub-steps, positions the indexer scored and kept for live queries over full layers
+    #: layers and sub-steps, positions the indexer scored and kept for live queries over full layers, key tiles
+    #: the chunk form's attention kernel visited (rows x tiles, summed over full layers)
     STATS = ("expert_assignments_local", "expert_assignments", "expert_tokens_max",
-             "index_candidates", "index_selected")
+             "index_candidates", "index_selected", "attn_key_tiles")
 
     @classmethod
     def refuse_engine_features(cls, **features):
@@ -144,9 +149,10 @@ class LatentInferenceModel(LaunchCounts, PagedInferenceModel):
                     idx = write_rows(pool.idx, k_i, tables[:, 0], positions, valid, li)
             pool = dataclasses.replace(pool, kv=kv, idx=idx)
             form = self._full_decode if h.shape[1] == 1 else self._full_chunk
-            o, scored, kept = form(attn, pool, li, tables[:, 0], positions, valid, q_nope, q_pe, q_i, w_i, d)
+            o, scored, kept, tiles = form(attn, pool, li, tables[:, 0], positions, valid, q_nope, q_pe, q_i, w_i, d)
             with jax.named_scope("index_topk"):
-                pool = self._count(pool, index_candidates=scored.sum(), index_selected=kept.sum())
+                pool = self._count(pool, index_candidates=scored.sum(), index_selected=kept.sum(),
+                                   attn_key_tiles=tiles)
         else:
             d = self.dims[WINDOW]
             with jax.named_scope("mla_proj"):
@@ -177,7 +183,8 @@ class LatentInferenceModel(LaunchCounts, PagedInferenceModel):
     def _full_decode(self, attn, pool, li, table, positions, valid, q_nope, q_pe, q_i, w_i, d):
         """One query a row, absorbed: the indexer scores the row's whole table,
         its top-k positions' latent rows are gathered, the query folded into
-        the latent attends them. q_* [B, 1, H, .] -> [B, 1, H, v]."""
+        the latent attends them. q_* [B, 1, H, .] -> ([B, 1, H, v], positions
+        scored, positions kept, key tiles visited: none in this form)."""
         cfg, bs = self.config, self.block_size
         b, m = table.shape
         s = m * bs
@@ -192,13 +199,14 @@ class LatentInferenceModel(LaunchCounts, PagedInferenceModel):
             blocks = jnp.take_along_axis(table, chosen // bs, axis=1)
             rows = pool.kv[li, blocks, chosen % bs]  # [B, K, kv_lora + rope]
         with jax.named_scope("mla_attn"):
-            return _absorbed(attn, rows, kept[:, None, :], q_nope, q_pe, d), can & valid, kept & valid
+            return _absorbed(attn, rows, kept[:, None, :], q_nope, q_pe, d), can & valid, kept & valid, 0
 
     def _full_chunk(self, attn, pool, li, table, positions, valid, q_nope, q_pe, q_i, w_i, d):
         """A chunk of queries a row, expanded, over tiles of the cached
         positions: the indexer scores tile by tile, the k-th largest score a
         query is found by counting (``kth_largest``), and attention runs dense
-        under the selection mask with a running softmax. q_* [B, T, H, .]."""
+        under the selection mask in one kernel (``latent_chunk_attention``),
+        which visits as many key tiles as the longest row needs. q_* [B, T, H, .]."""
         cfg, bs = self.config, self.block_size
         b, t = positions.shape
         m = table.shape[1]
@@ -212,13 +220,10 @@ class LatentInferenceModel(LaunchCounts, PagedInferenceModel):
         kpos = jnp.arange(s)[None, None, :]
         can = (kpos <= positions[:, :, None]) & valid[:, :, None]  # [B, T, S]
 
-        def tile_rows(plane, j):
-            blocks = jax.lax.dynamic_slice_in_dim(table, j * per_tile, per_tile, 1)
-            return plane[li, blocks].reshape(b, tile, -1)
-
         with jax.named_scope("indexer"):
             def score_tile(j, acc):
-                part = M.index_scores(q_i, w_i, tile_rows(pool.idx, j))
+                blocks = jax.lax.dynamic_slice_in_dim(table, j * per_tile, per_tile, 1)
+                part = M.index_scores(q_i, w_i, pool.idx[li, blocks].reshape(b, tile, -1))
                 return jax.lax.dynamic_update_slice_in_dim(acc, part, j * tile, 2)
 
             scores = jax.lax.fori_loop(0, n_tiles, score_tile, jnp.full((b, t, s), -jnp.inf, jnp.float32))
@@ -229,36 +234,11 @@ class LatentInferenceModel(LaunchCounts, PagedInferenceModel):
                 lambda: (lambda keys, thr: (keys >= thr) & can)(*M.kth_largest(scores, can, cfg.index_topk)),
                 lambda: can)
         w_k, w_v = M.kv_b_split(attn, d)
-        w_k, w_v = w_k.astype(self.dtype), w_v.astype(self.dtype)
-        scale = (d["nope"] + d["rope"]) ** -0.5
-        h = d["heads"]
-
-        q_cat = jnp.concatenate([q_nope, q_pe], -1)  # one product of width nope + rope, one score tensor
-
-        def attend_tile(j, carry):
-            top, norm, acc = carry
-            rows = tile_rows(pool.kv, j)
-            c_kv, k_pe = rows[..., : d["kv_lora"]], rows[..., d["kv_lora"]:]
-            k_nope = jnp.einsum("bsc,chn->bshn", c_kv, w_k)
-            k_cat = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe[:, :, None, :], k_nope.shape[:3] + k_pe.shape[-1:])], -1)
-            v = jnp.einsum("bsc,chv->bshv", c_kv, w_v)
-            sc = jnp.einsum("bthn,bshn->bhts", q_cat, k_cat, preferred_element_type=jnp.float32) * scale
-            sel = jax.lax.dynamic_slice_in_dim(keep, j * tile, tile, 2)
-            sc = jnp.where(sel[:, None], sc, NEG)
-            new_top = jnp.maximum(top, sc.max(-1))
-            # a query with nothing kept in the tiles so far sums rubbish at weight 1: its first kept
-            # position fades that to nothing (exp(NEG - score) = 0), and every live query keeps one
-            p = jnp.exp(sc - new_top[..., None])
-            fade = jnp.exp(top - new_top)
-            acc = acc * fade.transpose(0, 2, 1)[..., None] + jnp.einsum(
-                "bhts,bshv->bthv", p.astype(self.dtype), v, preferred_element_type=jnp.float32)
-            return new_top, norm * fade + p.sum(-1), acc
-
         with jax.named_scope("mla_attn"):
-            init = (jnp.full((b, h, t), NEG, jnp.float32), jnp.zeros((b, h, t), jnp.float32),
-                    jnp.zeros((b, t, h, d["v"]), jnp.float32))
-            _, norm, acc = jax.lax.fori_loop(0, n_tiles, attend_tile, init)
-            return (acc / jnp.maximum(norm, 1e-30).transpose(0, 2, 1)[..., None]).astype(self.dtype), can, keep
+            rows = pool.kv[li, table].reshape(b, s, -1)  # the table's latent rows, contiguous for the kernel
+            o = latent_chunk_attention(q_nope, q_pe, rows, w_k, w_v, keep, n_tiles,
+                                       scale=(d["nope"] + d["rope"]) ** -0.5, tile=tile)
+            return o, can, keep, n_tiles * b
 
     # ------------------------------------------------------------------ window layers
     def _window_attention(self, attn, plane, li, wtable, positions, q_nope, q_pe, d):

@@ -100,7 +100,8 @@ LAUNCH_GEOMETRY = ("rows_live", "rows", "kv_positions")
 #: positions the indexer scored and kept, routed choices that landed on held
 #: experts and all of them, the busiest held expert's tokens (summed over the
 #: launch's expert layers and decode sub-steps, so that x experts held /
-#: assignments_local is max over mean); from the state-space kinds
+#: assignments_local is max over mean), key tiles the chunk form's attention
+#: kernel visited; from the state-space kinds
 #: (``experimental/state_model.py:StateSpaceInferenceModel.STATS``) the three
 #: expert counts and: rows whose recurrent state the scan layers read and wrote
 #: (rows x decode sub-steps, dead ones too), those of them that fed a token,
@@ -108,7 +109,7 @@ LAUNCH_GEOMETRY = ("rows_live", "rows", "kv_positions")
 #: ``totals`` where a launch carries them (a program without such layers never does)
 KIND_COUNTERS = ("index_candidates", "index_selected", "expert_assignments_local",
                  "expert_assignments", "expert_tokens_max",
-                 "state_rows", "state_rows_live", "state_resets")
+                 "state_rows", "state_rows_live", "state_resets", "attn_key_tiles")
 
 #: step-program vocabulary the ledger accounts by (also the ``{program}``
 #: label of the serving compile counters)

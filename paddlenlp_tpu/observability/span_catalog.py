@@ -90,7 +90,7 @@ DEVICE_SCOPES = {
     "indexer": "latent_full: indexer projections and the scores of cached positions against a query",
     "index_topk": "latent_full: the k-th largest score a query (counting passes) or top_k of a decode row",
     "latent_gather": "latent_full decode: gather of the kept positions' latent rows through the block table",
-    "mla_attn": "latent_full: attention over the kept positions (absorbed for one token, expanded tiles for a chunk)",
+    "mla_attn": "latent_full: attention over the kept positions (absorbed for one token; for a chunk the gather of the table's rows and the latent_chunk_attention kernel)",
     "window_attn": "latent_window: gather of the window's blocks and attention over them",
     "attn_gate": "latent kinds: headwise sigmoid gate on the attention output",
     "router": "expert layer: float32 sigmoid scores, top-k of score + bias, per-expert counts",
@@ -117,4 +117,5 @@ LAUNCH_ARGS = {
     "state_rows": "rows whose recurrent state the scan layers read and wrote: rows x decode sub-steps, dead ones too (device count)",
     "state_rows_live": "those of them that fed a token (device count)",
     "state_resets": "those that fed a sequence's position 0 and so started from zeros: admissions and re-prefills (device count)",
+    "attn_key_tiles": "key tiles of cached positions the full layers' chunk-form attention kernel visited: rows x tiles, over full layers (device count)",
 }

@@ -1,1 +1,1 @@
-"""Pallas TPU kernels (flash attention, paged/ragged paged attention)."""
+"""Pallas TPU kernels (flash attention, paged/ragged paged attention, latent attention for a prompt chunk)."""

@@ -99,6 +99,22 @@ def test_a_stack_without_window_layers_is_served_on_the_block_table_alone(ref):
     assert (logits.max(-1) - logits[np.arange(len(o)), o]).max() < 1e-5
 
 
+def test_a_prompt_over_three_key_tiles_and_five_chunks(ref, model, monkeypatch):
+    """Key tiles of 16 positions (4 of this table's 16 blocks) where the engine's
+    table is one tile of 64: a 40-token prompt enters in five chunks of 8 whose
+    attention kernel visits 1, 1, 2, 2 and 3 tiles, the running softmax carried
+    from tile to tile; the tiles past those are in the table and never visited."""
+    from paddlenlp_tpu.experimental import latent_model
+
+    monkeypatch.setattr(latent_model, "KEY_TILE", 16)
+    eng = InferenceEngine(model, **ENGINE)
+    p = prompts(40)[0]
+    o = eng.generate([p], SamplingParams(max_new_tokens=6))[0]
+    logits = np.asarray(ref.forward(SMALL, SEED, np.asarray(p + o)))[len(p) - 1: len(p) + len(o) - 1]
+    assert (logits.max(-1) - logits[np.arange(len(o)), o]).max() < 1e-5
+    assert eng.ledger.totals["attn_key_tiles"] == (1 + 1 + 2 + 2 + 3) * 2  # both full layers
+
+
 def test_indexer_selection_equals_the_references(ref, model):
     """Layer 1 (a full layer past the dense one) on a 40-token input: the set of
     positions each query may attend, program against reference, exactly."""
@@ -190,6 +206,9 @@ def test_launch_counts_and_ledger_totals(served):
     assert t["index_candidates"] == 2 * seen.sum()
     assert 2 * np.minimum(seen, 8).sum() <= t["index_selected"] < 1.1 * 2 * np.minimum(seen, 8).sum()
     assert t["expert_tokens_max"] * 4 >= t["expert_assignments_local"]  # max >= mean over 4 held
+    # the chunk form's kernel: one key tile (the whole table of 64 positions) x 2 full layers x the chunks of 8
+    # that prompts of 30, 21 and 13 tokens enter in; the decode form visits none
+    assert t["attn_key_tiles"] == 1 * 2 * (4 + 3 + 2)
 
 
 @pytest.mark.parametrize("feature, value, named", [

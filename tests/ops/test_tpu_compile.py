@@ -9,8 +9,9 @@ partition it at all. The shapes are the ones ``chip_smoke.py`` runs: Qwen2-1.5B
 serving (12 query / 2 kv heads, head_dim 128, 9600-block pool of 16-token
 blocks, 160-block tables) and Qwen2-0.5B training (14 / 2 heads, head_dim 64,
 4 rows of 2048: the benchmark's training cell); the step programs are the benchmark cell's (28 layers, 16
-slots, 192-block tables). Nothing runs; a compile that passes says nothing
-about results or times.
+slots, 192-block tables); the latent kernel's are ``longdoc``'s full layers
+(one chunk of 1,024, 128 heads, a 512 + 64 latent, a table of 1,056 blocks of
+16). Nothing runs; a compile that passes says nothing about results or times.
 """
 
 import os
@@ -133,6 +134,30 @@ def test_flash_attention_compiles(chip, shape, masking, backward):
     assert text.count('custom_call_target="tpu_custom_call"') == (3 if backward else 1)
     # half the chip's VMEM for a 1024 x 1024 step, the compiler's default for the caller's small blocks
     assert text.count('"size":"67108864"}],"custom_call_config"') == (0 if blocks[0] else 3 if backward else 1)
+
+
+def test_latent_chunk_attention_compiles_within_its_vmem_request(chip):
+    """The full layers' chunk form at ``longdoc``'s shapes: one kernel, holding
+    the scoped VMEM the kernel file sizes by hand (the compiler refuses a step
+    that needs more than it was given: 28 MiB at four heads a step)."""
+    from paddlenlp_tpu.ops.pallas import latent_attention as kernel_file
+
+    chunk, heads, nope, rope, v, kv_lora, tile = 1024, 128, 128, 64, 128, 512, 512
+    cached = 1056 * 16  # the longest table: 33 key tiles
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def attend(q_nope, q_pe, rows, w_k, w_v, keep, n_tiles):
+        return kernel_file.latent_chunk_attention(q_nope, q_pe, rows, w_k, w_v, keep, n_tiles,
+                                                  scale=(nope + rope) ** -0.5, tile=tile, interpret=False)
+
+    text = compiled_text(attend, aval((1, chunk, heads, nope), jnp.bfloat16), aval((1, chunk, heads, rope), jnp.bfloat16),
+                         aval((1, cached, kv_lora + rope), jnp.bfloat16), aval((kv_lora, heads, nope), jnp.bfloat16),
+                         aval((kv_lora, heads, v), jnp.bfloat16), aval((1, chunk, cached), jnp.bool_),
+                         aval((), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    request = kernel_file.vmem_bytes(chunk, tile, kernel_file.HEAD_BLOCK, kv_lora, nope, rope, v, 2)
+    assert request <= 64 << 20  # half the chip's VMEM at most
+    assert f'"size":"{request}"}}],"custom_call_config"' in text
 
 
 def test_flash_attention_on_a_mesh_compiles(topology, monkeypatch):
