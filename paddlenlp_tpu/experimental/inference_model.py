@@ -63,7 +63,8 @@ def refuse_unserved(config, max_context: int):
     if window is not None and window < max_context:
         raise ValueError(
             f"{name}: sliding_window={window} is in use (sequences reach {max_context} positions) and the "
-            "llama layer kind attends the whole context; a kind with a window cache is needed (paged_cache.LatentKVPool)")
+            "llama layer kind attends the whole context; a kind with a window cache is needed (per-head K and V: "
+            "window_model.WindowedInferenceModel over paged_cache.WindowKVPool; latent rows: paged_cache.LatentKVPool)")
     if getattr(config, "kv_lora_rank", None):
         raise ValueError(f"{name}: kv_lora_rank={config.kv_lora_rank} (latent attention) is not computed by the "
                          "llama layer kind: its pool holds per-head K and V")
@@ -181,6 +182,18 @@ class LaunchCounts:
 
     def _decode_q_lens(self, done):
         return (~done).astype(jnp.int32)
+
+    def _count_experts(self, pool, chosen, valid):
+        """Count an expert layer's routed choices ``chosen`` [N, k] of the tokens that are ``valid`` [B, T]
+        (``expert_assignments_local``, ``expert_assignments``, ``expert_tokens_max`` of ``STATS``)."""
+        from ..transformers.latent_layers import held_counts
+
+        with jax.named_scope("router"):
+            first, count = self.config.experts_held
+            per_expert = held_counts(jnp.where(valid.reshape(-1, 1), chosen, -1), first, count)
+            return self._count(pool, expert_assignments_local=per_expert.sum(),
+                               expert_assignments=valid.sum() * chosen.shape[-1],
+                               expert_tokens_max=per_expert.max())
 
 
 class PagedInferenceModel:
@@ -341,8 +354,8 @@ class PagedInferenceModel:
         out = jnp.einsum("bnts,bsnh->btnh", probs, v.astype(jnp.float32))
         return out.astype(q.dtype)
 
-    def _paged_attention(self, q, kv, kv_scale, block_tables, q_start, q_lens, layer):
-        """Fused block-table walk + attend over layer ``layer`` of the whole
+    def _paged_attention(self, q, kv, kv_scale, block_tables, q_start, q_lens, layer, window=None):
+        """Fused block-table walk (or, with ``window``, window walk) + attend over layer ``layer`` of the whole
         pool: the Pallas ragged kernel streams addressed KV blocks instead of
         materializing the gathered cache (dequant rides in-kernel for int8/fp8
         pools). One launch covers the whole ragged batch — decode rows
@@ -351,7 +364,7 @@ class PagedInferenceModel:
         from ..ops.pallas.paged_attention import ragged_paged_attention
 
         return ragged_paged_attention(q, kv, block_tables, q_start=q_start, q_lens=q_lens,
-                                      layer=layer, kv_scale=kv_scale)
+                                      layer=layer, kv_scale=kv_scale, window=window)
 
     def _attention(self, x, pool: PagedKVPool, attn, lora_layer, adapter_idx, block_tables, q_positions,
                    kv_len_mask, write_pos, q_lens, layer):

@@ -171,12 +171,7 @@ class LatentInferenceModel(LaunchCounts, PagedInferenceModel):
             x = _rms(h, lp["post_attention_layernorm"]["scale"], self.eps)
         y, chosen = M.mlp(lp["mlp"], x, cfg, layer, live=valid.reshape(-1))
         if chosen is not None:
-            with jax.named_scope("router"):
-                first, count = cfg.experts_held
-                per_expert = M.held_counts(jnp.where(valid.reshape(-1, 1), chosen, -1), first, count)
-                pool = self._count(pool, expert_assignments_local=per_expert.sum(),
-                                   expert_assignments=valid.sum() * chosen.shape[-1],
-                                   expert_tokens_max=per_expert.max())
+            pool = self._count_experts(pool, chosen, valid)
         return h + y, pool
 
     # ------------------------------------------------------------------ full layers

@@ -17,11 +17,17 @@ preempt + recover). TPU-native split:
 - beside it, for layer kinds that keep something else (``latent_model.py``):
   ``LatentKVPool``, planes of one latent row a token for full-attention
   layers, their indexer keys, and window layers' rows under a second table
-  that holds the window only; and ``StatePool`` (``state_model.py``): the
+  that holds the window only; ``StatePool`` (``state_model.py``): the
   per-head planes of the attention layers, addressed by block table as
   above, beside **state rows addressed by slot** for the scan layers, whose
   whole past is one recurrent state and a few convolution inputs a sequence,
-  in no block of tokens;
+  in no block of tokens; and ``WindowKVPool`` (``window_model.py``): per-head
+  K and V in **two planes of the layout above**, ``kv`` for the layers that
+  attend the whole context, under a sequence's block table, and ``win`` for
+  the layers that attend a window, under its second table
+  (``BlockManager.window_span`` / ``table_array`` row 1), which holds blocks
+  at the logical blocks inside the window only, so that plane's block count
+  is slots x (window + the tokens a launch feeds) whatever the context;
 - host side: ``BlockManager`` does the step.cu bookkeeping (free list, per-seq
   tables, allocate/extend/free, preemption candidates) in plain Python — the
   allocator runs between device steps, so there is no launch-latency reason to
@@ -40,8 +46,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["PagedKVPool", "LatentKVPool", "StatePool", "BlockManager", "init_paged_pool", "init_latent_pool",
-           "init_state_pool", "write_kv_block", "write_rows", "read_state_rows", "write_state_rows", "gather_kv",
+__all__ = ["PagedKVPool", "LatentKVPool", "StatePool", "WindowKVPool", "BlockManager", "init_paged_pool",
+           "init_latent_pool", "init_state_pool", "init_window_pool", "write_kv_block", "write_rows", "read_state_rows", "write_state_rows", "gather_kv",
            "copy_blocks"]
 
 @dataclasses.dataclass
@@ -202,6 +208,54 @@ def write_state_rows(plane: jnp.ndarray, layer: int, slots: Optional[jnp.ndarray
         return plane.at[layer, : new.shape[0]].set(jnp.where(keep, new, old))
     return plane.at[layer, jnp.where(live, slots, plane.shape[1] - 1)].set(new)
 
+
+@dataclasses.dataclass
+class WindowKVPool:
+    """Per-head K and V in two planes, each in :class:`PagedKVPool`'s layout
+    (written by ``write_kv_block``, read by the ragged paged kernel through a
+    ``PagedKVPool`` view of the plane), donated and carried whole:
+
+    - ``kv``   [full layers, 2, blocks, block_size, n_kv * head_dim]: the layers
+      that attend the whole context, under a sequence's block table
+      (``tables[:, 0]``);
+    - ``win``  [window layers, 2, window_blocks, block_size, n_kv * head_dim]:
+      the layers that attend a window, under the second table
+      (``tables[:, 1]``), whose blocks the ``BlockManager`` gives back once
+      they lie wholly behind the window: its block count does not grow with
+      the context.
+
+    A layer addresses its plane by its index among the layers of its kind.
+    ``stats`` int32 [n] rides along: what the last launch's layers counted on
+    the device (``WindowedInferenceModel.STATS``)."""
+
+    kv: jnp.ndarray
+    win: jnp.ndarray
+    stats: jnp.ndarray
+    scale = None  # no quantized form
+
+    @property
+    def num_blocks(self) -> int:
+        return self.kv.shape[2]
+
+    @property
+    def block_size(self) -> int:
+        return self.kv.shape[3]
+
+    @property
+    def quantized(self) -> bool:
+        return False
+
+
+jax.tree_util.register_dataclass(WindowKVPool, data_fields=["kv", "win", "stats"], meta_fields=[])
+
+
+def init_window_pool(n_full: int, n_window: int, num_blocks: int, num_window_blocks: int, block_size: int,
+                     kv_width: int, n_stats: int, dtype=jnp.bfloat16) -> WindowKVPool:
+    plane = lambda layers, blocks: jnp.zeros((max(layers, 1), 2, blocks, block_size, kv_width), dtype)
+    return WindowKVPool(kv=plane(n_full, num_blocks), win=plane(n_window, num_window_blocks),
+                        stats=jnp.zeros((n_stats,), jnp.int32))
+
+
 _QMAX = {"int8": 127.0, "fp8": 448.0}  # float8_e4m3 max normal
 
 
@@ -348,7 +402,7 @@ class BlockManager:
                  enable_prefix_cache: bool = False, window_back: Optional[int] = None,
                  num_window_blocks: int = 0):
         # a second table a sequence for layer kinds that keep a window only
-        # (``LatentKVPool.win``): ``window_back`` = how many
+        # (``LatentKVPool.win``, ``WindowKVPool.win``): ``window_back`` = how many
         # positions behind a query its layers still read; logical block ->
         # block of the window plane, given back once wholly behind the window
         self.window_back = window_back

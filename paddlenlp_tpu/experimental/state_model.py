@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from ..transformers import state_layers as S
-from ..transformers.latent_layers import RELU2, held_counts, moe
+from ..transformers.latent_layers import RELU2, moe
 from .inference_model import LaunchCounts, PagedInferenceModel, _rms, layer_kinds
 from .paged_cache import PagedKVPool, StatePool, init_state_pool, read_state_rows, write_state_rows
 
@@ -124,12 +124,7 @@ class StateSpaceInferenceModel(LaunchCounts, PagedInferenceModel):
                 y, pool = self._scan_layer(lp["mixer"], u, pool, li, slots, valid, live, fresh, q_lens)
             elif kind == S.EXPERTS:
                 y, chosen = moe(lp["mixer"], u, self.config, live=valid.reshape(-1), body=RELU2)
-                with jax.named_scope("router"):
-                    first, count = self.config.experts_held
-                    per_expert = held_counts(jnp.where(valid.reshape(-1, 1), chosen, -1), first, count)
-                    pool = self._count(pool, expert_assignments_local=per_expert.sum(),
-                                       expert_assignments=valid.sum() * chosen.shape[-1],
-                                       expert_tokens_max=per_expert.max())
+                pool = self._count_experts(pool, chosen, valid)
             else:
                 y, view = self._attention(u, PagedKVPool(kv=pool.kv), lp["mixer"], None, adapter_idx, block_tables,
                                           q_positions, kv_len_mask, write_pos, q_lens, li)

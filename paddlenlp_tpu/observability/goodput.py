@@ -105,11 +105,16 @@ LAUNCH_GEOMETRY = ("rows_live", "rows", "kv_positions")
 #: (``experimental/state_model.py:StateSpaceInferenceModel.STATS``) the three
 #: expert counts and: rows whose recurrent state the scan layers read and wrote
 #: (rows x decode sub-steps, dead ones too), those of them that fed a token,
-#: and those that started from zeros. Launch-span args, and monotone
+#: and those that started from zeros; from the windowed kinds
+#: (``experimental/window_model.py:WindowedInferenceModel.STATS``) the three
+#: expert counts and the cached positions visible to the launch's live rows,
+#: summed over the layers that attend the whole context and over those that
+#: attend a window (and over decode sub-steps). Launch-span args, and monotone
 #: ``totals`` where a launch carries them (a program without such layers never does)
 KIND_COUNTERS = ("index_candidates", "index_selected", "expert_assignments_local",
                  "expert_assignments", "expert_tokens_max",
-                 "state_rows", "state_rows_live", "state_resets", "attn_key_tiles")
+                 "state_rows", "state_rows_live", "state_resets", "attn_key_tiles",
+                 "attn_kv_full", "attn_kv_window")
 
 #: step-program vocabulary the ledger accounts by (also the ``{program}``
 #: label of the serving compile counters)

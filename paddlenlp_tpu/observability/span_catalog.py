@@ -82,8 +82,11 @@ SPAN_CATALOG = {
 DEVICE_SCOPES = {
     "embed": "token embedding lookup", "attn_norm": "the layer's input RMS norm",
     "qkv": "llama kind: q/k/v projections", "rope": "llama kind: rotary embedding of q and k",
-    "kv_write": "scatter of the fed tokens' rows into the pool (latent kinds: nested latent_plane / index_plane / window_plane)",
-    "paged_attn": "llama kind: the Pallas ragged paged attention kernel", "attn_gather": "llama kind: XLA gather + attend",
+    "kv_write": "scatter of the fed tokens' rows into the pool (latent kinds: nested latent_plane / index_plane / window_plane; gqa_window: nested window_plane)",
+    "paged_attn": "llama kind, and the windowed kinds' full layers: the Pallas ragged paged attention kernel walking the block table",
+    "paged_attn_window": "gqa_window: the same kernel walking a window (a grid sized by the window, over the window plane)",
+    "attn_gather": "llama and windowed kinds: XLA gather + attend (no kernel)",
+    "qk_norm": "windowed kinds: RMS norm of q and k over each head's dims, before any rotation",
     "o_proj": "attention output projection", "mlp_norm": "post-attention RMS norm", "mlp": "dense SwiGLU MLP",
     "final_norm": "final RMS norm", "lm_head": "output head", "sample": "on-device sampler", "bookkeeping": "counts, stops, positions",
     "mla_proj": "latent kinds: low-rank q and kv chains (norm, rescale, RoPE of the pe slices)",
@@ -118,4 +121,6 @@ LAUNCH_ARGS = {
     "state_rows_live": "those of them that fed a token (device count)",
     "state_resets": "those that fed a sequence's position 0 and so started from zeros: admissions and re-prefills (device count)",
     "attn_key_tiles": "key tiles of cached positions the full layers' chunk-form attention kernel visited: rows x tiles, over full layers (device count)",
+    "attn_kv_full": "windowed kinds: cached positions visible to the live rows, summed over the layers that attend the whole context and over decode sub-steps (device count)",
+    "attn_kv_window": "the same over the layers that attend a window: at most the window a fed token (device count)",
 }

@@ -37,7 +37,18 @@ Design:
   not change any row's result;
 - GQA: queries fold to [B, K, T*group, H]; each grid cell attends its kv
   head's whole query group for every token of its query tile at once;
-- ``q_lens = 1`` everywhere reduces to the classic paged decode kernel.
+- ``q_lens = 1`` everywhere reduces to the classic paged decode kernel;
+- **a window call** (``window=w``, static: a query sees itself and the ``w - 1``
+  positions before it) walks a window, not a table: the block axis of the grid
+  is as long as the window plus a query tile's tokens
+  (``ceil((w - 1 + tq) / bs) + 1`` steps: 9 for a decode row at ``w`` 128 and
+  blocks of 16, where the table may have a thousand entries) and is counted
+  from the tile's first block, ``max(q_start[b] + t0 - (w - 1), 0) // bs``,
+  which the index map and the body both derive from the prefetched
+  ``q_start``. The table it reads may be a window table, holding blocks only at
+  the logical blocks inside the window (``BlockManager.window_span``), and the
+  pool a window plane with its own block count. Without ``window`` the kernel
+  lowers exactly as before: same grid, same index maps, same body.
 
 Off-TPU (tests), the kernel runs in Pallas interpret mode.
 """
@@ -72,7 +83,7 @@ def _q_tile_tokens(T: int, group: int) -> int:
 
 
 def _kernel(tables_ref, start_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
-            bs, scale, use_kv_scale, group, tq):
+            bs, scale, use_kv_scale, group, tq, window=None):
     if use_kv_scale:
         ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = rest
     else:
@@ -95,8 +106,10 @@ def _kernel(tables_ref, start_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, *res
     live = jnp.minimum(qlen - t0, tq)  # live tokens in this tile (<= 0: none)
     # highest live query position: blocks past it contribute nothing to any row
     hi = start + t0 + live - 1
+    # the logical block this step reads: the table's j-th, or with a window the j-th from the tile's first
+    jb = j if window is None else jnp.maximum(start + t0 - (window - 1), 0) // bs + j
 
-    @pl.when((live > 0) & (j * bs <= hi))
+    @pl.when((live > 0) & (jb * bs <= hi))
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)  # [tq*group, H]
         k = k_ref[...].astype(jnp.float32)  # [bs, H]
@@ -108,9 +121,11 @@ def _kernel(tables_ref, start_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, *res
             k = k * jnp.sum(jnp.where(mine, ks, 0.0), axis=-1, keepdims=True)
             v = v * jnp.sum(jnp.where(mine, vs, 0.0), axis=-1, keepdims=True)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # [tq*group, bs]
-        kv_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        kv_pos = jb * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         t = t0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group  # query token idx
         valid = (kv_pos <= start + t) & (t < qlen)
+        if window is not None:
+            valid &= kv_pos > start + t - window
         s = jnp.where(valid, s, NEG_INF)
         m_prev = m_s[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -136,6 +151,7 @@ def ragged_paged_attention(
     scale: Optional[float] = None,
     interpret: Optional[bool] = None,
     kv_scale: Optional[jnp.ndarray] = None,  # [L, 2, num_blocks, bs, K] quantized-pool scales
+    window: Optional[int] = None,  # static: positions a query sees, itself included (None: all before it)
 ) -> jnp.ndarray:
     """One-launch attention for a ragged mixed prefill/decode batch.
 
@@ -143,8 +159,10 @@ def ragged_paged_attention(
     pool layer ``layer``, read through ``block_tables[b]`` — the KV for
     positions ``< q_start`` was written by earlier chunks/steps, the chunk's
     own KV by this step's scatter (ordered before the kernel by jit data
-    dependence on the pool). Returns ``[B, T, N, H]`` with rows
-    ``t >= q_lens[b]`` zeroed.
+    dependence on the pool). With ``window`` it attends positions
+    ``(q_start[b] + t - window, q_start[b] + t]`` only and the grid's block
+    axis is sized by the window (module docstring). Returns ``[B, T, N, H]``
+    with rows ``t >= q_lens[b]`` zeroed.
     """
     B, T, N, H = q.shape
     bs, K = kv.shape[3], kv.shape[4] // H
@@ -162,12 +180,21 @@ def ragged_paged_attention(
     qf = q.reshape(B, T, K, group, H).transpose(0, 2, 1, 3, 4).reshape(B, K, T * group, H)
     q_spec = pl.BlockSpec((1, 1, rows, H), lambda b, kh, qt, j, t, s, n, l: (b, kh, qt, 0))
 
+    n_steps = max_blocks if window is None else min(-(-(window - 1 + tq) // bs) + 1, max_blocks)
+
+    def logical(b, qt, j, s):
+        """The table entry grid step j reads: the j-th, or with a window the j-th from the tile's first block
+        (a step past the table names its last entry again: no new fetch, and the body skips it)."""
+        if window is None:
+            return j
+        return jnp.minimum(jnp.maximum(s[b] + qt * tq - (window - 1), 0) // bs + j, max_blocks - 1)
+
     def pool_spec(plane, width, per_head):
         # rows of block tables[b, j] in one plane of layer l[0], in place in the
         # pool: kv head kh's H lanes of the KV rows, or the whole scale row
         return pl.BlockSpec(
             (None, None, None, bs, width),
-            lambda b, kh, qt, j, t, s, n, l: (l[0], plane, t[b, j], 0, kh if per_head else 0))
+            lambda b, kh, qt, j, t, s, n, l: (l[0], plane, t[b, logical(b, qt, j, s)], 0, kh if per_head else 0))
 
     in_specs = [q_spec, pool_spec(0, H, True), pool_spec(1, H, True)]
     operands = [qf, kv, kv]
@@ -176,7 +203,7 @@ def ragged_paged_attention(
         operands += [kv_scale, kv_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(B, K, T // tq, max_blocks),
+        grid=(B, K, T // tq, n_steps),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
@@ -187,7 +214,7 @@ def ragged_paged_attention(
     )
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, scale=scale, use_kv_scale=use_kv_scale,
-                          group=group, tq=tq),
+                          group=group, tq=tq, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, T * group, H), q.dtype),
         compiler_params=pltpu.CompilerParams(

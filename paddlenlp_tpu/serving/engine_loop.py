@@ -553,6 +553,12 @@ class ServingMetrics:
             "kind=live: those that fed a token; kind=reset: those that "
             "started from zeros at a sequence's first token)",
             labelnames=("kind",))
+        self.attn_kv_positions = r.counter(
+            "paddlenlp_serving_attn_kv_positions_total",
+            "Cached positions visible to the launches' live rows, summed over "
+            "layers and decode sub-steps (layers=full: layers that attend the "
+            "whole context; layers=window: layers that attend a window)",
+            labelnames=("layers",))
         self.wasted_tokens = r.counter(
             "paddlenlp_serving_wasted_tokens_total",
             "Non-useful fed positions by waste kind (padding = bucket pads + "
@@ -771,7 +777,9 @@ class ServingMetrics:
                     ("index_selected", self.index_positions, {"kind": "selected"}),
                     ("state_rows", self.state_rows, {"kind": "computed"}),
                     ("state_rows_live", self.state_rows, {"kind": "live"}),
-                    ("state_resets", self.state_rows, {"kind": "reset"})):
+                    ("state_resets", self.state_rows, {"kind": "reset"}),
+                    ("attn_kv_full", self.attn_kv_positions, {"layers": "full"}),
+                    ("attn_kv_window", self.attn_kv_positions, {"layers": "window"})):
                 delta = totals.get(key, 0) - self._gp_last.get(key, 0)
                 if delta > 0:
                     counter.inc(delta, **label)

@@ -38,6 +38,7 @@ def _populate():
     from ..bart.configuration import BartConfig
     from ..deepseek_v2.configuration import DeepseekV2Config
     from ..dots3_note.configuration import Dots3NoteConfig
+    from ..exaone_moe.configuration import ExaoneMoeConfig
     from ..mamba.configuration import MambaConfig
     from ..nemotron_h.configuration import NemotronHConfig
     from ..rw.configuration import RWConfig
@@ -71,7 +72,7 @@ def _populate():
 
     for cfg in (LlamaConfig, GPTConfig, Qwen2Config, MistralConfig, GemmaConfig, BertConfig,
                 ErnieConfig, MixtralConfig, Qwen2MoeConfig, BaichuanConfig, BloomConfig,
-                OPTConfig, QWenConfig, ChatGLMv2Config, T5Config, BartConfig, DeepseekV2Config, Dots3NoteConfig,
+                OPTConfig, QWenConfig, ChatGLMv2Config, T5Config, BartConfig, DeepseekV2Config, Dots3NoteConfig, ExaoneMoeConfig,
                 NemotronHConfig,
                 MambaConfig, RWConfig, ChatGLMConfig, YuanConfig, JambaConfig,
                 AlbertConfig, ElectraConfig, RobertaConfig,

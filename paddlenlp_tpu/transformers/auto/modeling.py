@@ -104,6 +104,10 @@ def _populate_models():
 
     register_model("dots3_note", "base", dots3_note.Dots3NoteModel)
     register_model("dots3_note", "causal_lm", dots3_note.Dots3NoteForCausalLM)
+    from ..exaone_moe import modeling as exaone_moe
+
+    register_model("exaone_moe", "base", exaone_moe.ExaoneMoeModel)
+    register_model("exaone_moe", "causal_lm", exaone_moe.ExaoneMoeForCausalLM)
     # state-space families: nemotron_h is served by the engine (its configuration names the step programs,
     # experimental/state_model.py); mamba and jamba are whole-sequence only (model.generate with their own caches)
     from ..nemotron_h import modeling as nemotron_h

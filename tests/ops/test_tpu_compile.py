@@ -101,6 +101,21 @@ def test_ragged_paged_attention_compiles(chip, batch, tokens, kv_heads, pool_dty
     assert "tpu_custom_call" in compiled_text(attend, *avals)
 
 
+@pytest.mark.parametrize("rows,tokens", [(16, 1), (1, 1024)], ids=["decode16", "chunk1024"])
+def test_ragged_paged_attention_window_call_compiles(chip, rows, tokens):
+    """The window call at the windowed kinds' cell sizes (64 query / 8 KV heads of 128, window 128, tables of 1,088 over
+    a window plane of 6 layers x 1,185 blocks): the first block of a tile comes from the prefetched ``q_start`` inside
+    the index map, and a chunk of 1,024 runs in four query tiles of 2,048 rows."""
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    avals = [aval((rows, tokens, 64, 128), jnp.bfloat16), aval((6, 2, 1185, 16, 8 * 128), jnp.bfloat16),
+             aval((rows, 1088), jnp.int32), aval((rows,), jnp.int32), aval((rows,), jnp.int32), aval((), jnp.int32)]
+
+    def attend(q, kv, tables, start, lens, layer):
+        return ragged_paged_attention(q, kv, tables, start, lens, layer, interpret=False, window=128)
+
+    assert "tpu_custom_call" in compiled_text(attend, *avals)
+
+
 FLASH_SHAPES = {  # batch, tokens, query heads, kv heads, head_dim
     "cell-4x2048-h64-group7": (4, 2048, 14, 2, 64),  # qwen2-0.5b-pretrain.seq2k, exactly
     "h128-group6-4096": (2, 4096, 12, 2, 128),  # Qwen2-1.5B's heads
