@@ -10,12 +10,19 @@ configuration names (``config.inference_model``) when its ``layer_kinds()`` are
                   per-head pool, under the block table.
 
 Both planes have ``PagedKVPool``'s layout, so both kinds write through
-``write_kv_block`` and read through the ragged paged Pallas kernel
-(``ops/pallas/paged_attention.py``): a full layer's call walks the table, a
-window layer's call (``window=``) a grid sized by the window, with the window
-plane as its whole pool and the layer's index among window layers as its
-layer. In both kinds q and k pass an RMS norm over each head's dims; the MLP is
-dense SwiGLU or sigmoid-routed SwiGLU experts held in part
+``write_kv_block`` and read through the ragged paged Pallas kernel, each kind
+through a walk sized by what it reads: a window layer's call
+(``ops/pallas/paged_attention.py``, ``window=``) a grid sized by the window,
+with the window plane as its whole pool and the layer's index among window
+layers as its layer; a full layer's call (``ops/pallas/paged_run_attention.py``)
+its table by runs of 512 keys for every KV head at once, as many turns as the
+row has runs. The layer kind chooses the walk, at ``_attention_kind`` and
+nowhere else: the llama and state kinds (``PagedInferenceModel._attention``) keep
+the walk of the whole table a block and head a step until the benchmark can
+judge a faster decode in their cells (ROADMAP S2, W1a). ``attn_kv_fetched``
+counts what the full layers' walk fetched beside what its rows could see
+(``attn_kv_full``). In both kinds q and k pass an RMS norm over each head's
+dims; the MLP is dense SwiGLU or sigmoid-routed SwiGLU experts held in part
 (``transformers/window_layers.py`` and ``latent_layers.py`` have the layer
 mathematics). Layers differ, so the stack is unrolled and each layer addresses
 its plane by its index among the layers of its kind.
@@ -40,6 +47,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas.paged_run_attention import ragged_paged_run_attention, run_blocks, runs_visited
 from ..transformers import latent_layers as M
 from ..transformers import window_layers as W
 from ..transformers.window_layers import GQA_FULL as FULL
@@ -55,7 +63,9 @@ class WindowedInferenceModel(LaunchCounts, PagedInferenceModel):
     #: live tokens that landed on held experts and all of them, the busiest held expert's tokens summed over expert
     #: layers and sub-steps; cached positions visible to the live rows, summed over the full layers and over the
     #: window layers (a row that feeds n tokens from position s: s + n a full layer, min(s, window - 1) + n a window layer)
-    STATS = ("expert_assignments_local", "expert_assignments", "expert_tokens_max", "attn_kv_full", "attn_kv_window")
+    #: and the cached positions the full layers' table walk fetched for them (``_fetched``)
+    STATS = ("expert_assignments_local", "expert_assignments", "expert_tokens_max", "attn_kv_full", "attn_kv_window",
+             "attn_kv_fetched")
 
     @classmethod
     def refuse_engine_features(cls, **features):
@@ -121,7 +131,8 @@ class WindowedInferenceModel(LaunchCounts, PagedInferenceModel):
         valid = jnp.arange(h.shape[1])[None, :] < q_lens[:, None]
         seen = jnp.where(q_lens > 0, write_pos + q_lens, 0).sum()
         seen_window = jnp.where(q_lens > 0, jnp.minimum(write_pos, self.window - 1) + q_lens, 0).sum()
-        pool = self._count(pool, attn_kv_full=self.n_full * seen, attn_kv_window=self.n_window * seen_window)
+        pool = self._count(pool, attn_kv_full=self.n_full * seen, attn_kv_window=self.n_window * seen_window,
+                           attn_kv_fetched=self.n_full * self._fetched(tables[FULL], write_pos, q_lens))
         for layer, kind in enumerate(self.kinds):
             lp = m[f"layers_{layer}"]
             with jax.named_scope("attn_norm"):
@@ -138,12 +149,23 @@ class WindowedInferenceModel(LaunchCounts, PagedInferenceModel):
             h = h + y
         return h, pool
 
+    def _fetched(self, table, start, q_lens):
+        """Cached positions a full layer's table walk fetches for the live rows: with the kernel a row's own runs
+        (``runs_visited`` x positions a run; a chunk row's query tiles each walk theirs, the row counts once), through
+        the XLA gather the whole table. ``attn_kv_full`` over it is the share of what was read that a row could see."""
+        if not self.use_paged_kernel:
+            return (q_lens > 0).sum() * (table.shape[1] * self.block_size)
+        run = run_blocks(self.block_size, table.shape[1]) * self.block_size
+        return runs_visited(start, q_lens, run).sum() * run
+
     def _attention_kind(self, attn, x, pool, kind, li, table, positions, start, q_lens):
         """One layer's attention on the normed input x [B, T, hidden] through
         layer ``li`` of its kind's plane: projections, the norm of q and k,
         rotation on window layers, the fed tokens' K and V written at their
-        positions, the ragged paged kernel (walking the table, or with
-        ``window`` the window) or the XLA gather. -> ([B, T, heads, head_dim], pool)."""
+        positions, the ragged paged kernel (a full layer walks its table by
+        runs, ``paged_run_attention.py``; a window layer the window, the llama
+        kind's kernel with ``window``) or the XLA gather. The layer kind
+        chooses the walk, here and nowhere else. -> ([B, T, heads, head_dim], pool)."""
         windowed = kind == WINDOW
         q, k, v = W.project_qkv(attn, x, positions, self.dims, kind, self.eps)
         plane = pool.win if windowed else pool.kv
@@ -151,9 +173,11 @@ class WindowedInferenceModel(LaunchCounts, PagedInferenceModel):
             plane = write_kv_block(PagedKVPool(kv=plane), k, v, table, start, li).kv
         pool = dataclasses.replace(pool, **{"win" if windowed else "kv": plane})
         if self.use_paged_kernel:
-            with jax.named_scope("paged_attn_window" if windowed else "paged_attn"):
-                return self._paged_attention(q, plane, None, table, start, q_lens, li,
-                                             window=self.window if windowed else None), pool
+            if windowed:
+                with jax.named_scope("paged_attn_window"):
+                    return self._paged_attention(q, plane, None, table, start, q_lens, li, window=self.window), pool
+            with jax.named_scope("paged_attn"):
+                return ragged_paged_run_attention(q, plane, table, start, q_lens, li), pool
         with jax.named_scope("attn_gather"):
             return self._gathered(q, plane, li, table, positions, windowed), pool
 

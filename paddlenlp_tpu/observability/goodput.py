@@ -109,12 +109,13 @@ LAUNCH_GEOMETRY = ("rows_live", "rows", "kv_positions")
 #: (``experimental/window_model.py:WindowedInferenceModel.STATS``) the three
 #: expert counts and the cached positions visible to the launch's live rows,
 #: summed over the layers that attend the whole context and over those that
-#: attend a window (and over decode sub-steps). Launch-span args, and monotone
+#: attend a window (and over decode sub-steps), and the cached positions the
+#: former's table walk fetched: whole runs of them. Launch-span args, and monotone
 #: ``totals`` where a launch carries them (a program without such layers never does)
 KIND_COUNTERS = ("index_candidates", "index_selected", "expert_assignments_local",
                  "expert_assignments", "expert_tokens_max",
                  "state_rows", "state_rows_live", "state_resets", "attn_key_tiles",
-                 "attn_kv_full", "attn_kv_window")
+                 "attn_kv_full", "attn_kv_window", "attn_kv_fetched")
 
 #: step-program vocabulary the ledger accounts by (also the ``{program}``
 #: label of the serving compile counters)

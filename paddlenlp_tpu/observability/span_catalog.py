@@ -123,4 +123,5 @@ LAUNCH_ARGS = {
     "attn_key_tiles": "key tiles of cached positions the full layers' chunk-form attention kernel visited: rows x tiles, over full layers (device count)",
     "attn_kv_full": "windowed kinds: cached positions visible to the live rows, summed over the layers that attend the whole context and over decode sub-steps (device count)",
     "attn_kv_window": "the same over the layers that attend a window: at most the window a fed token (device count)",
+    "attn_kv_fetched": "windowed kinds: cached positions the full layers' table walk fetched for the live rows: runs visited x positions a run with the kernel, the whole table a row through the gather; attn_kv_full / attn_kv_fetched is the share of what was read that a row could see (device count)",
 }

@@ -45,10 +45,10 @@ Design:
   blocks of 16, where the table may have a thousand entries) and is counted
   from the tile's first block, ``max(q_start[b] + t0 - (w - 1), 0) // bs``,
   which the index map and the body both derive from the prefetched
-  ``q_start``. The table it reads may be a window table, holding blocks only at
-  the logical blocks inside the window (``BlockManager.window_span``), and the
-  pool a window plane with its own block count. Without ``window`` the kernel
-  lowers exactly as before: same grid, same index maps, same body.
+  ``q_start``. The table may be a window table, with blocks only at the logical
+  blocks inside the window (``BlockManager.window_span``), the pool a window
+  plane. Without ``window``: the table's walk, a block of one head a step (the
+  llama and state kinds'; the windowed kinds' is ``paged_run_attention.py``'s).
 
 Off-TPU (tests), the kernel runs in Pallas interpret mode.
 """

@@ -557,7 +557,8 @@ class ServingMetrics:
             "paddlenlp_serving_attn_kv_positions_total",
             "Cached positions visible to the launches' live rows, summed over "
             "layers and decode sub-steps (layers=full: layers that attend the "
-            "whole context; layers=window: layers that attend a window)",
+            "whole context; layers=window: layers that attend a window), and "
+            "those the former's table walk fetched (layers=fetched: whole runs)",
             labelnames=("layers",))
         self.wasted_tokens = r.counter(
             "paddlenlp_serving_wasted_tokens_total",
@@ -779,7 +780,8 @@ class ServingMetrics:
                     ("state_rows_live", self.state_rows, {"kind": "live"}),
                     ("state_resets", self.state_rows, {"kind": "reset"}),
                     ("attn_kv_full", self.attn_kv_positions, {"layers": "full"}),
-                    ("attn_kv_window", self.attn_kv_positions, {"layers": "window"})):
+                    ("attn_kv_window", self.attn_kv_positions, {"layers": "window"}),
+                    ("attn_kv_fetched", self.attn_kv_positions, {"layers": "fetched"})):
                 delta = totals.get(key, 0) - self._gp_last.get(key, 0)
                 if delta > 0:
                     counter.inc(delta, **label)

@@ -1,7 +1,8 @@
 """The windowed grouped-query layer kinds behind the engine, at a small size on the CPU (hidden 48, eight layers
 ``LLLG LLLG``, 8 query / 2 KV heads of 8, a window of 8, blocks of 4, chunks of 8, 4 experts held of 16): program
 against the plain reference (``bench/reference/exaone_moe.py``) through the two planes, float32 on both sides, logits
-and not tokens; the kernel's path beside the gathered one; slots reused, dead rows, preemption, and the doors.
+and not tokens; the kernel's path beside the gathered one; slots reused, dead rows, preemption, and the doors; what the
+full layers' walk by runs fetched, counted; and which layer kinds' step programs hold which walk of the table.
 
 Weights are drawn at std 0.14 = 1 / sqrt(hidden), so that projections of a normed input have the spread they have at
 the published widths (0.02 x sqrt(6144) = 1.57) and the logits a std of 1.
@@ -16,9 +17,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import hashlib
+
 from bench.harness import loader
 from paddlenlp_tpu.experimental import InferenceEngine
 from paddlenlp_tpu.experimental.engine import SamplingParams
+from paddlenlp_tpu.observability.tracer import TRACER
+from paddlenlp_tpu.ops.pallas import paged_run_attention
 from paddlenlp_tpu.transformers import ExaoneMoeConfig, ExaoneMoeForCausalLM
 
 L, G = "sliding_attention", "full_attention"
@@ -224,6 +229,138 @@ def test_launch_counts_and_ledger_totals(served):
         full += sum(s + n for s, n in feeds)
         window += sum(min(s, 7) + n for s, n in feeds)
     assert (t["attn_kv_full"], t["attn_kv_window"]) == (2 * full, 6 * window)
+
+
+RUN = 16  # positions a run of the walk below: four blocks of 4 (at the file's own 512 the table of 64 is one run)
+
+
+@pytest.fixture(scope="module")
+def served_by_runs(model):
+    """``served``'s requests through the kernels, the full layers walking runs of 16 positions (read as the step
+    programs are traced), with the launch spans the engine left."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(paged_run_attention, "_RUN_KEYS", RUN)
+        TRACER.clear()
+        ps = prompts(30, 21, 5)
+        eng, outs = served_by(model, True, ps)
+        launches = [s for s in TRACER.snapshot() if s.cat == "engine" and s.name in ("decode", "mixed_step")]
+    return eng, ps, outs, launches
+
+
+def test_the_walk_by_runs_serves_the_same_tokens_and_counts_what_it_fetched(served, served_by_runs):
+    """Mixed lengths (30, 21 and 5 prompt tokens, ten new each) through the walk by runs: the gathered path's tokens;
+    ``attn_kv_fetched`` is the arithmetic of the rows' lengths (a row that feeds n tokens from s fetches
+    ceil((s + n) / 16) runs of 16 positions in each of the 2 full layers), in the ledger's totals and, summed, in the
+    launch spans' args, and never below ``attn_kv_full`` in any launch: whole runs hold what a row may see."""
+    _, _, want = served
+    eng, ps, outs, launches = served_by_runs
+    assert [list(o) for o in outs] == [list(o) for o in want]
+    fetched = full = 0
+    for p, o in zip(ps, outs):
+        feeds = chunks_then_steps(len(p), len(p) + len(o) - 1)
+        fetched += sum(-(-(s + n) // RUN) * RUN for s, n in feeds)
+        full += sum(s + n for s, n in feeds)
+    t = eng.ledger.totals
+    assert (t["attn_kv_fetched"], t["attn_kv_full"]) == (2 * fetched, 2 * full)
+    assert launches and sum(s.args["attn_kv_fetched"] for s in launches) == t["attn_kv_fetched"]
+    assert all(s.args["attn_kv_fetched"] >= s.args["attn_kv_full"] > 0 for s in launches)
+    assert 1.0 < t["attn_kv_fetched"] / t["attn_kv_full"] < 1.5  # near what a row may see, not the table's 64 a feed
+    assert eng.efficiency()["ledger"]["totals"]["attn_kv_fetched"] == t["attn_kv_fetched"]  # /debug/efficiency
+
+
+def test_through_the_gather_the_whole_table_is_fetched(served):
+    """Without the kernel a full layer gathers its row's whole table, 16 blocks of 4, whatever the row holds."""
+    eng, ps, outs = served
+    feeds = sum(len(chunks_then_steps(len(p), len(p) + len(o) - 1)) for p, o in zip(ps, outs))
+    assert eng.ledger.totals["attn_kv_fetched"] == 2 * 64 * feeds > 2 * eng.ledger.totals["attn_kv_full"]
+
+
+# ------------------------------------------------------------------ which kinds hold which walk
+def kernel_calls(jaxpr, found):
+    """Every ``pallas_call`` of a traced program, through its scans and inner jits, in order."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    kernel_calls(inner, found)
+    return found
+
+
+def small_of(kind):
+    from paddlenlp_tpu.transformers import LlamaConfig, LlamaForCausalLM, NemotronHConfig, NemotronHForCausalLM
+    from test_state_serving import SMALL as STATE_SMALL  # beside this file: scan and expert blocks round two of attention
+
+    if kind == "llama":
+        return LlamaForCausalLM, LlamaConfig(vocab_size=97, hidden_size=48, intermediate_size=96, num_hidden_layers=2,
+                                             num_attention_heads=6, num_key_value_heads=2, head_dim=8)
+    if kind == "state":
+        return NemotronHForCausalLM, NemotronHConfig(**STATE_SMALL)
+    return ExaoneMoeForCausalLM, ExaoneMoeConfig(**SMALL)
+
+
+def traced(kind, program):
+    """A kind's step program traced with the kernels on, abstract weights: 4 slots, blocks of 4, tables of 16, one
+    chunk row of 8 in the mixed step."""
+    from paddlenlp_tpu.experimental.backend import samp_arrays
+    from paddlenlp_tpu.experimental.inference_model import inference_model_class
+
+    cls, cfg = small_of(kind)
+    m = cls(cfg, dtype=jnp.float32, param_dtype=jnp.float32)
+    m.params = m.param_shapes
+    infer = inference_model_class(cfg)(m, 4, 64, 16, dtype=jnp.float32, decode_steps=4, prefill_chunk_tokens=8,
+                                       max_batch_size=4, use_paged_kernel=True)
+    pool = jax.eval_shape(lambda: infer.init_pool(64, 4, jnp.float32))
+    table = (2, 16) if kind == "windowed" else (16,)
+    aval = jax.ShapeDtypeStruct
+    rows = lambda *shape: aval((4,) + shape, jnp.int32)
+    chunk = lambda *shape, dtype=jnp.int32: aval((1,) + shape, dtype)
+    samp = lambda n: jax.eval_shape(lambda: samp_arrays([None] * n, n))
+    if program == "decode":
+        return jax.make_jaxpr(infer._decode_impl)(m.params, pool, rows(), rows(*table), rows(), aval((4,), jnp.bool_),
+                                                  rows(), rows(97), samp(4))
+    return jax.make_jaxpr(infer._mixed_flat_impl)(
+        m.params, pool, chunk(8), chunk(*table), chunk(), chunk(), chunk(), chunk(dtype=jnp.bool_), rows(), rows(*table),
+        rows(), rows(), aval((4,), jnp.bool_), rows(97), samp(5))
+
+
+# sha256 of the text of the programs' walk-by-blocks kernel calls (kernel jaxpr, grid and index maps inside it) as the
+# tree before PR 36 traces them, from the same function run there. A change to ``ops/pallas/paged_attention.py`` or to
+# how the llama and state kinds (or the window layers) call it moves these: say so in PERF.md and pin anew
+WALK_BY_BLOCKS = {
+    ("llama", "decode"): "7f38493074bac93cfcf453c4e4b822512e09ca419e64aa5396d0773fb0a0b0ff",
+    ("llama", "mixed"): "07ac55077af46544576222f3fbcbb4de59631f9841354d2ba25c5ac2b3cf7ea3",
+    ("state", "decode"): "53e43a7466929ffeff73e1fea8144508447639cdf6e402e48aaf21408f506512",
+    ("state", "mixed"): "7f2d04e5507b3464cbbc3e5ad9ca1f2ea75f687e8af36778ab73c9f13e24a391",
+    ("windowed", "decode"): "7e017497791bfbceef1a19df663f3410a7306c166546b896bedbb40b4d71d1aa",  # its six window calls
+    ("windowed", "mixed"): "81ee4f3166a08ccb5e1e966a0b3a5d4efb37b4ac7699f1c226255791cd70140a",  # its twelve
+}
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed"])
+@pytest.mark.parametrize("kind", ["llama", "state", "windowed"])
+def test_the_layer_kind_chooses_the_walk_and_the_other_kinds_programs_are_the_parents(kind, program):
+    """The llama and state kinds' step programs hold the walk by blocks and nothing else: a grid whose innermost axis is
+    the table's width (16), and the very kernel calls the tree before PR 36 traced (``chat`` and ``shortchat`` run
+    these programs, so no metric of theirs can move). The windowed kinds' window layers hold it too, over a window's
+    steps (3 for a decode row, 5 for a chunk of 8), unchanged; their two full layers hold the walk by runs, a grid of
+    rows x head groups x query tiles without an axis of the table."""
+    calls = kernel_calls(traced(kind, program).jaxpr, [])
+    grids = [tuple(c.params["grid_mapping"].grid) for c in calls]
+    by_blocks = [c for c, g in zip(calls, grids) if len(g) == 4]
+    by_runs = [g for g in grids if len(g) == 3]
+    assert all(c.params["name"] == "ragged_paged_attention" for c in calls)
+    segments = 1 if program == "decode" else 2  # the mixed step: its chunk row, then its decode rows
+    if kind == "windowed":
+        assert len(by_blocks) == 6 * segments and {g[-1] for g in grids if len(g) == 4} <= {3, 5}
+        assert by_runs == ([(4, 1, 1)] * 2 if program == "decode" else [(1, 1, 1)] * 2 + [(4, 1, 1)] * 2)
+    else:
+        assert not by_runs and {g[-1] for g in grids} == {16}
+        assert len(by_blocks) == segments * (1 if kind == "llama" else 2)  # a scan's body, or two attention blocks
+    text = "\n".join(str(c) for c in by_blocks)
+    assert hashlib.sha256(text.encode()).hexdigest() == WALK_BY_BLOCKS[kind, program]
 
 
 @pytest.mark.parametrize("window", [7, 9])

@@ -11,6 +11,8 @@ blocks, 160-block tables) and Qwen2-0.5B training (14 / 2 heads, head_dim 64,
 4 rows of 2048: the benchmark's training cell); the step programs are the benchmark cell's (28 layers, 16
 slots, 192-block tables); the latent kernel's are ``longdoc``'s full layers
 (one chunk of 1,024, 128 heads, a 512 + 64 latent, a table of 1,056 blocks of
+16); the walk by runs and the windowed kinds' decode program are ``mixedlen``'s
+(64 query / 8 KV heads of 128, 16 slots, tables of 1,088 over 17,408 blocks of
 16). Nothing runs; a compile that passes says nothing about results or times.
 """
 
@@ -114,6 +116,26 @@ def test_ragged_paged_attention_window_call_compiles(chip, rows, tokens):
         return ragged_paged_attention(q, kv, tables, start, lens, layer, interpret=False, window=128)
 
     assert "tpu_custom_call" in compiled_text(attend, *avals)
+
+
+@pytest.mark.parametrize("rows,tokens", [(16, 1), (1, 1024)], ids=["decode16", "chunk1024"])
+def test_ragged_paged_run_attention_compiles_within_the_default_vmem(chip, rows, tokens):
+    """The full layers' walk by runs at the windowed kinds' cell sizes (64 query / 8 KV heads of 128, tables of 1,088
+    over the full layers' plane of 2 x 17,408 blocks, bf16): 16 decode rows take every KV head a step (two slots of a
+    run of 512 keys x 1,024 lanes of K and of V: 4 MiB), a chunk of 1,024 one head and 256 tokens a step (scores of
+    [2048, 512] float32); both inside the 16 MiB of scoped VMEM a kernel gets without asking, so the call asks for none."""
+    from paddlenlp_tpu.ops.pallas.paged_run_attention import ragged_paged_run_attention
+
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    avals = [aval((rows, tokens, 64, 128), jnp.bfloat16), aval((2, 2, 17408, 16, 8 * 128), jnp.bfloat16),
+             aval((rows, 1088), jnp.int32), aval((rows,), jnp.int32), aval((rows,), jnp.int32), aval((), jnp.int32)]
+
+    def attend(q, kv, tables, start, lens, layer):
+        return ragged_paged_run_attention(q, kv, tables, start, lens, layer, interpret=False)
+
+    text = compiled_text(attend, *avals)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert '"scoped_memory_configs":[]' in text  # no vmem_limit_bytes was asked for
 
 
 FLASH_SHAPES = {  # batch, tokens, query heads, kv heads, head_dim
@@ -376,16 +398,17 @@ def test_step_programs_copy_no_pool(chip, monkeypatch, program):
 
     pool_bytes = pool.kv.size * pool.kv.dtype.itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < 0.25 * pool_bytes
+    weights = {a.shape for a in jax.tree.leaves(model.params)}  # only ever read
+    assert_pool_sized_values_stay_in_place(compiled.as_text(), pool.kv.size // LAYERS, weights)
 
-    # every instruction with an output of one layer of pool or more either
-    # passes a buffer on or updates the donated pool in place (the bound on
-    # temporaries above is what says the update's operand is aliased)
-    text = compiled.as_text()
+
+def assert_pool_sized_values_stay_in_place(text, layer_elements, weights):
+    """Every instruction of the compiled program ``text`` with a bf16 output of one layer of pool or more
+    (``layer_elements``) either passes a buffer on or updates the donated pool in place (a bound on temporaries is
+    what says the update's operand is aliased); ``weights`` are shapes only ever read."""
     fusion_roots = dict(re.findall(
         r"^%?(fused_computation[\w.\-]*) [^\n]*\{\n(?:[^}][^\n]*\n)*?\s*ROOT %?[\w.\-]+ = \S+ ([a-z\-]+)\(",
         text, flags=re.M))
-    weights = {a.shape for a in jax.tree.leaves(model.params)}  # only ever read
-    layer_elements = pool.kv.size // LAYERS
     in_place = {"scatter", "dynamic-update-slice"}
     passed_on = {"parameter", "get-tuple-element", "bitcast"}
     found = set()
@@ -399,3 +422,45 @@ def test_step_programs_copy_no_pool(chip, monkeypatch, program):
         assert op in in_place | passed_on, f"{name}: a pool-sized {op} {shape}"
         found.add(op)
     assert found & in_place, "no write into the pool was found: the scan reads nothing"
+
+
+def test_the_windowed_kinds_decode_program_copies_no_plane(chip, monkeypatch):
+    """``mixedlen``'s decode program (K-EXAONE's eight layers at the cell's geometry, abstract weights, 16 slots,
+    tables of 1,088): the full layers' walk by runs takes the donated plane as it lies in HBM (``pl.ANY``, copied
+    from by hand), so nothing of a plane's size is made round the call: the program's temporaries stay under half
+    the full layers' plane (886 MiB of 2,176, to the MiB what the walk by blocks left), and every value of a
+    layer of that plane or more is the plane passed on or updated in place."""
+    import json
+    import os
+
+    from bench.harness import common
+    from paddlenlp_tpu.experimental.backend import samp_arrays
+    from paddlenlp_tpu.experimental.inference_model import inference_model_class
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "bench", "configs", "k-exaone-serve-ep16.json")) as f:
+        config = json.load(f)
+    engine = config["bench"]["engine"]
+    cfg, make = common.build_model(config, jnp.bfloat16, jnp.bfloat16)
+    model = make()
+    model.params = model.param_shapes  # shapes only: there is no device to hold arrays
+    # the kernels ask jax.default_backend() whether to interpret; a described chip does not change that answer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, table = engine["max_batch_size"], engine["max_blocks_per_seq"]
+    infer = inference_model_class(cfg)(model, engine["block_size"], engine["num_blocks"], table, dtype=jnp.bfloat16,
+                                       decode_steps=engine["decode_steps"], max_batch_size=slots,
+                                       prefill_chunk_tokens=engine["prefill_chunk_tokens"])
+    assert infer.use_paged_kernel and (infer.n_full, infer.n_window) == (2, 6)
+    on_chip = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree)
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    pool = on_chip(jax.eval_shape(lambda: infer.init_pool(engine["num_blocks"], engine["block_size"], jnp.bfloat16)))
+    rows = lambda *shape: aval((slots,) + shape, jnp.int32)
+    args = (on_chip(model.params), pool, rows(), rows(2, table), rows(), aval((slots,), jnp.bool_), rows(),
+            rows(cfg.vocab_size), on_chip(jax.eval_shape(lambda: samp_arrays([None] * slots, slots))))
+    compiled = jax.jit(infer._decode_impl, donate_argnums=(1,)).lower(*args).compile()
+
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 8  # one kernel a layer (the stack is unrolled; the sub-steps' scan compiles its body once)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * pool.kv.size * pool.kv.dtype.itemsize
+    weights = {a.shape for a in jax.tree.leaves(model.params)}
+    assert_pool_sized_values_stay_in_place(text, pool.kv.size // infer.n_full, weights)
