@@ -146,6 +146,13 @@ class Request:
     # ... decode-window seconds spent riding mixed steps that also carried
     # other requests' prefill chunks (the per-request decode-stall share) ...
     chunk_stall_s: float = 0.0
+    # ... the mirror of it before the first token (_launch books both at the
+    # end of every launch): launches that carried this request's prompt tokens
+    # and their seconds, and the seconds of launches it sat in a slot behind
+    # while they carried none of its prompt ...
+    prefill_steps: int = 0
+    prefill_own_s: float = 0.0
+    prefill_behind_s: float = 0.0
     # ... and seconds spent waiting for prefill->decode block migration
     # (accumulated on land; migrate_start_t marks an episode still open)
     migration_wait_s: float = 0.0
@@ -1080,12 +1087,28 @@ class InferenceEngine:
         return finished
 
     @contextlib.contextmanager
-    def _launch(self, name: str, program: str, **args):
+    def _launch(self, name: str, program: str, carried=(), pending=(), **args):
         """One backend call: the launch span (mirrored to the profiler; what
         the launch was asked to do joins its args once the backend has stamped
         it) under compile attribution. The span's own duration is the step
-        anatomy's device time: anatomy and span are one measurement."""
-        span = TRACER.span(name, cat="engine", step=self._cur_step, **args)  # span-names: prefill decode mixed_step spec_verify
+        anatomy's device time: anatomy and span are one measurement.
+
+        ``carried`` are the requests whose prompt tokens ride in the launch,
+        ``pending`` those admitted with them that no slot holds yet (the later
+        buckets of a monolithic prefill batch). The span says whose prompt it
+        carried (``carried``: their req_ids) and how many admitted requests
+        still need prefill and are not in it (``prefill_waiting``: what a
+        second chunk row would have served); at its end every admitted request
+        without a first token is booked the launch's duration, as its own or
+        as another's (``Request.prefill_own_s`` / ``prefill_behind_s``)."""
+        ids = [r.req_id for r in carried]
+        # a promotion copy in flight is promote_wait's: no launch could carry it
+        others = [r for r in (*self.slots, *pending)
+                  if r is not None and r.req_id not in ids and r.kv_stage != "promoting"]
+        span = TRACER.span(name, cat="engine", step=self._cur_step,  # span-names: prefill decode mixed_step spec_verify
+                           carried=ids,
+                           prefill_waiting=sum(1 for r in others if r.needs_prefill),
+                           **args)
         try:
             with span, compile_attribution(self.ledger, program):
                 yield span
@@ -1094,6 +1117,15 @@ class InferenceEngine:
                 span.set(**{g: acct[g] for g in KIND_COUNTERS if g in acct})
         finally:
             self._step_device_s += span.dur
+            # a request preempted after its first token is carried again and
+            # books nothing: its time to first token is over
+            for req in carried:
+                if req.first_token_t is None:
+                    req.prefill_steps += 1
+                    req.prefill_own_s += span.dur
+            for req in others:
+                if req.first_token_t is None:
+                    req.prefill_behind_s += span.dur
 
     def _free_slot_indices(self) -> List[int]:
         return [i for i, r in enumerate(self.slots) if r is None]
@@ -1445,7 +1477,11 @@ class InferenceEngine:
         for slot, req, n_cached in admitted:
             by_bucket.setdefault(_bucket(len(req.prompt_ids) - n_cached),
                                  []).append((slot, req, n_cached))
-        for padded, group in by_bucket.items():
+        groups = list(by_bucket.values())
+        for g, (padded, group) in enumerate(by_bucket.items()):
+            carried = [req for _, req, _ in group]
+            # the later buckets' requests wait out this launch in no slot yet
+            pending = [req for later in groups[g + 1:] for _, req, _ in later]
             with TRACER.span("launch_build", cat="engine", step=self._cur_step,
                              program="prefill"):
                 n = _bucket(len(group), minimum=1)
@@ -1468,8 +1504,8 @@ class InferenceEngine:
                 extra = ({"adapter_table": [r.adapter_slot for _, r, _ in group]}
                          if self.adapter_registry is not None else {})
             # the per-request prefill spans join this launch on step=
-            with self._launch("prefill", "prefill", bucket=padded, batch=len(group),
-                              cached_tokens=cached_total):
+            with self._launch("prefill", "prefill", carried=carried, pending=pending,
+                              bucket=padded, batch=len(group), cached_tokens=cached_total):
                 tokens = self.backend.prefill(
                     ids, tables, suffix_lens, entries, sampling,
                     [slot for slot, _, _ in group], **extra)
@@ -1628,7 +1664,8 @@ class InferenceEngine:
                 return
             chunk_rows, decode_rows, chunk_payload, dec_payload = rows
         t0 = time.perf_counter()
-        with self._launch("mixed_step", "mixed", chunks=len(chunk_rows), decodes=len(decode_rows),
+        with self._launch("mixed_step", "mixed", carried=[req for _, req, _ in chunk_rows],
+                          chunks=len(chunk_rows), decodes=len(decode_rows),
                           chunk_tokens=int(sum(n for _, _, n in chunk_rows))):
             tokens = self.backend.mixed_step(chunk_payload, dec_payload)
         acct = self.backend.step_accounting

@@ -60,6 +60,16 @@ def _sync_dropped_counter(registry, tracer: SpanTracer):
             counter.inc(delta)
 
 
+def since_ts_cursor(query: dict) -> Optional[float]:
+    """The ``?since_ts=<epoch seconds>`` cursor of a parsed query string (None
+    when absent); ValueError, worded for the 400 body, when it is no number."""
+    raw = query.get("since_ts", [None])[0]
+    try:
+        return float(raw) if raw is not None else None
+    except ValueError:
+        raise ValueError(f"since_ts must be a number, got {raw!r}") from None
+
+
 def route_observability(path: str, registry, tracer: SpanTracer):
     """Shared GET routing for the observability surface: returns
     ``(status, content_type, body_bytes)`` or None for unknown paths. All three
@@ -84,12 +94,10 @@ def route_observability(path: str, registry, tracer: SpanTracer):
         return 200, PROMETHEUS_CONTENT_TYPE, registry.expose().encode()
     if route in ("/debug/trace", "/debug/spans"):
         trace = query.get("trace", [None])[0]
-        since_raw = query.get("since_ts", [None])[0]
         try:
-            since_ts = float(since_raw) if since_raw is not None else None
-        except ValueError:
-            return (400, "application/json",
-                    json.dumps({"error": f"since_ts must be a number, got {since_raw!r}"}).encode())
+            since_ts = since_ts_cursor(query)
+        except ValueError as e:
+            return 400, "application/json", json.dumps({"error": str(e)}).encode()
         spans = tracer.snapshot(since_ts=since_ts, trace=trace)
         if route == "/debug/trace":
             doc = tracer.chrome_trace(spans)

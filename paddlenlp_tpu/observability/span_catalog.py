@@ -27,7 +27,7 @@ SPAN_CATALOG = {
     "admission": "waiting->slot binding + KV allocation for one engine step, kept only when something was admitted or rejected (also the scheduler-side admission span, cat=scheduler)",
     "prefix_cache": "prefix-cache match/COW bookkeeping + owed device block copies during admission",
     "launch_build": "host work before a backend call: capacity pass, numpy tables and inputs of the launch (program=prefill|decode|mixed|verify)",
-    "prefill": "the backend call of one batched monolithic prompt prefill, one span per padded suffix-length bucket, launch geometry in its args (also the retrospective per-request prefill phase)",
+    "prefill": "the backend call of one batched monolithic prompt prefill, one span per padded suffix-length bucket, launch geometry in its args (also the retrospective per-request prefill phase, admission -> first token, whose args steps / own_ms / behind_ms split it into the request's own launches, the launches it sat behind and, the span less both, host time)",
     "mixed_step": "the backend call of one ragged mixed prefill-chunk + decode forward (chunked prefill), launch geometry in its args",
     "decode": "the backend call of the multi-token decode jit over all running slots, launch geometry in its args (also the retrospective per-request decode phase)",
     "dispatch": "child of a launch span: host arrays to the device and the jit call returning (program=...)",
@@ -108,8 +108,10 @@ DEVICE_SCOPES = {
 
 #: args a launch span (``prefill`` / ``decode`` / ``mixed_step`` / ``spec_verify``) carries once the
 #: launch has returned: the geometry (``goodput.LAUNCH_GEOMETRY``) and, from programs whose layers
-#: count them, ``goodput.KIND_COUNTERS``
+#: count them, ``goodput.KIND_COUNTERS``; ``carried`` and ``prefill_waiting`` are there from its start
 LAUNCH_ARGS = {
+    "carried": "req_ids of the requests whose prompt tokens ride in the launch: a prefill's group, a mixed step's chunk rows, none on decode and spec_verify",
+    "prefill_waiting": "admitted requests that still need prefill and are not carried: the backlog a second chunk row would have served",
     "rows_live": "rows that fed at least one real token", "rows": "padded rows of the launch",
     "kv_positions": "cached positions the live rows' attention had to cover",
     "index_candidates": "cached positions the sparse indexer scored for live queries, over full layers (device count)",

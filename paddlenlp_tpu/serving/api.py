@@ -65,15 +65,17 @@ import os
 import threading
 from http.server import ThreadingHTTPServer
 from typing import Dict, Optional
+from urllib.parse import parse_qs, urlsplit
 
-from ..observability.exporter import handle_profile_request, route_observability
+from ..observability.exporter import handle_profile_request, route_observability, since_ts_cursor
 from ..observability.postmortem import handle_postmortem_request
 from ..observability.tracer import TRACEPARENT_HEADER, TRACER, parse_traceparent, use_trace
 from ..utils.env import enable_compile_cache
 from ..utils.faults import FaultPoint
 from ..utils.log import logger
 from .chat import ChatTemplate
-from .engine_loop import CANARY_PROMPT_IDS, EngineLoop, RequestHandle, ServingMetrics, SupervisorPolicy
+from .engine_loop import (CANARY_PROMPT_IDS, EngineLoop, RequestHandle, ServingMetrics,
+                          SupervisorPolicy, ttft_tail)
 from .httputil import JsonRequestHandler
 from .metrics import REGISTRY, MetricsRegistry
 from .brownout import PRIORITIES
@@ -337,6 +339,20 @@ class ServingServer:
         self.scheduler.stop_drain()
         return {"draining": False}
 
+    def debug_requests(self, path: str = "/debug/requests") -> dict:
+        """The ``GET /debug/requests`` document: the in-flight view, the tail
+        of finished requests and ``ttft_tail``, where the worst tenth of them
+        spent their time to first token. ``?since_ts=<epoch seconds>`` keeps
+        the finished requests submitted at or after it (a ``request`` span's
+        start, so the cursor means what it does on ``/debug/spans``): one load
+        window's requests without the warm-up's."""
+        recent = list(self.loop.recent_finished)
+        since_ts = since_ts_cursor(parse_qs(urlsplit(path).query))  # ValueError: the handler's 400
+        if since_ts is not None:
+            recent = [r for r in recent if r["arrival_t"] >= since_ts]
+        return {"inflight": self.loop.inflight_info(), "recent": recent,
+                "ttft_tail": ttft_tail(recent)}
+
     def efficiency(self) -> dict:
         """The ``GET /debug/efficiency`` document: the live engine's goodput
         ledger + step anatomy (the loop swaps engines on rebuild, so this
@@ -587,11 +603,11 @@ class ServingServer:
                             # router's RTT-midpoint clock-skew estimate
                             "now": server.tracer.now(),
                         }, headers=headers)
-                    elif self.path == "/debug/requests":
-                        self._send_json(200, {
-                            "inflight": server.loop.inflight_info(),
-                            "recent": list(server.loop.recent_finished),
-                        })
+                    elif self.path.split("?", 1)[0] == "/debug/requests":
+                        try:
+                            self._send_json(200, server.debug_requests(self.path))
+                        except ValueError as e:
+                            self._send_json(400, {"error": str(e)})
                     elif self.path == "/debug/efficiency":
                         self._send_json(200, server.efficiency())
                     elif self.path == "/debug/usage":

@@ -51,6 +51,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import itertools
+import math
 import queue
 import threading
 import time
@@ -69,21 +70,24 @@ from .tenancy.metering import UsageMeter
 from .tenancy.quotas import DEFAULT_TENANT
 
 __all__ = ["EngineLoop", "RequestHandle", "ServingMetrics", "SupervisorPolicy",
-           "ATTRIBUTION_PHASES", "request_attribution", "canary_digest",
-           "CANARY_PROMPT_IDS"]
+           "ATTRIBUTION_PHASES", "request_attribution", "ttft_tail",
+           "TTFT_TAIL_PARTS", "canary_digest", "CANARY_PROMPT_IDS"]
 
 #: the per-request latency-attribution phase vocabulary. Non-overlapping by
 #: construction: inbox (submission on the HTTP thread -> the loop thread put it
 #: on the engine's waiting queue: the time on the command inbox while the loop
 #: was inside a step) + queue + admission_gate span submission -> first
 #: admission, the admission -> first-token window splits into promote_wait (waiting on a
-#: host-tier KV promotion copy) + prefill remainder, and the decode window
+#: host-tier KV promotion copy) + prefill_behind (launches that carried none of
+#: this request's prompt: others' chunks, the earlier buckets of its prefill
+#: batch) + prefill remainder (its own launches and the host time between
+#: launches), and the decode window
 #: (first token -> finish) splits into chunk_stall + migration_wait + decode
 #: remainder — so the phases always sum to e2e exactly when the timeline is
-#: complete. The router adds an eighth phase, ``hedge_race``, to the same
+#: complete. The router adds one more phase, ``hedge_race``, to the same
 #: histogram family for its first-token races.
-ATTRIBUTION_PHASES = ("inbox", "queue", "admission_gate", "promote_wait", "prefill",
-                      "chunk_stall", "migration_wait", "decode")
+ATTRIBUTION_PHASES = ("inbox", "queue", "admission_gate", "promote_wait", "prefill_behind",
+                      "prefill", "chunk_stall", "migration_wait", "decode")
 
 
 def request_attribution(req) -> Optional[Dict[str, float]]:
@@ -125,8 +129,10 @@ def request_attribution(req) -> Optional[Dict[str, float]]:
             # flight: the open episode ends at the prefill window's end
             promote += max(end_prefill - open_promote, 0.0)
         promote = min(promote, prefill_raw)
+        behind = min(max(getattr(req, "prefill_behind_s", 0.0), 0.0), prefill_raw - promote)
         out["promote_wait"] = promote
-        out["prefill"] = prefill_raw - promote
+        out["prefill_behind"] = behind
+        out["prefill"] = prefill_raw - promote - behind
     if first is not None:
         # a request requeued across an engine rebuild keeps its first token's
         # instant, which then predates its last admission: that stretch is in
@@ -144,6 +150,49 @@ def request_attribution(req) -> Optional[Dict[str, float]]:
         out["migration_wait"] = mig
         out["decode"] = decode_raw - stall - mig
     return out
+
+
+#: what a time to first token is made of, in ``ttft_tail``'s shares: the
+#: attribution phases before the first token, with ``prefill`` cut at the
+#: request's own launches (``prefill_own``) and the rest (``prefill_host``:
+#: the host time between launches, build and emit)
+TTFT_TAIL_PARTS = ATTRIBUTION_PHASES[:ATTRIBUTION_PHASES.index("prefill")] + ("prefill_own", "prefill_host")
+
+
+#: finished-request rows kept for /debug/requests and postmortem bundles: more
+#: than a load-test window of short requests finishes (108 in 45 s at 2.4/s)
+RECENT_FINISHED = 256
+
+
+def ttft_tail(rows) -> Dict:
+    """Where the worst tenth's time to first token went: over finished-request
+    rows (``EngineLoop.recent_finished``, a postmortem bundle's
+    ``health.recent_finished``) the worst ceil(n / 10) by server-side TTFT,
+    which is a p90 as a load test reads it: their count, their least TTFT,
+    their mean launches of their own, and the share (%) of their summed TTFT
+    under each of ``TTFT_TAIL_PARTS``. Rows without a first token or an
+    attribution are left out; no row gives an empty block."""
+    rows = [r for r in rows if r.get("ttft_s") is not None and r.get("attribution")]
+    if not rows:
+        return {}
+    tail = sorted(rows, key=lambda r: r["ttft_s"])[-math.ceil(len(rows) / 10):]
+    parts = dict.fromkeys(TTFT_TAIL_PARTS, 0.0)
+    for row in tail:
+        attribution = row["attribution"]
+        own = min(max(row.get("prefill_own_s") or 0.0, 0.0), attribution.get("prefill", 0.0))
+        for part in TTFT_TAIL_PARTS[:-2]:
+            parts[part] += attribution.get(part, 0.0)
+        parts["prefill_own"] += own
+        parts["prefill_host"] += attribution.get("prefill", 0.0) - own
+    total = sum(parts.values())
+    return {
+        "requests": len(rows),
+        "count": len(tail),
+        "ttft_min_ms": tail[0]["ttft_s"] * 1e3,
+        "ttft_sum_ms": total * 1e3,
+        "steps_mean": sum(r.get("prefill_steps") or 0 for r in tail) / len(tail),
+        "share": {part: 100.0 * v / total if total else 0.0 for part, v in parts.items()},
+    }
 
 _END = object()  # token-queue sentinel: stream closed
 
@@ -435,8 +484,9 @@ class ServingMetrics:
         self.latency_attribution = r.histogram(
             "paddlenlp_serving_latency_attribution_seconds",
             "Per-request e2e latency decomposed by phase (inbox/queue/"
-            "admission_gate/promote_wait/prefill/chunk_stall/migration_wait/"
-            "decode on replicas; hedge_race on the router) — phases sum to e2e",
+            "admission_gate/promote_wait/prefill_behind/prefill/chunk_stall/"
+            "migration_wait/decode on replicas; hedge_race on the router) — "
+            "phases sum to e2e",
             labelnames=("phase",))
         self.ttft = r.histogram(
             "paddlenlp_serving_ttft_seconds", "Time from submission to first token")
@@ -900,7 +950,7 @@ class EngineLoop:
         self._default_queue_wait_s = 0.05
         # /debug/requests tail: finished-request summaries (appended only on
         # the loop thread; deque ops are atomic so HTTP readers need no lock)
-        self.recent_finished: deque = deque(maxlen=64)
+        self.recent_finished: deque = deque(maxlen=RECENT_FINISHED)
         # live weight-swap state: the version string this replica serves
         # (reported on /health; the rollout orchestrator's convergence check),
         # the swap currently quiescing, and submissions held while it does.
@@ -1708,9 +1758,15 @@ class EngineLoop:
             phases["prefill"] = (req.sched_t, req.first_token_t)
         if req.first_token_t is not None and req.finish_t is not None:
             phases["decode"] = (req.first_token_t, req.finish_t)
+        # the prefill span says what it is made of: its own launches, the
+        # launches it sat behind, and (the span less both) host time between
+        # launches — ttft_tail sums the same split over the worst tenth
+        own_s = getattr(req, "prefill_own_s", 0.0)
+        split = {"prefill": dict(steps=getattr(req, "prefill_steps", 0), own_ms=own_s * 1e3,
+                                 behind_ms=getattr(req, "prefill_behind_s", 0.0) * 1e3)}
         for name, (t0, t1) in phases.items():
             TRACER.add_span(name, t0, t1 - t0, cat="request", trace=trace,  # span-names: queue prefill decode
-                            wall=True, **meta)
+                            wall=True, **meta, **split.get(name, {}))
         if req.finish_t is not None:
             TRACER.add_span("request", req.arrival_t, req.finish_t - req.arrival_t,
                             cat="request", trace=trace, wall=True,
@@ -1755,6 +1811,8 @@ class EngineLoop:
             "decode_time_s": req.decode_time,
             "finish_t": req.finish_t,
             "attribution": attribution,
+            "prefill_steps": getattr(req, "prefill_steps", 0),
+            "prefill_own_s": own_s,
             "usage": None if usage_record is None else {
                 k: usage_record[k]
                 for k in ("prompt_tokens", "cached_tokens", "completion_tokens",
@@ -1850,6 +1908,7 @@ class EngineLoop:
         """Bundle health snapshot: loop + scheduler-visible state, engine
         stats, the in-flight view and the finished tail (which carries each
         request's latency attribution — the offline analyzer reads it)."""
+        recent = list(self.recent_finished)
         return {
             "loop_state": self._state,
             "phase": self._phase,
@@ -1858,7 +1917,8 @@ class EngineLoop:
             "slot_quarantines": self.slot_quarantines,
             "engine": self.engine.stats(),
             "inflight": self.inflight_info(),
-            "recent_finished": list(self.recent_finished),
+            "recent_finished": recent,
+            "ttft_tail": ttft_tail(recent),
             "usage": self.usage.snapshot(),
         }
 

@@ -90,7 +90,7 @@ class TestAttributionParity:
             # parity with the pre-existing request timing fields
             assert attr["inbox"] + attr["queue"] + attr["admission_gate"] == \
                 pytest.approx(row["queue_wait_s"], rel=0.05, abs=1e-6)
-            assert attr["prefill"] == \
+            assert attr["promote_wait"] + attr["prefill_behind"] + attr["prefill"] == \
                 pytest.approx(row["ttft_s"] - row["queue_wait_s"], rel=0.05, abs=1e-6)
             assert attr["chunk_stall"] + attr["migration_wait"] + attr["decode"] == \
                 pytest.approx(row["decode_time_s"], rel=0.05, abs=1e-6)

@@ -206,6 +206,13 @@ def _summary(bundles: List[Dict]) -> List[str]:
         top = _top_tenant_line(b)
         if top is not None:
             lines.append(f"  {top}")
+        tail = health.get("ttft_tail") or {}
+        if tail:
+            # where the worst tenth's time to first token went (the replica
+            # sums it over its finished tail: engine_loop.ttft_tail)
+            shares = " ".join(f"{k}={v:.1f}%" for k, v in tail["share"].items())
+            lines.append(f"  ttft tail: worst {tail['count']} of {tail['requests']} from "
+                         f"{tail['ttft_min_ms']:.1f}ms, {tail['steps_mean']:.1f} steps: {shares}")
     return lines
 
 
