@@ -34,9 +34,14 @@ preemption history, speculative acceptance) the backend deliberately does
 not have.
 
 Each entry point records two child spans of the engine's launch span:
-``dispatch`` (host arrays to the device and the jit call returning) and
-``wait`` (the ``np.asarray`` sync point), mirrored to the profiler like every
-live engine span.
+``dispatch`` and ``wait`` (the ``np.asarray`` sync point), mirrored to the
+profiler like every live engine span. ``dispatch`` holds ONE host-to-device
+transfer and the jit call returning: every host array the launch takes (ids,
+block tables, lengths, flags, the rows' sampling parameters, adapter rows)
+rides one packed buffer (launch_pack.py) that the step program takes apart, so
+a launch pays one transfer where it paid 13 to 19. The span's ``h2d_arrays`` /
+``h2d_bytes`` args say what crossed inside it: 1 and the buffer's bytes, one
+more array where a prefix hit ships its cached counts.
 
 External weight updates (serving epochs, PPO rollouts) flow through the
 ``params`` property: callers rebind ``model.params`` and the backend picks it
@@ -72,6 +77,7 @@ touches the device — it only polls tickets).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Callable, List, Optional, Sequence, Tuple
 
@@ -80,8 +86,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..observability.tracer import TRACER
-from .inference_model import PagedInferenceModel, inference_model_class
+from .inference_model import SAMP_FIELDS, PagedInferenceModel, inference_model_class
 from .kv_host_tier import HostPromoteTicket, gather_blocks, scatter_blocks
+from .launch_pack import pack
 from .paged_cache import PagedKVPool, copy_blocks
 
 __all__ = ["ModelBackend", "SingleDeviceBackend", "MixedRow", "samp_arrays",
@@ -100,25 +107,25 @@ def launch_geometry(rows: int, q_lens, kv_lens) -> dict:
             "kv_positions": int(kv_lens[live].sum())}  # sync-ok: host numpy
 
 
+#: SamplingParams attribute, padding-row default and dtype of each of ``SAMP_FIELDS``
+_SAMP_SOURCE = dict(zip(SAMP_FIELDS, (
+    ("seed", 0, np.int32), ("temperature", 1.0, np.float32), ("top_k", 0, np.int32), ("top_p", 1.0, np.float32),
+    ("do_sample", False, np.bool_), ("repetition_penalty", 1.0, np.float32), ("presence_penalty", 0.0, np.float32),
+    ("frequency_penalty", 0.0, np.float32))))
+
+
 def samp_arrays(sampling: Sequence, n: Optional[int] = None):
-    """Per-row sampling-parameter arrays for the device kernels.
+    """Per-row sampling-parameter arrays for the device kernels, as host
+    arrays: they ride the launch's packed buffer (``SAMP_FIELDS`` by name).
 
     ``sampling`` holds SamplingParams-shaped objects (duck-typed) or None for
     padding rows; ``n`` pads/truncates to a fixed row count."""
     rows = list(sampling)
     if n is not None:
         rows = (rows + [None] * n)[:n]
-    get = lambda f, d: np.asarray([getattr(s, f) if s is not None else d for s in rows])
-    return dict(
-        seeds=jnp.asarray(get("seed", 0), jnp.int32),
-        temperature=jnp.asarray(get("temperature", 1.0), jnp.float32),
-        top_k=jnp.asarray(get("top_k", 0), jnp.int32),
-        top_p=jnp.asarray(get("top_p", 1.0), jnp.float32),
-        do_sample=jnp.asarray(get("do_sample", False), bool),
-        repetition_penalty=jnp.asarray(get("repetition_penalty", 1.0), jnp.float32),
-        presence_penalty=jnp.asarray(get("presence_penalty", 0.0), jnp.float32),
-        frequency_penalty=jnp.asarray(get("frequency_penalty", 0.0), jnp.float32),
-    )
+    # astype, not a dtype argument: a seed past 31 bits wraps, as it did when the device converted it
+    return {name: np.asarray([getattr(s, attr) if s is not None else default for s in rows]).astype(dtype)  # sync-ok: a list of host scalars
+            for name, (attr, default, dtype) in _SAMP_SOURCE.items()}
 
 
 @dataclasses.dataclass
@@ -260,6 +267,8 @@ class SingleDeviceBackend(ModelBackend):
         # read by a kind whose mixed program has one fixed shape (see _build_infer)
         self.prefill_chunk_tokens = prefill_chunk_tokens
         self.step_accounting = {"fed": 0, "shape": ()}
+        # host arrays handed to the device since the last dispatch began (_to_device)
+        self._h2d_arrays = self._h2d_bytes = 0
         # multi-LoRA: with a registry attached, EVERY step passes the device
         # adapter pool + a per-row slot index (identity rows gather slot 0's
         # zeros) — one program serves mixed adapter/no-adapter batches. No
@@ -325,7 +334,8 @@ class SingleDeviceBackend(ModelBackend):
         return self._lora_dev
 
     def _adapter_idx(self, adapter_table, n: int):
-        """Per-row pool-slot indices for an n-row launch (None -> identity).
+        """Per-row pool-slot indices for an n-row launch (None -> identity), a
+        host array for the launch's buffer; None without a registry.
         Raises when adapters are requested without a registry attached — a
         scheduler bug that must not silently serve base-model tokens."""
         if adapter_table is None:
@@ -338,7 +348,7 @@ class SingleDeviceBackend(ModelBackend):
                 raise ValueError("adapter_table has non-identity rows but the "
                                  "backend has no adapter_registry")
             return None
-        return jnp.asarray(idx)
+        return idx
 
     # ---------------------------------------------------------------- counts
     def _cached_counts(self, cached_entries, n_rows: int) -> jnp.ndarray:
@@ -360,7 +370,7 @@ class SingleDeviceBackend(ModelBackend):
                     minlength=vocab)[:vocab]
         if counts_in is None:
             return jnp.zeros((n_rows, vocab), jnp.int32)
-        return jnp.asarray(counts_in)
+        return self._to_device(counts_in)
 
     def seed_counts(self, slot_idx, cached_entries):
         # one shape whatever the group's size: the index is padded past the last slot and those rows are dropped,
@@ -374,25 +384,57 @@ class SingleDeviceBackend(ModelBackend):
         self.counts = jnp.zeros_like(self.counts)
 
     # ---------------------------------------------------------------- steps
+    def _place_launch(self, host):
+        """Start the H2D transfer of a launch's host input (the sharded
+        backend lands it replicated on its mesh)."""
+        return jax.device_put(host)
+
+    def _to_device(self, host):
+        """THE transfer call of the step entry points: every host array a
+        launch hands to the device goes through here and is counted on the
+        launch's ``dispatch`` span."""
+        self._h2d_arrays += 1
+        self._h2d_bytes += host.nbytes
+        return self._place_launch(host)
+
+    @contextlib.contextmanager
+    def _dispatch(self, program: str):
+        """A launch's ``dispatch`` span, stamped with what crossed to the
+        device inside it (``h2d_arrays``, ``h2d_bytes``)."""
+        self._h2d_arrays = self._h2d_bytes = 0
+        with TRACER.span("dispatch", cat="engine", program=program) as span:
+            yield
+            span.set(h2d_arrays=self._h2d_arrays, h2d_bytes=self._h2d_bytes)
+
+    def _send(self, **fields):
+        """One launch's host inputs as one packed buffer on the device, and its
+        layout. A field that is None is left out: the adapter rows without a
+        registry attached (``_adapter_idx``)."""
+        buf, layout = pack({k: v for k, v in fields.items() if v is not None})
+        return self._to_device(buf), layout
+
     def prefill(self, input_ids, block_tables, suffix_lens, cached_entries,
                 sampling, slot_idx, adapter_table=None) -> np.ndarray:
         n = input_ids.shape[0]
         cached_lens = np.zeros(n, np.int32)
         for row, _ids, n_cached in cached_entries:
             cached_lens[row] = n_cached
+        suffix_lens = np.asarray(suffix_lens, np.int32)  # sync-ok: suffix_lens is host numpy
         self.step_accounting = dict(
             {"fed": n * input_ids.shape[1], "shape": ("prefill", n, input_ids.shape[1])},
-            **launch_geometry(n, suffix_lens, cached_lens + np.asarray(suffix_lens)))  # sync-ok: suffix_lens is host numpy
-        with TRACER.span("dispatch", cat="engine", program="prefill"):
+            **launch_geometry(n, suffix_lens, cached_lens + suffix_lens))
+        # the batch's count rows land at their slots inside the program: the
+        # index is padded past the last slot, and those rows are dropped
+        slots = np.full(n, self.counts.shape[0], np.int32)
+        slots[: len(slot_idx)] = slot_idx
+        with self._dispatch("prefill"):
             counts_dev = self._cached_counts(cached_entries, n)
-            tokens, counts_rows, self.pool = self.infer.prefill(
-                self.params, self.pool, jnp.asarray(input_ids), jnp.asarray(block_tables),
-                jnp.asarray(suffix_lens), jnp.asarray(cached_lens), counts_dev,
-                samp_arrays(sampling, n),
-                lora=self._lora_tree(), adapter_idx=self._adapter_idx(adapter_table, n),
-            )
-            self.counts = self.counts.at[jnp.asarray(np.asarray(slot_idx))].set(  # sync-ok: slot_idx is a host int list
-                counts_rows[: len(slot_idx)])
+            packed, layout = self._send(
+                input_ids=np.asarray(input_ids, np.int32), block_tables=np.asarray(block_tables, np.int32),  # sync-ok: host numpy
+                suffix_lens=suffix_lens, cached_lens=cached_lens, slot_idx=slots,
+                **samp_arrays(sampling, n), adapter_idx=self._adapter_idx(adapter_table, n))
+            tokens, self.counts, self.pool = self.infer.prefill(
+                self.params, self.pool, packed, layout, counts_dev, self.counts, lora=self._lora_tree())
         with TRACER.span("wait", cat="engine", program="prefill"):
             return np.asarray(tokens)  # sync-ok: THE prefill sync point — sampled int32 ids only
 
@@ -404,13 +446,14 @@ class SingleDeviceBackend(ModelBackend):
         acct = dict({"fed": B * steps, "shape": ("decode", B, steps)},
                     **launch_geometry(B, live, live * (ctx + 1)))
         self.step_accounting = acct
-        with TRACER.span("dispatch", cat="engine", program="decode"):
+        with self._dispatch("decode"):
+            packed, layout = self._send(
+                tokens=np.asarray(last_tokens, np.int32), block_tables=np.asarray(block_tables, np.int32),  # sync-ok: host numpy
+                context_lens=ctx.astype(np.int32), done0=~live,
+                remaining=np.asarray(remaining, np.int32),  # sync-ok: remaining is host numpy
+                **samp_arrays(sampling, len(sampling)), adapter_idx=self._adapter_idx(adapter_table, B))
             toks, valid, _, _, self.counts, self.pool = self.infer.decode(
-                self.params, self.pool, jnp.asarray(last_tokens), jnp.asarray(block_tables),
-                jnp.asarray(context_lens), jnp.asarray(done0), jnp.asarray(remaining),
-                self.counts, samp_arrays(sampling, len(sampling)),
-                lora=self._lora_tree(), adapter_idx=self._adapter_idx(adapter_table, B),
-            )
+                self.params, self.pool, packed, layout, self.counts, lora=self._lora_tree())
         with TRACER.span("wait", cat="engine", program="decode"):
             toks, valid = np.asarray(toks), np.asarray(valid)  # sync-ok: THE decode sync point — int32 ids + validity flags only
         # what the launch really read is known only now: a row still emitting
@@ -432,14 +475,13 @@ class SingleDeviceBackend(ModelBackend):
         self.step_accounting = dict(
             {"fed": B * T, "shape": ("verify", B, T)},
             **launch_geometry(B, live, live * (np.asarray(start_pos, np.int64) + T)))  # sync-ok: start_pos is host numpy
-        with TRACER.span("dispatch", cat="engine", program="verify"):
+        with self._dispatch("verify"):
+            packed, layout = self._send(
+                tokens=np.asarray(tokens, np.int32), block_tables=np.asarray(block_tables, np.int32),  # sync-ok: host numpy
+                start_pos=np.asarray(start_pos, np.int32),  # sync-ok: start_pos is host numpy
+                adapter_idx=self._adapter_idx(adapter_table, B))
             argmax, logits, self.pool = self.infer.verify(
-                self.params, self.pool, jnp.asarray(tokens), jnp.asarray(block_tables),
-                jnp.asarray(start_pos),
-                lora=self._lora_tree(),
-                adapter_idx=self._adapter_idx(adapter_table, B),
-                need_logits=need_logits,
-            )
+                self.params, self.pool, packed, layout, lora=self._lora_tree(), need_logits=need_logits)
         with TRACER.span("wait", cat="engine", program="verify"):
             return np.asarray(argmax), (np.asarray(logits) if need_logits else None)  # sync-ok: THE verify sync point (logits only when rejection sampling asks)
 
@@ -528,7 +570,7 @@ class SingleDeviceBackend(ModelBackend):
         prefill-stage and decode-stage programs back to back and only then
         collect, so the two device groups compute concurrently instead of the
         host serializing them at the first sync."""
-        with TRACER.span("dispatch", cat="engine", program="mixed"):
+        with self._dispatch("mixed"):
             tokens_dev, mapper = self._mixed_flat_launch(chunk_rows, decode_rows)
 
         def collect() -> np.ndarray:
@@ -587,16 +629,13 @@ class SingleDeviceBackend(ModelBackend):
             d_adapter[j] = r.adapter
         sampling = ([r.sampling for r in chunk_rows] + [None] * (C - len(chunk_rows))
                     + [r.sampling for r in decode_rows] + [None] * (D - len(decode_rows)))
+        packed, layout = self._send(
+            chunk_ids=c_ids, chunk_tables=c_tables, chunk_qlens=c_qlens, chunk_start=c_start,
+            chunk_slots=c_slots, chunk_emit=c_emit, dec_tokens=d_tokens, dec_tables=d_tables,
+            dec_start=d_start, dec_slots=d_slots, dec_live=d_live, **samp_arrays(sampling, C + D),
+            chunk_adapter=self._adapter_idx(c_adapter, C), dec_adapter=self._adapter_idx(d_adapter, D))
         tokens, self.counts, self.pool = self.infer.mixed_step_flat(
-            self.params, self.pool,
-            jnp.asarray(c_ids), jnp.asarray(c_tables), jnp.asarray(c_qlens),
-            jnp.asarray(c_start), jnp.asarray(c_slots), jnp.asarray(c_emit),
-            jnp.asarray(d_tokens), jnp.asarray(d_tables), jnp.asarray(d_start),
-            jnp.asarray(d_slots), jnp.asarray(d_live),
-            self.counts, samp_arrays(sampling, C + D),
-            lora=self._lora_tree(), chunk_adapter=self._adapter_idx(c_adapter, C),
-            dec_adapter=self._adapter_idx(d_adapter, D),
-        )
+            self.params, self.pool, packed, layout, self.counts, lora=self._lora_tree())
         n_c, n_d = len(chunk_rows), len(decode_rows)
         return tokens, lambda host: np.concatenate([host[:n_c], host[C : C + n_d]])
 

@@ -32,9 +32,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.rope import apply_rotary_pos_emb, rope_frequencies, rope_tables
+from .launch_pack import unpack
 from .paged_cache import PagedKVPool, gather_kv, init_paged_pool, write_kv_block
 
-__all__ = ["PagedInferenceModel", "LaunchCounts", "sample_tokens", "layer_kinds", "refuse_unserved", "inference_model_class"]
+__all__ = ["PagedInferenceModel", "LaunchCounts", "sample_tokens", "SAMP_FIELDS", "layer_kinds", "refuse_unserved",
+           "inference_model_class"]
+
+#: the per-row sampling parameters a launch carries: ``sample_tokens``' keyword arrays, in buffer order
+SAMP_FIELDS = ("seeds", "temperature", "top_k", "top_p", "do_sample",
+               "repetition_penalty", "presence_penalty", "frequency_penalty")
 
 
 def layer_kinds(config):
@@ -149,6 +155,14 @@ def _rms(x, scale, eps):
     return (x32 * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)).astype(x.dtype)
 
 
+def _launch_fields(packed, layout):
+    """A launch's host inputs out of its one buffer (``launch_pack.unpack``): the
+    fields by name, and the sampling parameters as the dict ``sample_tokens``
+    takes. The first thing every step program does."""
+    fields = unpack(packed, layout)
+    return fields, {k: fields.pop(k) for k in SAMP_FIELDS if k in fields}
+
+
 class LaunchCounts:
     """For a kind whose layers count on the device and whose prompts enter in
     chunks only (mix in ahead of :class:`PagedInferenceModel`): ``STATS`` names
@@ -172,12 +186,12 @@ class LaunchCounts:
     def _verify_impl(self, *args, **kwargs):
         raise NotImplementedError(f"{type(self).__name__} has no speculative verify program")
 
-    def _mixed_flat_impl(self, params, pool, *args, **kwargs):
-        return super()._mixed_flat_impl(params, dataclasses.replace(pool, stats=jnp.zeros_like(pool.stats)),
+    def _mixed_flat_body(self, params, pool, *args, **kwargs):
+        return super()._mixed_flat_body(params, dataclasses.replace(pool, stats=jnp.zeros_like(pool.stats)),
                                         *args, **kwargs)
 
-    def _decode_impl(self, params, pool, *args, **kwargs):
-        return super()._decode_impl(params, dataclasses.replace(pool, stats=jnp.zeros_like(pool.stats)),
+    def _decode_body(self, params, pool, *args, **kwargs):
+        return super()._decode_body(params, dataclasses.replace(pool, stats=jnp.zeros_like(pool.stats)),
                                     *args, **kwargs)
 
     def _decode_q_lens(self, done):
@@ -284,12 +298,13 @@ class PagedInferenceModel:
         """Compile the step entry points. The sharded subclass overrides this
         to attach explicit ``in_shardings``/``out_shardings``; the base keeps
         the historical un-annotated jits."""
-        self._prefill = jax.jit(self._prefill_impl, donate_argnums=(1,))
-        self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
-        # need_logits is static BY POSITION: a jit with in_shardings (the
-        # sharded subclass) takes no keyword arguments
-        self._verify = jax.jit(self._verify_impl, donate_argnums=(1,), static_argnums=(7,))
-        self._mixed_flat = jax.jit(self._mixed_flat_impl, donate_argnums=(1,))
+        # the launch buffer's layout (and verify's need_logits) is static BY
+        # POSITION: a jit with in_shardings (the sharded subclass) takes no
+        # keyword arguments
+        self._prefill = jax.jit(self._prefill_impl, donate_argnums=(1,), static_argnums=(6,))
+        self._decode = jax.jit(self._decode_impl, donate_argnums=(1,), static_argnums=(5,))
+        self._verify = jax.jit(self._verify_impl, donate_argnums=(1,), static_argnums=(4, 5))
+        self._mixed_flat = jax.jit(self._mixed_flat_impl, donate_argnums=(1,), static_argnums=(5,))
 
     def _hint(self, x, kind: str):
         """Activation-layout hook: identity here; the sharded subclass turns
@@ -506,7 +521,42 @@ class PagedInferenceModel:
         return h, new_pool
 
     # ------------------------------------------------------------------ entry points
-    def _prefill_impl(self, params, pool, input_ids, block_tables, suffix_lens,
+    # A step program is ``_<step>_impl``: it takes the launch's host inputs as
+    # ONE packed int32 buffer with its static layout (launch_pack.py; the
+    # backend sends it in one transfer), takes it apart and runs
+    # ``_<step>_body`` on the fields, which is the step itself on plain arrays.
+    # The benchmark keys on the jitted names (jit__decode_impl, ...; "flat"
+    # stays in the mixed step's for that).
+    def _prefill_impl(self, params, pool, packed, cached_counts, counts, lora, layout):
+        """The prefill program. ``counts`` [B, V] is the running per-slot count:
+        the batch's rows land in it at ``slot_idx`` (padded past the last slot,
+        those rows dropped). Returns (tokens [n], counts', new pool)."""
+        f, samp = _launch_fields(packed, layout)
+        tokens, rows, pool = self._prefill_body(
+            params, pool, f["input_ids"], f["block_tables"], f["suffix_lens"], f["cached_lens"],
+            cached_counts, samp, lora, f.get("adapter_idx"))
+        with jax.named_scope("bookkeeping"):
+            counts = counts.at[f["slot_idx"]].set(rows, mode="drop")
+        return tokens, counts, pool
+
+    def _decode_impl(self, params, pool, packed, counts, lora, layout):
+        f, samp = _launch_fields(packed, layout)
+        return self._decode_body(params, pool, f["tokens"], f["block_tables"], f["context_lens"], f["done0"],
+                                 f["remaining"], counts, samp, lora, f.get("adapter_idx"))
+
+    def _verify_impl(self, params, pool, packed, lora, layout, need_logits: bool = True):
+        f, _ = _launch_fields(packed, layout)
+        return self._verify_body(params, pool, f["tokens"], f["block_tables"], f["start_pos"], lora,
+                                 f.get("adapter_idx"), need_logits)
+
+    def _mixed_flat_impl(self, params, pool, packed, counts, lora, layout):
+        f, samp = _launch_fields(packed, layout)
+        return self._mixed_flat_body(
+            params, pool, f["chunk_ids"], f["chunk_tables"], f["chunk_qlens"], f["chunk_start"], f["chunk_slots"],
+            f["chunk_emit"], f["dec_tokens"], f["dec_tables"], f["dec_start"], f["dec_slots"], f["dec_live"],
+            counts, samp, lora, f.get("chunk_adapter"), f.get("dec_adapter"))
+
+    def _prefill_body(self, params, pool, input_ids, block_tables, suffix_lens,
                       cached_lens, cached_counts, samp, lora=None, adapter_idx=None):
         """Batched prefill: [n, T_pad] SUFFIX sequences; samples the first token
         on device.
@@ -547,8 +597,7 @@ class PagedInferenceModel:
             counts = counts + jax.nn.one_hot(tokens, V, dtype=jnp.int32)
         return tokens, counts, new_pool
 
-    # the one mixed layout; "flat" stays in the name because the benchmark keys on jit__mixed_flat_impl
-    def _mixed_flat_impl(self, params, pool, chunk_ids, chunk_tables, chunk_qlens,
+    def _mixed_flat_body(self, params, pool, chunk_ids, chunk_tables, chunk_qlens,
                          chunk_start, chunk_slots, chunk_emit, dec_tokens, dec_tables,
                          dec_start, dec_slots, dec_live, counts, samp, lora=None,
                          chunk_adapter=None, dec_adapter=None):
@@ -617,7 +666,7 @@ class PagedInferenceModel:
         that counts or routes its live tokens is told which rows are."""
         return None
 
-    def _decode_impl(self, params, pool, tokens, block_tables, context_lens, done0,
+    def _decode_body(self, params, pool, tokens, block_tables, context_lens, done0,
                      remaining, counts, samp, lora=None, adapter_idx=None):
         """Multi-step decode: advance every slot up to ``decode_steps`` tokens in ONE
         jit — the host round-trip carries ids and flags only (the reference's whole
@@ -659,7 +708,7 @@ class PagedInferenceModel:
         )
         return toks, valid, done, ctx, counts, pool
 
-    def _verify_impl(self, params, pool, tokens, block_tables, start_pos,
+    def _verify_body(self, params, pool, tokens, block_tables, start_pos,
                      lora=None, adapter_idx=None, need_logits: bool = True):
         """Speculative-decoding verify: one forward over ``[last_token, d_1..d_K]``.
 
@@ -694,28 +743,14 @@ class PagedInferenceModel:
             return argmax, None, new_pool
         return argmax, logits.astype(jnp.float32), new_pool
 
-    def verify(self, params, pool: PagedKVPool, tokens, block_tables, start_pos,
-               lora=None, adapter_idx=None, need_logits: bool = True):
-        return self._verify(params, pool, tokens, block_tables, start_pos,
-                            lora, adapter_idx, need_logits)
+    def verify(self, params, pool: PagedKVPool, packed, layout, lora=None, need_logits: bool = True):
+        return self._verify(params, pool, packed, lora, layout, need_logits)
 
-    def prefill(self, params, pool: PagedKVPool, input_ids, block_tables, suffix_lens,
-                cached_lens, cached_counts, samp, lora=None, adapter_idx=None):
-        return self._prefill(params, pool, input_ids, block_tables, suffix_lens,
-                             cached_lens, cached_counts, samp, lora, adapter_idx)
+    def prefill(self, params, pool: PagedKVPool, packed, layout, cached_counts, counts, lora=None):
+        return self._prefill(params, pool, packed, cached_counts, counts, lora, layout)
 
-    def decode(self, params, pool: PagedKVPool, tokens, block_tables, context_lens, done0,
-               remaining, counts, samp, lora=None, adapter_idx=None):
-        return self._decode(
-            params, pool, tokens, block_tables, context_lens, done0, remaining, counts,
-            samp, lora, adapter_idx
-        )
+    def decode(self, params, pool: PagedKVPool, packed, layout, counts, lora=None):
+        return self._decode(params, pool, packed, counts, lora, layout)
 
-    def mixed_step_flat(self, params, pool: PagedKVPool, chunk_ids, chunk_tables,
-                        chunk_qlens, chunk_start, chunk_slots, chunk_emit, dec_tokens,
-                        dec_tables, dec_start, dec_slots, dec_live, counts, samp,
-                        lora=None, chunk_adapter=None, dec_adapter=None):
-        return self._mixed_flat(params, pool, chunk_ids, chunk_tables, chunk_qlens,
-                                chunk_start, chunk_slots, chunk_emit, dec_tokens,
-                                dec_tables, dec_start, dec_slots, dec_live, counts, samp,
-                                lora, chunk_adapter, dec_adapter)
+    def mixed_step_flat(self, params, pool: PagedKVPool, packed, layout, counts, lora=None):
+        return self._mixed_flat(params, pool, packed, counts, lora, layout)

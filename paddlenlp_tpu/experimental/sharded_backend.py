@@ -202,27 +202,28 @@ class ShardedPagedInferenceModel(PagedInferenceModel):
         )(q, kv, kv_scale, block_tables, q_start, q_lens, layer)
 
     def _build_jits(self):
-        # every step's trailing args are the multi-LoRA pair(s): the adapter
-        # pool (column-parallel / replicated per _lora_layout; a replicated
-        # prefix when LoRA is off and the arg is always None) and the
-        # replicated per-row slot indices.
+        # a step takes its host inputs as one packed buffer, replicated (the
+        # backend lands it so: ShardedBackend._place_launch), then the counts
+        # and the multi-LoRA pool (column-parallel / replicated per
+        # _lora_layout; a replicated prefix when LoRA is off and the arg is
+        # always None); the buffer's layout is static by position.
         ps, pool_s, r = self.param_shardings, self.pool_shardings, self._repl
         lora_s = self.lora_shardings
         self._prefill = jax.jit(
-            self._prefill_impl, donate_argnums=(1,),
-            in_shardings=(ps, pool_s) + (r,) * 6 + (lora_s, r),
+            self._prefill_impl, donate_argnums=(1,), static_argnums=(6,),
+            in_shardings=(ps, pool_s, r, r, r, lora_s),
             out_shardings=(r, r, pool_s))
         self._decode = jax.jit(
-            self._decode_impl, donate_argnums=(1,),
-            in_shardings=(ps, pool_s) + (r,) * 7 + (lora_s, r),
+            self._decode_impl, donate_argnums=(1,), static_argnums=(5,),
+            in_shardings=(ps, pool_s, r, r, lora_s),
             out_shardings=(r, r, r, r, r, pool_s))
         self._verify = jax.jit(
-            self._verify_impl, donate_argnums=(1,), static_argnums=(7,),
-            in_shardings=(ps, pool_s) + (r,) * 3 + (lora_s, r),
+            self._verify_impl, donate_argnums=(1,), static_argnums=(4, 5),
+            in_shardings=(ps, pool_s, r, lora_s),
             out_shardings=(r, r, pool_s))
         self._mixed_flat = jax.jit(
-            self._mixed_flat_impl, donate_argnums=(1,),
-            in_shardings=(ps, pool_s) + (r,) * 13 + (lora_s, r, r),
+            self._mixed_flat_impl, donate_argnums=(1,), static_argnums=(5,),
+            in_shardings=(ps, pool_s, r, r, lora_s),
             out_shardings=(r, r, pool_s))
 
 
@@ -293,6 +294,11 @@ class ShardedBackend(SingleDeviceBackend):
 
     def _init_counts(self):
         return jax.device_put(super()._init_counts(), self.infer._repl)
+
+    def _place_launch(self, host):
+        # replicated, as the step programs' in_shardings take it: one transfer
+        # call, and dispatch never re-shards it
+        return jax.device_put(host, self.infer._repl)
 
     def _build_host_tier_jits(self):
         # host-tier spill/promote with the step programs' explicit-placement

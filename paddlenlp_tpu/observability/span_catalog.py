@@ -30,7 +30,7 @@ SPAN_CATALOG = {
     "prefill": "the backend call of one batched monolithic prompt prefill, one span per padded suffix-length bucket, launch geometry in its args (also the retrospective per-request prefill phase, admission -> first token, whose args steps / own_ms / behind_ms split it into the request's own launches, the launches it sat behind and, the span less both, host time)",
     "mixed_step": "the backend call of one ragged mixed prefill-chunk + decode forward (chunked prefill), launch geometry in its args",
     "decode": "the backend call of the multi-token decode jit over all running slots, launch geometry in its args (also the retrospective per-request decode phase)",
-    "dispatch": "child of a launch span: host arrays to the device and the jit call returning (program=...)",
+    "dispatch": "child of a launch span: the launch's one packed host-to-device transfer and the jit call returning (program=..., h2d_arrays, h2d_bytes)",
     "wait": "child of a launch span: the np.asarray sync point, the host waiting for the device (program=...)",
     "emit": "host work after a backend call: ledger entry, settle, stream callbacks, free/shrink (program=...)",
     "step_tail": "end of an engine step: usage metering and the step-anatomy record",

@@ -306,6 +306,7 @@ def traced(kind, program):
     chunk row of 8 in the mixed step."""
     from paddlenlp_tpu.experimental.backend import samp_arrays
     from paddlenlp_tpu.experimental.inference_model import inference_model_class
+    from paddlenlp_tpu.experimental.launch_pack import layout_of, packed_size
 
     cls, cfg = small_of(kind)
     m = cls(cfg, dtype=jnp.float32, param_dtype=jnp.float32)
@@ -317,13 +318,20 @@ def traced(kind, program):
     aval = jax.ShapeDtypeStruct
     rows = lambda *shape: aval((4,) + shape, jnp.int32)
     chunk = lambda *shape, dtype=jnp.int32: aval((1,) + shape, dtype)
-    samp = lambda n: jax.eval_shape(lambda: samp_arrays([None] * n, n))
+    flag = lambda n: aval((n,), jnp.bool_)
+
+    def trace(step, counts, **fields):  # the launch's host inputs ride one packed buffer, its layout static
+        layout = layout_of(fields)
+        return jax.make_jaxpr(step, static_argnums=(5,))(
+            m.params, pool, aval((packed_size(layout),), jnp.int32), counts, None, layout)
+
     if program == "decode":
-        return jax.make_jaxpr(infer._decode_impl)(m.params, pool, rows(), rows(*table), rows(), aval((4,), jnp.bool_),
-                                                  rows(), rows(97), samp(4))
-    return jax.make_jaxpr(infer._mixed_flat_impl)(
-        m.params, pool, chunk(8), chunk(*table), chunk(), chunk(), chunk(), chunk(dtype=jnp.bool_), rows(), rows(*table),
-        rows(), rows(), aval((4,), jnp.bool_), rows(97), samp(5))
+        return trace(infer._decode_impl, rows(97), tokens=rows(), block_tables=rows(*table), context_lens=rows(),
+                     done0=flag(4), remaining=rows(), **samp_arrays([None] * 4, 4))
+    return trace(infer._mixed_flat_impl, rows(97), chunk_ids=chunk(8), chunk_tables=chunk(*table), chunk_qlens=chunk(),
+                 chunk_start=chunk(), chunk_slots=chunk(), chunk_emit=chunk(dtype=jnp.bool_), dec_tokens=rows(),
+                 dec_tables=rows(*table), dec_start=rows(), dec_slots=rows(), dec_live=flag(4),
+                 **samp_arrays([None] * 5, 5))
 
 
 # sha256 of the text of the programs' walk-by-blocks kernel calls (kernel jaxpr, grid and index maps inside it) as the

@@ -19,6 +19,7 @@ import pytest
 from paddlenlp_tpu.experimental import InferenceEngine, SamplingParams
 from paddlenlp_tpu.experimental.backend import launch_geometry, samp_arrays
 from paddlenlp_tpu.experimental.inference_model import PagedInferenceModel
+from paddlenlp_tpu.experimental.launch_pack import layout_of, packed_size
 from paddlenlp_tpu.experimental.paged_cache import PagedKVPool
 from paddlenlp_tpu.observability.goodput import LAUNCH_GEOMETRY, GoodputLedger
 from paddlenlp_tpu.observability.span_catalog import SPAN_CATALOG
@@ -50,17 +51,19 @@ def _program_text(infer, program, batch=2, vocab=96, table=8, pool=None, aval=No
     """Compiled HLO text of one serving step program at a tiny size (nothing runs)."""
     aval = aval or (lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype))
     params = jax.tree.map(lambda a: aval(a.shape, a.dtype), infer.model.params)
-    samp = {k: aval(v.shape, v.dtype) for k, v in samp_arrays([None] * batch).items()}
     ints = lambda *shape: aval(shape, jnp.int32)
     if program == "decode":
-        args = (params, pool, ints(batch), ints(batch, table), ints(batch), aval((batch,), jnp.bool_),
-                ints(batch), ints(batch, vocab), samp)
-        fn = infer._decode_impl
+        fn, counts, fields = infer._decode_impl, (ints(batch, vocab),), dict(
+            tokens=ints(batch), block_tables=ints(batch, table), context_lens=ints(batch),
+            done0=aval((batch,), jnp.bool_), remaining=ints(batch))
     else:
-        args = (params, pool, ints(batch, 16), ints(batch, table), ints(batch), ints(batch),
-                ints(batch, vocab), samp)
-        fn = infer._prefill_impl
-    return jax.jit(fn).lower(*args).compile().as_text()
+        fn, counts, fields = infer._prefill_impl, (ints(batch, vocab), ints(batch, vocab)), dict(
+            input_ids=ints(batch, 16), block_tables=ints(batch, table), suffix_lens=ints(batch),
+            cached_lens=ints(batch), slot_idx=ints(batch))
+    # the launch's host inputs ride one packed buffer; its layout is the program's last, static argument
+    layout = layout_of(dict(fields, **samp_arrays([None] * batch)))
+    args = (params, pool, ints(packed_size(layout)), *counts, None, layout)
+    return jax.jit(fn, static_argnums=(len(args) - 1,)).lower(*args).compile().as_text()
 
 
 def _scoped(text):

@@ -361,6 +361,7 @@ def test_step_programs_copy_no_pool(chip, monkeypatch, program):
     geometry launches) runs two forwards in series over the one donated pool."""
     from paddlenlp_tpu.experimental.backend import samp_arrays
     from paddlenlp_tpu.experimental.inference_model import PagedInferenceModel
+    from paddlenlp_tpu.experimental.launch_pack import layout_of, packed_size
     from paddlenlp_tpu.experimental.paged_cache import init_paged_pool
     from paddlenlp_tpu.transformers import Qwen2Config, Qwen2ForCausalLM
 
@@ -380,21 +381,26 @@ def test_step_programs_copy_no_pool(chip, monkeypatch, program):
     aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
     params = on_chip(model.params)
     pool = on_chip(jax.eval_shape(lambda: init_paged_pool(config, NUM_BLOCKS, BLOCK)))
-    samp = lambda n: on_chip(jax.eval_shape(lambda: samp_arrays([None] * n, n)))
     rows = lambda *shape: aval((slots,) + shape, jnp.int32)
     if program == "decode":
-        step, args = infer._decode_impl, (
-            params, pool, rows(), rows(table), rows(), aval((slots,), jnp.bool_), rows(),
-            rows(vocab), samp(slots))
+        step, fields, counts = infer._decode_impl, dict(
+            tokens=rows(), block_tables=rows(table), context_lens=rows(), done0=aval((slots,), jnp.bool_),
+            remaining=rows(), **samp_arrays([None] * slots, slots)), (rows(vocab),)
     elif program == "prefill16x512":
-        step, args = infer._prefill_impl, (
-            params, pool, rows(512), rows(table), rows(), rows(), rows(vocab), samp(slots))
+        step, fields, counts = infer._prefill_impl, dict(
+            input_ids=rows(512), block_tables=rows(table), suffix_lens=rows(), cached_lens=rows(), slot_idx=rows(),
+            **samp_arrays([None] * slots, slots)), (rows(vocab), rows(vocab))
     else:
         chunk = lambda *shape, dtype=jnp.int32: aval((1,) + shape, dtype)
-        step, args = infer._mixed_flat_impl, (
-            params, pool, chunk(512), chunk(table), chunk(), chunk(), chunk(), chunk(dtype=jnp.bool_),
-            rows(), rows(table), rows(), rows(), aval((slots,), jnp.bool_), rows(vocab), samp(1 + slots))
-    compiled = jax.jit(step, donate_argnums=(1,)).lower(*args).compile()
+        step, fields, counts = infer._mixed_flat_impl, dict(
+            chunk_ids=chunk(512), chunk_tables=chunk(table), chunk_qlens=chunk(), chunk_start=chunk(),
+            chunk_slots=chunk(), chunk_emit=chunk(dtype=jnp.bool_), dec_tokens=rows(), dec_tables=rows(table),
+            dec_start=rows(), dec_slots=rows(), dec_live=aval((slots,), jnp.bool_),
+            **samp_arrays([None] * (1 + slots), 1 + slots)), (rows(vocab),)
+    # the launch's host inputs ride one packed buffer; its layout is the program's last, static argument
+    layout = layout_of(fields)
+    args = (params, pool, aval((packed_size(layout),), jnp.int32), *counts, None, layout)
+    compiled = jax.jit(step, donate_argnums=(1,), static_argnums=(len(args) - 1,)).lower(*args).compile()
 
     pool_bytes = pool.kv.size * pool.kv.dtype.itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < 0.25 * pool_bytes
@@ -436,6 +442,7 @@ def test_the_windowed_kinds_decode_program_copies_no_plane(chip, monkeypatch):
     from bench.harness import common
     from paddlenlp_tpu.experimental.backend import samp_arrays
     from paddlenlp_tpu.experimental.inference_model import inference_model_class
+    from paddlenlp_tpu.experimental.launch_pack import layout_of, packed_size
 
     root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     with open(os.path.join(root, "bench", "configs", "k-exaone-serve-ep16.json")) as f:
@@ -455,9 +462,10 @@ def test_the_windowed_kinds_decode_program_copies_no_plane(chip, monkeypatch):
     aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
     pool = on_chip(jax.eval_shape(lambda: infer.init_pool(engine["num_blocks"], engine["block_size"], jnp.bfloat16)))
     rows = lambda *shape: aval((slots,) + shape, jnp.int32)
-    args = (on_chip(model.params), pool, rows(), rows(2, table), rows(), aval((slots,), jnp.bool_), rows(),
-            rows(cfg.vocab_size), on_chip(jax.eval_shape(lambda: samp_arrays([None] * slots, slots))))
-    compiled = jax.jit(infer._decode_impl, donate_argnums=(1,)).lower(*args).compile()
+    layout = layout_of(dict(tokens=rows(), block_tables=rows(2, table), context_lens=rows(),
+                            done0=aval((slots,), jnp.bool_), remaining=rows(), **samp_arrays([None] * slots, slots)))
+    args = (on_chip(model.params), pool, aval((packed_size(layout),), jnp.int32), rows(cfg.vocab_size), None, layout)
+    compiled = jax.jit(infer._decode_impl, donate_argnums=(1,), static_argnums=(5,)).lower(*args).compile()
 
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 8  # one kernel a layer (the stack is unrolled; the sub-steps' scan compiles its body once)

@@ -43,6 +43,13 @@ def test_a_tiny_window_reads_its_tail(capsys, tiny_root, tmp_path):
     assert len(doc["tail_rows"]) == 2 and doc["tail_rows"][1]["ttft_ms"] == pytest.approx(block["ttft_min_ms"])
     assert doc["tail_rows"][0]["steps"] == 1  # monolithic prefill
     assert doc["readings"]["chunk_backlog_mean"] >= 0.0
+    # every launch's dispatch handed the device one host array: its packed buffer (no prefix is shared here)
+    sent = {k: v for k, v in doc["launch_children"].items() if k.startswith("dispatch.")}
+    assert set(sent) == {"dispatch.prefill", "dispatch.decode"} and set(doc["launch_children"]) - set(sent) == {
+        "wait.prefill", "wait.decode"}
+    assert all(v["h2d_arrays_min"] == v["h2d_arrays_max"] == 1 and v["without_h2d_args"] == 0 and v["h2d_bytes_mean"] > 0
+               and v["ms_mean"] > 0 for v in sent.values())
+    assert sum(v["n"] for v in sent.values()) == checks["launches"] and checks["launch_ms_mean"]["decode"] > 0
     assert doc["readings"]["ttft_tail_wait_share"] + doc["readings"]["ttft_tail_behind_share"] \
         + doc["readings"]["ttft_tail_own_share"] + doc["readings"]["ttft_tail_host_share"] \
         + block["share"]["promote_wait"] == pytest.approx(100.0, abs=0.1)
@@ -64,3 +71,19 @@ def test_the_backlog_is_read_off_the_launches_that_carry_prompt_tokens():
     assert out["checks"]["launches_by_name"]["mixed_step"] == 3
     assert out["checks"]["prefill_spans"] == 2 and out["checks"]["prefill_spans_split_over_the_span"] == 1
     assert out["readings"]["ttft_tail_behind_share"] is None and out["tail_rows"] == []
+
+
+def test_the_launches_children_are_read_by_program_and_a_tree_without_the_args_reads_none():
+    child = lambda name, program, ts, ms, **args: {"name": name, "cat": "engine", "ts": ts, "dur": ms / 1e3,
+                                                   "args": dict(program=program, **args)}
+    spans = [child("dispatch", "mixed", 9.0, 50.0, h2d_arrays=1, h2d_bytes=8),     # before the window opened
+             child("dispatch", "mixed", 10.0, 2.0, h2d_arrays=1, h2d_bytes=100),
+             child("dispatch", "mixed", 11.0, 4.0, h2d_arrays=2, h2d_bytes=300),
+             child("wait", "mixed", 11.1, 60.0), child("dispatch", "decode", 12.0, 1.0),  # a tree that stamps neither
+             {"name": "dispatch", "cat": "other", "ts": 12.5, "dur": 1.0, "args": {}}]
+    out = tool.launch_children(spans, 10.0, 20.0)
+    assert out["dispatch.mixed"] == {"n": 2, "ms_mean": pytest.approx(3.0), "h2d_arrays_min": 1, "h2d_arrays_max": 2,
+                                     "h2d_bytes_mean": 200.0, "without_h2d_args": 0}
+    assert out["wait.mixed"] == {"n": 1, "ms_mean": pytest.approx(60.0)}
+    assert out["dispatch.decode"] == {"n": 1, "ms_mean": pytest.approx(1.0), "h2d_arrays_min": None,
+                                      "h2d_arrays_max": None, "h2d_bytes_mean": None, "without_h2d_args": 1}

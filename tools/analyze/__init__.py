@@ -91,7 +91,8 @@ DEFAULT_CONFIG: Dict = {
         ],
         "paddlenlp_tpu/experimental/backend.py": [
             "ModelBackend.migration_ready", "ModelBackend.kv_writeback",
-            "launch_geometry", "SingleDeviceBackend.prefill", "SingleDeviceBackend.decode",
+            "launch_geometry", "samp_arrays", "SingleDeviceBackend._send", "SingleDeviceBackend._to_device",
+            "SingleDeviceBackend.prefill", "SingleDeviceBackend.decode",
             "SingleDeviceBackend.verify", "SingleDeviceBackend.mixed_step",
             "SingleDeviceBackend.mixed_step_begin",
             "SingleDeviceBackend._mixed_flat_launch",
@@ -99,8 +100,9 @@ DEFAULT_CONFIG: Dict = {
             "SingleDeviceBackend.reset_counts", "SingleDeviceBackend.apply_cow",
             "SingleDeviceBackend.kv_spill", "SingleDeviceBackend.kv_promote",
         ],
+        "paddlenlp_tpu/experimental/launch_pack.py": ["pack", "layout_of"],
         "paddlenlp_tpu/experimental/sharded_backend.py": [
-            "ShardedBackend.params",
+            "ShardedBackend.params", "ShardedBackend._place_launch",
         ],
         "paddlenlp_tpu/experimental/disagg_backend.py": [
             "DisaggBackend.prefill", "DisaggBackend.decode",

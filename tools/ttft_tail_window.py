@@ -8,7 +8,9 @@ The harness (``bench/harness/serve.py``) scrapes the server three times: as the
 window opens, as it closes, and once the load generator has exited. This wraps
 that scrape: at the third, ``GET /debug/requests?since_ts=<the first one's
 instant>`` gives the window's finished requests and their ``ttft_tail`` block, and
-the program's span ring gives the launches between the first two. One line
+the program's span ring gives the launches between the first two, with their
+``dispatch`` / ``wait`` children (what each dispatch handed to the device:
+``h2d_arrays``, ``h2d_bytes``). One line
 ``{"phase": "ttft_tail", ...}`` is printed before the result line, and the whole
 reading is written to ``<--tail-out>/<cell>.<seed>.json`` (default
 ``chiprun_out/ttft_tail``). The five readings ISSUE 38 names, which join
@@ -32,6 +34,28 @@ LAUNCHES = ("prefill", "decode", "mixed_step", "spec_verify")
 WAIT = ("inbox", "queue", "admission_gate")
 
 
+def launch_children(spans, t_open, t_close):
+    """The ``dispatch`` and ``wait`` children of the window's launches, by program:
+    how many, their mean duration, and what the dispatches handed to the device
+    (``h2d_arrays``, ``h2d_bytes``: args of every ``dispatch`` span since PR 39;
+    ``None`` on a tree that stamps neither)."""
+    out = {}
+    for s in spans:
+        if s.get("cat") == "engine" and s["name"] in ("dispatch", "wait") and t_open <= s["ts"] < t_close:
+            out.setdefault((s["name"], (s.get("args") or {}).get("program")), []).append(s)
+    mean = lambda xs: sum(xs) / len(xs) if xs else None
+    rows = {}
+    for (name, program), group in sorted(out.items(), key=str):
+        row = {"n": len(group), "ms_mean": mean([s["dur"] * 1e3 for s in group])}
+        if name == "dispatch":
+            arrays = [s["args"]["h2d_arrays"] for s in group if "h2d_arrays" in s["args"]]
+            row.update(h2d_arrays_min=min(arrays, default=None), h2d_arrays_max=max(arrays, default=None),
+                       h2d_bytes_mean=mean([s["args"]["h2d_bytes"] for s in group if "h2d_bytes" in s["args"]]),
+                       without_h2d_args=len(group) - len(arrays))
+        rows[f"{name}.{program}"] = row
+    return rows
+
+
 def read_window(requests, spans, t_open, t_close):
     """The reading, from the ``/debug/requests`` document of the window's
     requests and the span ring's dicts: the block, the five readings, and what
@@ -40,6 +64,7 @@ def read_window(requests, spans, t_open, t_close):
     rows, block = requests["recent"], requests["ttft_tail"]
     launches = [s for s in spans if s.get("cat") == "engine" and s["name"] in LAUNCHES
                 and t_open <= s["ts"] < t_close]
+    launch_durs = {n: [s["dur"] for s in launches if s["name"] == n] for n in LAUNCHES}
     carrying = [s for s in launches if (s.get("args") or {}).get("carried")]
     prefills = [s for s in spans if s.get("cat") == "request" and s["name"] == "prefill" and s["ts"] >= t_open]
     share = block.get("share", {})
@@ -59,7 +84,8 @@ def read_window(requests, spans, t_open, t_close):
             "phases_sum_worst_error_s": max((abs(sum(r["attribution"].values()) - (r["finish_t"] - r["arrival_t"]))
                                              for r in rows), default=None),
             "launches": len(launches),
-            "launches_by_name": {n: sum(1 for s in launches if s["name"] == n) for n in LAUNCHES},
+            "launches_by_name": {n: len(durs) for n, durs in launch_durs.items()},
+            "launch_ms_mean": {n: sum(durs) * 1e3 / max(1, len(durs)) for n, durs in launch_durs.items()},
             "launches_without_both_args": sum(1 for s in launches if not {"carried", "prefill_waiting"}
                                               <= set(s.get("args") or {})),
             "decode_launches_that_carry": sum(1 for s in launches if s["name"] in ("decode", "spec_verify")
@@ -70,6 +96,7 @@ def read_window(requests, spans, t_open, t_close):
             "prefill_spans_split_over_the_span": sum(
                 1 for s in prefills if s["args"]["own_ms"] + s["args"]["behind_ms"] > s["dur"] * 1e3 + 1e-3),
         },
+        "launch_children": launch_children(spans, t_open, t_close),
         "tail_rows": sorted(({"req_id": r["req_id"], "prompt_len": r["prompt_len"], "ttft_ms": r["ttft_s"] * 1e3,
                               "steps": r["prefill_steps"], "own_ms": r["prefill_own_s"] * 1e3,
                               **{k: r["attribution"][k] * 1e3 for k in (*WAIT, "promote_wait", "prefill_behind",
@@ -106,7 +133,7 @@ def main(argv=None):
             doc["window"] = {k: obj[k] for k in ("rate", "attempted", "failed", "window_compiles", "ttft_p90_ms",
                                                  "ttft_p50_ms", "ttft_ms_sorted", "tpot_mean_ms",
                                                  "inflight_at_quarters")}
-            common.log(phase="ttft_tail", **{k: doc[k] for k in ("ttft_tail", "readings", "checks")})
+            common.log(phase="ttft_tail", **{k: doc[k] for k in ("ttft_tail", "readings", "checks", "launch_children")})
         log_of(**obj)
 
     serve.snapshot, serve.log = snapshot, log
