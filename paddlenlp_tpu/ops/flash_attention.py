@@ -71,7 +71,7 @@ def alibi_bias(n_heads: int, q_len: int, kv_len: int, offset=0) -> jnp.ndarray:
 def dot_product_attention(
     query: jnp.ndarray,  # [B, T, n_heads, head_dim]
     key: jnp.ndarray,  # [B, S, n_kv, head_dim]
-    value: jnp.ndarray,  # [B, S, n_kv, head_dim]
+    value: jnp.ndarray,  # [B, S, n_kv, head_dim], or a value head of its own width (latent attention: 192 and 128)
     *,
     attention_mask: Optional[jnp.ndarray] = None,  # [B, S] padding mask (1 = keep)
     segment_ids: Optional[jnp.ndarray] = None,  # [B, S] packed-batch segments
@@ -86,7 +86,11 @@ def dot_product_attention(
     use_alibi: bool = False,  # additive -slope*(q_pos-k_pos) bias (bloom/baichuan-13b)
     bias: Optional[jnp.ndarray] = None,  # [B|1, N|1, T, S] additive bias (t5 relative positions)
 ) -> jnp.ndarray:
-    """Fused attention; returns [B, T, n_heads, head_dim] in query dtype.
+    """Fused attention; returns [B, T, n_heads, value head_dim] in query dtype.
+
+    A value head narrower or wider than the query/key head goes to the Pallas
+    kernels as it is (they carry both widths); the XLA path, which wants one
+    width, runs on zero-padded heads and slices the result back.
 
     ``positions``: when the sequence axis is physically permuted (context-parallel
     zigzag layout), index order != causal order; pass absolute positions and the
@@ -124,6 +128,11 @@ def dot_product_attention(
         out = _pallas_dispatch(query, key, value, segment_ids, scale, window)
         if out is not None:
             return out
+
+    Hv = value.shape[-1]
+    if Hv != H:  # the XLA path wants one head width: zeros add nothing to a score or to a value
+        pad = lambda x, to: jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, to - x.shape[-1])))
+        query, key, value = pad(query, max(H, Hv)), pad(key, max(H, Hv)), pad(value, max(H, Hv))
 
     mask = None
     if causal and positions is not None:
@@ -164,7 +173,7 @@ def dot_product_attention(
     # one value, one name: the kernel names its own output where its forward rule
     # makes it ("flash_out"), so the callers name nothing. A second name on the
     # same array is a second saved copy a layer under a scanned remat.
-    return checkpoint_name(out, "core_attn")
+    return checkpoint_name(out[..., :Hv] if Hv != H else out, "core_attn")
 
 
 def _pallas_dispatch(query, key, value, segment_ids, scale, window):
@@ -188,11 +197,11 @@ def _pallas_dispatch(query, key, value, segment_ids, scale, window):
             f"{why}; using the XLA attention path")
         return None
 
-    if jax.default_backend() == "tpu" and not (T % 128 == 0 and H % 64 == 0):
+    if jax.default_backend() == "tpu" and not (T % 128 == 0 and H % 64 == 0 and value.shape[-1] % 64 == 0):
         # Mosaic tiling gate: a shape the kernel cannot tile is turned away
         # here, by rule, and not discovered as a compile error of the
-        # enclosing jit
-        return turned_away("needs T % 128 == 0 and head_dim % 64 == 0")
+        # enclosing jit. The value head may differ from the query/key head.
+        return turned_away("needs T % 128 == 0 and head_dim % 64 == 0 (query/key and value)")
     mesh = _current_mesh()
     if mesh is None:
         return pallas_flash(query, key, value, segment_ids, scale, True, window)

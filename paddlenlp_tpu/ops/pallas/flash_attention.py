@@ -20,7 +20,10 @@ Structure (classic flash-attention-2 schedule):
   scheme. The dk/dv kernel works on the transposed score tile [block_kv, block_q],
   so P^T dO and dS^T Q are plain matmuls and no tile is transposed;
 - ``segment_ids`` restricts attention to same-segment tokens (ZeroPadding packed
-  batches); ``window`` adds the mistral sliding-window lower bound.
+  batches); ``window`` adds the mistral sliding-window lower bound;
+- the value head may be narrower or wider than the query/key head (latent
+  attention: 192 and 128): v, dO, the output, its accumulator and dV carry the
+  value width, q, k, dQ and dK the key width. Alike, the calls are what they were.
 
 What one grid step does. A step costs about 0.35 us whatever it does, so it
 has to do a tile's worth of work:
@@ -261,6 +264,7 @@ def _segments(segments, B, T):
 
 def _flash_fwd(q, k, v, segments, scale, causal, window, block_q, block_kv, interpret):
     B, T, N, H = q.shape
+    Hv = v.shape[-1]  # the value head: the accumulator's and the output's width, H where the heads are alike
     if causal and T != k.shape[1]:
         raise ValueError(
             f"causal flash_attention requires T == S (got T={T}, S={k.shape[1]}); "
@@ -289,28 +293,28 @@ def _flash_fwd(q, k, v, segments, scale, causal, window, block_q, block_kv, inte
         in_specs=[
             pl.BlockSpec((1, block_q, H), lambda bn, qi, ki: (bn, qi, 0)),
             pl.BlockSpec((1, block_kv, H), lambda bn, qi, ki: (bn // group, kv_block(qi, ki), 0)),
-            pl.BlockSpec((1, block_kv, H), lambda bn, qi, ki: (bn // group, kv_block(qi, ki), 0)),
+            pl.BlockSpec((1, block_kv, Hv), lambda bn, qi, ki: (bn // group, kv_block(qi, ki), 0)),
             pl.BlockSpec((1, block_q, 1), lambda bn, qi, ki: (bn // N, qi, 0)),
             pl.BlockSpec((1, 1, block_kv), lambda bn, qi, ki: (bn // N, 0, kv_block(qi, ki))),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, H), lambda bn, qi, ki: (bn, qi, 0)),
+            pl.BlockSpec((1, block_q, Hv), lambda bn, qi, ki: (bn, qi, 0)),
             pl.BlockSpec((1, 1, block_q), lambda bn, qi, ki: (bn, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * N, T, H), q.dtype),
+            jax.ShapeDtypeStruct((B * N, T, Hv), q.dtype),
             jax.ShapeDtypeStruct((B * N, 1, T), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # m
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # l
-            pltpu.VMEM((block_q, H), jnp.float32),  # acc
+            pltpu.VMEM((block_q, Hv), jnp.float32),  # acc
         ],
         compiler_params=_compiler_params(block_q, block_kv),
         interpret=interpret,
         name="flash_attention_fwd",
     )(qf, kf, vf, seg_col, seg_row)
-    return out.reshape(B, N, T, H).transpose(0, 2, 1, 3), lse  # lse: [B*N, 1, T]
+    return out.reshape(B, N, T, Hv).transpose(0, 2, 1, 3), lse  # lse: [B*N, 1, T]
 
 
 # ---------------------------------------------------------------- backward
@@ -402,7 +406,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_
 
 def _flash_bwd(q, k, v, segments, out, lse, g, scale, causal, window, block_q, block_kv, interpret):
     B, T, N, H = q.shape
-    S, K = k.shape[1], k.shape[2]
+    S, K, Hv = k.shape[1], k.shape[2], v.shape[-1]  # dO and dV at the value head's width, dQ and dK at the key's
     group = N // K
     qf, kf, vf, dof = _fold(q), _fold(k), _fold(v), _fold(g)
     # from ``out`` and ``g`` as they come, the small result folded: ``out`` is read
@@ -426,8 +430,8 @@ def _flash_bwd(q, k, v, segments, out, lse, g, scale, causal, window, block_q, b
         in_specs=[
             pl.BlockSpec((1, bq, H), lambda bn, qi, ki: (bn, qi, 0)),
             pl.BlockSpec((1, bkv, H), lambda bn, qi, ki: (bn // group, kv_block(qi, ki), 0)),
-            pl.BlockSpec((1, bkv, H), lambda bn, qi, ki: (bn // group, kv_block(qi, ki), 0)),
-            pl.BlockSpec((1, bq, H), lambda bn, qi, ki: (bn, qi, 0)),
+            pl.BlockSpec((1, bkv, Hv), lambda bn, qi, ki: (bn // group, kv_block(qi, ki), 0)),
+            pl.BlockSpec((1, bq, Hv), lambda bn, qi, ki: (bn, qi, 0)),
             pl.BlockSpec((1, 1, bq), lambda bn, qi, ki: (bn, 0, qi)),
             pl.BlockSpec((1, 1, bq), lambda bn, qi, ki: (bn, 0, qi)),
             pl.BlockSpec((1, bq, 1), lambda bn, qi, ki: (bn // N, qi, 0)),
@@ -458,8 +462,8 @@ def _flash_bwd(q, k, v, segments, out, lse, g, scale, causal, window, block_q, b
         in_specs=[
             pl.BlockSpec((1, bq, H), lambda bk, ki, j: (q_head(bk, j), q_block(ki, j), 0)),
             pl.BlockSpec((1, bkv, H), lambda bk, ki, j: (bk, ki, 0)),
-            pl.BlockSpec((1, bkv, H), lambda bk, ki, j: (bk, ki, 0)),
-            pl.BlockSpec((1, bq, H), lambda bk, ki, j: (q_head(bk, j), q_block(ki, j), 0)),
+            pl.BlockSpec((1, bkv, Hv), lambda bk, ki, j: (bk, ki, 0)),
+            pl.BlockSpec((1, bq, Hv), lambda bk, ki, j: (q_head(bk, j), q_block(ki, j), 0)),
             pl.BlockSpec((1, 1, bq), lambda bk, ki, j: (q_head(bk, j), 0, q_block(ki, j))),
             pl.BlockSpec((1, 1, bq), lambda bk, ki, j: (q_head(bk, j), 0, q_block(ki, j))),
             pl.BlockSpec((1, 1, bq), lambda bk, ki, j: (bk // K, 0, q_block(ki, j))),
@@ -467,15 +471,15 @@ def _flash_bwd(q, k, v, segments, out, lse, g, scale, causal, window, block_q, b
         ],
         out_specs=[
             pl.BlockSpec((1, bkv, H), lambda bk, ki, j: (bk, ki, 0)),
-            pl.BlockSpec((1, bkv, H), lambda bk, ki, j: (bk, ki, 0)),
+            pl.BlockSpec((1, bkv, Hv), lambda bk, ki, j: (bk, ki, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * K, S, H), jnp.float32),
-            jax.ShapeDtypeStruct((B * K, S, H), jnp.float32),
+            jax.ShapeDtypeStruct((B * K, S, Hv), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bkv, H), jnp.float32),
-            pltpu.VMEM((bkv, H), jnp.float32),
+            pltpu.VMEM((bkv, Hv), jnp.float32),
         ],
         compiler_params=_compiler_params(bq, bkv),
         interpret=interpret,
@@ -484,7 +488,7 @@ def _flash_bwd(q, k, v, segments, out, lse, g, scale, causal, window, block_q, b
 
     dq = dq.reshape(B, N, T, H).transpose(0, 2, 1, 3)
     dk = dk_p.reshape(B, K, S, H).transpose(0, 2, 1, 3).astype(k.dtype)
-    dv = dv_p.reshape(B, K, S, H).transpose(0, 2, 1, 3).astype(v.dtype)
+    dv = dv_p.reshape(B, K, S, Hv).transpose(0, 2, 1, 3).astype(v.dtype)
     return dq, dk, dv
 
 
@@ -493,7 +497,7 @@ def _flash_bwd(q, k, v, segments, out, lse, g, scale, causal, window, block_q, b
 def flash_attention(
     q: jnp.ndarray,  # [B, T, N, H]
     k: jnp.ndarray,  # [B, S, K, H]
-    v: jnp.ndarray,
+    v: jnp.ndarray,  # [B, S, K, Hv]: the value head may differ from the key's (latent attention: 192 and 128)
     segment_ids: Optional[jnp.ndarray] = None,  # [B, T] packed-batch segments
     scale: Optional[float] = None,
     causal: bool = True,

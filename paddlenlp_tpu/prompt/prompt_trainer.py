@@ -94,13 +94,15 @@ class SoftPromptModelForCausalLM:
         out = self.model.module.apply({"params": base}, inputs_embeds=inputs_embeds,
                                       attention_mask=attention_mask,
                                       deterministic=deterministic, **kw)
+        # with ``mutable`` (the Trainer asks for the layers' counters) flax returns (outputs, collections)
+        out, *collections = out if kw.get("mutable") else (out,)
         # slice the virtual-token span off so logits align with the caller's
         # [B, T] labels (the built-in causal-LM loss shifts against them)
         if hasattr(out, "logits"):
             import dataclasses as _dc
 
-            return _dc.replace(out, logits=out.logits[:, self.n_prompt_tokens:])
-        return out
+            out = _dc.replace(out, logits=out.logits[:, self.n_prompt_tokens:])
+        return (out, *collections) if collections else out
 
     def _embedding(self, params):
         prefix = type(self.model).base_model_prefix

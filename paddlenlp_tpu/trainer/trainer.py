@@ -262,10 +262,18 @@ class Trainer:
     def compute_loss(self, params, inputs: Dict[str, Any], dropout_rng=None):
         """Override point (reference trainer.py compute_loss). ``labels`` follow the
         HF convention (unshifted; shift happens here for causal LM)."""
+        return self._loss_and_counters(params, inputs, dropout_rng)[0]
+
+    def _loss_and_counters(self, params, inputs: Dict[str, Any], dropout_rng=None):
+        """The built-in loss, and what the model's layers counted on the device while
+        computing it: the ``counters`` collection a module sows into (an expert
+        layer's assignments and loads, ``latent_layers.GROUPED_COUNTERS``), summed
+        over the modules by name. Empty for a model that counts nothing."""
         inputs = dict(inputs)
         labels = inputs.pop("labels", None)
         rngs = {"dropout": dropout_rng} if dropout_rng is not None else {}
-        outputs = self.model.module.apply({"params": params}, **inputs, deterministic=False, rngs=rngs)
+        outputs, sown = self.model.module.apply({"params": params}, **inputs, deterministic=False, rngs=rngs,
+                                                mutable=["counters"])
         if labels is None:
             raise ValueError("training requires `labels` in inputs (or override compute_loss)")
         logits = outputs.logits if hasattr(outputs, "logits") else outputs[0]
@@ -277,7 +285,11 @@ class Trainer:
         aux = getattr(outputs, "aux_loss", None)
         if aux is not None:  # MoE router load-balancing (pre-weighted by its coef)
             loss = loss + aux
-        return loss
+        counters: Dict[str, Any] = {}
+        for path, value in jax.tree_util.tree_flatten_with_path(sown.get("counters", {}))[0]:
+            name = str(getattr(path[-1], "key", path[-1]))
+            counters[name] = counters.get(name, 0.0) + value
+        return loss, counters
 
     # ------------------------------------------------------------------ train step
     def _use_pipeline(self) -> bool:
@@ -343,8 +355,13 @@ class Trainer:
 
             return self._with_rules(jax.jit(pipeline_train_step, donate_argnums=(0,)))
 
+        builtin_loss = type(self).compute_loss is Trainer.compute_loss
+
         def loss_for_micro(params, micro, rng):
-            return self.compute_loss(params, micro, dropout_rng=rng)
+            """(loss, counters): an overridden ``compute_loss`` gives the loss alone."""
+            if builtin_loss:
+                return self._loss_and_counters(params, micro, dropout_rng=rng)
+            return self.compute_loss(params, micro, dropout_rng=rng), {}
 
         def train_step(state: TrainState, batch, dropout_rng):
             import optax
@@ -353,25 +370,26 @@ class Trainer:
             if accum > 1:
                 def micro_step(carry, micro):
                     grads_acc, loss_acc, i = carry
-                    loss, grads = jax.value_and_grad(loss_for_micro)(
+                    (loss, counters), grads = jax.value_and_grad(loss_for_micro, has_aux=True)(
                         state.params, micro, jax.random.fold_in(rng, i)
                     )
                     grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
-                    return (grads_acc, loss_acc + loss, i + 1), None
+                    return (grads_acc, loss_acc + loss, i + 1), counters
 
                 zero_grads = jax.tree.map(jnp.zeros_like, state.params)
-                (grads, loss, _), _ = jax.lax.scan(
+                (grads, loss, _), counters = jax.lax.scan(
                     micro_step, (zero_grads, jnp.zeros((), jnp.float32), 0), batch
                 )
                 grads = jax.tree.map(lambda g: g / accum, grads)
                 loss = loss / accum
+                counters = jax.tree.map(lambda c: jnp.sum(c, axis=0), counters)  # over the micro-batches
             else:
-                loss, grads = jax.value_and_grad(loss_for_micro)(state.params, batch, rng)
+                (loss, counters), grads = jax.value_and_grad(loss_for_micro, has_aux=True)(state.params, batch, rng)
             grad_norm = optax.global_norm(grads)
             updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
             params = optax.apply_updates(state.params, updates)
             new_state = TrainState(params=params, opt_state=opt_state, step=state.step + 1)
-            metrics = {"loss": loss, "grad_norm": grad_norm}
+            metrics = {"loss": loss, "grad_norm": grad_norm, **counters}
             return new_state, metrics
 
         return self._with_rules(jax.jit(train_step, donate_argnums=(0,)))
@@ -671,6 +689,8 @@ class Trainer:
                     self.timers("forward-backward-optimizer").stop(
                         block_on=metrics["loss"] if will_log else None
                     )
+                    if will_log:  # the step has finished: what its layers counted comes to the host in one fetch
+                        metrics = {**metrics, **_counted(metrics)}
                     last_metrics = metrics
                     self._interval_losses.append(metrics["loss"])
                     self.state.global_step += 1
@@ -694,10 +714,13 @@ class Trainer:
                     self.control = self.callback_handler.on_step_end(
                         args, self.state, self.control, step_tokens=step_tokens,
                         seq_len=seq_len)
+                    # what the step's layers counted rides the span of a step that logs: the
+                    # Trainer has blocked on that step's loss by now, so reading them waits for nothing
+                    counted = _counted(metrics) if will_log else {}
                     TRACER.add_span("train_step", TRACER.epoch_time(step_t0),
                                     time.perf_counter() - step_t0, cat="trainer",
                                     trace="train", step=self.state.global_step,
-                                    tokens=step_tokens)
+                                    tokens=step_tokens, **counted)
                     self._maybe_log_save_evaluate(last_metrics, train_start, tokens_seen)
                     if self.control.should_training_stop or self.state.global_step >= max_steps:
                         break
@@ -759,6 +782,7 @@ class Trainer:
                 else args.learning_rate,
                 "global_step": self.state.global_step,
             }
+            logs.update(_counted(metrics))
             logs.update(
                 speed_metrics(
                     "interval",
@@ -992,6 +1016,13 @@ class Trainer:
 
     def remove_callback(self, callback):
         self.callback_handler.remove_callback(callback)
+
+
+def _counted(metrics: Dict[str, Any]) -> Dict[str, float]:
+    """What a step's layers counted on the device (``_loss_and_counters``): its metrics but the loss and the
+    norm, as host floats (one ``device_get`` for all of them; a float already on the host passes through)."""
+    counted = {k: v for k, v in metrics.items() if k not in ("loss", "grad_norm")}
+    return {k: float(v) for k, v in jax.device_get(counted).items()}
 
 
 def _default_collator(features: List[Dict[str, Any]]) -> Dict[str, np.ndarray]:

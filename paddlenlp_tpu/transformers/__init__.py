@@ -39,6 +39,7 @@ from .ernie import (  # noqa: F401
     ErnieModel,
 )
 from .deepseek_v2 import DeepseekV2Config, DeepseekV2ForCausalLM, DeepseekV2Model  # noqa: F401
+from .deepseek_v3 import DeepseekV3Config, DeepseekV3ForCausalLM, DeepseekV3Model  # noqa: F401
 from .dots3_note import Dots3NoteConfig, Dots3NoteForCausalLM, Dots3NoteModel  # noqa: F401
 from .exaone_moe import ExaoneMoeConfig, ExaoneMoeForCausalLM, ExaoneMoeModel  # noqa: F401
 from .gemma import GemmaConfig, GemmaForCausalLM, GemmaModel  # noqa: F401

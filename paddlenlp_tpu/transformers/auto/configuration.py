@@ -37,6 +37,7 @@ def _populate():
     from ..qwen2_moe.configuration import Qwen2MoeConfig
     from ..bart.configuration import BartConfig
     from ..deepseek_v2.configuration import DeepseekV2Config
+    from ..deepseek_v3.configuration import DeepseekV3Config
     from ..dots3_note.configuration import Dots3NoteConfig
     from ..exaone_moe.configuration import ExaoneMoeConfig
     from ..mamba.configuration import MambaConfig
@@ -72,7 +73,7 @@ def _populate():
 
     for cfg in (LlamaConfig, GPTConfig, Qwen2Config, MistralConfig, GemmaConfig, BertConfig,
                 ErnieConfig, MixtralConfig, Qwen2MoeConfig, BaichuanConfig, BloomConfig,
-                OPTConfig, QWenConfig, ChatGLMv2Config, T5Config, BartConfig, DeepseekV2Config, Dots3NoteConfig, ExaoneMoeConfig,
+                OPTConfig, QWenConfig, ChatGLMv2Config, T5Config, BartConfig, DeepseekV2Config, DeepseekV3Config, Dots3NoteConfig, ExaoneMoeConfig,
                 NemotronHConfig,
                 MambaConfig, RWConfig, ChatGLMConfig, YuanConfig, JambaConfig,
                 AlbertConfig, ElectraConfig, RobertaConfig,

@@ -100,6 +100,10 @@ def _populate_models():
 
     register_model("deepseek_v2", "base", deepseek_v2.DeepseekV2Model)
     register_model("deepseek_v2", "causal_lm", deepseek_v2.DeepseekV2ForCausalLM)
+    from ..deepseek_v3 import modeling as deepseek_v3
+
+    register_model("deepseek_v3", "base", deepseek_v3.DeepseekV3Model)
+    register_model("deepseek_v3", "causal_lm", deepseek_v3.DeepseekV3ForCausalLM)
     from ..dots3_note import modeling as dots3_note
 
     register_model("dots3_note", "base", dots3_note.Dots3NoteModel)

@@ -93,24 +93,26 @@ class DeepseekV2Attention(nn.Module):
         q_head_dim = d_nope + d_rope
         d_v = cfg.v_head_dim
 
-        # ---- q path (optionally low-rank: hidden -> q_lora -> heads)
-        if cfg.q_lora_rank is None:
-            q = _dense(n_heads * q_head_dim, False, cfg, self.dtype, self.param_dtype, "q_proj")(hidden_states)
-        else:
-            qa = _dense(cfg.q_lora_rank, cfg.attention_bias, cfg, self.dtype, self.param_dtype, "q_a_proj")(hidden_states)
-            qa = LlamaRMSNorm(cfg.q_lora_rank, cfg.rms_norm_eps, name="q_a_layernorm")(qa)
-            q = _dense(n_heads * q_head_dim, False, cfg, self.dtype, self.param_dtype, "q_b_proj")(qa)
-        q = q.reshape(B, T, n_heads, q_head_dim)
+        # scopes (forward and backward operations carry them): mla_proj, rope, mla_attn, o_proj
+        with jax.named_scope("mla_proj"):
+            # ---- q path (optionally low-rank: hidden -> q_lora -> heads)
+            if cfg.q_lora_rank is None:
+                q = _dense(n_heads * q_head_dim, False, cfg, self.dtype, self.param_dtype, "q_proj")(hidden_states)
+            else:
+                qa = _dense(cfg.q_lora_rank, cfg.attention_bias, cfg, self.dtype, self.param_dtype, "q_a_proj")(hidden_states)
+                qa = LlamaRMSNorm(cfg.q_lora_rank, cfg.rms_norm_eps, name="q_a_layernorm")(qa)
+                q = _dense(n_heads * q_head_dim, False, cfg, self.dtype, self.param_dtype, "q_b_proj")(qa)
+            q = q.reshape(B, T, n_heads, q_head_dim)
 
-        # ---- kv path: compressed latent + a single shared rope head (MQA-style)
-        ckv = _dense(cfg.kv_lora_rank + d_rope, cfg.attention_bias, cfg, self.dtype, self.param_dtype,
-                     "kv_a_proj_with_mqa")(hidden_states)
-        c_kv, k_pe = ckv[..., : cfg.kv_lora_rank], ckv[..., cfg.kv_lora_rank :]
-        c_kv = LlamaRMSNorm(cfg.kv_lora_rank, cfg.rms_norm_eps, name="kv_a_layernorm")(c_kv)
-        kvb = _dense(n_heads * (d_nope + d_v), False, cfg, self.dtype, self.param_dtype, "kv_b_proj")(c_kv)
-        kvb = kvb.reshape(B, T, n_heads, d_nope + d_v)
-        k_nope, v = kvb[..., :d_nope], kvb[..., d_nope:]
-        k_pe = k_pe.reshape(B, T, 1, d_rope)
+            # ---- kv path: compressed latent + a single shared rope head (MQA-style)
+            ckv = _dense(cfg.kv_lora_rank + d_rope, cfg.attention_bias, cfg, self.dtype, self.param_dtype,
+                         "kv_a_proj_with_mqa")(hidden_states)
+            c_kv, k_pe = ckv[..., : cfg.kv_lora_rank], ckv[..., cfg.kv_lora_rank :]
+            c_kv = LlamaRMSNorm(cfg.kv_lora_rank, cfg.rms_norm_eps, name="kv_a_layernorm")(c_kv)
+            kvb = _dense(n_heads * (d_nope + d_v), False, cfg, self.dtype, self.param_dtype, "kv_b_proj")(c_kv)
+            kvb = kvb.reshape(B, T, n_heads, d_nope + d_v)
+            k_nope, v = kvb[..., :d_nope], kvb[..., d_nope:]
+            k_pe = k_pe.reshape(B, T, 1, d_rope)
 
         q = shard_constraint(q, P("batch", "act_seq_attn", "act_heads", None))
         k_nope = shard_constraint(k_nope, P("batch", "act_seq_attn", "act_heads", None))
@@ -138,10 +140,11 @@ class DeepseekV2Attention(nn.Module):
             x32 = x.astype(jnp.float32)
             return (x32 * cos[:, :, None, :] + rotate_half(x32) * sin[:, :, None, :]).astype(x.dtype)
 
-        q_pe = rope(q[..., d_nope:])
-        k_pe = rope(k_pe)
-        q = jnp.concatenate([q[..., :d_nope], q_pe], axis=-1)
-        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe, (B, T, n_heads, d_rope))], axis=-1)
+        with jax.named_scope("rope"):
+            q_pe = rope(q[..., d_nope:])
+            k_pe = rope(k_pe)
+            q = jnp.concatenate([q[..., :d_nope], q_pe], axis=-1)
+            k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe, (B, T, n_heads, d_rope))], axis=-1)
 
         q_offset = 0
         new_kv = None
@@ -158,25 +161,24 @@ class DeepseekV2Attention(nn.Module):
         dropout_rng = self.make_rng("dropout") if dropout_rate > 0.0 else None
         q = checkpoint_name(q, "attn_qkv")
         k = checkpoint_name(k, "attn_qkv")
-        # V runs padded up to the q/k head dim so every attention backend (flash
-        # kernel included) sees uniform head dims; the pad is sliced off after
-        # (the reference does the same around FA, modeling.py:154-175). The
-        # cached-decode path is already padded.
-        if kv is None:
-            v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, q_head_dim - d_v)))
+        # Without a cache V goes to the dispatcher at its own width: the flash kernels
+        # carry a value head beside the query/key head (192 and 128), and the XLA
+        # path pads it there. The cached-decode path is already padded.
         v_run = checkpoint_name(v, "attn_qkv")
-        attn_out = dot_product_attention(
-            q, k, v_run,
-            attention_mask=attention_mask,
-            segment_ids=segment_ids,
-            causal=True,
-            q_offset=q_offset,
-            scale=softmax_scale,
-            dropout_rate=dropout_rate,
-            dropout_rng=dropout_rng,
-        )
+        with jax.named_scope("mla_attn"):
+            attn_out = dot_product_attention(
+                q, k, v_run,
+                attention_mask=attention_mask,
+                segment_ids=segment_ids,
+                causal=True,
+                q_offset=q_offset,
+                scale=softmax_scale,
+                dropout_rate=dropout_rate,
+                dropout_rng=dropout_rng,
+            )
         attn_out = attn_out[..., :d_v].reshape(B, T, n_heads * d_v)  # named "core_attn" by the call
-        out = _dense(cfg.hidden_size, cfg.attention_bias, cfg, self.dtype, self.param_dtype, "o_proj")(attn_out)
+        with jax.named_scope("o_proj"):
+            out = _dense(cfg.hidden_size, cfg.attention_bias, cfg, self.dtype, self.param_dtype, "o_proj")(attn_out)
         return out, new_kv
 
 

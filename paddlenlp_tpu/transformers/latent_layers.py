@@ -29,7 +29,11 @@ squared ReLU), the routing and the held share are the same. The functions:
                       sorted by expert into tile-aligned groups and a loop of
                       data-dependent length runs one tile of one expert at a
                       time (the caller's ``ExpertBody``), so no token is
-                      dropped and an expert nobody chose is never read.
+                      dropped and an expert nobody chose is never read;
+- ``experts_grouped`` the same part of the result with a backward, for
+                      training: assignments sorted by expert, the three
+                      products grouped over the held stacks at the rows each
+                      expert received (``megablox.gmm``), combined in float32.
 
 What they read of a configuration ``cfg``: ``attention_dims(kind)`` (heads,
 q_lora, kv_lora, nope, rope, v, theta, window, s_q, s_kv), ``index_n_heads``,
@@ -234,6 +238,103 @@ def experts_held(p, x2d, idx, w, first, count, live=None, body: ExpertBody = SWI
     picked = y_rows[jnp.minimum(dest, rows - 1)].reshape(n, k, hidden)
     weight = jnp.where(ok, w, 0.0).astype(jnp.float32)
     return jnp.einsum("nkd,nk->nd", picked.astype(jnp.float32), weight).astype(x2d.dtype)
+
+
+# ------------------------------------------------------------------ the held experts with a backward (training)
+GROUPED_COUNTERS = ("expert_assignments", "expert_assignments_local", "expert_tokens_max", "expert_product_rows")
+
+
+def _gmm_tiling(m, k, n):
+    """(rows, contraction, columns) of one grid step of a grouped product: 256 rows
+    of one expert against up to 1024 x 1024 of its matrix (a 256 x 1024 x 768 step is
+    2 us of MXU work against 0.35 us of step cost; the weights are read once a 256
+    rows, at the chip's ridge of 240 FLOP a byte). Small products (tests) in one step."""
+    return (256 if m % 256 == 0 else 8), min(k, 1024), min(n, 1024)
+
+
+def _gmm(x, w, sizes):
+    """x [m, k] rows sorted by group, w [count, k, n], sizes [count + 1] (the last group
+    is the rows no held expert takes) -> [m, n]: row r of group e times ``w[e]``, zeros
+    in the last group. Only the tiles the first ``count`` groups fill are visited (the
+    grid's length is computed on the device), and the call has a backward."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    return megablox.gmm(x, w.astype(x.dtype), sizes, x.dtype, _gmm_tiling, None, None, False,
+                        jax.default_backend() != "tpu")
+
+
+def _product_rows(sizes, m, count):
+    """Rows of one ``_gmm`` call's products: a grid step along the rows covers one row
+    tile for one group, so a group costs the tiles from the one its first row lies in
+    to the one its last row lies in (``megablox``'s ``make_group_metadata`` counts its
+    grid so; tests/transformers/test_deepseek_v3.py holds the two together)."""
+    tm = _gmm_tiling(m, 1, 1)[0]
+    ends = jnp.cumsum(sizes)[:count]
+    starts = ends - sizes[:count]
+    return jnp.sum(jnp.where(sizes[:count] > 0, -(-ends // tm) - starts // tm, 0)) * tm
+
+
+def experts_grouped(p, x2d, idx, w, first, count, total):
+    """``experts_held`` with a reverse-mode derivative: the held experts' part of
+    ``sum_k w_k E_k(x)`` for SwiGLU experts stacked ``[count, ...]`` in ``p``, and
+    what the layer counted (``GROUPED_COUNTERS``, float32 scalars). ``total`` is the
+    router's width.
+
+    No capacity and no drop: the N*k assignments are sorted by held expert (those
+    no held expert takes last) and walked in chunks of a static number of rows,
+    twice the rows an even router would send here; a chunk past the last held
+    assignment is skipped (``lax.cond``), so under any imbalance every held
+    assignment is computed and memory stays that of one chunk. Inside a chunk the
+    three products are grouped: an expert's matrices meet exactly the rows that
+    chose it, padded to the kernel's row tile. The weighted rows are added into
+    the tokens in float32."""
+    n, k = idx.shape
+    hidden, a = x2d.shape[-1], n * k
+    local = idx - first
+    ok = (local >= 0) & (local < count)
+    flat = jnp.where(ok, local, count).reshape(-1)  # [A]; count = no held expert
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)  # assignments by held expert, the others last
+    sizes = jnp.sum(jax.nn.one_hot(flat, count + 1, dtype=jnp.int32), 0)  # [count + 1]
+    held = a - sizes[count]
+    ends = jnp.cumsum(sizes)
+    tile = _gmm_tiling(a, 1, 1)[0]
+    rows = -(-min(a, -(-2 * a * count // total)) // tile) * tile  # a chunk: twice an even load, whole row tiles
+    chunks = -(-a // rows)
+    order = jnp.pad(order, (0, chunks * rows - a), constant_values=a)  # past the end: no assignment
+    w_flat = jnp.concatenate([jnp.where(ok, w, 0.0).astype(jnp.float32).reshape(-1), jnp.zeros((1,), jnp.float32)])
+    x_pad = jnp.concatenate([x2d, jnp.zeros((1, hidden), x2d.dtype)], 0)  # row n: what a padded row reads
+
+    @jax.checkpoint  # the backward needs a chunk's rows again, not every chunk's at once
+    def chunk_rows(x_pad, p, w_flat, ends, picks, lo):
+        with jax.named_scope("expert_dispatch"):
+            token = jnp.minimum(picks // k, n)
+            xs = x_pad[token]
+            in_chunk = jnp.clip(ends, lo, lo + rows) - lo
+            sz = jnp.diff(in_chunk, prepend=0)
+            sz = sz.at[count].set(rows - in_chunk[count - 1])  # the chunk's tail: not held, or padding
+        with jax.named_scope("expert_mm"):
+            act = jax.nn.silu(_gmm(xs, p["gate_proj"], sz)) * _gmm(xs, p["up_proj"], sz)
+            ys = _gmm(act, p["down_proj"], sz)
+        with jax.named_scope("expert_combine"):
+            return ys.astype(jnp.float32) * w_flat[picks][:, None], token, _product_rows(sz, rows, count)
+
+    def one_chunk(carry, c):
+        y, visited = carry
+        lo = c * rows
+        picks = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+
+        def run(y, visited):
+            weighted, token, steps = chunk_rows(x_pad, p, w_flat, ends, picks, lo)
+            with jax.named_scope("expert_combine"):
+                return y.at[token].add(weighted), visited + steps
+
+        return jax.lax.cond(lo < held, run, lambda y, visited: (y, visited), y, visited), None
+
+    init = (jnp.zeros((n + 1, hidden), jnp.float32), jnp.zeros((), jnp.int32))
+    (y, visited), _ = jax.lax.scan(one_chunk, init, jnp.arange(chunks, dtype=jnp.int32))
+    counters = dict(zip(GROUPED_COUNTERS, (jnp.float32(a), held.astype(jnp.float32),
+                                           jnp.max(sizes[:count]).astype(jnp.float32), visited.astype(jnp.float32))))
+    return y[:n].astype(x2d.dtype), counters
 
 
 def moe(p, x, cfg, live=None, body: ExpertBody = SWIGLU):

@@ -131,7 +131,7 @@ from test_modeling_common import CAUSAL_CASES  # noqa: E402
 # config-zoo dir -> tiny family case (test_modeling_common registry)
 ZOO_FAMILY = {
     "qwen": "qwen", "qwen2": "qwen2", "mixtral": "mixtral", "mistral": "mistral",
-    "baichuan": "baichuan", "deepseek-v2": "deepseek_v2", "gpt-3": "gpt",
+    "baichuan": "baichuan", "deepseek-v2": "deepseek_v2", "deepseek-v3": "deepseek_v3", "gpt-3": "gpt",
     "opt": "opt", "bloom": "bloom", "chatglm": "chatglm", "chatglm2": "chatglm_v2",
     "gemma": "gemma", "yuan": "yuan", "llama": "llama",
 }

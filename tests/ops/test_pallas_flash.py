@@ -293,3 +293,65 @@ def test_saved_residuals_are_the_recomputed_ones(case):
         for name, a, b in zip("qkv", remat(q, k, v), plain):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=f"d{name}")
         assert str(jax.make_jaxpr(remat)(q, k, v)).count("name=flash_attention_fwd") == forward_kernels
+
+
+# ---------------------------------------------------------------- a value head of its own width
+def _qkv_two_widths(B, T, N, K, H, Hv, seed=0):
+    rng = np.random.default_rng(seed)
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    return draw(B, T, N, H), draw(B, T, K, H), draw(B, T, K, Hv), draw(B, T, N, Hv)
+
+
+@pytest.mark.parametrize("case", ["192-128", "192-128-segments", "64-128-gqa"])
+def test_value_head_of_its_own_width_forward_and_all_three_gradients(case):
+    """Latent attention's head sizes (query/key 192, value 128: two tiles of 128 a side) and a value head wider
+    than the key's under GQA, interpret mode, against the plain attention: the output and dQ, dK (key width), dV
+    (value width)."""
+    from paddlenlp_tpu.ops.flash_attention import _math_attention, make_causal_mask, make_segment_mask
+
+    H, Hv = (64, 128) if case.startswith("64") else (192, 128)
+    N, K = (4, 2) if case.endswith("gqa") else (2, 2)
+    B, T = 1, 256
+    q, k, v, probe = _qkv_two_widths(B, T, N, K, H, Hv)
+    seg = packed_segments(B, T) if case.endswith("segments") else None
+    mask = make_causal_mask(T, T)
+    if seg is not None:
+        mask = jnp.logical_and(mask, make_segment_mask(seg, seg))
+
+    def kernel(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, seg, H ** -0.5, True, None, 128, 128, True) * probe)
+
+    def plain(q, k, v):
+        return jnp.sum(_math_attention(q, k, v, mask, H ** -0.5) * probe)
+
+    (a, ga), (b, gb) = jax.value_and_grad(kernel, (0, 1, 2))(q, k, v), jax.value_and_grad(plain, (0, 1, 2))(q, k, v)
+    assert flash_attention(q, k, v, seg, H ** -0.5, True, None, 128, 128, True).shape == (B, T, N, Hv)
+    assert abs(float(a) - float(b)) < 1e-3
+    assert [g.shape for g in ga] == [q.shape, k.shape, v.shape]
+    for got, want in zip(ga, gb):
+        assert float(jnp.abs(got - want).max()) < 2e-5
+
+
+def test_the_dispatcher_takes_a_value_head_of_its_own_width_on_both_paths():
+    """With the kernel (forced: interpret mode off the chip) and through the XLA path, which pads and slices."""
+    q, k, v, _ = _qkv_two_widths(1, 128, 2, 2, 192, 128, seed=3)
+    with_kernel = dot_product_attention(q, k, v, causal=True, scale=192 ** -0.5, use_pallas=True)
+    through_xla = dot_product_attention(q, k, v, causal=True, scale=192 ** -0.5, use_pallas=False)
+    assert with_kernel.shape == through_xla.shape == (1, 128, 2, 128)
+    assert float(jnp.abs(with_kernel - through_xla).max()) < 2e-5
+    wide = dot_product_attention(v, v, q, causal=True, use_pallas=False)  # a value head wider than the key's
+    assert wide.shape == (1, 128, 2, 192)
+
+
+def test_at_equal_head_sizes_the_kernel_calls_are_what_they_were():
+    """The forward and both backward calls at one head size, as a jaxpr with source positions cut out, against the
+    digest of the same text made on the commit before the kernels took a second width (PR 39's tree:
+    ``python3 -c`` of this test's body there gives the digest; it changes only when the calls do)."""
+    import hashlib
+    import re
+
+    q = jnp.zeros((2, 256, 4, 64), jnp.bfloat16)
+    kv = jnp.zeros((2, 256, 2, 64), jnp.bfloat16)
+    loss = lambda q, k, v: flash_attention(q, k, v, None, None, True, None, 128, 128, True).astype(jnp.float32).sum()
+    text = re.sub(r" at [^\s\]]+:\d+", "", str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv)))
+    assert hashlib.sha256(text.encode()).hexdigest() == "8251a34c54ac40e19280c70f32106e9b18917da15e276209348419a37ea0f68e"

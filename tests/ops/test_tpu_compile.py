@@ -138,9 +138,10 @@ def test_ragged_paged_run_attention_compiles_within_the_default_vmem(chip, rows,
     assert '"scoped_memory_configs":[]' in text  # no vmem_limit_bytes was asked for
 
 
-FLASH_SHAPES = {  # batch, tokens, query heads, kv heads, head_dim
+FLASH_SHAPES = {  # batch, tokens, query heads, kv heads, head_dim (query/key, then the value head where it differs)
     "cell-4x2048-h64-group7": (4, 2048, 14, 2, 64),  # qwen2-0.5b-pretrain.seq2k, exactly
     "h128-group6-4096": (2, 4096, 12, 2, 128),  # Qwen2-1.5B's heads
+    "cell-2x8192-h192-v128": (2, 8192, 32, 32, 192, 128),  # kanana2-30b-a3b-pretrain-ep8.seq8k, exactly
 }
 
 
@@ -151,11 +152,12 @@ def test_flash_attention_compiles(chip, shape, masking, backward):
     """At the tiles the kernel file's rule picks (no block argument) and with the
     64 MiB of scoped VMEM it asks for on this chip, and with a caller's own
     128 x 128 blocks under segments, as before the rule."""
-    batch, seq, heads, kv_heads, head_dim = FLASH_SHAPES[shape]
+    batch, seq, heads, kv_heads, head_dim, *value_dim = FLASH_SHAPES[shape]
     blocks = (128, 128) if masking.endswith("128x128") else (None, None)
     aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
     q = aval((batch, seq, heads, head_dim), jnp.bfloat16)
     kv = aval((batch, seq, kv_heads, head_dim), jnp.bfloat16)
+    value = aval((batch, seq, kv_heads, *(value_dim or [head_dim])), jnp.bfloat16)
     segments = aval((batch, seq), jnp.int32)  # packed batches: [B,T,1] / [B,1,S] tiles
 
     def forward(q, k, v, seg):
@@ -166,7 +168,7 @@ def test_flash_attention_compiles(chip, shape, masking, backward):
         return forward(q, k, v, seg).astype(jnp.float32).sum()
 
     text = compiled_text(jax.grad(loss, argnums=(0, 1, 2)) if backward else forward,
-                         q, kv, kv, segments)
+                         q, kv, value, segments)
     # forward alone is one kernel; the gradient adds dq and dk/dv
     assert text.count('custom_call_target="tpu_custom_call"') == (3 if backward else 1)
     # half the chip's VMEM for a 1024 x 1024 step, the compiler's default for the caller's small blocks

@@ -21,6 +21,8 @@ from paddlenlp_tpu.transformers import (
     BaichuanConfig,
     DeepseekV2Config,
     DeepseekV2ForCausalLM,
+    DeepseekV3Config,
+    DeepseekV3ForCausalLM,
     MambaConfig,
     MambaForCausalLM,
     BaichuanForCausalLM,
@@ -101,6 +103,13 @@ CAUSAL_CASES = {
                       "mscale": 0.707, "mscale_all_dim": 0.707,
                       "beta_fast": 32, "beta_slow": 1},
         **TINY)),
+    # MLA without a q latent at two head sizes, dense layer 0, then sigmoid bias-corrected routing over 8 experts
+    # of which this process holds 4 (a share: the router stays 8 wide), grouped products with a backward
+    "deepseek_v3": (DeepseekV3ForCausalLM, lambda: DeepseekV3Config(
+        vocab_size=96, intermediate_size=112, moe_intermediate_size=48,
+        q_lora_rank=None, kv_lora_rank=16, qk_rope_head_dim=8, qk_nope_head_dim=8, v_head_dim=16,
+        n_routed_experts=4, n_routed_experts_total=8, first_held_expert=2, n_shared_experts=2, num_experts_per_tok=3,
+        n_group=1, topk_group=1, first_k_dense_replace=1, routed_scaling_factor=2.448, **TINY)),
     # hybrid: NoPE attention at layer 1, mamba elsewhere; MoE ffn on odd layers
     "jamba": (JambaForCausalLM, lambda: JambaConfig(
         vocab_size=96, hidden_size=64, intermediate_size=112, num_hidden_layers=4,
