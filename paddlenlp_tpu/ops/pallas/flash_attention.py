@@ -25,32 +25,32 @@ Structure (classic flash-attention-2 schedule):
   attention: 192 and 128): v, dO, the output, its accumulator and dV carry the
   value width, q, k, dQ and dK the key width. Alike, the calls are what they were.
 
-What one grid step does. A step costs about 0.35 us whatever it does, so it
-has to do a tile's worth of work:
-- the tile comes from the shape (``_blocks``): ``_TILE`` on both axes, cut to
-  the sequence, with half the chip's VMEM asked for (``_vmem_share``);
-- a step the causal mask (or the window) skips names the block already resident:
-  the index maps clip to the first / last block the row of tiles needs
-  (``_kv_blocks``, ``_q_blocks``), so Pallas issues no copy for it;
-- every tile that runs builds the element mask (``_visible`` drops the tests
-  the call rules out); a second body without it for tiles wholly below the
-  diagonal bought nothing on the chip;
-- the MXU takes the operands in the inputs' precision with float32 accumulation
-  (bf16 q, k, v, dO as they are; p and dS cast to the inputs' dtype); running
-  maximum, sum, logsumexp, delta and every accumulator stay float32;
-- per-row statistics never have the shape [rows, 1]: logsumexp and delta cross
-  HBM as lane-dense [1, T] rows (a [T, 1] array is padded to 128 lanes there)
-  and live in VMEM as [rows, 128] with every lane alike (``_across``), since
-  arithmetic on a [rows, 1] value costs by the row.
+What one grid step does. A step costs about 0.35 us whatever it does, so it has to do a tile's worth of work:
+- the tile comes from the shape (``_blocks``): ``_TILE`` on both axes, cut to the sequence; half the VMEM asked for;
+- a step the causal mask (or the window) skips names the block already resident: the index maps clip to the
+  first / last block the row of tiles needs (``_kv_blocks``, ``_q_blocks``), so Pallas issues no copy for it;
+- the tile the diagonal crosses (the last of a causal row) has a body of its own, walked in strips of ``_strip``
+  rows of the accumulators (q rows in the forward and dq, kv rows in dkv; static slices): strip ``i`` takes the
+  columns its rows see, so the rectangles that ``cols <= rows`` masks whole are not computed (``_strips``: 10 of a
+  tile's 16 sub-blocks at strips of 256: 56.25% of the score square at 2,048 tokens where whole tiles were 75%,
+  51.6% at 8,192 where they were 56.25%) and a row's sums keep their order: the results are the whole tile's bit for
+  bit. Below the diagonal dq and dkv run the whole-tile body; the forward walks every tile in full strips (one
+  strip's products beside the last one's softmax: 5-7% of a call). Not causal, a caller's unequal blocks, a tile of
+  one strip: the whole-tile body, the kernels as they were. XLA is told what a call computes (``_cost``);
+- every sub-block that runs builds the element mask (``_visible`` drops the tests the call rules out, and below
+  the diagonal the causal one): segments, a window and a partial last tile mask inside it and skip nothing more;
+- the MXU takes the operands in the inputs' precision with float32 accumulation (bf16 q, k, v, dO as they are; p
+  and dS cast to the inputs' dtype); running maximum, sum, logsumexp, delta and every accumulator stay float32;
+- per-row statistics never have the shape [rows, 1]: logsumexp and delta cross HBM as lane-dense [1, T] rows (a
+  [T, 1] array is padded to 128 lanes there) and live in VMEM as [rows, 128] with every lane alike (``_across``).
 
-Under remat the forward rule names the two residuals its backward reads and no
-caller can name (``flash_out``, ``flash_lse``: ``_fwd``); a policy that saves
-both (llama/modeling.py:_remat_policy) runs the forward kernel once a layer.
+Once a program: ``_flash_fwd`` and ``_flash_bwd`` sit behind ``jax.jit``, so a kernel's body is traced and lowered to
+Mosaic once for each distinct call, not once a call site (an unrolled model paid both a layer on every start, warm
+compile cache or not: ``setup_s``); the compiled program is what it was. Under remat the forward rule names the two
+residuals its backward reads (``flash_out``, ``flash_lse``: ``_fwd``); a policy that saves both runs the forward once.
 
-Left for later (sizes: PERF.md section 5, ROADMAP S3): the part of a tile above
-the diagonal (strips inside a step would skip it); two 64-wide heads in one
-128-lane tile; the softmax of one strip over the matmul of the next.
-
+Left for later (PERF.md section 7, ROADMAP S3): two 64-wide heads in one 128-lane tile (at 64 the kernels are bound
+by MXU passes half empty); the lower edge of a window (the diagonal's mirror image); one backward kernel for two.
 Off-TPU (tests), the kernels run in Pallas interpret mode.
 """
 
@@ -203,10 +203,95 @@ def _zero_oob(x, start, limit, block, axis=0):
     return jnp.where(idx < limit, x.astype(jnp.float32), 0.0).astype(x.dtype)
 
 
+# ---------------------------------------------------------------- strips inside a tile
+# Height of the strips a grid step walks its tile in (chip sweep on v5e at 64/64 x 2,048 and
+# 192/128 x 8,192: 512 is slower everywhere, 128 within 1%. PERF.md section 6, PRs 41 and 42).
+_STRIP = 256
+
+
+def _strip(block_q, block_kv, causal):
+    """Height of the strips a step walks its tile in, from the shape: ``_STRIP`` where it
+    divides the tile into several, else 128 (whole sublanes of the operands, whole lanes of
+    the rows of statistics and segment ids). None where the whole-tile body runs: a call that
+    is not causal, unequal blocks (a caller's own), a tile of one strip."""
+    if not causal or block_q != block_kv:
+        return None
+    return next((strip for strip in (_STRIP, _LANES) if block_q % strip == 0 and strip < block_q), None)
+
+
+def _strips(block, strip, crossed, transposed=False):
+    """(rows, columns) of the sub-blocks a step computes of its tile: strip ``i`` of the
+    tile's rows, which are the accumulators' rows, so each is updated once a tile and a
+    row's sums keep their order. Of a tile the diagonal crosses a strip takes the columns
+    its rows see: ``[0, (i + 1) strip)`` of the [q, kv] tile, ``[i strip, block)`` of the
+    transposed one; what is left out is what ``cols <= rows`` masks whole."""
+    for start in range(0, block, strip):
+        rows = slice(start, start + strip)
+        yield rows, slice(0, block) if not crossed else slice(start, block) if transposed else slice(0, rows.stop)
+
+
+def computed_elements(q_len, kv_len, block_q, block_kv, strip, causal, window=None):
+    """Score elements one head of a call computes: a tile that runs, whole; of the tile the
+    diagonal crosses, with strips, the sub-blocks that hold a visible element."""
+    n_q, n_k = pl.cdiv(q_len, block_q), pl.cdiv(kv_len, block_kv)
+    tiles = 0
+    for qi in range(n_q):  # ``_kv_blocks`` on plain integers
+        hi = min((qi * block_q + block_q - 1) // block_kv, n_k - 1) if causal else n_k - 1
+        lo = max(qi * block_q - window + 1, 0) // block_kv if window is not None else 0
+        tiles += hi - lo + 1
+    if strip is None:
+        return tiles * block_q * block_kv
+    crossed = sum((r.stop - r.start) * (c.stop - c.start) for r, c in _strips(block_q, strip, True))
+    return (tiles - n_q) * block_q * block_kv + n_q * crossed
+
+
+def _cost(elements, heads, qk_products, v_products, head_dim, value_dim, arrays):
+    """What a call does, for XLA's schedule round the kernel (with it ``seq8k``'s step is 3.5 ms shorter on the
+    chip: PERF.md section 6, PR 42) and the trace's operation statistics: ``elements`` scores a head
+    (``computed_elements``), each an exponential and a row of every product."""
+    return pl.CostEstimate(
+        flops=2 * heads * elements * (qk_products * head_dim + v_products * value_dim),
+        transcendentals=heads * elements,
+        bytes_accessed=sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize for a in arrays))
+
+
+def _run(strip, tile, lo, hi, crossed, whole, walk, below_in_strips):
+    """The body of the step at ``tile`` of its row of tiles ``lo..hi``. Without strips ``whole``. With them (causal,
+    equal blocks) the diagonal crosses the tile ``crossed``, an end of the row, which runs ``walk(True)``; a tile
+    below it runs ``whole``, as it was, or ``walk(False)``: full strips, where the chip is faster for them."""
+    runs = jnp.logical_and(lo <= tile, tile <= hi)
+    if strip is None:
+        pl.when(runs)(whole)
+    else:
+        pl.when(jnp.logical_and(runs, tile != crossed))(functools.partial(walk, False) if below_in_strips else whole)
+        pl.when(tile == crossed)(functools.partial(walk, True))
+
+
+def _sub_visible(geometry, q_rows, k_rows, below, seg_q, seg_k, q_axis=0):
+    """``_visible`` for the sub-block ``q_rows`` x ``k_rows`` of a tile that ``_run`` walks in strips. ``below``:
+    the tile lies wholly below the diagonal, so the causal test is left out. Of a ragged last tile the rows
+    past the sequence are masked (its columns past it are ``cols > rows`` of the rows that are left)."""
+    q_start, k_start, block_q, _, causal, window, q_len, _ = geometry
+    n_q, n_k = q_rows.stop - q_rows.start, k_rows.stop - k_rows.start
+    valid = _visible(q_start + q_rows.start, k_start + k_rows.start, n_q, n_k, causal and not below, window,
+                     n_q, n_k, seg_q, seg_k, q_axis)
+    if q_len % block_q:
+        rows = jax.lax.broadcasted_iota(jnp.int32, (n_q, n_k) if q_axis == 0 else (n_k, n_q), q_axis)
+        inside = q_start + q_rows.start + rows < q_len
+        valid = inside if valid is None else jnp.logical_and(valid, inside)
+    return valid
+
+
+def _sub(ref, rows, start, limit, block, axis=0):
+    """Rows ``rows`` (columns, with ``axis=1``) of the block in ``ref``, which starts at ``start`` of ``limit``."""
+    x = ref[0, rows, :] if axis == 0 else ref[0, :, rows]
+    return _zero_oob(x, start + rows.start, limit, block, axis)
+
+
 # ---------------------------------------------------------------- forward
 def _fa_kernel(q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, lse_ref,
                m_scratch, l_scratch, acc_scratch, *,
-               scale, block_q, block_kv, causal, window, q_len, kv_len, use_segments):
+               scale, block_q, block_kv, causal, window, q_len, kv_len, use_segments, strip):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     n_k = pl.num_programs(2)
@@ -222,25 +307,39 @@ def _fa_kernel(q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, lse_ref,
     geometry = (q_start, k_start, block_q, block_kv, causal, window, q_len, kv_len)
     lo, hi = _kv_blocks(qi, block_q, block_kv, n_k, causal, window)
 
-    @pl.when(jnp.logical_and(lo <= ki, ki <= hi))
-    def _tile():
+    def update(rows, q, k, v, valid):
+        """Online softmax of the accumulators' rows ``rows`` (``...``: all) over the scores of ``q`` against ``k``."""
+        s = _nt(q, k) * scale
+        if valid is not None:
+            s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_scratch[rows]  # [rows, 128]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _across(m_new, s.shape[1]))
+        if valid is not None:
+            p = jnp.where(valid, p, 0.0)  # exp(NEG-NEG)=1 on fully-masked rows
+        alpha = jnp.exp(m_prev - m_new)
+        l_scratch[rows] = alpha * l_scratch[rows] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scratch[rows] = acc_scratch[rows] * _across(alpha, acc_scratch.shape[1]) + _dot(p.astype(v.dtype), v)
+        m_scratch[rows] = m_new
+
+    def whole():
         q = _zero_oob(q_ref[0], q_start, q_len, block_q)
         k = _zero_oob(k_ref[0], k_start, kv_len, block_kv)
         v = _zero_oob(v_ref[0], k_start, kv_len, block_kv)
         valid = _visible(*geometry, sq_ref[0] if use_segments else None,
                          sk_ref[0] if use_segments else None)
-        s = _nt(q, k) * scale
-        if valid is not None:
-            s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_scratch[...]  # [block_q, 128]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - _across(m_new, block_kv))
-        if valid is not None:
-            p = jnp.where(valid, p, 0.0)  # exp(NEG-NEG)=1 on fully-masked rows
-        alpha = jnp.exp(m_prev - m_new)
-        l_scratch[...] = alpha * l_scratch[...] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scratch[...] = acc_scratch[...] * _across(alpha, acc_scratch.shape[1]) + _dot(p.astype(v.dtype), v)
-        m_scratch[...] = m_new
+        update(..., q, k, v, valid)
+
+    def walk(crossed):
+        for rows, cols in _strips(block_q, strip, crossed):
+            valid = _sub_visible(geometry, rows, cols, not crossed,
+                                 sq_ref[0, rows, :] if use_segments else None,
+                                 sk_ref[0, :, cols] if use_segments else None)
+            update(rows, _sub(q_ref, rows, q_start, q_len, block_q), _sub(k_ref, cols, k_start, kv_len, block_kv),
+                   _sub(v_ref, cols, k_start, kv_len, block_kv), valid)
+
+    # full strips below the diagonal too: one strip's products run beside the last strip's softmax
+    _run(strip, ki, lo, hi, qi, whole, walk, below_in_strips=True)
 
     @pl.when(ki == n_k - 1)
     def _finalize():
@@ -262,6 +361,13 @@ def _segments(segments, B, T):
     return seg[:, :, None], seg[:, None, :]
 
 
+# A program traces and lowers a kernel once for every (shapes, these arguments) it calls it with, not once a call
+# site: unrolled layers, and a layer's recomputed forward, share the jaxpr, and the module holds one function for it.
+_once_a_program = functools.partial(
+    jax.jit, static_argnames=("scale", "causal", "window", "block_q", "block_kv", "interpret"))
+
+
+@_once_a_program
 def _flash_fwd(q, k, v, segments, scale, causal, window, block_q, block_kv, interpret):
     B, T, N, H = q.shape
     Hv = v.shape[-1]  # the value head: the accumulator's and the output's width, H where the heads are alike
@@ -283,10 +389,15 @@ def _flash_fwd(q, k, v, segments, scale, causal, window, block_q, block_kv, inte
     def kv_block(qi, ki):  # a skipped step names the nearest block its row of tiles needs: no copy
         return jnp.clip(ki, *_kv_blocks(qi, block_q, block_kv, n_k, causal, window))
 
+    strip = _strip(block_q, block_kv, causal)
     kernel = functools.partial(
         _fa_kernel, scale=scale, block_q=block_q, block_kv=block_kv,
-        causal=causal, window=window, q_len=T, kv_len=S, use_segments=use_seg,
+        causal=causal, window=window, q_len=T, kv_len=S, use_segments=use_seg, strip=strip,
     )
+    out_shape = [
+        jax.ShapeDtypeStruct((B * N, T, Hv), q.dtype),
+        jax.ShapeDtypeStruct((B * N, 1, T), jnp.float32),
+    ]
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * N, n_q, n_k),
@@ -301,16 +412,15 @@ def _flash_fwd(q, k, v, segments, scale, causal, window, block_q, block_kv, inte
             pl.BlockSpec((1, block_q, Hv), lambda bn, qi, ki: (bn, qi, 0)),
             pl.BlockSpec((1, 1, block_q), lambda bn, qi, ki: (bn, 0, qi)),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * N, T, Hv), q.dtype),
-            jax.ShapeDtypeStruct((B * N, 1, T), jnp.float32),
-        ],
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # m
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # l
             pltpu.VMEM((block_q, Hv), jnp.float32),  # acc
         ],
         compiler_params=_compiler_params(block_q, block_kv),
+        cost_estimate=_cost(computed_elements(T, S, block_q, block_kv, strip, causal, window), B * N, 1, 1, H, Hv,
+                            [qf, kf, vf, *out_shape]),
         interpret=interpret,
         name="flash_attention_fwd",
     )(qf, kf, vf, seg_col, seg_row)
@@ -320,7 +430,7 @@ def _flash_fwd(q, k, v, segments, scale, causal, window, block_q, block_kv, inte
 # ---------------------------------------------------------------- backward
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_ref,
                    dq_ref, dq_scratch, lse_scratch, delta_scratch, *,
-                   scale, block_q, block_kv, causal, window, q_len, kv_len, use_segments):
+                   scale, block_q, block_kv, causal, window, q_len, kv_len, use_segments, strip):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     n_k = pl.num_programs(2)
@@ -336,8 +446,15 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_r
     geometry = (q_start, k_start, block_q, block_kv, causal, window, q_len, kv_len)
     lo, hi = _kv_blocks(qi, block_q, block_kv, n_k, causal, window)
 
-    @pl.when(jnp.logical_and(lo <= ki, ki <= hi))
-    def _tile():
+    def update(rows, q, k, v, do, lse, delta, valid):
+        """dQ of the rows ``rows`` (``...``: all) from the scores of ``q`` against ``k``."""  # lse, delta: [rows, 128]
+        p = jnp.exp(_nt(q, k) * scale - _across(lse, k.shape[0]))
+        if valid is not None:
+            p = jnp.where(valid, p, 0.0)
+        ds = p * (_nt(do, v) - _across(delta, k.shape[0]))  # times scale, once, in _finalize
+        dq_scratch[rows] += _dot(ds.astype(k.dtype), k)
+
+    def whole():
         q = _zero_oob(q_ref[0], q_start, q_len, block_q)
         k = _zero_oob(k_ref[0], k_start, kv_len, block_kv)
         v = _zero_oob(v_ref[0], k_start, kv_len, block_kv)
@@ -348,11 +465,19 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_r
         delta = _zero_oob(delta_scratch[...], q_start, q_len, block_q)
         valid = _visible(*geometry, sq_ref[0] if use_segments else None,
                          sk_ref[0] if use_segments else None)
-        p = jnp.exp(_nt(q, k) * scale - _across(lse, block_kv))
-        if valid is not None:
-            p = jnp.where(valid, p, 0.0)
-        ds = p * (_nt(do, v) - _across(delta, block_kv))  # times scale, once, in _finalize
-        dq_scratch[...] += _dot(ds.astype(k.dtype), k)
+        update(..., q, k, v, do, lse, delta, valid)
+
+    def walk(crossed):
+        for rows, cols in _strips(block_q, strip, crossed):
+            valid = _sub_visible(geometry, rows, cols, not crossed,
+                                 sq_ref[0, rows, :] if use_segments else None,
+                                 sk_ref[0, :, cols] if use_segments else None)
+            update(rows, _sub(q_ref, rows, q_start, q_len, block_q), _sub(k_ref, cols, k_start, kv_len, block_kv),
+                   _sub(v_ref, cols, k_start, kv_len, block_kv), _sub(do_ref, rows, q_start, q_len, block_q),
+                   lse_scratch[rows, :], _zero_oob(delta_scratch[rows, :], q_start + rows.start, q_len, block_q),
+                   valid)
+
+    _run(strip, ki, lo, hi, qi, whole, walk, below_in_strips=False)
 
     @pl.when(ki == n_k - 1)
     def _finalize():
@@ -362,7 +487,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_r
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_ref,
                     dk_ref, dv_ref, dk_scratch, dv_scratch, *,
                     scale, block_q, block_kv, causal, window, q_len, kv_len, use_segments,
-                    n_q):
+                    n_q, strip):
     """Works on the transposed tile: scores [block_kv, block_q], logsumexp,
     delta and the q-side segment ids as [1, block_q] rows."""
     ki = pl.program_id(1)
@@ -380,8 +505,16 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_
     geometry = (q_start, k_start, block_q, block_kv, causal, window, q_len, kv_len)
     lo, hi = _q_blocks(ki, block_q, block_kv, n_q, causal, window)
 
-    @pl.when(jnp.logical_and(lo <= qi, qi <= hi))
-    def _tile():
+    def update(rows, q, k, v, do, lse, delta, valid):
+        """dK, dV of the kv rows ``rows`` from the scores of ``k`` against ``q``."""  # lse, delta: [1, q]
+        pt = jnp.exp(_nt(k, q) * scale - lse)  # p^T
+        if valid is not None:
+            pt = jnp.where(valid, pt, 0.0)
+        dv_scratch[rows] += _dot(pt.astype(do.dtype), do)
+        dst = pt * (_nt(v, do) - delta)  # dS^T; times scale, once, in _finalize
+        dk_scratch[rows] += _dot(dst.astype(q.dtype), q)
+
+    def whole():
         q = _zero_oob(q_ref[0], q_start, q_len, block_q)
         k = _zero_oob(k_ref[0], k_start, kv_len, block_kv)
         v = _zero_oob(v_ref[0], k_start, kv_len, block_kv)
@@ -391,12 +524,18 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_
         delta = _zero_oob(delta_ref[0], q_start, q_len, block_q, axis=1)
         valid = _visible(*geometry, sq_ref[0] if use_segments else None,
                          sk_ref[0] if use_segments else None, q_axis=1)
-        pt = jnp.exp(_nt(k, q) * scale - lse)  # p^T
-        if valid is not None:
-            pt = jnp.where(valid, pt, 0.0)
-        dv_scratch[...] += _dot(pt.astype(do.dtype), do)
-        dst = pt * (_nt(v, do) - delta)  # dS^T; times scale, once, in _finalize
-        dk_scratch[...] += _dot(dst.astype(q.dtype), q)
+        update(..., q, k, v, do, lse, delta, valid)
+
+    def walk(crossed):
+        for rows, cols in _strips(block_kv, strip, crossed, transposed=True):  # kv rows, q columns
+            valid = _sub_visible(geometry, cols, rows, not crossed,
+                                 sq_ref[0, :, cols] if use_segments else None,
+                                 sk_ref[0, rows, :] if use_segments else None, q_axis=1)
+            update(rows, _sub(q_ref, cols, q_start, q_len, block_q), _sub(k_ref, rows, k_start, kv_len, block_kv),
+                   _sub(v_ref, rows, k_start, kv_len, block_kv), _sub(do_ref, cols, q_start, q_len, block_q),
+                   lse_ref[0, :, cols], _sub(delta_ref, cols, q_start, q_len, block_q, axis=1), valid)
+
+    _run(strip, qi, lo, hi, ki, whole, walk, below_in_strips=False)
 
     @pl.when(j == n_j - 1)
     def _finalize():
@@ -404,6 +543,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_
         dv_ref[0] = dv_scratch[...].astype(dv_ref.dtype)
 
 
+@_once_a_program
 def _flash_bwd(q, k, v, segments, out, lse, g, scale, causal, window, block_q, block_kv, interpret):
     B, T, N, H = q.shape
     S, K, Hv = k.shape[1], k.shape[2], v.shape[-1]  # dO and dV at the value head's width, dQ and dK at the key's
@@ -415,11 +555,12 @@ def _flash_bwd(q, k, v, segments, out, lse, g, scale, causal, window, block_q, b
     delta = delta.transpose(0, 2, 1).reshape(B * N, 1, T)
     use_seg = segments is not None
     seg_col, seg_row = _segments(segments, B, T)
-    common = dict(scale=scale, causal=causal, window=window, q_len=T, kv_len=S, use_segments=use_seg)
-
     # dq: as the forward. Logsumexp and delta are [B*N, 1, T] rows in both kernels
     bq, bkv = _blocks(block_q, block_kv, T, S)
     n_q, n_k = pl.cdiv(T, bq), pl.cdiv(S, bkv)
+    strip = _strip(bq, bkv, causal)
+    elements = computed_elements(T, S, bq, bkv, strip, causal, window)
+    common = dict(scale=scale, causal=causal, window=window, q_len=T, kv_len=S, use_segments=use_seg, strip=strip)
 
     def kv_block(qi, ki):
         return jnp.clip(ki, *_kv_blocks(qi, bq, bkv, n_k, causal, window))
@@ -442,6 +583,7 @@ def _flash_bwd(q, k, v, segments, out, lse, g, scale, causal, window, block_q, b
         scratch_shapes=[pltpu.VMEM((bq, H), jnp.float32),
                         pltpu.VMEM((bq, _LANES), jnp.float32), pltpu.VMEM((bq, _LANES), jnp.float32)],
         compiler_params=_compiler_params(bq, bkv),
+        cost_estimate=_cost(elements, B * N, 2, 1, H, Hv, [qf, kf, vf, dof, lse, delta, qf]),  # dq: q's shape
         interpret=interpret,
         name="flash_attention_bwd_dq",
     )(qf, kf, vf, dof, lse, delta, seg_col, seg_row)
@@ -456,6 +598,10 @@ def _flash_bwd(q, k, v, segments, out, lse, g, scale, causal, window, block_q, b
     def q_block(ki, j):
         return jnp.clip(j % n_q, *_q_blocks(ki, bq, bkv, n_q, causal, window))
 
+    dkv_shape = [
+        jax.ShapeDtypeStruct((B * K, S, H), jnp.float32),
+        jax.ShapeDtypeStruct((B * K, S, Hv), jnp.float32),
+    ]
     dk_p, dv_p = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **common, block_q=bq, block_kv=bkv, n_q=n_q),
         grid=(B * K, n_k, group * n_q),
@@ -473,15 +619,13 @@ def _flash_bwd(q, k, v, segments, out, lse, g, scale, causal, window, block_q, b
             pl.BlockSpec((1, bkv, H), lambda bk, ki, j: (bk, ki, 0)),
             pl.BlockSpec((1, bkv, Hv), lambda bk, ki, j: (bk, ki, 0)),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * K, S, H), jnp.float32),
-            jax.ShapeDtypeStruct((B * K, S, Hv), jnp.float32),
-        ],
+        out_shape=dkv_shape,
         scratch_shapes=[
             pltpu.VMEM((bkv, H), jnp.float32),
             pltpu.VMEM((bkv, Hv), jnp.float32),
         ],
         compiler_params=_compiler_params(bq, bkv),
+        cost_estimate=_cost(elements, B * N, 2, 2, H, Hv, [qf, kf, vf, dof, lse, delta, *dkv_shape]),
         interpret=interpret,
         name="flash_attention_bwd_dkv",
     )(qf, kf, vf, dof, lse, delta, seg_row, seg_col)

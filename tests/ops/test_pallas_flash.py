@@ -182,37 +182,67 @@ def packed_segments(B, T, seed=0):
     return jnp.asarray((pos >= cuts[:, :1]).astype(np.int32) + (pos >= cuts[:, 1:]))
 
 
-# (T, query heads, kv heads, head_dim, dtype, segments, window): the tiles are the rule's own
-# (block_q / block_kv not passed), so the rule and the clamped index maps are what is under
-# test. 1024 x 1024 tiles at these T:
+def case(T, N, K, H, dtype=jnp.float32, Hv=None, cuts=None, window=None, blocks=(None, None), strip=None):
+    """``cuts``: "seeded" (three documents a row, cut at seeded places) or the two places themselves;
+    ``strip``: the height of the strips the kernels must walk a crossed tile in, None for the whole-tile body."""
+    return dict(T=T, N=N, K=K, H=H, dtype=dtype, Hv=Hv or H, cuts=cuts, window=window, blocks=blocks, strip=strip)
+
+
+# The tiles are the rule's own (no block argument but in the last two), so the rule, the clamped
+# index maps and the strip walk are what is under test. 1024 x 1024 tiles at these T:
 DEFAULT_TILE_CASES = {
-    "one-tile-h64-gqa7": (128, 7, 1, 64, jnp.float32, False, None),
-    "one-tile-h128-mha-bf16": (128, 2, 2, 128, jnp.bfloat16, False, None),
-    "several-tiles-h64-gqa7": (1536, 7, 1, 64, jnp.float32, False, None),  # 1024 + 512, a partial last block
-    "cell-2048-h64-gqa7-bf16": (2048, 7, 1, 64, jnp.bfloat16, False, None),
-    "cell-2048-h128-mha": (2048, 2, 2, 128, jnp.float32, False, None),
-    "segments-h64-gqa7": (1536, 7, 1, 64, jnp.float32, True, None),
-    "segments-h128-mha-bf16": (2048, 2, 2, 128, jnp.bfloat16, True, None),
-    "window-h64-gqa7": (2048, 7, 1, 64, jnp.float32, False, 300),
-    "window-skips-tiles-h64": (3072, 2, 1, 64, jnp.float32, False, 300),  # the last rows' first tile lies below it
-    "window-h128-mha-bf16": (2048, 2, 2, 128, jnp.bfloat16, False, 640),
-    "segments-and-window": (1536, 2, 1, 64, jnp.float32, True, 200),
-    "ragged-every-kernel-h128": (1152, 2, 1, 128, jnp.float32, False, None),  # 1024 + 128
-    "ragged-h64-bf16": (1664, 2, 2, 64, jnp.bfloat16, False, None),
+    "one-tile-h64-gqa7": case(128, 7, 1, 64),
+    "one-tile-h128-mha-bf16": case(128, 2, 2, 128, jnp.bfloat16),
+    "several-tiles-h64-gqa7": case(1536, 7, 1, 64, strip=256),  # 1024 + 512: the crossed last tile is partial
+    "cell-2048-h64-gqa7-bf16": case(2048, 7, 1, 64, jnp.bfloat16, strip=256),
+    "cell-2048-h128-mha": case(2048, 2, 2, 128, strip=256),
+    "segments-h64-gqa7": case(1536, 7, 1, 64, cuts="seeded", strip=256),
+    "segments-h128-mha-bf16": case(2048, 2, 2, 128, jnp.bfloat16, cuts="seeded", strip=256),
+    "window-h64-gqa7": case(2048, 7, 1, 64, window=300, strip=256),  # the window cuts into both diagonal tiles
+    "window-skips-tiles-h64": case(3072, 2, 1, 64, window=300, strip=256),  # the last rows' first tile lies below it
+    "window-h128-mha-bf16": case(2048, 2, 2, 128, jnp.bfloat16, window=640, strip=256),
+    "segments-and-window": case(1536, 2, 1, 64, cuts="seeded", window=200, strip=256),
+    "ragged-every-kernel-h128": case(1152, 2, 1, 128, strip=256),  # 1024 + 128: three strips past the sequence
+    "ragged-h64-bf16": case(1664, 2, 2, 64, jnp.bfloat16, strip=256),  # the sequence ends inside a strip
+    "ragged-h192-v128-segments-blocks-of-512": case(1280, 2, 2, 192, Hv=128, cuts=(300, 1100), blocks=(512, 512),
+                                                    strip=256),  # 3 x 3 tiles, the last a strip long
+    # the strip walk: one diagonal tile and 2 x 2 tiles, the head sizes of both training cells and 128
+    "strips-one-tile-h64-gqa7": case(1024, 7, 1, 64, strip=256),
+    "strips-one-tile-h64-mha-bf16": case(1024, 2, 2, 64, jnp.bfloat16, strip=256),
+    "strips-one-tile-h128-mha": case(1024, 2, 2, 128, strip=256),
+    "strips-one-tile-h192-v128": case(1024, 2, 2, 192, Hv=128, strip=256),
+    "strips-2x2-h192-v128-bf16": case(2048, 2, 2, 192, jnp.bfloat16, Hv=128, strip=256),
+    "strips-2x2-h64-mha": case(2048, 2, 2, 64, strip=256),
+    "strips-segments-cut-inside-strips": case(2048, 2, 1, 64, cuts=(300, 1500), strip=256),
+    "strips-segments-cut-on-strip-edges": case(2048, 2, 1, 64, cuts=(256, 1280), strip=256),
+    "strips-segments-h192-v128-bf16": case(1024, 2, 2, 192, jnp.bfloat16, Hv=128, cuts=(512, 700), strip=256),
+    "strips-window-inside-a-strip-h192-v128": case(1024, 2, 2, 192, Hv=128, window=100, strip=256),
+    "strips-segments-and-window": case(2048, 2, 1, 64, cuts=(256, 1100), window=400, strip=256),
+    "strips-of-128-a-tile-of-384": case(384, 2, 1, 64, strip=128),
+    "strips-of-128-callers-blocks-of-256": case(512, 2, 2, 128, jnp.bfloat16, blocks=(256, 256), strip=128),
+    "unequal-blocks-take-the-whole-tile-body": case(2048, 2, 1, 64, blocks=(512, 1024)),
+    "unequal-blocks-segments-bf16": case(2048, 2, 2, 64, jnp.bfloat16, cuts=(300, 1500), blocks=(1024, 512)),
 }
 
 
-@pytest.mark.parametrize("case", DEFAULT_TILE_CASES)
-def test_default_tiles_match_the_xla_path(case):
+@pytest.mark.parametrize("name", DEFAULT_TILE_CASES)
+def test_default_tiles_match_the_xla_path(name):
     """Forward and all three gradients at the tiles the rule picks, every row of
-    tiles (the first, whose later steps clamp to its one block, and the last)."""
-    T, N, K, H, dtype, segmented, window = DEFAULT_TILE_CASES[case]
-    q, k, v = qkv(B=1, T=T, N=N, K=K, H=H, seed=T + N, dtype=dtype)
-    w = qkv(B=1, T=T, N=N, K=K, H=H, seed=1)[0]  # float32 weights of the scalar that is differentiated
-    seg = packed_segments(1, T) if segmented else None
+    tiles (the first, whose later steps clamp to its one block, and the last), with
+    the body the case names: strips in the tiles the diagonal crosses, or the whole tile."""
+    c = DEFAULT_TILE_CASES[name]
+    T, N, K, H, Hv, dtype, window, blocks = (c[key] for key in ("T", "N", "K", "H", "Hv", "dtype", "window", "blocks"))
+    assert kernel_file._strip(*kernel_file._blocks(*blocks, T, T), True) == c["strip"]
+    q, k, _ = qkv(B=1, T=T, N=N, K=K, H=H, seed=T + N, dtype=dtype)
+    v = qkv(B=1, T=T, N=N, K=K, H=Hv, seed=T + N + 1, dtype=dtype)[2]
+    w = qkv(B=1, T=T, N=N, K=K, H=Hv, seed=1)[0]  # float32 weights of the scalar that is differentiated
+    if c["cuts"] is None or c["cuts"] == "seeded":
+        seg = packed_segments(1, T) if c["cuts"] else None
+    else:
+        seg = jnp.asarray(sum((np.arange(T)[None] >= cut).astype(np.int32) for cut in c["cuts"]))
 
     def f_pallas(q, k, v):
-        out = flash_attention(q, k, v, seg, None, True, window, interpret=True)
+        out = flash_attention(q, k, v, seg, None, True, window, *blocks, interpret=True)
         return (out.astype(jnp.float32) * w).sum(), out
 
     def f_ref(q, k, v):
@@ -221,6 +251,7 @@ def test_default_tiles_match_the_xla_path(case):
 
     (_, out), grads = jax.value_and_grad(f_pallas, argnums=(0, 1, 2), has_aux=True)(q, k, v)
     (_, ref), ref_grads = jax.value_and_grad(f_ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    assert out.shape == (1, T, N, Hv) and [g.shape for g in grads] == [q.shape, k.shape, v.shape]
     f32 = lambda a: np.asarray(a, dtype=np.float32)
     if dtype == jnp.float32:
         np.testing.assert_allclose(f32(out), f32(ref), atol=2e-5)
@@ -261,6 +292,122 @@ def test_tile_spans_against_the_mask(block_q, block_kv, window):
             assert (lo <= ki <= hi) == bool(tile.any()), (qi, ki)
             q_lo, q_hi = (int(x) for x in kernel_file._q_blocks(ki, block_q, block_kv, n_q, True, window))
             assert (q_lo <= qi <= q_hi) == bool(tile.any()), (qi, ki)
+
+
+# ---------------------------------------------------------------- strips inside a tile
+@pytest.mark.parametrize("block,strip,run,of", [(1024, 256, 10, 16), (1024, 128, 36, 64), (512, 256, 3, 4),
+                                                (384, 128, 6, 9), (256, 128, 3, 4)])
+@pytest.mark.parametrize("transposed", [False, True], ids=["q-by-kv", "kv-by-q"])
+def test_strips_cover_what_is_visible_of_a_crossed_tile(block, strip, run, of, transposed):
+    """Every visible element of a tile the diagonal crosses lies in a sub-block that runs, no element in two, the
+    sub-blocks are ``run`` of the tile's ``of`` squares of ``strip``, and their corners lie on whole strips (sublanes
+    of the operands, lanes of the rows of statistics). Of a tile below the diagonal the strips cover everything."""
+    q, kv = np.arange(block)[:, None], np.arange(block)[None, :]
+    visible = (kv <= q).T if transposed else kv <= q  # [kv, q] or [q, kv]
+    covered = np.zeros((block, block), np.int32)
+    for rows, cols in kernel_file._strips(block, strip, True, transposed):
+        covered[rows, cols] += 1
+        assert visible[rows, cols].any()
+        assert all(edge % strip == 0 for edge in (rows.start, rows.stop, cols.start, cols.stop))
+    assert covered.max() == 1 and (covered[visible] == 1).all()
+    assert covered.sum() * of == run * block * block
+    below = np.zeros((block, block), np.int32)
+    for rows, cols in kernel_file._strips(block, strip, False, transposed):
+        below[rows, cols] += 1
+    assert (below == 1).all()
+
+
+@pytest.mark.parametrize("T,block,strip,window,tiles", [
+    (2048, 1024, None, None, 3), (2048, 1024, 256, None, 2.25), (2048, 1024, 128, None, 2.125),
+    (8192, 1024, None, None, 36), (8192, 1024, 256, None, 33), (8192, 1024, 128, None, 32.5),
+    (3072, 1024, 256, 300, 2 + 3 * 10 / 16),  # rows of tiles 1 and 2 each reach one tile back
+    (1024, 1024, 256, None, 10 / 16), (2176, 1024, None, None, 6), (1536, 512, 256, None, 3 + 3 * 3 / 4),
+])
+def test_computed_share_of_the_square_against_the_mask(T, block, strip, window, tiles):
+    """What a call computes (``computed_elements``, from shapes alone) in tiles' worth, and against the element
+    mask: a tile is counted iff it holds a visible element, whole without strips; with them, of a crossed tile the
+    sub-blocks ``_strips`` gives, each of which holds a visible element. At the rule's own tile and strip: 56.25% of
+    the square at 2,048 where it was 75%, 51.6% at 8,192 where it was 56.25% (the kernel file's docstring)."""
+    assert kernel_file.computed_elements(T, T, block, block, strip, True, window) == tiles * block * block
+    assert kernel_file.computed_elements(T, T, block, block, None, False) == (-(-T // block) * block) ** 2  # not causal
+    rows, cols = np.arange(T)[:, None], np.arange(T)[None, :]
+    visible = cols <= rows
+    if window is not None:
+        visible &= cols > rows - window
+    n = -(-T // block)
+    counted = 0
+    for qi in range(n):
+        for ki in range(n):
+            tile = visible[qi * block:(qi + 1) * block, ki * block:(ki + 1) * block]
+            if not tile.any():
+                continue
+            if strip is None or qi != ki:
+                counted += block * block
+            else:
+                counted += sum((r.stop - r.start) * (c.stop - c.start)
+                               for r, c in kernel_file._strips(block, strip, True) if tile[r, c].any())
+    assert counted == tiles * block * block
+    if window is None and strip == kernel_file._strip(*kernel_file._blocks(None, None, T, T), True):
+        assert {2048: 100 * tiles / 4 == 56.25, 8192: 100 * tiles / 64 == 51.5625}.get(T, True)
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation under ``jaxpr``, those inside a ``jit`` or a rule's own jaxpr too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+def _score_products(jaxpr, found):
+    """Sizes of the [rows, columns] score products (``_nt``: both operands contracted over their minor axis) of
+    every branch of every ``pl.when`` in a kernel's jaxpr, a list a branch."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            for branch in eqn.params["branches"]:
+                sizes = []
+                _collect_products(branch.jaxpr, sizes)
+                if sizes:
+                    found.append(sizes)
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                _score_products(sub, found)
+    return found
+
+
+def _collect_products(jaxpr, sizes):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and eqn.params["dimension_numbers"] == (((1,), (1,)), ((), ())):
+            sizes.append(int(np.prod(eqn.outvars[0].aval.shape)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _collect_products(sub, sizes)
+
+
+@pytest.mark.parametrize("head_dim,value_dim", [(64, 64), (192, 128)])
+def test_the_kernels_compute_what_the_count_says(head_dim, value_dim):
+    """The three kernels' own jaxprs at 2 x 2 tiles: the body of a tile the diagonal crosses makes score products of
+    10/16 of a tile (one a sub-block in the forward, two in dq and in dkv: QK^T and dO V^T), the body of a tile
+    below it of the whole tile: in four strips in the forward, in one piece (the body it was) in dq and dkv; and
+    each call's cost estimate is ``computed_elements`` times its 2 / 3 / 4 products."""
+    T, N, block = 2048, 2, 1024
+    q = jnp.zeros((1, T, N, head_dim), jnp.bfloat16)
+    v = jnp.zeros((1, T, N, value_dim), jnp.bfloat16)
+    loss = lambda q, k, v: flash_attention(q, k, v, interpret=True).astype(jnp.float32).sum()
+    calls = {eqn.params["name"]: eqn.params
+             for eqn in _pallas_calls(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, v).jaxpr)}
+    strip = kernel_file._strip(block, block, True)
+    elements = kernel_file.computed_elements(T, T, block, block, strip, True)
+    assert strip == 256 and elements == 2.25 * block * block
+    crossed = kernel_file.computed_elements(block, block, block, block, strip, True)
+    for name, score_products, below, at_v in (("flash_attention_fwd", 1, 4, 1), ("flash_attention_bwd_dq", 2, 1, 1),
+                                              ("flash_attention_bwd_dkv", 2, 1, 2)):
+        bodies = sorted(_score_products(calls[name]["jaxpr"], []), key=sum)
+        assert [sum(sizes) for sizes in bodies] == [score_products * crossed, score_products * block * block], name
+        assert [len(sizes) for sizes in bodies] == [score_products * 4, score_products * below], name
+        cost = calls[name]["cost_estimate"]
+        assert cost.flops == 2 * N * elements * (score_products * head_dim + at_v * value_dim), name
+        assert cost.transcendentals == N * elements
 
 
 REMAT_CASES = {  # query heads, kv heads, packed segments
@@ -343,15 +490,43 @@ def test_the_dispatcher_takes_a_value_head_of_its_own_width_on_both_paths():
     assert wide.shape == (1, 128, 2, 192)
 
 
-def test_at_equal_head_sizes_the_kernel_calls_are_what_they_were():
-    """The forward and both backward calls at one head size, as a jaxpr with source positions cut out, against the
-    digest of the same text made on the commit before the kernels took a second width (PR 39's tree:
-    ``python3 -c`` of this test's body there gives the digest; it changes only when the calls do)."""
-    import hashlib
+def _kernel_call_text(eqn):
+    """A ``pallas_call`` equation (its grid, block maps and the kernel's own jaxpr) as text, with source positions
+    cut out and the cost estimate, which PR 42 added for XLA's schedule round the kernel."""
     import re
 
-    q = jnp.zeros((2, 256, 4, 64), jnp.bfloat16)
-    kv = jnp.zeros((2, 256, 2, 64), jnp.bfloat16)
-    loss = lambda q, k, v: flash_attention(q, k, v, None, None, True, None, 128, 128, True).astype(jnp.float32).sum()
-    text = re.sub(r" at [^\s\]]+:\d+", "", str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv)))
-    assert hashlib.sha256(text.encode()).hexdigest() == "8251a34c54ac40e19280c70f32106e9b18917da15e276209348419a37ea0f68e"
+    text, estimates = re.subn(r"cost_estimate=CostEstimate\([^)]*\)", "cost_estimate=None", str(eqn))
+    assert estimates == 1
+    return re.sub(r" at [^\s\]]+:\d+", "", text)
+
+
+KERNEL_CALL_DIGESTS = {  # tokens, causal, block: the digest of the three calls
+    # as on PR 40's tree: a call that is not causal, and a causal one whose tile is one strip, trace to the kernels
+    # they were (``_run`` gives them the whole-tile body, whose jaxpr is what ``_tile`` made), the estimate apart
+    "not-causal": (256, False, 128, "a0f3e8cb7a5c5388a84f4bbf2b83609be50ed8a8e76276bfe34a31e2f011dfe4"),
+    "not-causal-default-tiles": (2048, False, None, "c2506107f6d4d22c27b0790cbd6a9a46fbd33fc82682b4d494c4f6029e94e0e1"),
+    "causal-a-tile-of-one-strip": (256, True, 128, "73a65422bc72b57a3a80f0519b83b4a788858cc9c6731934b828fd98712e9aff"),
+    # re-made at PR 42, which changed it on purpose: two strips of 128 in a tile of 256 (on PR 40's tree b5f4b133...)
+    "causal-strips": (512, True, 256, "c561b622d99936d2440e5a5456cef1874dea6b3012badfba1f851a2cdc4d340f"),
+}
+
+
+@pytest.mark.parametrize("case", KERNEL_CALL_DIGESTS)
+def test_at_equal_head_sizes_the_kernel_calls_are_what_they_were(case):
+    """The forward and both backward kernel calls at one head size against the digest of the same text made on an
+    earlier tree (the lines below, run there, give it; it changes only when a call does). Until PR 42 the digest
+    was of the whole jaxpr of a causal call at blocks of 128, pinned on PR 39's tree, before the kernels took a second
+    width; the calls now sit inside a ``jit`` (once a program: the kernel file), so it is taken over the calls alone
+    and was made anew on PR 40's tree, where the old digest still held. A causal call whose tile holds several
+    strips changed at PR 42 on purpose, and its digest is that PR's; the other three must not change."""
+    import hashlib
+
+    T, causal, block, digest = KERNEL_CALL_DIGESTS[case]
+    q = jnp.zeros((2, T, 4, 64), jnp.bfloat16)
+    kv = jnp.zeros((2, T, 2, 64), jnp.bfloat16)
+    out = lambda q, k, v: flash_attention(q, k, v, None, None, causal, None, block, block, True)
+    loss = lambda q, k, v: out(q, k, v).astype(jnp.float32).sum()
+    calls = [_kernel_call_text(eqn)
+             for eqn in _pallas_calls(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv).jaxpr)]
+    assert len(calls) == 3
+    assert hashlib.sha256("\n".join(calls).encode()).hexdigest() == digest

@@ -142,16 +142,18 @@ FLASH_SHAPES = {  # batch, tokens, query heads, kv heads, head_dim (query/key, t
     "cell-4x2048-h64-group7": (4, 2048, 14, 2, 64),  # qwen2-0.5b-pretrain.seq2k, exactly
     "h128-group6-4096": (2, 4096, 12, 2, 128),  # Qwen2-1.5B's heads
     "cell-2x8192-h192-v128": (2, 8192, 32, 32, 192, 128),  # kanana2-30b-a3b-pretrain-ep8.seq8k, exactly
+    "partial-last-tile-2x2688-h64-group7": (2, 2688, 14, 2, 64),  # 2 x 1024 + 640: the last tile ends inside a strip
 }
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
-@pytest.mark.parametrize("masking", ["causal", "segments", "window", "segments-128x128"])
+@pytest.mark.parametrize("masking", ["causal", "segments", "window", "segments-window", "segments-128x128"])
 @pytest.mark.parametrize("shape", FLASH_SHAPES)
 def test_flash_attention_compiles(chip, shape, masking, backward):
-    """At the tiles the kernel file's rule picks (no block argument) and with the
-    64 MiB of scoped VMEM it asks for on this chip, and with a caller's own
-    128 x 128 blocks under segments, as before the rule."""
+    """At the tiles the kernel file's rule picks (no block argument: the tile the
+    diagonal crosses walked in strips, under a window of a whole tile and one of 300
+    that ends inside a strip) and with the 64 MiB of scoped VMEM it asks for on this
+    chip, and with a caller's own 128 x 128 blocks under segments, as before the rule."""
     batch, seq, heads, kv_heads, head_dim, *value_dim = FLASH_SHAPES[shape]
     blocks = (128, 128) if masking.endswith("128x128") else (None, None)
     aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
@@ -162,7 +164,7 @@ def test_flash_attention_compiles(chip, shape, masking, backward):
 
     def forward(q, k, v, seg):
         return flash_attention(q, k, v, seg if masking.startswith("segments") else None, None, True,
-                               1024 if masking == "window" else None, *blocks, interpret=False)
+                               {"window": 1024, "segments-window": 300}.get(masking), *blocks, interpret=False)
 
     def loss(q, k, v, seg):
         return forward(q, k, v, seg).astype(jnp.float32).sum()
@@ -173,6 +175,31 @@ def test_flash_attention_compiles(chip, shape, masking, backward):
     assert text.count('custom_call_target="tpu_custom_call"') == (3 if backward else 1)
     # half the chip's VMEM for a 1024 x 1024 step, the compiler's default for the caller's small blocks
     assert text.count('"size":"67108864"}],"custom_call_config"') == (0 if blocks[0] else 3 if backward else 1)
+
+
+@pytest.mark.parametrize("recomputed", [False, True], ids=["plain", "each-layer-recomputed"])
+def test_unrolled_layers_lower_each_distinct_kernel_once(chip, recomputed):
+    """The guard on ``setup_s``: five attention calls at ``seq8k``'s shape, one after the other as an unrolled
+    model's layers are, differentiated. Tracing a kernel's body and lowering it to Mosaic is paid on every run, warm
+    compile cache or not, so the lowered module holds one ``tpu_custom_call`` a distinct kernel (forward, dq, dkv;
+    under ``jax.checkpoint`` the recomputed forward's jaxpr is a second one) where it held one a call site (15 and
+    19), and the compiled program still runs a kernel a call site. Nothing is compiled but the last count's program."""
+    B, T, N, H, Hv = 2, 8192, 32, 192, 128
+    aval = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+
+    def layer(x, w):
+        out = flash_attention(x, x, x[..., :Hv], None, None, True, None, None, None, False)
+        return x + jnp.einsum("btnv,vh->btnh", out, w)
+
+    def loss(x, w):
+        for i in range(5):
+            x = (jax.checkpoint(layer) if recomputed else layer)(x, w[i])
+        return x.astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(aval(B, T, N, H), aval(5, Hv, H))
+    assert lowered.as_text().count("stablehlo.custom_call @tpu_custom_call") == (4 if recomputed else 3)
+    if not recomputed:
+        assert lowered.compile().as_text().count('custom_call_target="tpu_custom_call"') == 15
 
 
 def test_latent_chunk_attention_compiles_within_its_vmem_request(chip):
@@ -298,8 +325,9 @@ def test_flash_attention_compiles_for_16_mib_of_vmem(topology, monkeypatch):
 def test_flash_tile_rule(kernel):
     """The rule alone, no compiler: at the cell's shape a call is under 1,000 grid
     steps (it was 14,336), and for every ``T`` the dispatcher's gate lets through
-    (``T % 128 == 0``) a block is the whole axis or a multiple of 128 (sublanes
-    of the [block, head_dim] operands, lanes of the [1, block] rows)."""
+    (``T % 128 == 0``, up to 131,072) a block is the whole axis or a multiple of 128
+    (sublanes of the [block, head_dim] operands, lanes of the [1, block] rows) and
+    the strips inside a crossed tile are legal too."""
     from paddlenlp_tpu.ops.pallas import flash_attention as kernel_file
 
     block_q, block_kv = kernel_file._blocks(None, None, 2048, 2048)
@@ -307,9 +335,23 @@ def test_flash_tile_rule(kernel):
     steps = rows * kv_tiles * q_tiles * (7 if kernel == "dkv" else 1)
     assert 100 <= steps < 1000, (block_q, block_kv, steps)
 
-    for tokens in list(range(128, 4096 + 1, 128)) + [8192, 32768, 131072]:
-        for block in kernel_file._blocks(None, None, tokens, tokens):
+    for tokens in range(128, 131072 + 1, 128):
+        blocks = kernel_file._blocks(None, None, tokens, tokens)
+        for block in blocks:
             assert block == tokens or (block % 128 == 0 and 0 < block < tokens), (tokens, block)
+        # the strips a step walks a crossed tile in: none where a tile is one strip, else a divisor of the
+        # tile of 128 to 512 whose sub-blocks start and end on whole strips, so every slice is whole
+        # sublanes of the [block, head_dim] operands and whole lanes of the [1, block] rows
+        strip = kernel_file._strip(*blocks, True)
+        if blocks[0] == 128:
+            assert strip is None, (tokens, blocks, strip)
+            continue
+        assert strip in (128, 256, 512) and blocks[0] % strip == 0 and strip < blocks[0], (tokens, blocks, strip)
+        for rows, cols in kernel_file._strips(blocks[0], strip, True, transposed=kernel == "dkv"):
+            assert all(edge % strip == 0 and 0 <= edge <= blocks[0]
+                       for edge in (rows.start, rows.stop, cols.start, cols.stop)), (tokens, rows, cols)
+    assert kernel_file._strip(1024, 1024, False) is None  # not causal: the whole-tile body
+    assert kernel_file._strip(512, 1024, True) is None  # unequal blocks
     assert kernel_file._compiler_params(block_q, block_kv).vmem_limit_bytes is None  # no chip here: the default
     assert kernel_file._blocks(128, 256, 2048, 2048) == (128, 256)  # the caller's are honoured
     assert kernel_file._blocks(512, 512, 256, 384) == (256, 384)  # and cut to the sequence
