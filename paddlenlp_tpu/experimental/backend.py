@@ -91,7 +91,7 @@ from .kv_host_tier import HostPromoteTicket, gather_blocks, scatter_blocks
 from .launch_pack import pack
 from .paged_cache import PagedKVPool, copy_blocks
 
-__all__ = ["ModelBackend", "SingleDeviceBackend", "MixedRow", "samp_arrays",
+__all__ = ["ModelBackend", "SingleDeviceBackend", "MixedRow", "BlockRow", "samp_arrays",
            "launch_geometry"]
 
 
@@ -147,6 +147,23 @@ class MixedRow:
     #: adapter-pool slot this row's LoRA delta gathers from (0 = identity —
     #: the no-adapter row); the engine fills it from Request.adapter_slot
     adapter: int = 0
+
+
+@dataclasses.dataclass
+class BlockRow:
+    """One decode row of a kind that generates by diffusion over blocks
+    (``block_model.py``): the block the slot is at. ``tokens`` / ``masked``
+    [block_length] are its positions' tokens and which are still masked,
+    ``start`` its first position, ``fixed`` how many of its leading positions
+    are the prompt's, ``remaining`` the tokens the request is still owed."""
+
+    slot: int
+    tokens: np.ndarray
+    masked: np.ndarray
+    start: int
+    table: np.ndarray
+    fixed: int
+    remaining: int
 
 
 def _bucket(n: int, minimum: int = 16) -> int:
@@ -465,6 +482,63 @@ class SingleDeviceBackend(ModelBackend):
         if self.infer.launch_counts:
             self.step_accounting.update(self.infer.launch_counts(self.pool))
         return toks, valid
+
+    # ---------------------------------------------------------------- diffusion over blocks
+    def _block_results(self, program: str, packed, passes: int, rows: int) -> dict:
+        """The sync point of a block launch: its packed results as host arrays (``block_model.unpack_results``),
+        and the launch's device counts on ``step_accounting`` (``kv_positions`` is what they say a layer read)."""
+        from .block_model import unpack_results
+
+        with TRACER.span("wait", cat="engine", program=program):
+            out = unpack_results(np.asarray(packed), passes, rows, self.infer.block_length)  # sync-ok: THE sync point of a block launch — ids and flags only
+            counted = self.infer.launch_counts(self.pool)
+        self.step_accounting = dict(self.step_accounting, **counted,
+                                    kv_positions=counted["attn_kv_visible"] // self.infer.n_layers)
+        return out
+
+    def decode_blocks(self, block_tokens, block_masked, block_tables, start, fixed, done0, remaining) -> dict:
+        """``decode_steps`` passes of every live slot over its blocks (``block_model._decode_body``). Everything
+        is [slots, ...] host arrays; returns ``block_model.unpack_results``' dict."""
+        B, bk = block_tokens.shape
+        steps = self.infer.decode_steps
+        live = ~np.asarray(done0, bool)  # sync-ok: done0 is host numpy
+        self.step_accounting = dict({"fed": B * steps * bk, "shape": ("decode", B, steps, bk)},
+                                    **launch_geometry(B, live, live * (np.asarray(start, np.int64) + bk)))  # sync-ok: start is host numpy
+        with self._dispatch("decode"):
+            packed, layout = self._send(
+                block_tokens=np.asarray(block_tokens, np.int32), block_masked=np.asarray(block_masked, bool),  # sync-ok: host numpy
+                block_tables=np.asarray(block_tables, np.int32), start=np.asarray(start, np.int32),  # sync-ok: host numpy
+                fixed=np.asarray(fixed, np.int32), done0=~live, remaining=np.asarray(remaining, np.int32))  # sync-ok: host numpy
+            results, self.counts, self.pool = self.infer.decode(self.params, self.pool, packed, layout, self.counts)
+        return self._block_results("decode", results, steps, B)
+
+    def mixed_step_blocks(self, chunk_rows: List[MixedRow], decode_rows: List[BlockRow]) -> dict:
+        """One mixed step of a kind that generates by diffusion over blocks: the chunk rows' prompt blocks and one
+        pass of every decode row, at the kind's one fixed shape. Returns ``unpack_results``' dict, its rows in
+        ``decode_rows``' order (one pass)."""
+        C, T, D = self.infer.fixed_mixed_shape
+        bk = self.infer.block_length
+        M = (chunk_rows[0].table if chunk_rows else decode_rows[0].table).shape
+        self.step_accounting = dict(
+            {"fed": C * T + D * bk, "shape": ("mixed_flat", C, T, D, bk)},
+            **launch_geometry(C + D, [len(r.tokens) for r in chunk_rows] + [bk] * len(decode_rows),
+                              [r.start + len(r.tokens) for r in chunk_rows] + [r.start + bk for r in decode_rows]))
+        f = {"chunk_ids": np.zeros((C, T), np.int32), "chunk_tables": np.zeros((C,) + M, np.int32),
+             "chunk_qlens": np.zeros(C, np.int32), "chunk_start": np.zeros(C, np.int32),
+             "dec_tokens": np.zeros((D, bk), np.int32), "dec_masked": np.zeros((D, bk), bool),
+             "dec_tables": np.zeros((D,) + M, np.int32), "dec_start": np.zeros(D, np.int32),
+             "dec_fixed": np.zeros(D, np.int32), "dec_live": np.zeros(D, bool), "dec_remaining": np.zeros(D, np.int32)}
+        for j, r in enumerate(chunk_rows):
+            f["chunk_ids"][j, :len(r.tokens)] = r.tokens
+            f["chunk_tables"][j], f["chunk_qlens"][j], f["chunk_start"][j] = r.table, len(r.tokens), r.start
+        for j, r in enumerate(decode_rows):
+            f["dec_tokens"][j], f["dec_masked"][j], f["dec_tables"][j] = r.tokens, r.masked, r.table
+            f["dec_start"][j], f["dec_fixed"][j], f["dec_live"][j], f["dec_remaining"][j] = r.start, r.fixed, True, r.remaining
+        with self._dispatch("mixed"):
+            packed, layout = self._send(**f)
+            results, self.counts, self.pool = self.infer.mixed_step_flat(self.params, self.pool, packed, layout,
+                                                                         self.counts)
+        return self._block_results("mixed", results, 1, D)
 
     def verify(self, tokens, block_tables, start_pos, need_logits: bool,
                adapter_table=None):

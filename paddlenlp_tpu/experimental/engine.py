@@ -59,7 +59,7 @@ from ..serving.tenancy.adapters import AdapterPressure, UnknownAdapterError
 from ..serving.tenancy.quotas import DEFAULT_TENANT, TenantQuotas, tenant_goodput_fold
 from ..utils.faults import FaultPoint
 from ..utils.log import logger
-from .backend import MixedRow, ModelBackend, SingleDeviceBackend, _bucket
+from .backend import BlockRow, MixedRow, ModelBackend, SingleDeviceBackend, _bucket
 from .inference_model import inference_model_class
 from .kv_host_tier import HostKVTier, pool_block_bytes
 from .paged_cache import BlockManager
@@ -192,11 +192,25 @@ class Request:
     # bracket: acquire in _admit_slots, release in _free_kv)
     adapter_slot_seconds: float = 0.0
     adapter_acq_t: Optional[float] = None
+    # generation by diffusion over blocks (block_model.py): the block length the
+    # sequence advances by (1: a token a step, every other kind), and the passes
+    # its blocks took so far, by kind
+    block_length: int = 1
+    denoise_passes: int = 0
+    commit_passes: int = 0
+
+    @property
+    def prefill_len(self) -> int:
+        """Prompt tokens that enter through prefill: all of them, or under
+        diffusion over blocks the prompt's whole blocks (the ``len mod B`` left
+        over open the first generated block in the decode program)."""
+        n = len(self.prompt_ids)
+        return n - n % self.block_length
 
     @property
     def needs_prefill(self) -> bool:
         """True while part of the prompt still awaits a prefill chunk."""
-        return self.prefilled_len < len(self.prompt_ids)
+        return self.prefilled_len < self.prefill_len
 
     @property
     def total_len(self) -> int:
@@ -378,6 +392,14 @@ class InferenceEngine:
         self.slots: List[Optional[Request]] = [None] * max_batch_size
         self._next_id = itertools.count()
         self._last_token = np.zeros(max_batch_size, np.int32)
+        # generation by diffusion over blocks: the block length the kind's step
+        # programs advance a sequence by (None: a token a step), and between
+        # launches the block every slot is at: its tokens and which of its
+        # positions are still masked (the launch's carry, _open_block)
+        self.block: Optional[int] = getattr(self.backend.infer, "block_length", None)
+        if self.block:
+            self._block_tokens = np.zeros((max_batch_size, self.block), np.int32)
+            self._block_masked = np.ones((max_batch_size, self.block), bool)
         # speculative decoding: n-gram prompt-lookup OR draft-model proposer,
         # batched verify; greedy acceptance or rejection sampling
         self.use_speculative = use_speculative or draft_model is not None
@@ -474,6 +496,8 @@ class InferenceEngine:
         unknown id fails at submit, not mid-batch); ``tenant`` names the
         billing/quota identity the request's work is attributed to."""
         sampling = sampling or SamplingParams()
+        if self.block:
+            self.backend.infer.refuse_sampling(sampling)  # greedy only, by name
         if adapter_id is not None:
             if self.adapter_registry is None:
                 raise UnknownAdapterError(
@@ -492,6 +516,7 @@ class InferenceEngine:
             priority=priority,
             tenant=tenant,
             adapter_id=adapter_id,
+            block_length=self.block or 1,
         )
         req.enqueued_t = time.time()
         req.arrival_t = req.enqueued_t if arrival_t is None else min(arrival_t, req.enqueued_t)
@@ -1056,6 +1081,9 @@ class InferenceEngine:
                     # >=1 slot mid-prefill: one ragged mixed step (chunks +
                     # one decode token per running sequence)
                     self._mixed_step(finished)
+                elif self.block:
+                    # diffusion over blocks: decode_steps passes of every slot
+                    self._decode_blocks(finished)
                 else:
                     # steady state: the multi-token decode jit as usual
                     self._decode_running(finished)
@@ -1565,6 +1593,10 @@ class InferenceEngine:
             req.prefilled_len = n_cached
             self.slots[slot] = req
             slot_idx.append(slot)
+            if self.block:
+                self._open_block(slot, req)
+        if self.block:
+            return  # greedy only: no penalty counts to seed
         # seed the device-side penalty counts: the cached span never rides
         # through a chunk forward, so its counts come from a host bincount
         # (zeros rows still land — the slot's previous occupant is stale)
@@ -1589,7 +1621,9 @@ class InferenceEngine:
             if req is None or req.needs_prefill:
                 continue  # victim of an earlier iteration's preemption
             while True:
-                grow = req.total_len - self.mgr.lengths[req.req_id]
+                # the positions this step writes: the token fed, or its whole block
+                grow = (self._block_start(req) + self.block if self.block else req.total_len) \
+                    - self.mgr.lengths[req.req_id]
                 if grow <= 0 or self.mgr.extend(req.req_id, grow) is not None:
                     break
                 active = [s for s, r in enumerate(self.slots) if r is not None]
@@ -1622,7 +1656,7 @@ class InferenceEngine:
             if budget <= 0 or (most_rows and len(chunk_rows) >= most_rows):
                 break
             req = self.slots[slot]
-            n = min(budget, len(req.prompt_ids) - req.prefilled_len)
+            n = min(budget, req.prefill_len - req.prefilled_len)
             chunk_rows.append((slot, req, n))
             budget -= n
             RECORDER.record("chunk.grant", req_id=req.req_id, trace=req.trace,
@@ -1636,8 +1670,12 @@ class InferenceEngine:
             chunk_payload.append(MixedRow(
                 slot=slot, tokens=req.prompt_ids[p0 : p0 + n], start=p0,
                 table=self.mgr.table_array(req.req_id),
-                emit=p0 + n == len(req.prompt_ids),  # sampler on last chunk
+                # sampler on last chunk (never under diffusion over blocks: the
+                # first generated block starts from masks)
+                emit=not self.block and p0 + n == len(req.prompt_ids),
                 sampling=req.sampling, adapter=req.adapter_slot))
+        if self.block:
+            return chunk_rows, decode_rows, chunk_payload, [self._block_row(slot, req) for slot, req in decode_rows]
         for slot, req in decode_rows:
             self.mgr.window_span(req.req_id, req.total_len - 1, 1)
         dec_payload = [
@@ -1667,7 +1705,8 @@ class InferenceEngine:
         with self._launch("mixed_step", "mixed", carried=[req for _, req, _ in chunk_rows],
                           chunks=len(chunk_rows), decodes=len(decode_rows),
                           chunk_tokens=int(sum(n for _, _, n in chunk_rows))):
-            tokens = self.backend.mixed_step(chunk_payload, dec_payload)
+            tokens = (self.backend.mixed_step_blocks if self.block else self.backend.mixed_step)(
+                chunk_payload, dec_payload)
         acct = self.backend.step_accounting
         dur = time.perf_counter() - t0
         with TRACER.span("emit", cat="engine", step=self._cur_step, program="mixed"):
@@ -1686,11 +1725,18 @@ class InferenceEngine:
             g_useful += n - rw
             g_rework += rw
             self._merge_rework(g_by, by)
-        for _slot, req in decode_rows:
+        # rows that fed one token and sampled the next; under diffusion over blocks none does
+        token_rows = [] if self.block else decode_rows
+        for _slot, req in token_rows:
             rw, by = self._note_fed_span(req, req.total_len - 1, 1)
             g_useful += 1 - rw
             g_rework += rw
             self._merge_rework(g_by, by)
+        if self.block:
+            # a pass's useful positions are the tokens it hands on; they are emitted first, so that
+            # the ledger's entry holds them (``tokens`` is the launch's ``unpack_results``)
+            g_useful += self._settle_blocks([(j, slot, req) for j, (slot, req) in enumerate(decode_rows)],
+                                            tokens, finished)
         self.ledger.note_shape(acct["shape"])
         self.ledger.record(
             "mixed", acct["fed"], g_useful,
@@ -1708,9 +1754,9 @@ class InferenceEngine:
             self.chunk_stats["chunks"] += 1
             self.chunk_stats["chunk_tokens"] += n
             self.recent_chunk_sizes.append((next(self._chunk_seq), n))
-            if not req.needs_prefill:
+            if not req.needs_prefill and not self.block:
                 self._settle_sampled(slot, req, int(tokens[j]), finished)  # sync-ok: tokens already host (backend.mixed_step synced)
-        for j, (slot, req) in enumerate(decode_rows):
+        for j, (slot, req) in enumerate(token_rows):
             self._settle_sampled(slot, req, int(tokens[len(chunk_rows) + j]), finished)  # sync-ok: tokens already host (backend.mixed_step synced)
         if chunk_rows and decode_rows:
             # every decode token in this step waited out the chunk work: the
@@ -2006,6 +2052,100 @@ class InferenceEngine:
         p /= p.sum()
         emitted.append(int(rng.choice(len(p), p=p)))
         return emitted
+
+    # ------------------------------------------------------------------ diffusion over blocks
+    def _block_start(self, req: Request) -> int:
+        """First position of the block ``req`` is at: everything before it is
+        prompt or handed on (a block's tokens leave together, so ``total_len``
+        lies inside it only by the prompt's partial block)."""
+        return req.total_len - req.total_len % self.block
+
+    def _open_block(self, slot: int, req: Request):
+        """The first generated block of a (re)admitted request: the ``len mod
+        B`` prompt tokens left over as fixed positions, the rest masked."""
+        r = len(req.prompt_ids) % self.block
+        self._block_tokens[slot] = 0
+        self._block_tokens[slot, :r] = req.prompt_ids[len(req.prompt_ids) - r:]
+        self._block_masked[slot] = np.arange(self.block) >= r
+
+    def _block_row(self, slot: int, req: Request) -> BlockRow:
+        return BlockRow(slot=slot, tokens=self._block_tokens[slot].copy(), masked=self._block_masked[slot].copy(),
+                        start=self._block_start(req), table=self.mgr.table_array(req.req_id),
+                        fixed=req.total_len % self.block, remaining=req.remaining_new)
+
+    def _settle_blocks(self, rows, out, finished: List[Request]) -> int:
+        """The ``emit`` phase of a block launch: ``rows`` are (row of ``out``,
+        slot, request); every pass's handed-on block streams out in order, the
+        slot keeps the block it is now at, a finished request retires. Returns
+        the tokens emitted."""
+        n_emitted = 0
+        of_row = {j: req for j, _slot, req in rows}
+        # the (pass, row) pairs that handed a block on, pass by pass
+        for s, j in zip(*np.nonzero(out["valid"].any(-1))):  # sync-ok: out is host numpy (the launch synced)
+            req = of_row.get(j)
+            if req is None or req.done:
+                continue
+            for tok in out["tokens"][s, j][out["valid"][s, j]]:
+                self._emit(req, int(tok))  # sync-ok: out is host numpy (the launch synced)
+                n_emitted += 1
+                self._tenant_counts(req.tenant)["useful"] += 1
+                req.useful_tokens += 1
+                if req.done:
+                    break
+        for j, slot, req in rows:
+            req.denoise_passes += int(out["denoise"][j])  # sync-ok: host numpy
+            req.commit_passes += int(out["commit"][j])  # sync-ok: host numpy
+            # every position before the block it is at has been fed
+            req.fed_hwm = max(req.fed_hwm, req.total_len)
+            if req.done:
+                self._free_kv(req, cache=True)
+                self.slots[slot] = None
+                finished.append(req)
+            else:
+                self._block_tokens[slot] = out["block_tokens"][j]
+                self._block_masked[slot] = out["block_masked"][j]
+                self.mgr.shrink(req.req_id, req.total_len)  # pages reserved ahead and not reached
+        return n_emitted
+
+    def _decode_blocks(self, finished: List[Request]):
+        """``decode_steps`` passes of every slot in one launch (diffusion over
+        blocks): pages reserved for the blocks the passes can reach, as the
+        speculative K + 1 reservation does."""
+        steps, bk = self.decode_steps, self.block
+        with TRACER.span("launch_build", cat="engine", step=self._cur_step, program="decode") as build:
+            ahead = self.backend.infer.blocks_a_launch(steps)
+            active = [s for s in range(len(self.slots)) if self.slots[s] is not None]
+            for slot in sorted(active, key=lambda s: -self.slots[s].req_id):
+                req = self.slots[slot]
+                start = self._block_start(req)
+                owed = -(-(req.total_len - start + req.remaining_new) // bk)  # blocks until max_tokens
+                grow = start + min(ahead, owed) * bk - self.mgr.lengths[req.req_id]
+                if grow > 0 and self.mgr.extend(req.req_id, grow) is None:
+                    self._preempt(slot)
+            if not any(r is not None for r in self.slots):
+                build.discard()
+                return
+            B = self.max_batch_size
+            tables = np.zeros((B,) + self.mgr.table_shape, np.int32)
+            start, fixed, remaining = (np.zeros(B, np.int32) for _ in range(3))
+            done0 = np.ones(B, bool)
+            rows = []
+            for i, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                tables[i] = self.mgr.table_array(req.req_id)
+                start[i], fixed[i] = self._block_start(req), req.total_len % bk
+                remaining[i], done0[i] = req.remaining_new, False
+                rows.append((i, i, req))
+        with self._launch("decode", "decode", steps=steps, active=len(rows)):
+            out = self.backend.decode_blocks(self._block_tokens, self._block_masked, tables, start, fixed,
+                                             done0, remaining)
+        acct = self.backend.step_accounting
+        with TRACER.span("emit", cat="engine", step=self._cur_step, program="decode"):
+            n_emitted = self._settle_blocks(rows, out, finished)
+            # every pass feeds its block again: the positions a launch hands on are its useful ones
+            self.ledger.note_shape(acct["shape"])
+            self.ledger.record("decode", acct["fed"], n_emitted, padding=acct["fed"] - n_emitted, geometry=acct)
 
     def _decode_running(self, finished: List[Request]):
         # migrating slots (staged backends) hold KV that has not landed in the

@@ -354,8 +354,10 @@ class PagedInferenceModel:
         return y + delta.astype(y.dtype)
 
     # ------------------------------------------------------------------ forward core
-    def _attend(self, q, k, v, q_positions, kv_len_mask):
-        """q [B,T,N,H]; k/v [B,S,K,H]; causal by absolute position + length mask."""
+    def _attend(self, q, k, v, q_positions, kv_len_mask, block=None):
+        """q [B,T,N,H]; k/v [B,S,K,H]; causal by absolute position + length mask. ``block`` (static, a power
+        of two; None but for a kind that generates by diffusion over blocks): causal over blocks of that many
+        positions, a query seeing its own block whole."""
         B, T, N, H = q.shape
         S = k.shape[1]
         if self.n_kv != N:
@@ -363,6 +365,8 @@ class PagedInferenceModel:
             v = jnp.repeat(v, N // self.n_kv, axis=2)
         logits = jnp.einsum("btnh,bsnh->bnts", q.astype(jnp.float32), k.astype(jnp.float32)) * (H**-0.5)
         kv_pos = jnp.arange(S)[None, :]
+        if block is not None:
+            q_positions = q_positions | (block - 1)
         mask = (kv_pos[:, None, :] <= q_positions[:, :, None]) & kv_len_mask[:, None, :]
         logits = jnp.where(mask[:, None], logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1)

@@ -110,12 +110,19 @@ LAUNCH_GEOMETRY = ("rows_live", "rows", "kv_positions")
 #: expert counts and the cached positions visible to the launch's live rows,
 #: summed over the layers that attend the whole context and over those that
 #: attend a window (and over decode sub-steps), and the cached positions the
-#: former's table walk fetched: whole runs of them. Launch-span args, and monotone
+#: former's table walk fetched: whole runs of them; from generation by diffusion
+#: over blocks (``experimental/block_model.py:BlockDiffusionInferenceModel.STATS``)
+#: the three expert counts and: cached positions visible to the live rows summed
+#: over layers and passes, rows x passes that denoised and that committed, positions
+#: unmasked, tokens handed on, and tokens of a committed block past ``max_tokens``.
+#: Launch-span args, and monotone
 #: ``totals`` where a launch carries them (a program without such layers never does)
 KIND_COUNTERS = ("index_candidates", "index_selected", "expert_assignments_local",
                  "expert_assignments", "expert_tokens_max",
                  "state_rows", "state_rows_live", "state_resets", "attn_key_tiles",
-                 "attn_kv_full", "attn_kv_window", "attn_kv_fetched")
+                 "attn_kv_full", "attn_kv_window", "attn_kv_fetched",
+                 "attn_kv_visible", "denoise_passes", "commit_passes", "tokens_unmasked", "tokens_emitted",
+                 "tokens_discarded")
 
 #: step-program vocabulary the ledger accounts by (also the ``{program}``
 #: label of the serving compile counters)

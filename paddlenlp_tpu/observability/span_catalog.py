@@ -76,17 +76,18 @@ SPAN_CATALOG = {
 #: (a device profile shows them in each operation's ``tf_op``): the llama kind's
 #: (``experimental/inference_model.py``), the latent kinds'
 #: (``experimental/latent_model.py``, ``transformers/latent_layers.py``) and the
-#: state-space kinds' (``experimental/state_model.py``, ``transformers/state_layers.py``).
-#: ``bench/harness/program_spans.py``, ``bench/harness/latent_scopes.py`` and
-#: ``bench/harness/state_scopes.py`` read them.
+#: state-space kinds' (``experimental/state_model.py``, ``transformers/state_layers.py``),
+#: and generation by diffusion over blocks' (``experimental/block_model.py``).
+#: ``bench/harness/program_spans.py``, ``bench/harness/latent_scopes.py``,
+#: ``bench/harness/state_scopes.py``, ``window_scopes.py`` and ``diffusion_scopes.py`` read them.
 DEVICE_SCOPES = {
     "embed": "token embedding lookup", "attn_norm": "the layer's input RMS norm",
     "qkv": "llama kind: q/k/v projections", "rope": "llama kind: rotary embedding of q and k",
     "kv_write": "scatter of the fed tokens' rows into the pool (latent kinds: nested latent_plane / index_plane / window_plane; gqa_window: nested window_plane)",
-    "paged_attn": "llama kind, and the windowed kinds' full layers: the Pallas ragged paged attention kernel walking the block table",
+    "paged_attn": "llama kind, the windowed kinds' full layers and gqa_block (under the block mask): the Pallas ragged paged attention kernel walking the block table",
     "paged_attn_window": "gqa_window: the same kernel walking a window (a grid sized by the window, over the window plane)",
     "attn_gather": "llama and windowed kinds: XLA gather + attend (no kernel)",
-    "qk_norm": "windowed kinds: RMS norm of q and k over each head's dims, before any rotation",
+    "qk_norm": "windowed kinds and gqa_block: RMS norm of q and k over each head's dims, before any rotation",
     "o_proj": "attention output projection", "mlp_norm": "post-attention RMS norm", "mlp": "dense SwiGLU MLP",
     "final_norm": "final RMS norm", "lm_head": "output head", "sample": "on-device sampler", "bookkeeping": "counts, stops, positions",
     "mla_proj": "latent kinds: low-rank q and kv chains (norm, rescale, RoPE of the pe slices)",
@@ -96,7 +97,7 @@ DEVICE_SCOPES = {
     "mla_attn": "latent_full: attention over the kept positions (absorbed for one token; for a chunk the gather of the table's rows and the latent_chunk_attention kernel)",
     "window_attn": "latent_window: gather of the window's blocks and attention over them",
     "attn_gate": "latent kinds: headwise sigmoid gate on the attention output",
-    "router": "expert layer: float32 sigmoid scores, top-k of score + bias, per-expert counts",
+    "router": "expert layer: float32 sigmoid scores, top-k of score + bias (or softmax scores and their top-k), per-expert counts",
     "experts": "expert layer: the held experts' tiles (rows ranked by expert, one tile of one expert a loop turn)",
     "shared_expert": "expert layer: the shared expert on every token",
     "ssm_proj": "scan layer: the in-projection (z | xBC | dt) and the out-projection",
@@ -104,6 +105,10 @@ DEVICE_SCOPES = {
     "ssm_scan": "scan layer: the recurrence, chunk (SSD) form for a prompt chunk and one-step form for one token, and the D term",
     "ssm_gate_norm": "scan layer: gate by SiLU(z), then RMS norm in groups",
     "state_rw": "scan layer: read of the slots' state rows (zeros for a row at position 0) and the write back",
+    "denoise": "diffusion over blocks: a pass's forward over every live slot's block (the mask id laid over masked positions; the layers' scopes nest inside), its confidences and the unmasking",
+    "confidence": "diffusion over blocks: the best token and its softmax probability at every position of the block: one argmax and one logsumexp a row, the mask id's logit left out",
+    "unmask": "diffusion over blocks: the unmasking rule (low_confidence_static: the block_length / denoising_steps most confident masked positions; low_confidence_dynamic: every one over the threshold besides)",
+    "commit": "diffusion over blocks: a row with no masked position hands its block on (what is emitted, what max_tokens or an EOS discards) and opens the next, all masked; the pass's device counts",
 }
 
 #: args a launch span (``prefill`` / ``decode`` / ``mixed_step`` / ``spec_verify``) carries once the
@@ -126,4 +131,10 @@ LAUNCH_ARGS = {
     "attn_kv_full": "windowed kinds: cached positions visible to the live rows, summed over the layers that attend the whole context and over decode sub-steps (device count)",
     "attn_kv_window": "the same over the layers that attend a window: at most the window a fed token (device count)",
     "attn_kv_fetched": "windowed kinds: cached positions the full layers' table walk fetched for the live rows: runs visited x positions a run with the kernel, the whole table a row through the gather; attn_kv_full / attn_kv_fetched is the share of what was read that a row could see (device count)",
+    "attn_kv_visible": "diffusion over blocks: cached positions visible to the live rows (a row that feeds n positions from s: s + n, its own block whole), summed over layers and passes (device count)",
+    "denoise_passes": "diffusion over blocks: rows x passes that fed a block with masked positions and unmasked some (device count)",
+    "commit_passes": "diffusion over blocks: rows x passes that fed a block's final tokens once more and handed it on (device count)",
+    "tokens_unmasked": "diffusion over blocks: positions the denoising passes unmasked (device count)",
+    "tokens_emitted": "diffusion over blocks: tokens of committed blocks handed on to their requests (device count)",
+    "tokens_discarded": "diffusion over blocks: tokens of a committed block past the request's max_tokens or behind an EOS: denoised, never emitted (device count)",
 }

@@ -48,7 +48,13 @@ Design:
   ``q_start``. The table may be a window table, with blocks only at the logical
   blocks inside the window (``BlockManager.window_span``), the pool a window
   plane. Without ``window``: the table's walk, a block of one head a step (the
-  llama and state kinds'; the windowed kinds' is ``paged_run_attention.py``'s).
+  llama and state kinds'; the windowed kinds' is ``paged_run_attention.py``'s);
+- **a block call** (``block=B``, static, a power of two: generation by diffusion
+  over blocks) replaces the causal rule by causal over blocks of ``B`` positions
+  counted from position 0: a query at ``p`` sees kv positions ``<= p | (B - 1)``,
+  its whole block included. The caller feeds whole blocks (``q_start`` and
+  ``q_lens`` multiples of ``B``), so a block's last position was written by this
+  launch at the latest. ``None``, the default, traces nothing new.
 
 Off-TPU (tests), the kernel runs in Pallas interpret mode.
 """
@@ -83,7 +89,7 @@ def _q_tile_tokens(T: int, group: int) -> int:
 
 
 def _kernel(tables_ref, start_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
-            bs, scale, use_kv_scale, group, tq, window=None):
+            bs, scale, use_kv_scale, group, tq, window=None, block=None):
     if use_kv_scale:
         ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = rest
     else:
@@ -106,6 +112,8 @@ def _kernel(tables_ref, start_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, *res
     live = jnp.minimum(qlen - t0, tq)  # live tokens in this tile (<= 0: none)
     # highest live query position: blocks past it contribute nothing to any row
     hi = start + t0 + live - 1
+    if block is not None:  # its block's last position
+        hi = hi | (block - 1)
     # the logical block this step reads: the table's j-th, or with a window the j-th from the tile's first
     jb = j if window is None else jnp.maximum(start + t0 - (window - 1), 0) // bs + j
 
@@ -123,7 +131,7 @@ def _kernel(tables_ref, start_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, *res
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # [tq*group, bs]
         kv_pos = jb * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         t = t0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group  # query token idx
-        valid = (kv_pos <= start + t) & (t < qlen)
+        valid = (kv_pos <= (start + t if block is None else (start + t) | (block - 1))) & (t < qlen)
         if window is not None:
             valid &= kv_pos > start + t - window
         s = jnp.where(valid, s, NEG_INF)
@@ -152,6 +160,7 @@ def ragged_paged_attention(
     interpret: Optional[bool] = None,
     kv_scale: Optional[jnp.ndarray] = None,  # [L, 2, num_blocks, bs, K] quantized-pool scales
     window: Optional[int] = None,  # static: positions a query sees, itself included (None: all before it)
+    block: Optional[int] = None,  # static, a power of two: a query sees its whole block of this many positions
 ) -> jnp.ndarray:
     """One-launch attention for a ragged mixed prefill/decode batch.
 
@@ -161,9 +170,12 @@ def ragged_paged_attention(
     own KV by this step's scatter (ordered before the kernel by jit data
     dependence on the pool). With ``window`` it attends positions
     ``(q_start[b] + t - window, q_start[b] + t]`` only and the grid's block
-    axis is sized by the window (module docstring). Returns ``[B, T, N, H]``
+    axis is sized by the window (module docstring). With ``block`` it attends
+    positions ``[0, (q_start[b] + t) | (block - 1)]``. Returns ``[B, T, N, H]``
     with rows ``t >= q_lens[b]`` zeroed.
     """
+    if block is not None and (window is not None or block & (block - 1)):
+        raise ValueError(f"block={block}: a power of two, and no window beside it")
     B, T, N, H = q.shape
     bs, K = kv.shape[3], kv.shape[4] // H
     group = N // K
@@ -214,7 +226,7 @@ def ragged_paged_attention(
     )
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, scale=scale, use_kv_scale=use_kv_scale,
-                          group=group, tq=tq, window=window),
+                          group=group, tq=tq, window=window, **({} if block is None else {"block": block})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, T * group, H), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -89,7 +89,7 @@ def runs_visited(q_start, q_lens, run_positions: int):
 
 
 def _kernel(tables_ref, start_ref, len_ref, layer_ref, q_ref, kv_ref, o_ref, k_buf, v_buf, sem, m_s, l_s, acc_s,
-            *, bs, scale, group, tq, run, heads, H):
+            *, bs, scale, group, tq, run, heads, H, block=None):
     b = pl.program_id(0)
     hb = pl.program_id(1)
     start = start_ref[b]
@@ -98,8 +98,12 @@ def _kernel(tables_ref, start_ref, len_ref, layer_ref, q_ref, kv_ref, o_ref, k_b
     t0 = pl.program_id(2) * tq  # first query token of this tile
     live = jnp.minimum(qlen - t0, tq)  # live tokens in this tile (<= 0: none)
     hi = start + t0 + live - 1  # highest live query position: nothing past it is read
+    if block is not None:  # ... its block's last position under a block mask (the caller feeds whole blocks)
+        hi = hi | (block - 1)
     last_block = jnp.maximum(hi, 0) // bs
     n_runs = runs_visited(start + t0, live, run * bs)
+    if block is not None:  # the runs up to that position
+        n_runs = jnp.where(live > 0, hi // (run * bs) + 1, 0)
     lanes = pl.ds(pl.multiple_of(hb * (heads * H), heads * H), heads * H)
 
     def copy(block, i, plane, slot):
@@ -144,7 +148,7 @@ def _kernel(tables_ref, start_ref, len_ref, layer_ref, q_ref, kv_ref, o_ref, k_b
         rows = tq * group
         kv_pos = r * (run * bs) + jax.lax.broadcasted_iota(jnp.int32, (rows, run * bs), 1)
         t = t0 + jax.lax.broadcasted_iota(jnp.int32, (rows, run * bs), 0) // group  # query token idx
-        valid = (kv_pos <= start + t) & (t < qlen)
+        valid = (kv_pos <= (start + t if block is None else (start + t) | (block - 1))) & (t < qlen)
         for h in range(heads):
             q = q_ref[0, h]  # [tq*group, H]
             k = k_buf[slot, :, h * H:(h + 1) * H]  # [run*bs, H]
@@ -176,9 +180,15 @@ def ragged_paged_run_attention(
     layer,  # int32 scalar: the pool layer to read
     scale: Optional[float] = None,
     interpret: Optional[bool] = None,
+    block: Optional[int] = None,  # static, a power of two: a query sees its whole block of this many positions
 ) -> jnp.ndarray:
     """``ragged_paged_attention`` without a window, walking the table by runs (module docstring).
+    With ``block`` (generation by diffusion over blocks) query token t of row b sees kv positions
+    ``[0, (q_start[b] + t) | (block - 1)]``: causal over blocks counted from position 0, its own whole; the
+    caller feeds whole blocks, so nothing past what this launch wrote is read. ``None`` traces nothing new.
     Returns ``[B, T, N, H]`` with rows ``t >= q_lens[b]`` zeroed."""
+    if block is not None and block & (block - 1):
+        raise ValueError(f"block={block} is not a power of two")
     B, T, N, H = q.shape
     bs, K = kv.shape[3], kv.shape[4] // H
     group = N // K
@@ -209,7 +219,8 @@ def ragged_paged_run_attention(
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, bs=bs, scale=scale, group=group, tq=tq, run=run, heads=heads, H=H),
+        functools.partial(_kernel, bs=bs, scale=scale, group=group, tq=tq, run=run, heads=heads, H=H,
+                          **({} if block is None else {"block": block})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, T * group, H), q.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "parallel")),

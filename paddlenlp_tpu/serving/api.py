@@ -241,6 +241,11 @@ class ServingServer:
         tenant = str(payload.get("tenant", DEFAULT_TENANT))
         if not tenant:
             raise ValueError("tenant must be a non-empty string")
+        # a kind that does not compute some way of sampling (generation by
+        # diffusion over blocks: greedy only) refuses it at the door, by name
+        refuse = getattr(getattr(self.loop.engine, "infer", None), "refuse_sampling", None)
+        if refuse is not None:
+            refuse(sampling)
         adapter_id = payload.get("adapter_id")
         if adapter_id is not None:
             adapter_id = str(adapter_id)

@@ -607,9 +607,24 @@ class ServingMetrics:
             "paddlenlp_serving_attn_kv_positions_total",
             "Cached positions visible to the launches' live rows, summed over "
             "layers and decode sub-steps (layers=full: layers that attend the "
-            "whole context; layers=window: layers that attend a window), and "
-            "those the former's table walk fetched (layers=fetched: whole runs)",
+            "whole context; layers=window: layers that attend a window; "
+            "layers=block: layers under a block mask, a row's own block whole), "
+            "and those the former's table walk fetched (layers=fetched: whole runs)",
             labelnames=("layers",))
+        self.diffusion_passes = r.counter(
+            "paddlenlp_serving_diffusion_passes_total",
+            "Generation by diffusion over blocks: rows x passes of the decode "
+            "programs (kind=denoise: a block with masked positions was fed and "
+            "some unmasked; kind=commit: a block's final tokens were fed once "
+            "more and the block handed on)",
+            labelnames=("kind",))
+        self.diffusion_tokens = r.counter(
+            "paddlenlp_serving_diffusion_tokens_total",
+            "Generation by diffusion over blocks: positions unmasked "
+            "(kind=unmasked), tokens of committed blocks handed on "
+            "(kind=emitted) and those past max_tokens or an EOS, denoised and "
+            "never emitted (kind=discarded)",
+            labelnames=("kind",))
         self.wasted_tokens = r.counter(
             "paddlenlp_serving_wasted_tokens_total",
             "Non-useful fed positions by waste kind (padding = bucket pads + "
@@ -831,7 +846,13 @@ class ServingMetrics:
                     ("state_resets", self.state_rows, {"kind": "reset"}),
                     ("attn_kv_full", self.attn_kv_positions, {"layers": "full"}),
                     ("attn_kv_window", self.attn_kv_positions, {"layers": "window"}),
-                    ("attn_kv_fetched", self.attn_kv_positions, {"layers": "fetched"})):
+                    ("attn_kv_fetched", self.attn_kv_positions, {"layers": "fetched"}),
+                    ("attn_kv_visible", self.attn_kv_positions, {"layers": "block"}),
+                    ("denoise_passes", self.diffusion_passes, {"kind": "denoise"}),
+                    ("commit_passes", self.diffusion_passes, {"kind": "commit"}),
+                    ("tokens_unmasked", self.diffusion_tokens, {"kind": "unmasked"}),
+                    ("tokens_emitted", self.diffusion_tokens, {"kind": "emitted"}),
+                    ("tokens_discarded", self.diffusion_tokens, {"kind": "discarded"})):
                 delta = totals.get(key, 0) - self._gp_last.get(key, 0)
                 if delta > 0:
                     counter.inc(delta, **label)
@@ -1764,6 +1785,10 @@ class EngineLoop:
         own_s = getattr(req, "prefill_own_s", 0.0)
         split = {"prefill": dict(steps=getattr(req, "prefill_steps", 0), own_ms=own_s * 1e3,
                                  behind_ms=getattr(req, "prefill_behind_s", 0.0) * 1e3)}
+        if getattr(req, "block_length", 1) > 1:
+            # generation by diffusion over blocks: how many passes the request's blocks took
+            split["decode"] = dict(block_length=req.block_length, denoise_passes=req.denoise_passes,
+                                   commit_passes=req.commit_passes)
         for name, (t0, t1) in phases.items():
             TRACER.add_span(name, t0, t1 - t0, cat="request", trace=trace,  # span-names: queue prefill decode
                             wall=True, **meta, **split.get(name, {}))

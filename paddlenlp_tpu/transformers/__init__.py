@@ -42,6 +42,7 @@ from .deepseek_v2 import DeepseekV2Config, DeepseekV2ForCausalLM, DeepseekV2Mode
 from .deepseek_v3 import DeepseekV3Config, DeepseekV3ForCausalLM, DeepseekV3Model  # noqa: F401
 from .dots3_note import Dots3NoteConfig, Dots3NoteForCausalLM, Dots3NoteModel  # noqa: F401
 from .exaone_moe import ExaoneMoeConfig, ExaoneMoeForCausalLM, ExaoneMoeModel  # noqa: F401
+from .sdar_moe import SdarMoeConfig, SdarMoeForCausalLM, SdarMoeModel  # noqa: F401
 from .gemma import GemmaConfig, GemmaForCausalLM, GemmaModel  # noqa: F401
 from .gpt import GPTConfig, GPTForCausalLM, GPTModel  # noqa: F401
 from .llama import (  # noqa: F401

@@ -40,6 +40,7 @@ def _populate():
     from ..deepseek_v3.configuration import DeepseekV3Config
     from ..dots3_note.configuration import Dots3NoteConfig
     from ..exaone_moe.configuration import ExaoneMoeConfig
+    from ..sdar_moe.configuration import SdarMoeConfig
     from ..mamba.configuration import MambaConfig
     from ..nemotron_h.configuration import NemotronHConfig
     from ..rw.configuration import RWConfig
@@ -73,7 +74,7 @@ def _populate():
 
     for cfg in (LlamaConfig, GPTConfig, Qwen2Config, MistralConfig, GemmaConfig, BertConfig,
                 ErnieConfig, MixtralConfig, Qwen2MoeConfig, BaichuanConfig, BloomConfig,
-                OPTConfig, QWenConfig, ChatGLMv2Config, T5Config, BartConfig, DeepseekV2Config, DeepseekV3Config, Dots3NoteConfig, ExaoneMoeConfig,
+                OPTConfig, QWenConfig, ChatGLMv2Config, T5Config, BartConfig, DeepseekV2Config, DeepseekV3Config, Dots3NoteConfig, ExaoneMoeConfig, SdarMoeConfig,
                 NemotronHConfig,
                 MambaConfig, RWConfig, ChatGLMConfig, YuanConfig, JambaConfig,
                 AlbertConfig, ElectraConfig, RobertaConfig,

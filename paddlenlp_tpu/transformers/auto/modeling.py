@@ -112,6 +112,10 @@ def _populate_models():
 
     register_model("exaone_moe", "base", exaone_moe.ExaoneMoeModel)
     register_model("exaone_moe", "causal_lm", exaone_moe.ExaoneMoeForCausalLM)
+    from ..sdar_moe import modeling as sdar_moe
+
+    register_model("sdar_moe", "base", sdar_moe.SdarMoeModel)
+    register_model("sdar_moe", "causal_lm", sdar_moe.SdarMoeForCausalLM)
     # state-space families: nemotron_h is served by the engine (its configuration names the step programs,
     # experimental/state_model.py); mamba and jamba are whole-sequence only (model.generate with their own caches)
     from ..nemotron_h import modeling as nemotron_h

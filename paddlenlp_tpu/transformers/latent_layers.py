@@ -23,13 +23,17 @@ squared ReLU), the routing and the held share are the same. The functions:
                       the learned selection: which ``index_topk`` cached
                       positions a query may attend;
 - ``route``           sigmoid scores in float32, top-k of score + bias over the
-                      router's full width, weights normalised over the chosen;
+                      router's full width, weights normalised over the chosen
+                      (or, where ``cfg.scoring_func`` says ``softmax``, softmax
+                      scores, their top-k, no bias);
 - ``attention_dense`` one attention kind over whole sequences, expanded, causal;
 - ``experts_held``    the held experts' part of the result: assignments are
                       sorted by expert into tile-aligned groups and a loop of
                       data-dependent length runs one tile of one expert at a
                       time (the caller's ``ExpertBody``), so no token is
                       dropped and an expert nobody chose is never read;
+- ``experts_held_dense`` the same part of the result for a few rows: every held
+                      expert on every row in three grouped products;
 - ``experts_grouped`` the same part of the result with a backward, for
                       training: assignments sorted by expert, the three
                       products grouped over the held stacks at the rows each
@@ -186,6 +190,12 @@ def route(p, x2d, cfg):
     """x2d [N, hidden] -> (experts [N, k] int32 over the router's full width,
     weights [N, k] float32). Scores are float32 whatever the weights' dtype."""
     logits = jnp.matmul(x2d.astype(jnp.float32), p["gate"]["kernel"].astype(jnp.float32), precision="highest")
+    if getattr(cfg, "scoring_func", "sigmoid") == "softmax":
+        # softmax over the router's full width, its k largest, no selection bias and no scaling factor
+        chosen, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.num_experts_per_tok)
+        if cfg.norm_topk_prob:
+            chosen = chosen / jnp.sum(chosen, -1, keepdims=True)
+        return idx.astype(jnp.int32), chosen
     s = jax.nn.sigmoid(logits)
     _, idx = jax.lax.top_k(s + p["e_score_correction_bias"].astype(jnp.float32), cfg.num_experts_per_tok)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
@@ -238,6 +248,28 @@ def experts_held(p, x2d, idx, w, first, count, live=None, body: ExpertBody = SWI
     picked = y_rows[jnp.minimum(dest, rows - 1)].reshape(n, k, hidden)
     weight = jnp.where(ok, w, 0.0).astype(jnp.float32)
     return jnp.einsum("nkd,nk->nd", picked.astype(jnp.float32), weight).astype(x2d.dtype)
+
+
+def experts_held_dense(p, x2d, idx, w, first, count, live=None):
+    """``experts_held`` for a few rows (a decode pass, a short chunk) of SwiGLU experts: **every held expert on
+    every row**, the rows an expert was not chosen for weighted 0. With a hundred rows and sixteen held experts
+    nearly every expert is chosen by some row, so the tiles' loop reads the same weights in hundreds of small
+    steps a layer (a tile of one expert a turn, its bookkeeping before and after: 26k device operations a pass of
+    48 layers, 47 ms where the weights' bytes take 11) where three grouped products read them once; the products'
+    rows cost nothing beside the weights' bytes until rows x held experts reaches the thousands. ``p`` holds all
+    three matrices stacked [count, width, hidden] (gate_proj and up_proj out x in: handed them as [count, hidden,
+    width] the chip's compiler lays the whole stack out anew, 2.25 GB a matrix at 48 x 16 experts)."""
+    local = idx - first
+    ok = (local >= 0) & (local < count)
+    if live is not None:
+        ok &= live[:, None]
+    # [N, count] float32: the routing weight of each held expert for each row, 0 where it was not chosen
+    chosen = jax.nn.one_hot(jnp.where(ok, local, count), count + 1, dtype=jnp.float32)[..., :count]
+    share = jnp.einsum("nke,nk->ne", chosen, jnp.where(ok, w, 0.0).astype(jnp.float32))
+    wide = lambda name: jnp.einsum("nh,ewh->enw", x2d, p[name].astype(x2d.dtype))
+    act = jax.nn.silu(wide("gate_proj")) * wide("up_proj")
+    y = jnp.einsum("enw,ewh->enh", act, p["down_proj"].astype(x2d.dtype))
+    return jnp.einsum("enh,ne->nh", y.astype(jnp.float32), share).astype(x2d.dtype)
 
 
 # ------------------------------------------------------------------ the held experts with a backward (training)

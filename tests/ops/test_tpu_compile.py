@@ -516,3 +516,51 @@ def test_the_windowed_kinds_decode_program_copies_no_plane(chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * pool.kv.size * pool.kv.dtype.itemsize
     weights = {a.shape for a in jax.tree.leaves(model.params)}
     assert_pool_sized_values_stay_in_place(text, pool.kv.size // infer.n_full, weights)
+
+
+def test_the_diffusion_kinds_two_programs_fit_the_chip(chip, monkeypatch):
+    """``cot``'s two step programs (SDAR-30B-A3B's 48 layers at the cell's geometry, abstract weights, 32 slots of a
+    block of 4, tables of 128, one chunk row of 256): the chip's compiler takes the walk by runs under ``block=4`` at a
+    pass's 4 query tokens and at a chunk's 256; the stack is one scan, so each program holds the kernel once; and
+    weights, pool and temporaries together stay under the chip's 16 GB with room for the reference check."""
+    import json
+    import os
+
+    from bench.harness import common
+    from paddlenlp_tpu.experimental.inference_model import inference_model_class
+    from paddlenlp_tpu.experimental.launch_pack import layout_of, packed_size
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "bench", "configs", "sdar-30b-a3b-serve-ep8.json")) as f:
+        config = json.load(f)
+    engine = config["bench"]["engine"]
+    cfg, make = common.build_model(config, jnp.bfloat16, jnp.bfloat16)
+    model = make()
+    model.params = model.param_shapes  # shapes only: there is no device to hold arrays
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, table, bk = engine["max_batch_size"], engine["max_blocks_per_seq"], cfg.block_length
+    infer = inference_model_class(cfg)(model, engine["block_size"], engine["num_blocks"], table, dtype=jnp.bfloat16,
+                                       decode_steps=engine["decode_steps"], max_batch_size=slots,
+                                       prefill_chunk_tokens=engine["prefill_chunk_tokens"])
+    assert infer.use_paged_kernel and infer.fixed_mixed_shape == (1, 256, 32)
+    on_chip = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree)
+    aval = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    pool = on_chip(jax.eval_shape(lambda: infer.init_pool(engine["num_blocks"], engine["block_size"], jnp.bfloat16)))
+    rows = lambda *shape: aval((slots,) + shape)
+    flags = lambda *shape: aval(shape, jnp.bool_)
+    layouts = {
+        "_decode_impl": layout_of(dict(block_tokens=rows(bk), block_masked=flags(slots, bk), block_tables=rows(table),
+                                       start=rows(), fixed=rows(), done0=flags(slots), remaining=rows())),
+        "_mixed_flat_impl": layout_of(dict(
+            chunk_ids=aval((1, 256)), chunk_tables=aval((1, table)), chunk_qlens=aval((1,)), chunk_start=aval((1,)),
+            dec_tokens=rows(bk), dec_masked=flags(slots, bk), dec_tables=rows(table), dec_start=rows(), dec_fixed=rows(),
+            dec_live=flags(slots), dec_remaining=rows())),
+    }
+    for name, layout in layouts.items():
+        args = (on_chip(model.params), pool, aval((packed_size(layout),)), rows(cfg.vocab_size), None, layout)
+        compiled = jax.jit(getattr(infer, name), donate_argnums=(1,), static_argnums=(5,)).lower(*args).compile()
+        kernels = compiled.as_text().count('custom_call_target="tpu_custom_call"')
+        assert kernels == (1 if name == "_decode_impl" else 2), name  # the mixed step: the chunk row's call and the pass's
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= pool.kv.size * pool.kv.dtype.itemsize  # the donated pool is updated in place
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9, name
